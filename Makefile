@@ -36,7 +36,7 @@ native:
 # smoke scale (<60s, CPU). The full-scale soak is
 # `python bench.py --control-soak` with the default env.
 soak-smoke:
-	JAX_PLATFORMS=cpu RAY_TPU_JAX_PLATFORM=cpu RAY_TPU_BENCH_CHILD=1 \
+	JAX_PLATFORMS=cpu RAY_TPU_JAX_PLATFORM=cpu \
 	RAY_TPU_SOAK_N=40 RAY_TPU_SOAK_TASK_S=0.5 RAY_TPU_SOAK_FLAPS=1 \
 	RAY_TPU_SOAK_FLOOR=2000 RAY_TPU_BENCH_SOAK_ARTIFACT=0 \
 	$(PYTHON) bench.py --control-soak
@@ -47,7 +47,7 @@ soak-smoke:
 # `python bench.py --scale-chaos` with the default env (256 nodes,
 # 4 tenants) and writes BENCH_SCALE_CHAOS.json.
 scale-smoke:
-	JAX_PLATFORMS=cpu RAY_TPU_JAX_PLATFORM=cpu RAY_TPU_BENCH_CHILD=1 \
+	JAX_PLATFORMS=cpu RAY_TPU_JAX_PLATFORM=cpu \
 	RAY_TPU_SCALE_NODES=16 RAY_TPU_SCALE_TENANTS=2 RAY_TPU_SCALE_N=30 \
 	RAY_TPU_SCALE_BACKLOG=1500 RAY_TPU_SCALE_LEASES=600 \
 	RAY_TPU_BENCH_SCALE_ARTIFACT=0 \

@@ -8,6 +8,7 @@ runtime whose accelerator plane is JAX/XLA on TPU.
 
 from __future__ import annotations
 
+import os
 import threading
 from typing import Any, Iterable, Sequence
 
@@ -84,9 +85,19 @@ def init(address: str | None = None, *, resources: dict | None = None,
         if address is None:
             node = RuntimeNode(cfg)
             gcs_host, gcs_port = node.start_gcs()
+            from ray_tpu._private import accelerator
+
             head_res = dict(resources or {})
             if num_cpus is not None:
                 head_res.setdefault("CPU", num_cpus)
+            # The local head offers the chips this host has (what
+            # `ray_tpu start` does for a node): without it a task that
+            # asks for TPU pends for ever on the machine that has one.
+            detected, slice_labels = accelerator.node_resources_and_labels()
+            if detected:
+                head_res = {"CPU": float(os.cpu_count() or 1), **detected,
+                            **head_res}
+                labels = {**slice_labels, **(labels or {})}
             handle = node.start_raylet(resources=head_res or None, labels=labels,
                                        is_head=True)
             _runtime_node = node

@@ -93,20 +93,29 @@ def tpu_slice_labels() -> dict[str, str]:
 # Reference behavior: the raylet exports CUDA_VISIBLE_DEVICES /
 # TPU_VISIBLE_CHIPS per lease so a worker that did not reserve an
 # accelerator cannot touch it (ray_constants.py TPU_VISIBLE_CHIPS).
-# JAX analog: the platform choice is fixed at first backend use, and on
-# images that force-register a TPU platform the JAX_PLATFORMS env var is
-# ignored — only jax.config.update("jax_platforms", ...) works.  So pool
-# workers install an import hook that pins jax to CPU at jax-import time
-# unless the task being executed holds a TPU resource lease.  Without it,
-# two CPU-only workers importing jax would both open the (single-process)
-# TPU and deadlock.
+# JAX analog: the platform choice is fixed at first backend use, and a
+# chip belongs to one process at a time.  So pool workers pin jax right
+# after it is imported (import hook), or at the first task when the
+# zygote pre-imported it: "cpu" unless the task being executed holds a
+# TPU resource lease, the lease platform if it does.  A lease-holder
+# that cannot get its platform fails; it never computes on the CPU under
+# the chip's name.
 # ---------------------------------------------------------------------------
+
+# The one test seam: the platform a TPU lease-holder's jax is pinned to
+# (tests run lease-holders on the virtual CPU devices).  Workers without
+# a lease are pinned to "cpu" whatever this says.
+LEASE_PLATFORM_ENV = "RAY_TPU_JAX_PLATFORM"
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 _current_task_has_tpu: bool = False
 # Platform jax was actually pinned to in this process (None = not yet
 # imported/pinned). Frozen after first jax import — jax cannot switch
 # backends once initialized.
 _pinned_platform: str | None = None
+_lease_backend_verified: bool = False
 
 
 def set_current_task_tpu(has_tpu: bool) -> None:
@@ -118,24 +127,62 @@ def pinned_platform() -> str | None:
     return _pinned_platform
 
 
+def lease_platform() -> str:
+    return os.environ.get(LEASE_PLATFORM_ENV) or "tpu"
+
+
+def compile_cache_dir() -> str:
+    """Where the workers of this checkout keep jax's persistent compile
+    cache: the directory JAX_COMPILATION_CACHE_DIR names, else one fixed
+    path inside the checkout.  The path is part of the cache key, so it
+    never contains a pid, a session id or a temporary name."""
+    return os.environ.get(COMPILE_CACHE_ENV) \
+        or os.path.join(_CHECKOUT, ".jax_cache")
+
+
 def current_task_needs_fresh_worker() -> bool:
     """True when this worker's frozen jax pin can't serve the current
-    task: jax is pinned to CPU but the task holds a TPU lease.  The task
-    must be retried on a fresh worker (whose first import will pin TPU)."""
-    return _current_task_has_tpu and _pinned_platform == "cpu"
+    task: the task holds a TPU lease but jax was pinned (by an earlier
+    task without one) to another platform than a lease-holder gets.  The
+    task must be retried on a fresh worker (whose first pin is the
+    lease's)."""
+    return _current_task_has_tpu and _pinned_platform is not None \
+        and _pinned_platform != lease_platform()
 
 
 def _pin_jax_platform(jax_module) -> None:
     global _pinned_platform
-    plat = os.environ.get("RAY_TPU_JAX_PLATFORM")
-    if plat is None and not _current_task_has_tpu:
-        plat = "cpu"
-    _pinned_platform = plat or "tpu"
-    if plat:
-        try:
-            jax_module.config.update("jax_platforms", plat)
-        except Exception:
-            pass
+    plat = lease_platform() if _current_task_has_tpu else "cpu"
+    jax_module.config.update("jax_platforms", plat)
+    _pinned_platform = plat
+    if _current_task_has_tpu and not os.environ.get(COMPILE_CACHE_ENV):
+        # Lease-holders are the processes that compile for the chip.
+        # With the variable set jax already uses that directory.
+        jax_module.config.update("jax_compilation_cache_dir",
+                                 compile_cache_dir())
+
+
+def verify_lease_backend() -> None:
+    """Once per lease-holder, as its backend comes up: the devices are
+    the platform that was pinned, or the task fails.  Pinning "tpu"
+    explicitly makes jax raise when the chip cannot be opened; this
+    catches what is left — a backend that was initialized before the pin
+    and so ignored it."""
+    global _lease_backend_verified
+    import sys
+
+    if _lease_backend_verified or not _current_task_has_tpu:
+        return
+    jax_module = sys.modules.get("jax")
+    if jax_module is None or _pinned_platform is None:
+        return  # not imported yet: the import hook pins and verifies
+    got = jax_module.devices()[0].platform
+    if got != _pinned_platform:
+        raise RuntimeError(
+            f"task holds a TPU lease and jax was pinned to "
+            f"{_pinned_platform!r}, but its devices are {got!r}: refusing "
+            f"to run a lease-holder on another platform")
+    _lease_backend_verified = True
 
 
 def install_worker_jax_isolation() -> None:
@@ -197,6 +244,7 @@ class _PinningLoader:
     def exec_module(self, module):
         self._inner.exec_module(module)
         _pin_jax_platform(module)
+        verify_lease_backend()
 
     def __getattr__(self, item):
         return getattr(self._inner, item)

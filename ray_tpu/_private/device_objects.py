@@ -90,16 +90,18 @@ def _is_jax_array(value) -> bool:
 
 
 def _local_platform() -> str | None:
-    """Backend of THIS process's jax, or None when jax isn't imported.
-    Only called on resolution paths that are about to materialize device
-    arrays anyway, so triggering backend init here is free."""
+    """Backend THIS process's jax already runs on; None when jax isn't
+    imported or no backend is up.  Asking must never be what opens the
+    chip: a process without a backend shares no mesh with the producer,
+    so it takes the host route."""
     jax = sys.modules.get("jax")
     if jax is None:
         return None
-    try:
-        return jax.default_backend()
-    except Exception:
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
         return None
+    return jax.default_backend()
 
 
 def _local_device_ids() -> list[int]:

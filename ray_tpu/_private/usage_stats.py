@@ -55,9 +55,10 @@ def _collect(gcs_call=None) -> dict:
     # Passive only: NEVER import jax or initialize a backend from the
     # reporter. `jax.default_backend()` here used to spin up a PJRT
     # client inside every driver — a multi-second import racing user
-    # work, a second tunnel client per driver on TPU machines, and PJRT
-    # teardown aborts at exit. Record what's already in the process;
-    # accelerator inventory comes from the cluster resource view below.
+    # work, and on a TPU host a driver that takes the chip its workers
+    # lease (one process for each chip). Record what's already in the
+    # process; accelerator inventory comes from the cluster resource view
+    # below.
     jax_mod = sys.modules.get("jax")
     if jax_mod is not None:
         data["jax_version"] = getattr(jax_mod, "__version__", "unknown")

@@ -3226,6 +3226,7 @@ class CoreWorker:
         return out
 
     def _execute_task(self, spec: TaskSpec, yield_emit=None) -> dict:
+        from ray_tpu._private import accelerator
         from ray_tpu.runtime_env import runtime_env_context
 
         prev_task_id = self._current_task_id
@@ -3236,12 +3237,10 @@ class CoreWorker:
             # TPU_VISIBLE_CHIPS per-lease isolation).  Actors pin the
             # worker for life, so the constructor's lease decides — actor
             # METHOD specs carry resources={} and must not flip the flag.
-            from ray_tpu._private import accelerator
-
             accelerator.set_current_task_tpu(
                 (spec.resources or {}).get(accelerator.TPU_RESOURCE, 0) > 0)
-            # Workers whose jax was pre-imported (zygote fork / site
-            # hooks) pin at first task, now that the lease is known.
+            # Workers whose jax was pre-imported (zygote fork) pin at
+            # first task, now that the lease is known.
             accelerator.ensure_jax_pinned()
             if accelerator.current_task_needs_fresh_worker():
                 # jax is already pinned to CPU in this process and cannot
@@ -3259,6 +3258,9 @@ class CoreWorker:
         from ray_tpu.util import tracing
 
         try:
+            if not self.is_driver:
+                # A lease-holder is on its platform or the task fails.
+                accelerator.verify_lease_backend()
             if spec.actor_creation:
                 cls = self._run(self._fetch_function(spec.func_key))
                 args, kwargs = self._resolve_args(spec)
@@ -3907,22 +3909,11 @@ def main():
     from ray_tpu.util import tracing
 
     tracing.maybe_setup_from_env()
-    # Tests pin worker JAX to the CPU fake backend (the machine image
-    # force-registers the TPU platform via config, ignoring JAX_PLATFORMS).
-    plat = env.get("RAY_TPU_JAX_PLATFORM")
-    if plat:
-        try:
-            import jax
+    # Accelerator isolation: jax is pinned to "cpu" unless the task being
+    # executed holds a TPU resource lease (see accelerator.py).
+    from ray_tpu._private import accelerator
 
-            jax.config.update("jax_platforms", plat)
-        except ImportError:
-            pass
-    else:
-        # Default isolation: pin jax to CPU at import time unless the task
-        # being executed holds a TPU resource lease (see accelerator.py).
-        from ray_tpu._private import accelerator
-
-        accelerator.install_worker_jax_isolation()
+    accelerator.install_worker_jax_isolation()
     config = None
     if env.get("RAY_TPU_CONFIG_JSON"):
         try:

@@ -22,6 +22,7 @@ from typing import Any, NamedTuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops.attention import (
     flash_attention,
@@ -189,7 +190,7 @@ class Attention(nn.Module):
             out = jnp.einsum("bhqk,bhkd->bhqd", p,
                              v.astype(jnp.float32)).astype(cfg.dtype)
         elif cfg.attention == "flash":
-            out = flash_attention(q, k, v, None, True)
+            out = _flash_on_mesh(q, k, v)
         elif cfg.attention == "ring":
             out = ring_attention(q, k, v, axis="sp", causal=True)
         elif cfg.attention == "ulysses":
@@ -202,6 +203,25 @@ class Attention(nn.Module):
         if kv_cache is not None:
             return out, new_cache
         return out
+
+
+def _flash_on_mesh(q, k, v):
+    """Causal flash attention, per shard when traced under a mesh.
+
+    The compiler cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map"), so a step traced inside a multi-device mesh
+    (`shard_train_step` does that) runs the kernel on each device's own
+    batch rows and heads: batch over the data axes, heads over `tp`, as
+    TRANSFORMER_RULES lays the activations out."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return flash_attention(q, k, v, None, True)
+    data = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names)
+    spec = P(data or None, "tp" if "tp" in mesh.axis_names else None,
+             None, None)
+    return jax.shard_map(
+        lambda q, k, v: flash_attention(q, k, v, None, True),
+        in_specs=(spec, spec, spec), out_specs=spec)(q, k, v)
 
 
 class MLP(nn.Module):

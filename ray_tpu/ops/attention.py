@@ -27,13 +27,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.util.collective.ops import axis_size as _axis_size
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
 
 _NEG_INF = -1e30
 
@@ -120,39 +116,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0, 0] = lse.astype(jnp.float32)
 
 
-def _vma_supported() -> bool:
-    """Feature-detect ShapeDtypeStruct(vma=...) + jax.typeof: both arrived
-    together; on older JAX we skip vma (matching the lax.pvary fallback
-    path used by ring attention below)."""
-    global _VMA_OK
-    if _VMA_OK is None:
-        try:
-            jax.ShapeDtypeStruct((1,), jnp.float32, vma=frozenset())
-            _VMA_OK = hasattr(jax, "typeof")
-        except TypeError:
-            _VMA_OK = False
-    return _VMA_OK
-
-
-_VMA_OK = None
-
-
 def _operand_vma(*arrays) -> frozenset:
     """Union of mesh axes the operands vary over (empty outside shard_map)."""
     vma: frozenset = frozenset()
-    if not _vma_supported():
-        return vma
     for a in arrays:
-        t = jax.typeof(a)
-        vma = vma | getattr(t, "vma", frozenset())
+        vma = vma | jax.typeof(a).vma
     return vma
-
-
-def _out_struct(shape, dtype, vma):
-    """ShapeDtypeStruct with vma when this JAX supports it."""
-    if _vma_supported():
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
 
 
 def _flash_forward(q, k, v, sm_scale: float, causal: bool,
@@ -206,17 +175,19 @@ def _flash_forward(q, k, v, sm_scale: float, causal: bool,
             # vma: under shard_map (ring/Ulysses wrappers) outputs vary
             # over the same mesh axes as the operands; required when the
             # kernel is called with check_vma=True (the default).
-            _out_struct((B, H, Sq, D), q.dtype, _operand_vma(q, k, v)),
-            _out_struct((B, H, Sq, 1), jnp.float32, _operand_vma(q, k, v)),
+            jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype,
+                                 vma=_operand_vma(q, k, v)),
+            jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32,
+                                 vma=_operand_vma(q, k, v)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32) if pltpu else None,
-            pltpu.VMEM((block_q, 1), jnp.float32) if pltpu else None,
-            pltpu.VMEM((block_q, D), jnp.float32) if pltpu else None,
-        ] if pltpu else [],
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, D), jnp.float32),
+        ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ) if (pltpu and not _interpret_mode()) else None,
+        ) if not _interpret_mode() else None,
         interpret=_interpret_mode(),
     )(q, k, v)
     return out, lse.reshape(B, H, Sq)
@@ -272,6 +243,9 @@ def _flash_backward(sm_scale, causal, block_q, block_k, kv_valid_len, res, do):
         return (dk_acc, dv_acc), dq_blk
 
     init = (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32))
+    vma = tuple(_operand_vma(q, k, v, do))
+    if vma:  # under shard_map the carries vary like the operands
+        init = tuple(lax.pcast(x, vma, to="varying") for x in init)
     (dk, dv), dq_blocks = lax.scan(scan_body, init, jnp.arange(Sq // bq))
     # dq_blocks: (nq, B, H, bq, D) → (B, H, Sq, D)
     dq = jnp.moveaxis(dq_blocks, 0, 2).reshape(B, H, Sq, D)
@@ -371,16 +345,8 @@ def ring_attention(q, k, v, axis: str = "sp", *, causal: bool = False,
         return k_nxt, v_nxt, acc, m_new, l_run
 
     # Mark the carries as varying over the ring axis so the scan carry
-    # types match (shard_map's varying-axis type system). pcast is the
-    # current spelling; fall back to pvary on older JAX.
-    if hasattr(lax, "pcast"):
-        _vary = lambda x: lax.pcast(x, axis, to="varying")  # noqa: E731
-    elif hasattr(lax, "pvary"):
-        _vary = lambda x: lax.pvary(x, (axis,))  # noqa: E731
-    else:
-        # jax 0.4.x: shard_map has no varying-axis type system yet —
-        # no cast needed.
-        _vary = lambda x: x  # noqa: E731
+    # types match (shard_map's varying-axis type system).
+    _vary = lambda x: lax.pcast(x, axis, to="varying")  # noqa: E731
     acc0 = _vary(jnp.zeros((B, H, S, D), jnp.float32))
     m0 = _vary(jnp.full((B, H, S, 1), _NEG_INF, jnp.float32))
     l0 = _vary(jnp.zeros((B, H, S, 1), jnp.float32))

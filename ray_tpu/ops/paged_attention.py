@@ -24,11 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
@@ -156,7 +152,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, length,
             pltpu.VMEM((groups, 1), jnp.float32),
             pltpu.VMEM((groups, D), jnp.float32),
         ],
-    ) if pltpu else None
+    )
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, page_size=page_size,
                           num_pages=npages, groups=groups,
@@ -278,7 +274,7 @@ def paged_decode_attention_batch(q, k_pool, v_pool, page_tables, lengths,
                 pltpu.VMEM((Hkv * groups, 1), jnp.float32),
                 pltpu.VMEM((Hkv * groups, D), jnp.float32),
             ],
-        ) if pltpu else None
+        )
         out = pl.pallas_call(
             functools.partial(_paged_decode_batch_fused_kernel,
                               page_size=page_size, num_heads=Hkv,
@@ -308,7 +304,7 @@ def paged_decode_attention_batch(q, k_pool, v_pool, page_tables, lengths,
             pltpu.VMEM((groups, 1), jnp.float32),
             pltpu.VMEM((groups, D), jnp.float32),
         ],
-    ) if pltpu else None
+    )
     out = pl.pallas_call(
         functools.partial(_paged_decode_batch_kernel, page_size=page_size,
                           sm_scale=sm_scale),

@@ -65,13 +65,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_microbatches,
     # Carries vary over the pipeline axis (ppermute) AND any axes the input
     # varies over (e.g. dp-sharded batch): adding 0·x unions the two sets.
     def _vary(val):
-        # jax>=0.9 renames pvary to pcast(..., to='varying'); support
-        # both, and 0.4.x (no varying-axis types) needs no cast at all.
-        if hasattr(lax, "pcast"):
-            return lax.pcast(val, (axis,), to="varying")
-        if hasattr(lax, "pvary"):
-            return lax.pvary(val, (axis,))
-        return val
+        return lax.pcast(val, (axis,), to="varying")
 
     zero_like_x = jnp.zeros(mb_shape, x_microbatches.dtype) + \
         x_microbatches[0] * 0

@@ -30,6 +30,11 @@ CONTROLLER_CONCURRENCY = 32
 # fast re-polling) so control-plane RPCs always have free lanes.
 MAX_PARKED_POLLS = 20
 
+# How long a replica may take to answer its FIRST health probe (its
+# constructor: open the chip, load or initialise the weights) before
+# unanswered probes count against it.
+REPLICA_STARTUP_GRACE_S = 600.0
+
 
 @ray_tpu.remote(max_concurrency=CONTROLLER_CONCURRENCY)
 class ServeController:
@@ -55,6 +60,10 @@ class ServeController:
         self._poll_values: dict[str, object] = {}
         self._poll_cv = threading.Condition()
         self._poll_slots = threading.BoundedSemaphore(MAX_PARKED_POLLS)
+        # actor id -> when the replica was started, until it answers its
+        # first health probe: a constructor that loads a model takes
+        # minutes, and probes queue behind it.
+        self._starting: dict[str, float] = {}
         # Health-check loop: replace crashed replicas (reference: the
         # controller control loop at controller.py:312 reconciles
         # DeploymentState each tick; a dead replica actor is restarted).
@@ -131,10 +140,17 @@ class ServeController:
             try:
                 ray_tpu.get(ref, timeout=0.5)
                 fails.pop(key, None)
+                self._starting.pop(key, None)
             except ray_tpu.exceptions.ActorDiedError:
                 dead.add(key)
                 fails.pop(key, None)
+                self._starting.pop(key, None)
             except Exception:
+                born = self._starting.get(key)
+                if born is not None and \
+                        time.monotonic() - born < REPLICA_STARTUP_GRACE_S:
+                    continue  # still constructing: slow is not dead
+                self._starting.pop(key, None)
                 fails[key] = fails.get(key, 0) + 1
                 if fails[key] >= 3:
                     dead.add(key)
@@ -154,9 +170,9 @@ class ServeController:
         while not self._stop.wait(2.0):
             current = {r._actor_id.hex() for dd in self.deployments.values()
                        for r in dd["replicas"]}
-            for k in list(fails):
-                if k not in current:
-                    del fails[k]
+            for gone in (fails.keys() | self._starting.keys()) - current:
+                fails.pop(gone, None)
+                self._starting.pop(gone, None)
             for name in list(self.deployments):
                 d = self.deployments.get(name)
                 if d is None:
@@ -290,8 +306,10 @@ class ServeController:
             # streams need to finish.
             kwargs["max_concurrency"] = opts["max_concurrency"]
         cls = ReplicaActor.options(**kwargs) if kwargs else ReplicaActor
-        return cls.remote(d["callable_blob"], init_args, init_kwargs,
-                          user_config)
+        replica = cls.remote(d["callable_blob"], init_args, init_kwargs,
+                             user_config)
+        self._starting[replica._actor_id.hex()] = time.monotonic()
+        return replica
 
     def _reconcile(self, name: str):
         d = self.deployments[name]

@@ -31,6 +31,7 @@ behind Serve deployments); this engine is native and TPU-shaped:
 
 from __future__ import annotations
 
+import logging
 import queue
 import threading
 import time
@@ -41,6 +42,8 @@ import numpy as np
 
 from ray_tpu.models.generate import SamplingParams
 from ray_tpu.models.llama import LlamaConfig, LlamaModel, init_kv_caches
+
+logger = logging.getLogger(__name__)
 
 
 @dataclass
@@ -162,8 +165,10 @@ class LLMEngine:
         # (same-process → zero-copy handover of the live arrays), and
         # unpinned — pinned-KV bytes and handoff counts are observable
         # through the plane's gauges. Fails open: any plane error falls
-        # back to the direct in-memory handoff.
+        # back to the direct in-memory handoff, counted in
+        # handoff_fallbacks.
         self.use_device_plane = use_device_plane
+        self.handoff_fallbacks = 0
         # Paged KV mode (page_size > 0): admission is bounded by POOL
         # pages (resident tokens), not slot count x max_len.
         self.page_size = page_size
@@ -176,9 +181,9 @@ class LLMEngine:
                 raise ValueError(
                     "chunked prefill is not supported in paged mode")
         # Steps per compiled decode call: one host sync per CHUNK, not per
-        # token (dispatch/fetch latency dominates single-token decode —
-        # dramatically so through a tunneled device). Admission waits at
-        # most one chunk; tokens stream with chunk granularity.
+        # token (dispatch/fetch latency dominates single-token decode).
+        # Admission waits at most one chunk; tokens stream with chunk
+        # granularity.
         self.decode_chunk = max(1, decode_chunk)
         # >0: prompts longer than this prefill in chunks INTERLEAVED with
         # decode ticks, so one long prompt cannot stall every in-flight
@@ -343,8 +348,8 @@ class LLMEngine:
 
             # ---- batched prefill admission --------------------------------
             # Sequential slot prefills dominate end-to-end serving at
-            # large batch (each is a full program dispatch; measured on a
-            # real v5e in BENCH_NOTES.md). When several same-bucket
+            # large batch (each is a full program dispatch). When several
+            # same-bucket
             # requests are pending, ONE (W, bucket) prefill serves all of
             # them. W is FIXED (padding with rows that scatter into the
             # dummy page) so exactly one extra program per bucket
@@ -490,6 +495,7 @@ class LLMEngine:
             "tokens_in_flight": float(self.tokens_in_flight()),
             "active_streams": float(self.num_active()),
             "parked_events": float(self._parked_events),
+            "handoff_fallbacks": float(self.handoff_fallbacks),
             "ttft_p50_ms": pick(0.5) * 1e3,
             "ttft_p99_ms": pick(0.99) * 1e3,
         }
@@ -643,6 +649,9 @@ class LLMEngine:
 
             return device_objects.local_handoff("llm-prefill-kv", kv)
         except Exception:
+            self.handoff_fallbacks += 1
+            logger.warning("device-plane KV handoff failed; handing the "
+                           "arrays over directly", exc_info=True)
             return kv
 
     def _commit_first_token(self, slot: int, handle: RequestHandle,
@@ -829,6 +838,7 @@ class LLMEngine:
                     last_logits, kv_many = self._prefill_many(
                         self.params, jnp.asarray(tokens),
                         jnp.asarray(last_idx))
+                    kv_many = self._device_handoff(kv_many)
                     self._pools = self._write_prompt_pages_many(
                         self._pools, kv_many, jnp.asarray(page_rows))
                     # ONE sampling dispatch + host sync for the whole
@@ -881,6 +891,7 @@ class LLMEngine:
         padded = np.zeros((1, bucket), np.int32)
         padded[0, : len(prompt)] = prompt
         logits, kv_one = self._prefill_one(self.params, jnp.asarray(padded))
+        kv_one = self._device_handoff(kv_one)
         row = np.asarray(self._alloc.table(seq_id, self._np_pages))
         n_prompt_pages = self._alloc.pages_needed(len(prompt))
         prompt_pages = jnp.asarray(np.concatenate([

@@ -51,15 +51,21 @@ def make_train_step(loss_fn: Callable, optimizer: optax.GradientTransformation):
 
 def shard_train_step(train_step: Callable, mesh: Mesh, state_specs,
                      batch_spec) -> Callable:
-    """jit the step with input/output shardings pinned to the mesh."""
+    """jit the step with input/output shardings pinned to the mesh.  The
+    step is traced inside the mesh, so model code that must know it (a
+    Pallas kernel has to be shard_mapped by hand) can ask for it."""
     state_shardings = jax.tree_util.tree_map(
         lambda spec: NamedSharding(mesh, spec), state_specs,
         is_leaf=lambda x: isinstance(x, P))
     batch_shardings = jax.tree_util.tree_map(
         lambda spec: NamedSharding(mesh, spec), batch_spec,
         is_leaf=lambda x: isinstance(x, P))
+    def step_on_mesh(state, batch):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return train_step(state, batch)
+
     return jax.jit(
-        train_step,
+        step_on_mesh,
         in_shardings=(state_shardings, batch_shardings),
         out_shardings=(state_shardings, NamedSharding(mesh, P())),
         donate_argnums=(0,))

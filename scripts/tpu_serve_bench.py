@@ -15,6 +15,7 @@ Usage: PYTHONPATH=/root/repo python scripts/tpu_serve_bench.py
 from __future__ import annotations
 
 import json
+import sys
 import time
 
 import jax
@@ -23,7 +24,9 @@ import numpy as np
 
 
 def main():
-    assert jax.default_backend() != "cpu", "on-chip benchmark only"
+    if jax.default_backend() != "tpu":
+        sys.exit(f"tpu_serve_bench: an on-chip benchmark, and the JAX "
+                 f"backend here is {jax.default_backend()!r}")
 
     from ray_tpu.models.llama import LlamaConfig, LlamaModel
     from ray_tpu.serve.llm import LLMEngine, SamplingParams
@@ -70,7 +73,9 @@ def main():
                 "wall_s": round(dt, 2),
                 "decode_chunk": chunk,
                 "params_millions": 1205,
-                "backend": jax.default_backend(),
+                "device": {"platform": jax.devices()[0].platform,
+                           "kind": jax.devices()[0].device_kind,
+                           "count": len(jax.devices())},
                 "paged": True, "page_size": 64,
             },
         }), flush=True)

@@ -1,0 +1,227 @@
+"""Compile the smoke's kernels and whole programs for a described v5e.
+
+No chip is attached: the TPU compiler runs against a topology
+description (`on-chip-measurement` guide, section 2, third rehearsal),
+so what the chip's compiler would refuse — a misaligned block, too much
+VMEM, a program over 16 GB, a kernel that cannot be partitioned — fails
+here, at the widths `chip_smoke.py` runs.  Nothing executes; these say
+nothing about results or times.
+
+Everything that touches the topology happens inside fixtures and tests
+(never at import): only the xdist worker that is handed this file loads
+the TPU library.  Keep these cases in this one file.
+"""
+
+import os
+import signal
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import chip_smoke  # noqa: E402
+from ray_tpu.models.llama import LLAMA3_8B, LlamaModel  # noqa: E402
+from ray_tpu.ops import attention, paged_attention  # noqa: E402
+
+HBM_BYTES = 16 * 1024 ** 3
+KERNEL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    # The TPU library installs its own SIGTERM handler when it loads, and
+    # that handler prints a stack trace.  A test run that is cut by its
+    # clock ends in SIGTERM; the trace would land on the line of dots the
+    # run is counted by.  Keep the handler this process had.
+    sigterm = signal.getsignal(signal.SIGTERM)
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        signal.signal(signal.SIGTERM, sigterm)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _compile_for_the_chip(monkeypatch):
+    """The default backend here is the CPU, where both kernel files
+    choose interpret mode; these compiles are for the chip, at the
+    chip's default matmul precision (conftest asks for "highest", which
+    Mosaic refuses for bf16 operands).  The persistent cache cannot read
+    a described-device entry back, so it stays off around them."""
+    monkeypatch.setattr(attention, "_interpret_mode", lambda: False)
+    monkeypatch.setattr(paged_attention, "_interpret_mode", lambda: False)
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache = jax.config.jax_enable_compilation_cache
+    precision = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", cache)
+    jax.config.update("jax_default_matmul_precision", precision)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _peak_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def _qkv(one_chip, seq, hkv=32):
+    q = jax.ShapeDtypeStruct((1, 32, seq, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, hkv, seq, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    return q, kv, kv
+
+
+def _flash(q, k, v):
+    return attention.flash_attention(q, k, v, None, True)
+
+
+@pytest.mark.parametrize("seq", [2048, 200],
+                         ids=["seq2048", "bucket200_not_pow2"])
+def test_flash_forward(one_chip, seq):
+    compiled = jax.jit(_flash).lower(*_qkv(one_chip, seq)).compile()
+    assert KERNEL in compiled.as_text()
+
+
+def test_flash_forward_backward(one_chip):
+    def loss(q, k, v):
+        return _flash(q, k, v).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_qkv(one_chip, 2048)).compile()
+    assert KERNEL in compiled.as_text()
+
+
+@pytest.mark.parametrize("fused_heads", [False, True],
+                         ids=["head_on_grid", "fused_heads"])
+def test_paged_decode_batch(one_chip, fused_heads):
+    B, H, Hkv, D, page, npages = 32, 32, 8, 128, 64, 8
+    S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    pool = S((B * npages + 1, Hkv, page, D), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, kp, vp, pt, ln: paged_attention.paged_decode_attention_batch(
+            q, kp, vp, pt, ln, fused_heads=fused_heads)).lower(
+        S((B, H, D), jnp.bfloat16), pool, pool,
+        S((B, npages), jnp.int32), S((B,), jnp.int32)).compile()
+    assert KERNEL in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def engine_programs(one_chip):
+    """The serve phase's engine at the smoke's widths and depth, built
+    around parameter SHAPES (no array of that size exists here)."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg = chip_smoke.smoke_config(LLAMA3_8B, chip_smoke.SERVE_LAYERS)
+    params = jax.eval_shape(
+        lambda: LlamaModel(cfg).init(jax.random.PRNGKey(0),
+                                     jnp.zeros((1, 8), jnp.int32)))
+    eng = LLMEngine(cfg, params, **chip_smoke.ENGINE_KWARGS)
+    yield cfg, eng, _on(one_chip, params)
+    eng.shutdown()
+
+
+def test_engine_decode_step(engine_programs, one_chip):
+    cfg, eng, params = engine_programs
+    B = eng.max_batch
+    S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    compiled = eng._decode_chunk_paged.lower(
+        params, S((B,), jnp.int32), S((B,), jnp.int32),
+        _on(one_chip, eng._pools), S(eng._tables.shape, jnp.int32),
+        S((B,), jnp.int32), S((B,), jnp.float32), S((B,), jnp.int32),
+        S((B,), jnp.float32), S((2,), jnp.uint32)).compile()
+    assert KERNEL in compiled.as_text()
+    assert _peak_bytes(compiled) < HBM_BYTES
+
+
+def test_engine_batched_prefill(engine_programs, one_chip):
+    """The engine prefills through the dense masked path over its KV
+    cache (no Pallas kernel on it); what is checked is that the whole
+    (W, bucket) program compiles and fits beside the weights."""
+    cfg, eng, params = engine_programs
+    W = eng._batch_prefill_width
+    bucket = max(eng._bucket(chip_smoke.PROMPT_LENGTHS[-1]), eng.page_size)
+    compiled = eng._prefill_many.lower(
+        params,
+        jax.ShapeDtypeStruct((W, bucket), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((W,), jnp.int32, sharding=one_chip)).compile()
+    assert _peak_bytes(compiled) < HBM_BYTES
+
+
+def _abstract_train(cfg, mesh, batch, seq):
+    prog = chip_smoke.train_program(cfg, mesh)
+    state = jax.eval_shape(prog.build_state)
+    shard = lambda tree, specs: jax.tree_util.tree_map(  # noqa: E731
+        lambda x, s: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, s)),
+        tree, specs, is_leaf=lambda x: isinstance(x, P))
+    from ray_tpu.train.spmd import state_specs_from_rules
+    from ray_tpu.parallel import TRANSFORMER_RULES
+
+    specs = state_specs_from_rules(state, TRANSFORMER_RULES)
+    tok = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+    return prog.sharded_step(specs), shard(state, specs), \
+        shard((tok, tok), prog.batch_spec)
+
+
+def test_train_step_fits_one_chip(topo):
+    from ray_tpu.parallel import MeshConfig, make_mesh
+
+    cfg = chip_smoke.smoke_config(LLAMA3_8B, chip_smoke.TRAIN_LAYERS,
+                                  **chip_smoke.TRAIN_OVERRIDES)
+    mesh = make_mesh(MeshConfig(fsdp=1), devices=topo.devices[:1])
+    step, state, batch = _abstract_train(cfg, mesh, chip_smoke.TRAIN_BATCH,
+                                         chip_smoke.TRAIN_SEQ)
+    compiled = step.lower(state, batch).compile()
+    assert KERNEL in compiled.as_text()
+    assert _peak_bytes(compiled) < HBM_BYTES
+
+
+def test_train_step_sharded_over_four_chips(topo):
+    """`chip_smoke.py --chips 4` at a cut depth: the whole 32 layers take
+    three minutes to compile (done by hand; CHANGES.md has the bytes).
+    What this guards is depth-independent: the flash kernel inside a
+    program partitioned over four devices, and a state that is sharded."""
+    from ray_tpu.parallel import MeshConfig, make_mesh
+
+    cfg = chip_smoke.smoke_config(LLAMA3_8B, 4, **chip_smoke.TRAIN_OVERRIDES)
+    mesh = make_mesh(MeshConfig(**chip_smoke.FOUR_CHIP_MESH),
+                     devices=topo.devices)
+    step, state, batch = _abstract_train(
+        cfg, mesh, chip_smoke.FOUR_CHIP_BATCH, chip_smoke.TRAIN_SEQ)
+    compiled = step.lower(state, batch).compile()
+    assert KERNEL in compiled.as_text()
+    assert _peak_bytes(compiled) < HBM_BYTES
+    # Per-device bytes: what one device is handed of the state is a
+    # quarter of the whole (norm scales and scalars replicate).
+    whole = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                for x in jax.tree_util.tree_leaves(state))
+    assert compiled.memory_analysis().argument_size_in_bytes < 0.3 * whole
