@@ -1,0 +1,127 @@
+"""Find a cell's files by the names `BENCHMARK.json` gives: no registry.
+
+A cell names a configuration and a traffic mix.  The configuration's file
+is the `file` of its `configs` entry; the mix is `traffic/<traffic>.json`;
+its `kind` names `drivers/<kind>.py`; each per-layer metric the cell
+reports is read by `layer_metrics/<metric>.py`.  Every directory in
+`paths` is searched, then this harness's own, so a later PR (or a test)
+adds a cell by adding files and entries and edits none.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import importlib.util
+import json
+import os
+from types import ModuleType
+
+HARNESS_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+REPO_ROOT = os.path.dirname(HARNESS_ROOT)
+
+
+class BenchmarkError(RuntimeError):
+    """The benchmark cannot run or its result cannot be trusted."""
+
+
+@dataclasses.dataclass
+class Cell:
+    name: str
+    chips: int
+    config: dict
+    traffic: dict
+    driver: ModuleType
+    end_to_end: list      # metric entries of BENCHMARK.json this cell reports
+    per_layer: list
+    readers: dict         # per-layer metric name -> module with read(obs)
+
+
+def load_benchmark(root: str = REPO_ROOT) -> dict:
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def _search_dirs(bench: dict, root: str) -> list:
+    dirs = [os.path.join(root, p) for p in bench["paths"]]
+    if HARNESS_ROOT not in dirs:
+        dirs.append(HARNESS_ROOT)
+    return dirs
+
+
+def find_file(bench: dict, root: str, sub: str, filename: str) -> str:
+    tried = []
+    for d in _search_dirs(bench, root):
+        path = os.path.join(d, sub, filename)
+        tried.append(path)
+        if os.path.isfile(path):
+            return path
+    raise BenchmarkError(f"no {sub}/{filename}: looked for {tried}")
+
+
+def _load_json(path: str) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def _load_module(path: str, modname: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(modname, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def sibling_reader(here: str, name: str) -> ModuleType:
+    """The reader of metric `name` that lies beside the file `here`: for a
+    metric that is another's quantity under another `moves` (a cell that
+    reports other end-to-end metrics needs its own per-layer entries)."""
+    return _load_module(
+        os.path.join(os.path.dirname(os.path.abspath(here)), name + ".py"),
+        "_bench_metric_" + name.replace(".", "_").replace("-", "_"))
+
+
+def _reports(metric: dict, cell_name: str) -> bool:
+    return "workloads" not in metric or cell_name in metric["workloads"]
+
+
+def load_cell(name: str, root: str = REPO_ROOT) -> Cell:
+    bench = load_benchmark(root)
+    entry = next((w for w in bench["workloads"] if w["name"] == name), None)
+    if entry is None:
+        raise BenchmarkError(
+            f"no workload {name!r} in BENCHMARK.json; it has "
+            f"{[w['name'] for w in bench['workloads']]}")
+    cfg_entry = next((c for c in bench["configs"]
+                      if c["name"] == entry["config"]), None)
+    if cfg_entry is None:
+        raise BenchmarkError(f"workload {name!r} names configuration "
+                             f"{entry['config']!r}, which `configs` lacks")
+    cfg_path = os.path.join(root, cfg_entry["file"])
+    if not os.path.isfile(cfg_path):
+        raise BenchmarkError(f"no configuration file {cfg_path}")
+    config = _load_json(cfg_path)
+    traffic = _load_json(find_file(bench, root, "traffic",
+                                   entry["traffic"] + ".json"))
+    kind = traffic["kind"]
+    driver = _load_module(find_file(bench, root, "drivers", kind + ".py"),
+                          f"_bench_driver_{kind}")
+    per_layer = [m for m in bench["per_layer"] if _reports(m, name)]
+    readers = {
+        m["name"]: _load_module(
+            find_file(bench, root, "layer_metrics", m["name"] + ".py"),
+            "_bench_metric_" + m["name"].replace(".", "_").replace("-", "_"))
+        for m in per_layer}
+    return Cell(name=name, chips=int(entry["chips"]), config=config, traffic=traffic, driver=driver,
+                end_to_end=[m for m in bench["end_to_end"]
+                            if _reports(m, name)],
+                per_layer=per_layer, readers=readers)
+
+
+def read_layer_metrics(cell: Cell, obs: dict) -> dict:
+    """Each reader takes its number from the observations; one that finds
+    nothing to read returns None and the metric is left out of the line."""
+    out = {}
+    for m in cell.per_layer:
+        value = cell.readers[m["name"]].read(obs)
+        if value is not None:
+            out[m["name"]] = {"value": float(value), "unit": m["unit"]}
+    return out

@@ -1,0 +1,11 @@
+"""model step: one prefill call on the device, as `prefill_device_ms` reads it, for closed-loop cells: what
+their users feel is the work completed, so here it moves
+`batch_tokens_per_s`."""
+
+from benchmarks.harness.loader import sibling_reader
+
+LAYER = "model step"
+UNIT = "ms"
+MOVES = "batch_tokens_per_s"
+
+read = sibling_reader(__file__, "prefill_device_ms").read
