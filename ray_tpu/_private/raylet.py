@@ -830,6 +830,48 @@ class Raylet:
 
     # ---------- gcs sync ----------
 
+    async def _heartbeat_once(self):
+        """Report availability and pending demand to the GCS; take its
+        cluster view back."""
+        now = time.monotonic()
+        self._infeasible_demand = [
+            (ts, d) for ts, d in self._infeasible_demand
+            if now - ts < 10.0]
+        resp = await self.gcs_conn.call("Heartbeat", {
+            "node_id": self.node_id,
+            "available_resources": self.available,
+            # Demand signal for the autoscaler (reference: raylets
+            # report resource load via ray_syncer →
+            # gcs_autoscaler_state_manager).
+            "pending_demand": [item[0] for item in
+                               list(self.pending_leases)[:100]]
+            + [d for _ts, d in self._infeasible_demand],
+        }, timeout=self.config.health_check_timeout_s)
+        if resp.get("ok"):
+            self.cluster_view = resp.get("cluster", {})
+            self._sync_native_view()
+            # A fresher view may unblock queued leases via spillback.
+            self._pump_pending_leases()
+        elif resp.get("reregister"):
+            # One-way partition: this side's socket looks healthy
+            # but the GCS-side conn died and marked the node
+            # SUSPECT. Re-run the handshake over the live session
+            # to rebind — do NOT exit; nothing was failed over.
+            logger.warning("GCS marked node %s SUSPECT; "
+                           "re-registering over live connection",
+                           self.node_id[:8])
+            await self._gcs_handshake(self.gcs_conn)
+        else:
+            # A LIVE GCS answering not-ok has declared this node
+            # dead (SUSPECT grace expired / missed heartbeats) and
+            # may already have failed actors over; resurrecting
+            # would fork them. Exit like the reference's stale
+            # raylet. (A RESTARTED GCS is reached via the session
+            # reconnect + re-registration path instead.)
+            logger.error("GCS declared node %s dead; raylet exiting",
+                         self.node_id[:8])
+            os._exit(1)
+
     async def _heartbeat_loop(self):
         period = min(0.2, self.config.health_check_period_s)
         # Fixed intervals synchronize across the fleet into periodic
@@ -842,44 +884,7 @@ class Raylet:
         await asyncio.sleep(hb_rng.uniform(0.0, period))
         while True:
             try:
-                now = time.monotonic()
-                self._infeasible_demand = [
-                    (ts, d) for ts, d in self._infeasible_demand
-                    if now - ts < 10.0]
-                resp = await self.gcs_conn.call("Heartbeat", {
-                    "node_id": self.node_id,
-                    "available_resources": self.available,
-                    # Demand signal for the autoscaler (reference: raylets
-                    # report resource load via ray_syncer →
-                    # gcs_autoscaler_state_manager).
-                    "pending_demand": [item[0] for item in
-                                       list(self.pending_leases)[:100]]
-                    + [d for _ts, d in self._infeasible_demand],
-                }, timeout=self.config.health_check_timeout_s)
-                if resp.get("ok"):
-                    self.cluster_view = resp.get("cluster", {})
-                    self._sync_native_view()
-                    # A fresher view may unblock queued leases via spillback.
-                    self._pump_pending_leases()
-                elif resp.get("reregister"):
-                    # One-way partition: this side's socket looks healthy
-                    # but the GCS-side conn died and marked the node
-                    # SUSPECT. Re-run the handshake over the live session
-                    # to rebind — do NOT exit; nothing was failed over.
-                    logger.warning("GCS marked node %s SUSPECT; "
-                                   "re-registering over live connection",
-                                   self.node_id[:8])
-                    await self._gcs_handshake(self.gcs_conn)
-                else:
-                    # A LIVE GCS answering not-ok has declared this node
-                    # dead (SUSPECT grace expired / missed heartbeats) and
-                    # may already have failed actors over; resurrecting
-                    # would fork them. Exit like the reference's stale
-                    # raylet. (A RESTARTED GCS is reached via the session
-                    # reconnect + re-registration path instead.)
-                    logger.error("GCS declared node %s dead; raylet exiting",
-                                 self.node_id[:8])
-                    os._exit(1)
+                await self._heartbeat_once()
             except (rpc.ConnectionLost, asyncio.TimeoutError) as e:
                 # The resilient session redials and re-runs the handshake
                 # underneath; heartbeats just resume when it's back. The
@@ -940,12 +945,22 @@ class Raylet:
                 and payload["message"].get("event") == "finished":
             self.runtime_env_manager.release_job(payload["message"]["job_id"])
             return
-        if payload.get("channel") == "NODE" and payload["message"].get("event") == "dead":
+        if payload.get("channel") != "NODE":
+            return
+        msg = payload["message"]
+        if msg.get("event") == "dead":
             # Drop cached peer connection to the dead node.
-            msg = payload["message"]
             view = self.cluster_view.pop(msg.get("node_id", ""), None)
             if view:
                 self._peer_conns.pop((view["host"], view["raylet_port"]), None)
+        elif msg.get("event") in ("alive", "reconnected"):
+            # A node is in the view from the moment the GCS lists it, not
+            # one heartbeat later: a lease request sent in between was
+            # answered "no node in cluster fits" and its owner backed off.
+            node = msg["node"]
+            self.cluster_view[node["node_id"]] = node
+            self._mirror_node(node["node_id"], node)
+            self._pump_pending_leases()
 
     async def _reap_loop(self):
         """Detect worker process deaths (reference: raylet notices worker
@@ -1575,19 +1590,24 @@ class Raylet:
         """Mirror the GCS cluster view into the native scheduler core."""
         if self._native_sched is None:
             return
-        seen = set()
         for nid, info in self.cluster_view.items():
-            seen.add(nid)
-            self._native_sched.update_node(
-                nid, total=info.get("total_resources"),
-                available=info.get("available_resources"),
-                labels=info.get("labels"),
-                # Draining peers stay in the data-plane view (object
-                # pulls) but must not win spillback picks.
-                alive=info.get("state", "ALIVE") == "ALIVE")
-        for nid in self._native_known - seen:
+            self._mirror_node(nid, info)
+        for nid in self._native_known - self.cluster_view.keys():
             self._native_sched.remove_node(nid)
-        self._native_known = seen
+        self._native_known = set(self.cluster_view)
+
+    def _mirror_node(self, nid: str, info: dict):
+        """One node of the view into the native scheduler core."""
+        if self._native_sched is None:
+            return
+        self._native_known.add(nid)
+        self._native_sched.update_node(
+            nid, total=info.get("total_resources"),
+            available=info.get("available_resources"),
+            labels=info.get("labels"),
+            # Draining peers stay in the data-plane view (object
+            # pulls) but must not win spillback picks.
+            alive=info.get("state", "ALIVE") == "ALIVE")
 
     def _pick_spillback(self, resources: dict, view: dict | None = None,
                         debit: bool = False) -> dict | None:
@@ -1647,6 +1667,23 @@ class Raylet:
             (ts, d) for ts, d in self._infeasible_demand
             if now - ts < 10.0 and d != resources]
         self._infeasible_demand.append((now, resources))
+
+    async def _forget_infeasible(self, resources: dict):
+        """A demand rejected before now has a node to go to: it is no
+        longer pending. The GCS hears so BEFORE the owner hears of the
+        node, hence before that node reports the demand's resources as
+        taken; an autoscaler tick in between saw the demand still pending
+        beside a full node, and launched a second node for one task."""
+        kept = [(ts, d) for ts, d in self._infeasible_demand
+                if d != resources]
+        if len(kept) == len(self._infeasible_demand):
+            return
+        self._infeasible_demand = kept
+        try:
+            await self._heartbeat_once()
+        except Exception:
+            logger.debug("heartbeat after a demand was placed failed",
+                         exc_info=True)
 
     async def handle_request_worker_lease(self, conn, payload):
         """Grant a worker lease, spill back, or queue (reference:
@@ -1713,6 +1750,7 @@ class Raylet:
             spill = self._pick_spillback(resources)
             if spill is not None and (
                     is_spread or not resources_fit(self.available, resources)):
+                await self._forget_infeasible(resources)
                 return {"spillback": self._debit_spill(spill, resources)}
             if is_spread:
                 # No better peer: run locally if possible (same FIFO
@@ -1727,13 +1765,18 @@ class Raylet:
             if not locally_feasible:
                 # This node can never run it; hand off to any peer whose
                 # TOTAL capacity fits (it will queue there), else error.
+                peer = None
                 for nid, info in self.cluster_view.items():
                     if nid != self.node_id \
                             and info.get("state", "ALIVE") == "ALIVE" \
                             and resources_fit(
                                 info.get("total_resources", {}), resources):
-                        return {"spillback": {"node_id": nid, "host": info["host"],
-                                              "port": info["raylet_port"]}}
+                        peer = {"node_id": nid, "host": info["host"],
+                                "port": info["raylet_port"]}
+                        break
+                if peer is not None:
+                    await self._forget_infeasible(resources)
+                    return {"spillback": peer}
                 self._note_infeasible(resources)
                 return {"error": f"infeasible resource demand {resources} "
                                  f"(no node in cluster fits)", "infeasible": True}
@@ -1775,6 +1818,14 @@ class Raylet:
                              "worker startup failed")
             return {"error": f"worker startup failed: {reason}",
                     "retry": True, "spawn_failure": True}
+        if self.draining:
+            # The drain began while the worker attached and saw no running
+            # lease to wait for or to kill: granting now would hand the
+            # owner a worker on a node already reported DRAINED.
+            self.rcore.release(lease_id)
+            self._pool_worker(w)
+            return {"error": "node draining", "draining": True,
+                    "retry": True}
         self._num_leases_granted += 1
         w.leased = True
         w.leased_at = time.monotonic()

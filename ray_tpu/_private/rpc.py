@@ -474,6 +474,7 @@ class RpcServer:
         self.name = name
         self.on_connect = on_connect
         self._server: asyncio.AbstractServer | None = None
+        self._stopping = False
         self.connections: set[Connection] = set()
         self.port: int | None = None
         self.host: str | None = None
@@ -482,12 +483,18 @@ class RpcServer:
         self.stats = EventLoopStats(name)
 
     async def start(self, host: str = "127.0.0.1", port: int = 0):
+        self._stopping = False
         self._server = await asyncio.start_server(self._accept, host, port)
         sock = self._server.sockets[0]
         self.host, self.port = sock.getsockname()[:2]
         return self.host, self.port
 
     async def _accept(self, reader, writer):
+        if self._stopping:
+            # Accepted in the tick stop() ran: stop() never saw it, so
+            # close it here or wait_closed() waits for it forever.
+            writer.close()
+            return
         conn = Connection(reader, writer, self.handlers,
                           name=f"{self.name}-peer", stats=self.stats)
         self.connections.add(conn)
@@ -497,14 +504,20 @@ class RpcServer:
             self.on_connect(conn)
 
     async def stop(self) -> None:
-        if self._server is not None:
-            self._server.close()
-            try:
-                await self._server.wait_closed()
-            except Exception:
-                pass
+        server, self._server = self._server, None
+        if server is None:
+            return
+        self._stopping = True
+        server.close()  # stop accepting
+        # Connections go BEFORE wait_closed(): since Python 3.12 it waits
+        # until every accepted connection is gone, so the old order never
+        # returned while a client was still connected.
         for conn in list(self.connections):
             await conn.close()
+        try:
+            await server.wait_closed()
+        except Exception:
+            pass
 
 
 async def connect(host: str, port: int, handlers: dict[str, Callable] | None = None,

@@ -314,6 +314,9 @@ class CoreWorker:
         self._actor_id: str | None = None
         self._actor_callers: dict[str, dict] = {}
         self._shutdown = False
+        # _run's gate: shut by shutdown() before it stops the loop.
+        self._run_gate = threading.Lock()
+        self._run_gate_shut = False
         # loop thread
         self.loop = asyncio.new_event_loop()
         self._loop_thread = threading.Thread(target=self._run_loop, daemon=True,
@@ -391,6 +394,7 @@ class CoreWorker:
         # raylet→worker DrainNotice subscribers (pre-death signal for
         # processes ON the draining node).
         self._node_event_listeners: list = []
+        self._node_added: asyncio.Event | None = None  # see _wait_for_node_added
         self._drain_notice_listeners: list = []
         self._run(self._async_init())
         # GC tuning for task-burst workloads: default thresholds run a
@@ -426,13 +430,19 @@ class CoreWorker:
 
     def _run(self, coro, timeout: float | None = None):
         """Run a coroutine on the IO loop from any thread."""
-        try:
-            fut = asyncio.run_coroutine_threadsafe(coro, self.loop)
-        except RuntimeError:
-            # Loop already stopped (shutdown race): close the coroutine
-            # so it doesn't surface as a 'never awaited' RuntimeWarning.
-            coro.close()
-            raise
+        with self._run_gate:
+            # Gate shut (shutdown is about to stop the loop) or loop
+            # already closed: close the coroutine so it doesn't surface
+            # as a 'never awaited' RuntimeWarning. Under the gate's lock,
+            # so nothing is queued behind shutdown's last sweep of the
+            # loop, where it would be destroyed pending.
+            try:
+                if self._run_gate_shut:
+                    raise RuntimeError("core worker is shut down")
+                fut = asyncio.run_coroutine_threadsafe(coro, self.loop)
+            except RuntimeError:
+                coro.close()
+                raise
         return fut.result(timeout)
 
     def _spawn(self, coro):
@@ -551,6 +561,8 @@ class CoreWorker:
             self._run(self._final_cancel(), timeout=3)
         except Exception:
             pass
+        with self._run_gate:
+            self._run_gate_shut = True
         self.loop.call_soon_threadsafe(self.loop.stop)
         self._loop_thread.join(timeout=2)
         # Native pumps go after the loop stops (the reader was removed in
@@ -2081,7 +2093,7 @@ class CoreWorker:
                     logger.warning("task demand currently infeasible: %s; "
                                    "waiting for cluster resources",
                                    resp.get("error"))
-                    await asyncio.sleep(1.0)
+                    await self._wait_for_node_added(1.0)
                     raylet_conn = self.raylet
                     _hop = 0
                     continue
@@ -2090,6 +2102,21 @@ class CoreWorker:
                 return
         finally:
             self._lease_requests_in_flight[shape] -= 1
+
+    async def _wait_for_node_added(self, timeout: float) -> None:
+        """The infeasible back-off: `timeout` at most, cut short when the
+        GCS announces a node (the raylets put it in their view on the
+        same publish). The NODE channel is joined on the first call."""
+        if self._node_added is None:
+            self._node_added = asyncio.Event()
+            self.add_node_event_listener(
+                lambda msg: msg.get("event") in ("alive", "reconnected")
+                and self._node_added.set())
+        try:
+            await asyncio.wait_for(self._node_added.wait(), timeout)
+        except asyncio.TimeoutError:
+            pass
+        self._node_added.clear()
 
     def _fail_queued_infeasible(self, shape: str, reason: str):
         q = self._queues[shape]

@@ -95,7 +95,10 @@ def delete(name: str) -> None:
 
 
 def shutdown() -> None:
-    global _proxy_server
+    global _proxy_server, _rpc_ingress
+    if _rpc_ingress is not None:
+        _rpc_ingress.stop()
+        _rpc_ingress = None
     _ProxyHandler._route_poll_stop.set()
     _ProxyHandler._route_poll_started = False
     _ProxyHandler._routes = {}

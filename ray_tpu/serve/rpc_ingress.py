@@ -59,8 +59,9 @@ class RpcIngress:
             self._loop.run_until_complete(go())
             self._loop.run_forever()
 
-        threading.Thread(target=run, daemon=True,
-                         name="serve-rpc-ingress").start()
+        self._thread = threading.Thread(target=run, daemon=True,
+                                        name="serve-rpc-ingress")
+        self._thread.start()
         if not started.wait(10.0) or self.port is None:
             raise RuntimeError("serve rpc ingress failed to start")
         return self.port
@@ -146,9 +147,19 @@ class RpcIngress:
         return {"ok": True}
 
     def stop(self):
-        if self._loop is not None:
-            asyncio.run_coroutine_threadsafe(self._server.stop(), self._loop)
-            self._loop.call_soon_threadsafe(self._loop.stop)
+        if self._loop is None:
+            return
+
+        async def down():
+            # The loop stops only AFTER the server has closed its
+            # connections; stopping it beside stop() left the listener
+            # and every client socket open under a dead loop.
+            await self._server.stop()
+            self._loop.stop()
+
+        asyncio.run_coroutine_threadsafe(down(), self._loop)
+        self._thread.join(5.0)
+        self._loop = None
 
 
 class RpcIngressClient:

@@ -98,6 +98,9 @@ struct Actor {
   int64_t restarts = 0;
   int64_t max_restarts = 0;  // -1 = unlimited
   std::string node_id;       // current placement target
+  // CreateActor on node_id was answered ok: the next rung is ActorReady
+  // or that node's death. Not parked: re-driving it now forks the actor.
+  bool created = false;
   std::string spec_raw;      // raw msgpack, replayed into CreateActor
   std::string resources_raw; // raw msgpack map (may be empty = absent)
 };
@@ -412,6 +415,7 @@ void Schedule(ActorPlane* s, const std::string& actor_id,
   auto ait = s->actors.find(actor_id);
   if (ait == s->actors.end()) return;
   std::string node_id = PickNode(s, not_node);
+  ait->second.created = false;
   if (node_id.empty()) {
     if (AnyNodeParkable(s)) {
       ait->second.node_id.clear();  // parked: redriven on node recovery
@@ -481,9 +485,10 @@ void CreateFailed(ActorPlane* s, const std::string& actor_id,
   }
 }
 
-// Re-drive every parked PENDING actor (no creation in flight anywhere):
-// rehydrated actors waiting for their first node, and actors parked by
-// an all-nodes-unusable window. Caller holds mu.
+// Re-drive every parked PENDING actor (no creation in flight anywhere,
+// none answered and awaiting its ActorReady): rehydrated actors waiting
+// for their first node, and actors parked by an all-nodes-unusable
+// window. Caller holds mu.
 void RedrivePending(ActorPlane* s) {
   std::unordered_map<std::string, bool> inflight;
   for (const auto& [nid, ns] : s->node_sess) {
@@ -495,7 +500,7 @@ void RedrivePending(ActorPlane* s) {
   }
   std::vector<std::string> parked;
   for (const auto& [aid, a] : s->actors) {
-    if (a.state == kStatePending && !inflight.count(aid))
+    if (a.state == kStatePending && !a.created && !inflight.count(aid))
       parked.push_back(aid);
   }
   for (const std::string& aid : parked) Schedule(s, aid, "");
@@ -540,7 +545,11 @@ void OnCreateResponse(ActorPlane* s, int64_t msg_type, int64_t seq,
       }
     }
   }
-  if (ok) return;  // ladder continues at ActorReady
+  if (ok) {  // ladder continues at ActorReady
+    auto ait = s->actors.find(actor_id);
+    if (ait != s->actors.end()) ait->second.created = true;
+    return;
+  }
   if (reason.find("draining") != std::string_view::npos) {
     // Bounced off a drain race: repick WITHOUT consuming a restart
     // (mirrors gcs.py _schedule_actor's draining branch).
@@ -641,12 +650,18 @@ void gact_node_down(void* h, const char* node_id) {
     it->second.conn_id = -1;
     it->second.state = kNodeDead;
   }
-  auto sit = s->node_sess.find(nid);
-  if (sit == s->node_sess.end()) return;
   std::vector<std::string> failed;
-  for (const auto& [rseq, pc] : sit->second.outstanding)
-    failed.push_back(pc.actor_id);
-  sit->second.outstanding.clear();
+  auto sit = s->node_sess.find(nid);
+  if (sit != s->node_sess.end()) {
+    for (const auto& [rseq, pc] : sit->second.outstanding)
+      failed.push_back(pc.actor_id);
+    sit->second.outstanding.clear();
+  }
+  // Created there and never ready: died with the node, same ladder.
+  for (const auto& [aid, a] : s->actors) {
+    if (a.created && a.state == kStatePending && a.node_id == nid)
+      failed.push_back(aid);
+  }
   for (const std::string& aid : failed)
     CreateFailed(s, aid, "node died during actor creation");
 }

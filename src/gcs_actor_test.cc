@@ -14,6 +14,7 @@
 
 #include <time.h>
 
+#include <algorithm>
 #include <cstdint>
 #include <cstdio>
 #include <cstring>
@@ -402,6 +403,100 @@ void TestMalformedFrames() {
   CHECK(gact_on_frame(svc, 1, frame.data(), (uint32_t)frame.size()) == 0);
   CHECK(gact_proto_errors(svc) == errs_before);
   gact_destroy(svc);
+}
+
+// ---- an answered create awaits ActorReady; it is not parked ----
+//
+// Between CreateActor's ok and ActorReady an actor is PENDING with no
+// creation outstanding. A node event in that window (another node
+// registering, a suspect one recovering) used to re-drive it as
+// "parked": a second CreateActor, the actor forked. Only the death of
+// the node it was created on moves it, through the restart ladder.
+
+struct SentCreate {
+  int64_t conn;
+  int64_t seq;
+};
+std::vector<SentCreate> g_creates;
+std::vector<std::string> g_events;
+
+int RecordingSend(void* /*pump*/, int64_t conn, const void* buf,
+                  uint32_t len) {
+  std::string body((const char*)buf, len), method, payload;
+  int64_t msg_type, seq;
+  if (DecodeEnvelope(body, &msg_type, &seq, &method, &payload) &&
+      msg_type == 0 && method == "CreateActor")
+    g_creates.push_back({conn, seq});
+  return 0;
+}
+
+void RecordingInject(void* /*pump*/, int64_t /*token*/, const void* buf,
+                     uint32_t len) {
+  std::string event, payload;
+  if (DecodeInject(std::string((const char*)buf, len), &event, &payload))
+    g_events.push_back(event);
+}
+
+void TestAnsweredCreateIsNotRedriven() {
+  void* plane = gact_create((void*)&RecordingSend, (void*)&RecordingInject,
+                            nullptr, 1);
+  g_creates.clear();
+  g_events.clear();
+  gact_node_up(plane, "node-A", 7);
+  gact_node_up(plane, "node-B", 8);
+
+  std::string spec;
+  mplite::w_map(spec, 1);
+  mplite::w_str(spec, "cls");
+  mplite::w_str(spec, "Foo");
+  std::string reg = PackFrame(
+      0, 11, "RegisterActor", RegisterActorPayload("a1", spec, 2, "drv", 1));
+  CHECK(gact_on_frame(plane, 1, reg.data(), (uint32_t)reg.size()) == 1);
+  CHECK(g_creates.size() == 1);
+  const int64_t first_conn = g_creates[0].conn;
+  const char* first_node = first_conn == 7 ? "node-A" : "node-B";
+
+  auto answer_ok = [&](const SentCreate& c) {
+    std::string ok((const char*)kOkTrue, sizeof kOkTrue);
+    std::string r = PackFrame(1, c.seq, "CreateActor", ok);
+    CHECK(gact_on_frame(plane, c.conn, r.data(), (uint32_t)r.size()) == 1);
+  };
+  answer_ok(g_creates[0]);
+
+  // Node events while ActorReady is on its way: nothing is re-driven.
+  gact_node_up(plane, "node-C", 9);
+  gact_node_state(plane, "node-B", 1);  // SUSPECT
+  gact_node_state(plane, "node-B", 0);  // recovered
+  gact_on_close(plane, first_conn);
+  gact_node_up(plane, first_node, first_conn);  // re-registered
+  CHECK(g_creates.size() == 1);
+
+  // The node it was created on dies: one restart, one new create, on
+  // another node.
+  gact_node_down(plane, first_node);
+  CHECK(g_creates.size() == 2);
+  CHECK(g_creates[1].conn != first_conn);
+  CHECK(std::count(g_events.begin(), g_events.end(), "restarting") == 1);
+  answer_ok(g_creates[1]);
+  gact_node_up(plane, "node-D", 10);
+  CHECK(g_creates.size() == 2);
+
+  std::string ready;
+  mplite::w_map(ready, 2);
+  mplite::w_str(ready, "actor_id");
+  mplite::w_str(ready, "a1");
+  mplite::w_str(ready, "address");
+  mplite::w_array(ready, 2);
+  mplite::w_str(ready, "127.0.0.1");
+  mplite::w_int(ready, 47002);
+  std::string rf = PackFrame(0, 5, "ActorReady", ready);
+  CHECK(gact_on_frame(plane, g_creates[1].conn, rf.data(),
+                      (uint32_t)rf.size()) == 1);
+  char state[32];
+  CHECK(gact_actor_state(plane, "a1", state, sizeof state) == 1 &&
+        std::string(state) == "ALIVE");
+  CHECK(gact_proto_errors(plane) == 0);
+  gact_destroy(plane);
 }
 
 // ---- the creation ladder through a real pump ----
@@ -857,6 +952,7 @@ void TestEpochRestoreDegraded() {
 int main() {
   TestValidatorTableFuzz();
   TestMalformedFrames();
+  TestAnsweredCreateIsNotRedriven();
   TestChaining();
   TestLadderThroughPump();
   TestEpochRestoreDegraded();

@@ -185,3 +185,41 @@ def test_nested_fanout_wider_than_cpus(ray_start_regular):
     # spawning 6 leaves need blocked-release to make progress.
     out = ray_tpu.get([fan.remote(6), fan.remote(6)], timeout=120)
     assert out == [15, 15]
+
+
+def test_get_from_other_threads_while_shutting_down():
+    """Threads still calling get() when the driver shuts down are turned
+    away at _run's gate: nothing is queued behind shutdown's last sweep
+    of the loop, where it would be a task destroyed pending or a
+    coroutine never awaited (the conftest fixture fails the test on
+    either), and no thread hangs in a get that nothing will answer."""
+    import gc
+    import threading
+
+    for _ in range(4):
+        ray_tpu.init(num_cpus=2)
+
+        @ray_tpu.remote
+        def one():
+            return 1
+
+        stop = threading.Event()
+
+        def hammer():
+            while not stop.is_set():
+                try:
+                    ray_tpu.get(one.remote(), timeout=5)
+                except Exception:  # noqa: BLE001 - whatever shutdown raises
+                    pass
+
+        threads = [threading.Thread(target=hammer, daemon=True)
+                   for _ in range(4)]
+        for t in threads:
+            t.start()
+        time.sleep(0.5)
+        ray_tpu.shutdown()
+        stop.set()
+        for t in threads:
+            t.join(10)
+        assert not any(t.is_alive() for t in threads)
+        gc.collect()

@@ -192,6 +192,13 @@ def _abstract_train(cfg, mesh, batch, seq):
         shard((tok, tok), prog.batch_spec)
 
 
+# XLA compiles a whole train step for the described chip in native code:
+# 76-105 s on the 8-core sandbox beside five other workers, and it scales
+# with the machine. The limit is a guard against hangs, not a budget.
+_WHOLE_STEP_LIMIT = pytest.mark.time_limit(600)
+
+
+@_WHOLE_STEP_LIMIT
 def test_train_step_fits_one_chip(topo):
     from ray_tpu.parallel import MeshConfig, make_mesh
 
@@ -205,6 +212,7 @@ def test_train_step_fits_one_chip(topo):
     assert _peak_bytes(compiled) < HBM_BYTES
 
 
+@_WHOLE_STEP_LIMIT
 def test_train_step_sharded_over_four_chips(topo):
     """`chip_smoke.py --chips 4` at a cut depth: the whole 32 layers take
     three minutes to compile (done by hand; CHANGES.md has the bytes).

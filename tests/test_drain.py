@@ -70,6 +70,12 @@ def _node_info(node_id):
                  if n["node_id"] == node_id), None)
 
 
+def _raylet_state(node):
+    from ray_tpu.util import state
+
+    return state.node_stats(node.node_id)[0]
+
+
 def test_drain_e2e_evacuates_everything(drain_cluster):
     """The acceptance scenario: a 3-node cluster with queued + running
     tasks, a restartable named actor, primary object copies, and an
@@ -148,7 +154,9 @@ def test_drain_deadline_fails_running_lease_retryable(drain_cluster):
         return x + 1
 
     ref = stuck.remote(1)
-    time.sleep(1.5)  # running on target by now
+    # Running on the target: a fresh node takes seconds to attach its
+    # first worker, and until then the drain has no lease to kill.
+    wait_for_condition(lambda: _raylet_state(target)["leases_granted"] == 1)
     cluster.add_node(num_cpus=2, resources={"pin": 1})
     cluster.wait_for_nodes()
 
@@ -161,6 +169,35 @@ def test_drain_deadline_fails_running_lease_retryable(drain_cluster):
     cluster.remove_node(target)
     assert ray_tpu.get(ref, timeout=90) == 2
     assert cw._num_reconstructions == 0
+
+
+def test_drain_rejects_grant_still_attaching_its_worker(drain_cluster):
+    """A lease whose resources are acquired while its worker still starts
+    is no running lease yet: the drain neither waits for it nor kills it.
+    Whichever side of that the drain lands on, the drained node has
+    granted only what the drain killed (a grant whose worker comes up
+    after the drain began is refused), and the task completes elsewhere."""
+    cluster = drain_cluster
+    target = cluster.add_node(num_cpus=2, resources={"pin": 1})
+    cluster.wait_for_nodes()
+
+    @ray_tpu.remote(resources={"pin": 0.1}, max_retries=3)
+    def stuck_on(node_id):
+        here = ray_tpu.get_runtime_context().get_node_id()
+        time.sleep(20.0 if here == node_id else 0.0)
+        return here
+
+    ref = stuck_on.remote(target.node_id)
+    wait_for_condition(lambda: _raylet_state(target)["active_leases"] == 1,
+                       retry_interval_ms=10)
+    other = cluster.add_node(num_cpus=2, resources={"pin": 1})
+    cluster.wait_for_nodes()
+    resp = cluster.drain_node(target, deadline_s=2, reason="preemption")
+    assert resp.get("state") == "DRAINED", resp
+    wait_for_condition(lambda: _raylet_state(target)["active_leases"] == 0)
+    assert _raylet_state(target)["leases_granted"] \
+        == _node_info(target.node_id)["drain_stats"]["killed_leases"]
+    assert ray_tpu.get(ref, timeout=60) == other.node_id
 
 
 def test_drain_rejection_is_retry_elsewhere(drain_cluster):

@@ -214,26 +214,29 @@ def test_elastic_shrink_then_grow_back(elastic_cluster, tmp_path):
 
 
 def _deadline_loop(cfg):
-    """Workers NOT on the drain target block 5s mid-step (no report /
-    keep_state boundary), so a resize can never park the gang inside
-    reshard_timeout_s — the deadline-expiry rung. Only on a fresh,
-    never-restored run: the checkpoint retry completes normally."""
+    """Every worker blocks mid-step 3 (no report / keep_state boundary)
+    after leaving a `blocked-<rank>` file, so a resize can never park the
+    gang inside reshard_timeout_s — the deadline-expiry rung — and no
+    worker runs on to the last step, which would leave the retry nothing
+    to restore into. The block outlasts any timeout of the test: the
+    fallback kills these workers. Only on a fresh, never-restored run:
+    the checkpoint retry completes normally."""
+    import os
     import time as _t
 
     from ray_tpu.train import session
 
-    import ray_tpu as _rt
-
     ck = session.get_checkpoint()
     start = int(ck.to_dict()["step"]) + 1 if ck is not None else 0
-    my_node = _rt.get_runtime_context().node_id
     for step in range(start, cfg["total_steps"]):
         ckpt = {"step": step} if session.get_world_rank() == 0 else None
         session.report({"step": step, "restored": ck is not None},
                        checkpoint=ckpt)
-        if (step == 3 and ck is None and session.get_elastic_epoch() == 0
-                and my_node != cfg["drain_node"]):
-            _t.sleep(5.0)
+        if step == 3 and ck is None and session.get_elastic_epoch() == 0:
+            open(os.path.join(cfg["signal_dir"],
+                              f"blocked-{session.get_world_rank()}"),
+                 "w").close()
+            _t.sleep(300.0)
         _t.sleep(0.1)
 
 
@@ -250,16 +253,17 @@ def test_elastic_deadline_falls_back_to_checkpoint(elastic_cluster,
 
     trainer = JaxTrainer(
         _deadline_loop,
-        train_loop_config={"total_steps": 10,
-                           "drain_node": nodes[0].node_id},
+        train_loop_config={"total_steps": 10, "signal_dir": str(tmp_path)},
         scaling_config=_scaling(3, min_workers=2, reshard_timeout_s=1.5),
         run_config=RunConfig(storage_path=str(tmp_path),
                              failure_config=FailureConfig(max_failures=1)),
         collective_backend=None)
     th, holder = _fit_in_thread(trainer)
+    # The whole gang says it is inside its block, and rank 0's step-3
+    # checkpoint has reached the trainer: the fallback has one to restore.
     wait_for_condition(
-        lambda: trainer.latest_metrics.get("step", -1) >= 3, timeout=60)
-    time.sleep(0.5)  # the off-target workers are inside their 8s block
+        lambda: all((tmp_path / f"blocked-{r}").exists() for r in range(3))
+        and trainer.latest_metrics.get("step", -1) == 3, timeout=60)
 
     NodePreempter(cluster, deadline_s=6).preempt(nodes[0])
     _gang_node(cluster)  # capacity for the checkpoint-restart gang

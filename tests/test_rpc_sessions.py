@@ -194,6 +194,34 @@ def test_grace_exhaustion_fails_session_and_fires_on_close():
     run(main())
 
 
+def test_server_stop_returns_under_live_clients():
+    """stop() under a plain connection and a session still connected
+    returns promptly (asyncio.Server.wait_closed() waits for every
+    accepted connection since Python 3.12), both clients see the close,
+    and a second stop() does nothing."""
+    async def main():
+        server = echo_server()
+        host, port = await server.start()
+        plain = await rpc.connect(host, port, name="p")
+        sess = await rpc.connect_session(host, port, name="s", grace_s=0.3)
+        assert (await plain.call("Echo", {"v": 1}))["v"] == 1
+        assert (await sess.call("Echo", {"v": 2}))["v"] == 2
+        plain_closed, sess_closed = asyncio.Event(), asyncio.Event()
+        plain.on_close(plain_closed.set)
+        sess.on_close(sess_closed.set)
+        await asyncio.wait_for(server.stop(), 2)
+        assert not server.connections
+        await asyncio.wait_for(plain_closed.wait(), 2)
+        # The session redials a dead port until its grace runs out.
+        await asyncio.wait_for(sess_closed.wait(), 5)
+        assert plain.closed and sess.closed
+        with pytest.raises(OSError):
+            await rpc.connect(host, port, timeout=1)
+        await asyncio.wait_for(server.stop(), 2)
+
+    run(main())
+
+
 def test_grace_zero_still_gets_one_redial_attempt():
     """grace_s=0 (pool-worker semantics: die with the peer) still makes
     a single fast redial attempt — an instantly-rebound listener keeps

@@ -355,5 +355,11 @@ def test_rpc_binary_ingress(serve_cluster):
         # streaming response
         chunks = list(client.stream({"count": 4}, deployment="tokens"))
         assert chunks == [{"tok": 0}, {"tok": 1}, {"tok": 2}, {"tok": 3}]
+        # shutdown stops the ingress under a live client and frees its port
+        import socket
+
+        serve.shutdown()
+        with _pytest.raises(OSError):
+            socket.create_connection(("127.0.0.1", port), timeout=1).close()
     finally:
         client.close()

@@ -207,10 +207,10 @@ def test_disagg_two_pools_collective_route(tiny_model, collective_env,
 
 @pytest.mark.smoke
 def test_disagg_per_pool_autoscaling(tiny_model, ray_start_regular):
-    """Each pool scales on ITS OWN replica-reported signal: a burst of
-    slow-drained streams pushes prefill TTFT and decode tokens_in_flight
+    """Each pool scales on ITS OWN replica-reported signal: six callers
+    streaming back to back push prefill TTFT and decode tokens_in_flight
     over their targets, the controller grows both pools independently,
-    and once the load drains the decode pool (short downscale delay)
+    and once the load stops the decode pool (short downscale delay)
     returns to min while prefill (long delay) stays scaled out."""
     from ray_tpu import serve
     from ray_tpu.serve import llm_disagg
@@ -239,27 +239,30 @@ def test_disagg_per_pool_autoscaling(tiny_model, ray_start_regular):
     try:
         prompt = [1, 5, 9, 2, 7]
         expected = _reference_greedy(cfg, params, prompt, 48)
-        outs = [None] * 6
+        outs = [[] for _ in range(6)]
+        grown = threading.Event()
 
         def consume(i):
-            acc = []
-            for tok in h.stream({"prompt_tokens": prompt,
-                                 "max_new_tokens": 48}):
-                acc.append(tok)
-                time.sleep(0.05)  # slow drain keeps tokens_in_flight high
-            outs[i] = acc
+            # Stream after stream until both pools have grown: six callers
+            # on four slots keep every controller tick (2 s) under load.
+            # One burst of six streams was over in 4.5 s, two ticks, and
+            # as often as not the decode pool's signal never saw it.
+            while not grown.is_set():
+                outs[i].append(list(h.stream({"prompt_tokens": prompt,
+                                              "max_new_tokens": 48})))
 
         threads = [threading.Thread(target=consume, args=(i,))
                    for i in range(len(outs))]
         for t in threads:
             t.start()
         wait_for_condition(
-            lambda: len(h._prefill._get_replicas()) == 2, timeout=90)
-        wait_for_condition(
-            lambda: len(h._decode._get_replicas()) == 2, timeout=90)
+            lambda: len(h._prefill._get_replicas()) == 2
+            and len(h._decode._get_replicas()) == 2, timeout=90)
+        grown.set()
         for t in threads:
             t.join(timeout=120)
-        assert all(o == expected for o in outs)
+        assert all(o == expected for per in outs for o in per)
+        assert all(outs)
         # Load gone: decode's signal decays and it scales back to min.
         wait_for_condition(
             lambda: len(h._decode._get_replicas()) == 1, timeout=90)
