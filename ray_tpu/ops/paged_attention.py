@@ -7,12 +7,19 @@ not `max_len x slots` — the round-1 engine's admitted waste
 (reference: the reference serves LLMs through vLLM-style external
 engines whose core trick is exactly this block table).
 
-The kernel uses Pallas scalar prefetch (PrefetchScalarGridSpec): the
-page table rides in SMEM and the grid's index_map dereferences it, so
-each grid step DMAs one page of K/V straight from the pool — attention
-runs over scattered pages without ever materializing a contiguous
-per-sequence cache. Online softmax accumulates across pages (same
-recurrence as ops/attention.py's flash kernel).
+Both kernels attend over scattered pages without ever materializing a
+contiguous per-sequence cache, accumulating an online softmax across
+pages (same recurrence as ops/attention.py's flash kernel), with page
+tables and lengths scalar-prefetched into SMEM
+(PrefetchScalarGridSpec).
+
+The batched kernel, the one the engine runs, costs what the resident
+tokens cost: one grid step a sequence, and inside it a loop that ends at
+the sequence's last live page. The pools stay in HBM; a live page is
+copied whole (all KV heads, one contiguous transfer) into a
+double-buffered VMEM scratch, the next block's copies in flight while
+the current block is computed. The single-sequence kernel (tests only)
+still has one page per grid step, dereferenced by the index_map.
 
 On CPU (tests) the kernel runs in interpret mode.
 """
@@ -33,30 +40,30 @@ def _interpret_mode() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _online_softmax_update(pi, length, q, k, v, m_prev, l_prev, acc_prev,
-                           *, page_size: int, sm_scale: float):
-    """One page of the online-softmax recurrence, shared by EVERY paged
-    kernel variant (single-sequence, grid-batched, fused-heads) so a
-    numerics change cannot silently miss one of them.
+def _online_softmax_update(start, length, q, k, v, m_prev, l_prev, acc_prev,
+                           *, sm_scale: float):
+    """One block of the online-softmax recurrence, shared by BOTH paged
+    kernels (single-sequence, batched) so a numerics change cannot
+    silently miss one of them. `k`/`v` hold the cached tokens `start`,
+    `start + 1`, ...; those at or past `length` are masked.
 
-    Pure function of values: callers own the scratch-ref IO (the fused
-    kernel updates row SLICES of shared scratch). Every dot is a plain
-    2D (G, D) x (page, D) matmul: Mosaic lowers 2D dots onto the MXU
-    but rejects the batched `hgd,thd` einsum form ("batch dims must be
-    equal" on real TPU; caught by scripts/tpu_kernel_sweep.py on-chip
-    validation). Returns (m_new, l_new, acc_new).
+    Pure function of values: callers own the scratch-ref IO. Every dot is
+    a plain 2D (G, D) x (tokens, D) matmul: Mosaic lowers 2D dots onto
+    the MXU but rejects the batched `hgd,thd` einsum form ("batch dims
+    must be equal" on real TPU; caught by scripts/tpu_kernel_sweep.py
+    on-chip validation). Returns (m_new, l_new, acc_new).
     """
     # scores[g, t] = q[g, :] . k[t, :]  — 2D dot, MXU-safe
     scores = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ()))) * sm_scale
-    token_idx = pi * page_size + jax.lax.broadcasted_iota(
+    token_idx = start + jax.lax.broadcasted_iota(
         jnp.int32, scores.shape, 1)
     scores = jnp.where(token_idx < length, scores, _NEG_INF)
 
     m_cur = jnp.max(scores, axis=-1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new)                 # (G, page)
+    p = jnp.exp(scores - m_new)                 # (G, tokens)
     l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
     pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))  # (G, D)
     return m_new, l_new, acc_prev * alpha + pv
@@ -70,10 +77,9 @@ def _normalized(l, acc):
 def _online_softmax_page_step(pi, num_page_steps, length, q, k, v,
                               o_write, m_scratch, l_scratch, acc_scratch,
                               *, page_size: int, sm_scale: float):
-    """One grid step over whole-scratch refs (single-sequence and
-    head-on-grid batched kernels). pi: page-step program id; q: (G, D);
-    k/v: (page, D); o_write: callback writing the normalized (G, D)
-    output on the last step."""
+    """One grid step of the single-sequence kernel over whole-scratch
+    refs. pi: page-step program id; q: (G, D); k/v: (page, D); o_write:
+    callback writing the normalized (G, D) output on the last step."""
     @pl.when(pi == 0)
     def _init():
         m_scratch[...] = jnp.full_like(m_scratch, _NEG_INF)
@@ -81,8 +87,8 @@ def _online_softmax_page_step(pi, num_page_steps, length, q, k, v,
         acc_scratch[...] = jnp.zeros_like(acc_scratch)
 
     m_new, l_new, acc_new = _online_softmax_update(
-        pi, length, q, k, v, m_scratch[...], l_scratch[...],
-        acc_scratch[...], page_size=page_size, sm_scale=sm_scale)
+        pi * page_size, length, q, k, v, m_scratch[...], l_scratch[...],
+        acc_scratch[...], sm_scale=sm_scale)
     m_scratch[...] = m_new
     l_scratch[...] = l_new
     acc_scratch[...] = acc_new
@@ -165,154 +171,197 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, length,
     return out.reshape(H, D)
 
 
-def _paged_decode_batch_kernel(page_table_ref, length_ref,  # scalar prefetch
-                               q_ref, k_ref, v_ref, o_ref,
+# VMEM the K and V page buffers of the batched kernel may take together
+# (two slots each, so that one block is copied while one is computed).
+_KV_VMEM_BYTES = 4 * 1024 * 1024
+
+
+def _pages_per_block(page_bytes: int, table_pages: int) -> int:
+    """Pool pages one block of the batched kernel holds: as many as the
+    VMEM budget allows for 2 pools x 2 slots, at most a whole table."""
+    return max(1, min(table_pages, _KV_VMEM_BYTES // (4 * page_bytes)))
+
+
+def _paged_decode_batch_kernel(length_ref, page_table_ref,  # scalar prefetch
+                               q_ref, k_hbm, v_hbm, o_ref,
+                               k_buf, v_buf, sems, slot_ref,
                                m_scratch, l_scratch, acc_scratch,
-                               *, page_size: int, sm_scale: float):
-    # Grid: (B, Hkv, npages); pages iterate fastest, so per-(b, h)
-    # scratch resets at pi == 0 and writes back on the last page step.
+                               *, page_size: int, pages_per_block: int,
+                               table_pages: int, sm_scale: float):
+    # Grid: (B,), one step a sequence. The pools stay in HBM; the step
+    # loops over the sequence's LIVE blocks of `pages_per_block` pages,
+    # copying each live page (all KV heads: one contiguous transfer in
+    # the (P, Hkv, page, D) layout) into one of two VMEM slots while the
+    # other slot is computed. The last block of a sequence starts the
+    # first block of the next one, so only the very first copy of a call
+    # is waited for with nothing to compute. `slot_ref` (SMEM) carries the
+    # slot that copy went to from one grid step to the next.
     b = pl.program_id(0)
-    pi = pl.program_id(2)
-
-    def write(out):
-        o_ref[0, 0] = out.astype(o_ref.dtype)
-
-    _online_softmax_page_step(
-        pi, pl.num_programs(2), length_ref[b],
-        q_ref[0, 0].astype(jnp.float32),        # (G, D)
-        k_ref[0, 0].astype(jnp.float32),        # (page, D)
-        v_ref[0, 0].astype(jnp.float32),
-        write, m_scratch, l_scratch, acc_scratch,
-        page_size=page_size, sm_scale=sm_scale)
-
-
-def _paged_decode_batch_fused_kernel(page_table_ref, length_ref,  # prefetch
-                                     q_ref, k_ref, v_ref, o_ref,
-                                     m_scratch, l_scratch, acc_scratch,
-                                     *, page_size: int, num_heads: int,
-                                     groups: int, sm_scale: float):
-    # Grid: (B, npages) — each step DMAs a FULL pool page (all Hkv heads
-    # contiguous in the (P, Hkv, page, D) layout) and unrolls a static
-    # per-head loop of 2D dots. Hkv-times fewer grid steps and
-    # Hkv-times larger transfers than the head-on-grid variant: this
-    # kernel is DMA-bound, so transfer size sets throughput.
-    b = pl.program_id(0)
-    pi = pl.program_id(1)
+    num_seqs = pl.num_programs(0)
+    num_heads = q_ref.shape[1]
+    block_tokens = page_size * pages_per_block
     length = length_ref[b]
+    num_blocks = pl.cdiv(length, block_tokens)
 
-    @pl.when(pi == 0)
-    def _init():
-        m_scratch[...] = jnp.full_like(m_scratch, _NEG_INF)
-        l_scratch[...] = jnp.zeros_like(l_scratch)
-        acc_scratch[...] = jnp.zeros_like(acc_scratch)
+    def for_live_pages(seq, blk, slot, act):
+        """`act` on the K and the V copy of every live page of block
+        `blk` of sequence `seq`, into buffer `slot`."""
+        first = blk * pages_per_block
+        live = jnp.clip(pl.cdiv(length_ref[seq], page_size) - first,
+                        0, pages_per_block)
 
-    for h in range(num_heads):      # static: unrolled at trace time
-        rows = slice(h * groups, (h + 1) * groups)
-        m_new, l_new, acc_new = _online_softmax_update(
-            pi, length,
-            q_ref[0, h].astype(jnp.float32),       # (G, D)
-            k_ref[0, h].astype(jnp.float32),       # (page, D)
-            v_ref[0, h].astype(jnp.float32),
-            m_scratch[rows], l_scratch[rows], acc_scratch[rows],
-            page_size=page_size, sm_scale=sm_scale)
-        m_scratch[rows] = m_new
-        l_scratch[rows] = l_new
-        acc_scratch[rows] = acc_new
+        def page(j, carry):
+            src = page_table_ref[seq * table_pages + first + j]
+            act(pltpu.make_async_copy(
+                k_hbm.at[src], k_buf.at[slot, j], sems.at[0, slot]))
+            act(pltpu.make_async_copy(
+                v_hbm.at[src], v_buf.at[slot, j], sems.at[1, slot]))
+            return carry
 
-    @pl.when(pi == pl.num_programs(1) - 1)
-    def _finish():
-        o_ref[0] = _normalized(l_scratch[...],
-                               acc_scratch[...]).astype(o_ref.dtype)
+        jax.lax.fori_loop(0, live, page, None)
+
+    def start(seq, blk, slot):
+        for_live_pages(seq, blk, slot, lambda copy: copy.start())
+
+    def wait(seq, blk, slot):
+        for_live_pages(seq, blk, slot, lambda copy: copy.wait())
+
+    @pl.when(b == 0)
+    def _first():
+        # Pages past a sequence's length are never copied, and masked
+        # scores give them p == 0 exactly; what they multiply must still
+        # be finite, so the slots start as zeros, not as whatever VMEM
+        # held (after that they only ever hold pool pages).
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        start(0, 0, 0)
+
+    first_slot = slot_ref[0]
+    m_scratch[...] = jnp.full_like(m_scratch, _NEG_INF)
+    l_scratch[...] = jnp.zeros_like(l_scratch)
+    acc_scratch[...] = jnp.zeros_like(acc_scratch)
+
+    def block(blk, carry):
+        slot = (first_slot + blk) % 2
+        last = blk + 1 == num_blocks
+
+        @pl.when(jnp.logical_or(jnp.logical_not(last), b + 1 < num_seqs))
+        def _next():
+            # this sequence's next block, or the first of the next one
+            start(jnp.where(last, b + 1, b), jnp.where(last, 0, blk + 1),
+                  1 - slot)
+
+        wait(b, blk, slot)
+        # Dots over the whole block, dead pages too (masked): a dot for
+        # every 1, 2 or 4 pages spared those and was still slower in all
+        # three shapes measured, its fixed cost a head is most of it.
+        for h in range(num_heads):      # static: unrolled at trace time
+            k = k_buf[slot, :, h].reshape(block_tokens, -1)
+            v = v_buf[slot, :, h].reshape(block_tokens, -1)
+            m_new, l_new, acc_new = _online_softmax_update(
+                blk * block_tokens, length,
+                q_ref[0, h].astype(jnp.float32),       # (G, D)
+                k.astype(jnp.float32),                 # (tokens, D)
+                v.astype(jnp.float32),
+                m_scratch[h], l_scratch[h], acc_scratch[h],
+                sm_scale=sm_scale)
+            m_scratch[h] = m_new
+            l_scratch[h] = l_new
+            acc_scratch[h] = acc_new
+        return carry
+
+    jax.lax.fori_loop(0, num_blocks, block, None)
+
+    # A sequence of length 0 ran no block, so nothing started its
+    # successor's first copy; both slots are free, take the same one.
+    @pl.when(jnp.logical_and(num_blocks == 0, b + 1 < num_seqs))
+    def _empty():
+        start(b + 1, 0, first_slot)
+
+    slot_ref[0] = (first_slot + num_blocks) % 2
+    o_ref[0] = _normalized(l_scratch[...],
+                           acc_scratch[...]).astype(o_ref.dtype)
 
 
 def paged_decode_attention_batch(q, k_pool, v_pool, page_tables, lengths,
-                                 *, sm_scale: float | None = None,
-                                 fused_heads: bool = False):
+                                 *, sm_scale: float | None = None):
     """Batched single-token decode attention over paged KV.
 
-    The batch dimension is a leading GRID axis (not vmap — scalar-prefetch
-    pallas calls don't batch), so one compiled program serves every slot
-    of a continuous-batching engine per decode step.
+    One kernel for every slot of a continuous-batching engine. Its work
+    follows the tokens resident in the cache: a sequence costs its live
+    pages (`ceil(length / page_size)`, whatever the table's width), a
+    sequence of length 0 nothing. How many pages one block holds comes
+    from the shapes (`_pages_per_block`), not from the caller.
 
     q:           (B, H, D) one query per sequence
-    k/v_pool:    (P, Hkv, page_size, D) pools SHARED by all sequences
-                 (head-then-page minor layout; see paged_decode_attention)
-    page_tables: (B, NP) int32 pool indices per sequence
-    lengths:     (B,) int32 valid token counts (incl. current tokens)
-    fused_heads: one grid step per (sequence, page) covering ALL KV
-                 heads (full-page contiguous DMA, Hkv-times fewer grid
-                 steps) vs one per (sequence, head, page). Default stays
-                 False until the fused variant passes on-chip Mosaic
-                 validation (scripts/tpu_kernel_sweep.py) — interpret
-                 mode has accepted kernels real TPU rejects before.
+    k/v_pool:    (P, Hkv, page_size, D) pools SHARED by all sequences:
+                 page p is one contiguous Hkv x page_size x D block, and
+                 is copied whole (see paged_decode_attention for the
+                 layout)
+    page_tables: (B, NP) int32 pool indices per sequence (entries past
+                 the live length are never read)
+    lengths:     (B,) int32 valid token counts (incl. current tokens),
+                 at most NP * page_size; a row of length 0 returns zeros
     Returns (B, H, D).
     """
+    _, Hkv, page_size, D = k_pool.shape
+    if sm_scale is None:
+        sm_scale = 1.0 / (D ** 0.5)
+    return _paged_decode_batch_call(
+        q, k_pool, v_pool, page_tables, lengths, sm_scale=sm_scale,
+        pages_per_block=_pages_per_block(
+            Hkv * page_size * D * k_pool.dtype.itemsize,
+            page_tables.shape[1]),
+        interpret=_interpret_mode())
+
+
+# jit, so that the layers of a model share ONE lowering of the kernel:
+# its body (the heads are unrolled) takes a second or two to lower on the
+# chip's host, and a decode program lowered it once a layer, in every
+# process's set-up (30-39 s for 16 layers; my chip runs, PR 25).
+@functools.partial(jax.jit, static_argnames=("sm_scale", "pages_per_block",
+                                             "interpret"))
+def _paged_decode_batch_call(q, k_pool, v_pool, page_tables, lengths, *,
+                             sm_scale: float, pages_per_block: int,
+                             interpret: bool):
     B, H, D = q.shape
     P, Hkv, page_size, _ = k_pool.shape
     groups = H // Hkv
-    npages = page_tables.shape[1]
-    if sm_scale is None:
-        sm_scale = 1.0 / (D ** 0.5)
-
-    q4 = q.reshape(B, Hkv, groups, D)
-    if fused_heads:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, npages),
-            in_specs=[
-                pl.BlockSpec((1, Hkv, groups, D),
-                             lambda b, i, pt, ln: (b, 0, 0, 0)),
-                pl.BlockSpec((1, Hkv, page_size, D),
-                             lambda b, i, pt, ln: (pt[b, i], 0, 0, 0)),
-                pl.BlockSpec((1, Hkv, page_size, D),
-                             lambda b, i, pt, ln: (pt[b, i], 0, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, Hkv * groups, D),
-                                   lambda b, i, pt, ln: (b, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((Hkv * groups, 1), jnp.float32),
-                pltpu.VMEM((Hkv * groups, 1), jnp.float32),
-                pltpu.VMEM((Hkv * groups, D), jnp.float32),
-            ],
-        )
-        out = pl.pallas_call(
-            functools.partial(_paged_decode_batch_fused_kernel,
-                              page_size=page_size, num_heads=Hkv,
-                              groups=groups, sm_scale=sm_scale),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, Hkv * groups, D), q.dtype),
-            interpret=_interpret_mode(),
-        )(page_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-          q4, k_pool, v_pool)
-        return out.reshape(B, H, D)
-
+    table_pages = page_tables.shape[1]
+    buf = (2, pages_per_block, Hkv, page_size, D)
+    row = pl.BlockSpec((1, Hkv, groups, D), lambda b, ln, pt: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, npages),
-        in_specs=[
-            pl.BlockSpec((1, 1, groups, D),
-                         lambda b, h, i, pt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, D),
-                         lambda b, h, i, pt, ln: (pt[b, i], h, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, D),
-                         lambda b, h, i, pt, ln: (pt[b, i], h, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, groups, D),
-                               lambda b, h, i, pt, ln: (b, h, 0, 0)),
+        grid=(B,),
+        in_specs=[row,
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((groups, 1), jnp.float32),
-            pltpu.VMEM((groups, 1), jnp.float32),
-            pltpu.VMEM((groups, D), jnp.float32),
+            pltpu.VMEM(buf, k_pool.dtype),
+            pltpu.VMEM(buf, v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),        # (K | V, slot)
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((Hkv, groups, 1), jnp.float32),
+            pltpu.VMEM((Hkv, groups, 1), jnp.float32),
+            pltpu.VMEM((Hkv, groups, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_paged_decode_batch_kernel, page_size=page_size,
-                          sm_scale=sm_scale),
+                          pages_per_block=pages_per_block,
+                          table_pages=table_pages, sm_scale=sm_scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, groups, D), q.dtype),
-        interpret=_interpret_mode(),
-    )(page_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q4, k_pool, v_pool)
+        # The slot handed from one sequence to the next makes the grid a
+        # sequence, not a set.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(jnp.minimum(lengths.astype(jnp.int32), table_pages * page_size),
+      page_tables.astype(jnp.int32).reshape(-1),
+      q.reshape(B, Hkv, groups, D), k_pool, v_pool)
     return out.reshape(B, H, D)
 
 

@@ -169,6 +169,12 @@ class LLMEngine:
         # handoff_fallbacks.
         self.use_device_plane = use_device_plane
         self.handoff_fallbacks = 0
+        # Cumulative over decode dispatches (paged mode): the table pages
+        # the kernel had to visit, and the pages the tables hold. Counters,
+        # so that a reader takes the share over its own window (and leaves
+        # warm-up out) by difference.
+        self.paged_pages_live = 0
+        self.paged_pages_table = 0
         # Paged KV mode (page_size > 0): admission is bounded by POOL
         # pages (resident tokens), not slot count x max_len.
         self.page_size = page_size
@@ -496,6 +502,10 @@ class LLMEngine:
             "active_streams": float(self.num_active()),
             "parked_events": float(self._parked_events),
             "handoff_fallbacks": float(self.handoff_fallbacks),
+            "paged_pages_live": float(self.paged_pages_live),
+            "paged_pages_table": float(self.paged_pages_table),
+            "paged_live_share": (self.paged_pages_live
+                                 / max(1, self.paged_pages_table)),
             "ttft_p50_ms": pick(0.5) * 1e3,
             "ttft_p99_ms": pick(0.99) * 1e3,
         }
@@ -908,7 +918,20 @@ class LLMEngine:
         if self.page_size and st.seq_id:
             self._alloc.free(st.seq_id)
             self._tables[slot, :] = self._dummy_page
+            # The kernel's work follows `_lens`: an empty slot costs the
+            # one dummy page its garbage write lands in, not the pages
+            # of the stream that left.
+            self._lens[slot] = 0
             st.seq_id = ""
+
+    def _count_paged_pages(self):
+        """Table pages the paged kernel visits in the chunk about to be
+        dispatched, against those the table holds: step k of the chunk
+        attends over `_lens + k + 1` tokens of every slot."""
+        steps = 1 + np.arange(self.decode_chunk)
+        tokens = self._lens[:, None].astype(np.int64) + steps
+        self.paged_pages_live += int((-(-tokens // self.page_size)).sum())
+        self.paged_pages_table += tokens.size * self._np_pages
 
     def _advance_prefill(self, slot: int):
         """Write ONE chunk of a long prompt into the slot's cache; on the
@@ -1059,6 +1082,7 @@ class LLMEngine:
             try:
                 self._rng, srng = jax.random.split(self._rng)
                 if self.page_size:
+                    self._count_paged_pages()
                     toks, pools_out = self._decode_chunk_paged(
                         self.params, jnp.asarray(self._token),
                         jnp.asarray(self._pos), self._pools,
@@ -1100,11 +1124,11 @@ class LLMEngine:
                         # the uncommitted steps' writes are garbage that
                         # is simply rewritten.
                         break
+                    if st.request is None:  # eos/max_new hit mid-chunk:
+                        break               # _emit freed the slot
                     self._lens[i] += 1
                     self._pos[i] += 1
                     self._token[i] = tok
-                    if st.request is None:  # eos/max_new hit mid-chunk
-                        break
 
 
 # ---------------------------------------------------------------------------

@@ -118,18 +118,19 @@ def test_flash_forward_backward(one_chip):
     assert KERNEL in compiled.as_text()
 
 
-@pytest.mark.parametrize("fused_heads", [False, True],
-                         ids=["head_on_grid", "fused_heads"])
-def test_paged_decode_batch(one_chip, fused_heads):
-    B, H, Hkv, D, page, npages = 32, 32, 8, 128, 64, 8
+@pytest.mark.parametrize("B, pool_pages", [(32, 385), (4, 193)],
+                         ids=["chat_open_b32", "docs_closed_b4"])
+def test_paged_decode_batch(one_chip, B, pool_pages):
+    """The two serve cells' shapes (`benchmarks/configs/mistral-7b-v0.3-
+    l16*.json`: 32/8 heads of 128, pages of 64, a table of ceil((2304 + 8)
+    / 64) = 37 columns, the pool with its dummy page)."""
+    H, Hkv, D, page, table_pages = 32, 8, 128, 64, 37
     S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
-    pool = S((B * npages + 1, Hkv, page, D), jnp.bfloat16)
-    compiled = jax.jit(
-        lambda q, kp, vp, pt, ln: paged_attention.paged_decode_attention_batch(
-            q, kp, vp, pt, ln, fused_heads=fused_heads)).lower(
+    pool = S((pool_pages, Hkv, page, D), jnp.bfloat16)
+    compiled = jax.jit(paged_attention.paged_decode_attention_batch).lower(
         S((B, H, D), jnp.bfloat16), pool, pool,
-        S((B, npages), jnp.int32), S((B,), jnp.int32)).compile()
+        S((B, table_pages), jnp.int32), S((B,), jnp.int32)).compile()
     assert KERNEL in compiled.as_text()
 
 

@@ -133,3 +133,48 @@ def test_batched_prefill_used_and_bit_equal(tiny_model):
     finally:
         eng._prefill_many, eng._prefill_one = real_many, real_one
         eng.shutdown()
+
+
+def test_freed_slot_has_length_zero_and_live_pages_are_counted(tiny_model):
+    """A slot whose long stream left tells the kernel so (`_lens == 0`:
+    it costs the one dummy page, not the stream's pages), the stream
+    beside it goes on bit-equal to the Generator, and the engine's
+    counters read the table pages the kernel had to visit against the
+    pages the tables hold, for lengths known chunk by chunk."""
+    cfg, params = tiny_model
+    K, page, slots = 4, 16, 3
+    eng = LLMEngine(cfg, params, max_batch=slots, max_len=96,
+                    page_size=page, decode_chunk=K)
+    try:
+        long_prompt = [1 + i % 100 for i in range(40)]
+        short_prompt = [7, 3, 9, 2, 5]
+        # Admitted together (the loop is paused while both are submitted):
+        # the long stream's 5 tokens end with chunk 0, the short one's 21
+        # with chunk 4, and the third slot is never used.
+        assert eng.quiesce_for_drain()
+        long_h = eng.submit(long_prompt, SamplingParams(max_new_tokens=1 + K))
+        short_h = eng.submit(short_prompt,
+                             SamplingParams(max_new_tokens=1 + 5 * K))
+        eng.resume()
+        assert long_h.tokens() == _reference_greedy(
+            cfg, params, long_prompt, 1 + K)
+        assert eng.quiesce_for_drain()
+        assert eng._lens[0] == 0
+        assert (eng._tables[0] == eng._dummy_page).all()
+        eng.resume()
+        assert short_h.tokens() == _reference_greedy(
+            cfg, params, short_prompt, 1 + 5 * K)
+        assert eng.quiesce_for_drain()
+        assert (eng._lens == 0).all()
+
+        lens_by_chunk = [[40, 5, 0]] + [[0, 5 + K * c, 0] for c in (1, 2, 3, 4)]
+        live = sum(-(-(n + k) // page)
+                   for lens in lens_by_chunk for n in lens
+                   for k in range(1, K + 1))
+        table = len(lens_by_chunk) * K * slots * eng._np_pages
+        got = eng.report_metrics()
+        assert (got["paged_pages_live"], got["paged_pages_table"]) \
+            == (live, table)
+        assert got["paged_live_share"] == pytest.approx(live / table)
+    finally:
+        eng.shutdown()
