@@ -10,7 +10,6 @@ import time
 
 from benchmarks.harness import cluster, kernel_costs
 from benchmarks.harness.loader import BenchmarkError
-from benchmarks.harness.model import model_sizes
 
 FIT_DEADLINE_S = 1500.0
 # Random weights give logits of about unit variance, which puts the first
@@ -25,13 +24,14 @@ def run(cell, seed, seconds, trace, t_start, platform, log) -> dict:
 
     from benchmarks.harness.train_worker import train_loop
 
-    sizes = model_sizes(cell.config)
+    sizes = cell.family.sizes(cell.config)
     tr = cell.config["train"]
     cluster.require_tpu_resource(cell.chips)
     trainer = JaxTrainer(
         train_loop,
         train_loop_config={
-            "sizes": sizes, "train": tr, "traffic": cell.traffic,
+            "sizes": sizes, "family": cell.family_name, "root": cell.root,
+            "train": tr, "traffic": cell.traffic,
             "seed": seed, "seconds": seconds, "trace": trace,
             "chips": cell.chips,
             "reference_rows": int(tr["reference_rows"])},
@@ -119,13 +119,19 @@ def run(cell, seed, seconds, trace, t_start, platform, log) -> dict:
               "count": who["count"],
               "memory_peak_bytes": final["memory_peak_bytes"]}
     obs = {"sizes": sizes, "config": cell.config, "traffic": cell.traffic,
+           "family": cell.family_name,
            "device": device, "seconds": seconds, "steps": steps,
            "tokens_per_step": tokens_per_step, "rows": final["rows"],
            "seq": final["seq"], "trace": final["trace"],
            "worker_ready_s": worker_ready_s, "end_to_end": end_to_end,
            "peaks": kernel_costs.peaks(who["kind"])
            if who["platform"] == "tpu" else None}
+    checked = [
+        f"first loss - ln V = {above:.4f}, limits {FIRST_LOSS_BAND}",
+        f"loss {final['model_loss']} against the reference's "
+        f"{final['reference_loss']} on the same rows, limit "
+        f"{REFERENCE_TOLERANCE}"]
     return {"correct": not problems, "problems": problems,
-            "attempted": len(steps),
+            "checked": checked, "attempted": len(steps),
             "failed": sum(1 for s in steps if not math.isfinite(s["loss"])),
             "end_to_end": end_to_end, "device": device, "obs": obs}

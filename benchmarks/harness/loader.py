@@ -3,9 +3,12 @@
 A cell names a configuration and a traffic mix.  The configuration's file
 is the `file` of its `configs` entry; the mix is `traffic/<traffic>.json`;
 its `kind` names `drivers/<kind>.py`; each per-layer metric the cell
-reports is read by `layer_metrics/<metric>.py`.  Every directory in
-`paths` is searched, then this harness's own, so a later PR (or a test)
-adds a cell by adding files and entries and edits none.
+reports is read by `layer_metrics/<metric>.py`; the configuration's
+`family` (`dense_decoder` where the file names none) is
+`families/<family>.py`, which says how the file's sizes become the
+program's model and which plain reference it is held to.  Every directory
+in `paths` is searched, then this harness's own, so a later PR (or a test)
+adds a cell or a family by adding files and entries and edits none.
 """
 
 from __future__ import annotations
@@ -18,6 +21,7 @@ from types import ModuleType
 
 HARNESS_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REPO_ROOT = os.path.dirname(HARNESS_ROOT)
+DEFAULT_FAMILY = "dense_decoder"
 
 
 class BenchmarkError(RuntimeError):
@@ -31,9 +35,15 @@ class Cell:
     config: dict
     traffic: dict
     driver: ModuleType
+    family: ModuleType    # families/<family>.py; its name and `root` cross
+    root: str             # to the lease-holder, which loads it again
     end_to_end: list      # metric entries of BENCHMARK.json this cell reports
     per_layer: list
     readers: dict         # per-layer metric name -> module with read(obs)
+
+    @property
+    def family_name(self) -> str:
+        return self.config.get("family", DEFAULT_FAMILY)
 
 
 def load_benchmark(root: str = REPO_ROOT) -> dict:
@@ -63,6 +73,10 @@ def _load_json(path: str) -> dict:
         return json.load(f)
 
 
+def _modname(kind: str, name: str) -> str:
+    return f"_bench_{kind}_" + name.replace(".", "_").replace("-", "_")
+
+
 def _load_module(path: str, modname: str) -> ModuleType:
     spec = importlib.util.spec_from_file_location(modname, path)
     module = importlib.util.module_from_spec(spec)
@@ -76,7 +90,71 @@ def sibling_reader(here: str, name: str) -> ModuleType:
     reports other end-to-end metrics needs its own per-layer entries)."""
     return _load_module(
         os.path.join(os.path.dirname(os.path.abspath(here)), name + ".py"),
-        "_bench_metric_" + name.replace(".", "_").replace("-", "_"))
+        _modname("metric", name))
+
+
+def beside(here: str, sub: str, filename: str) -> ModuleType:
+    """The module `<sub>/<filename>` of the directory of `paths` that the
+    file `here` lies in (one level down): how a family that a later PR
+    adds finds the plain reference it brought with it."""
+    top = os.path.dirname(os.path.dirname(os.path.abspath(here)))
+    return _load_module(os.path.join(top, sub, filename),
+                        _modname(sub, os.path.splitext(filename)[0]))
+
+
+def load_family(name: str, root: str = REPO_ROOT,
+                bench: dict | None = None) -> ModuleType:
+    """`families/<name>.py`, found as drivers and readers are."""
+    return _load_module(
+        find_file(bench or load_benchmark(root), root, "families",
+                  name + ".py"),
+        _modname("family", name))
+
+
+def check_configuration(conf: dict, family: ModuleType) -> None:
+    """What the `model-configs` guide asks of any configuration file,
+    whatever its family; then the family's own `check_file`.  A family
+    says which keys may be cut (`REDUCIBLE`) and, where it has them,
+    which key counts the experts held (`EXPERTS_KEY`), the rows of the
+    vocabulary (`VOCAB_KEY`, `vocab_size`), and how to read the layer
+    pattern (`layer_pattern(conf)` -> leading dense layers, period)."""
+    def refuse(why):
+        raise BenchmarkError(f"configuration {conf.get('source')}: {why}")
+
+    for key in ("source", "reduced", "assumed", "deployment", "memory"):
+        if key not in conf:
+            refuse(f"no {key!r}")
+    if not conf["memory"]:
+        refuse("the compile's memory report is not recorded")
+    depth = getattr(family, "DEPTH_KEY", "num_hidden_layers")
+    experts = getattr(family, "EXPERTS_KEY", None)
+    vocab = getattr(family, "VOCAB_KEY", "vocab_size")
+    for key in conf["reduced"]:
+        # What is cut is named with its published value; no width is.
+        if key not in family.REDUCIBLE:
+            refuse(f"{key!r} is cut and the family lets only "
+                   f"{sorted(family.REDUCIBLE)} be")
+        if not conf.get("published", {}).get(key, 0) > conf[key]:
+            refuse(f"{key!r} is listed as cut but is not under its "
+                   "published value")
+    pattern = getattr(family, "layer_pattern", lambda conf: None)(conf)
+    if pattern is not None and depth in conf["reduced"]:
+        leading, period = pattern
+        if conf[depth] - leading < max(period, 4):
+            refuse(f"{conf[depth]} layers keep no whole period of "
+                   f"{period} and four layers after the {leading} "
+                   "leading dense ones")
+    if experts in conf["reduced"] and conf[experts] < 8:
+        refuse(f"{conf[experts]} experts held; the floor is 8")
+    if vocab in conf["reduced"] and \
+            conf[vocab] * 8 < conf["published"][vocab]:
+        refuse("the vocabulary's slice is under an eighth of the "
+               "published one")
+    if set(conf["reduced"]) - {depth} and \
+            "chips_sharing_a_layer" not in conf["deployment"]:
+        refuse("a cut beyond depth is a chip's share of a deployment: "
+               "`deployment.chips_sharing_a_layer` says of which")
+    family.check_file(conf)
 
 
 def _reports(metric: dict, cell_name: str) -> bool:
@@ -103,14 +181,16 @@ def load_cell(name: str, root: str = REPO_ROOT) -> Cell:
                                    entry["traffic"] + ".json"))
     kind = traffic["kind"]
     driver = _load_module(find_file(bench, root, "drivers", kind + ".py"),
-                          f"_bench_driver_{kind}")
+                          _modname("driver", kind))
+    family = load_family(config.get("family", DEFAULT_FAMILY), root, bench)
     per_layer = [m for m in bench["per_layer"] if _reports(m, name)]
     readers = {
         m["name"]: _load_module(
             find_file(bench, root, "layer_metrics", m["name"] + ".py"),
-            "_bench_metric_" + m["name"].replace(".", "_").replace("-", "_"))
+            _modname("metric", m["name"]))
         for m in per_layer}
-    return Cell(name=name, chips=int(entry["chips"]), config=config, traffic=traffic, driver=driver,
+    return Cell(name=name, chips=int(entry["chips"]), config=config,
+                traffic=traffic, driver=driver, family=family, root=root,
                 end_to_end=[m for m in bench["end_to_end"]
                             if _reports(m, name)],
                 per_layer=per_layer, readers=readers)

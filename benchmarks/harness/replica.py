@@ -14,23 +14,19 @@ import time
 
 from ray_tpu.serve.llm import LLMServer
 
-from benchmarks.harness import cluster, trace_reduce
-from benchmarks.harness.model import llama_config
+from benchmarks.harness import cluster, loader, trace_reduce
 
 SAMPLE_HZ = 20.0
 JAX_SEED_MASK = 0x7FFFFFFF   # --seed may pass 2**31; PRNGKey takes 31 bits
 
 
-def seeded_params(cfg, seed: int):
+def seeded_params(model, seed: int):
     """All weights in one jitted call, on the device, in the served type."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models.llama import LlamaModel
-
     # The key is an ARGUMENT: a seed closed over would be a constant of the
     # program, and every new seed would miss the compile cache.
-    model = LlamaModel(cfg)
     params = jax.jit(lambda key: model.init(
         key, jnp.zeros((1, 8), jnp.int32)))(
             jax.random.PRNGKey(seed & JAX_SEED_MASK))
@@ -38,13 +34,16 @@ def seeded_params(cfg, seed: int):
 
 
 class BenchLLM(LLMServer):
-    def __init__(self, sizes: dict, seed: int, engine: dict):
+    def __init__(self, sizes: dict, seed: int, engine: dict,
+                 family: str = loader.DEFAULT_FAMILY,
+                 root: str = loader.REPO_ROOT):
         cluster.uncap_compile_cache()
         self._compiles = cluster.CompileCounter()
         t0 = time.monotonic()
         self._sizes = sizes
-        self._cfg = llama_config(sizes)
-        self._params = seeded_params(self._cfg, seed)
+        self._family = loader.load_family(family, root)
+        self._cfg = self._family.program_config(sizes)
+        self._params = seeded_params(self._family.model(self._cfg), seed)
         self._init_params_s = time.monotonic() - t0
         super().__init__(self._cfg, self._params, **engine)
         self._lock = threading.Lock()
@@ -187,34 +186,75 @@ class BenchLLM(LLMServer):
 
     # ---- correctness, outside the window ----------------------------------
 
-    def check_reference(self, samples: list) -> list:
-        """For each (prompt, generated tokens): one teacher-forced pass of
-        the plain reference over prompt + generated, on these weights; at
-        every generated position, how far the reference's logit of the
-        engine's token lies under the reference's own best."""
-        import jax.numpy as jnp
+    def reference_logits(self, prompt: list, tokens: list, **how):
+        """The plain reference's logits at the positions that produced
+        `tokens`, teacher-forced over prompt + tokens on these weights."""
         import numpy as np
 
-        from benchmarks.reference import dense_decoder
+        seq = list(prompt) + list(tokens[:-1])
+        rows = list(range(len(prompt) - 1, len(seq)))
+        # One shape, one compile; the padding is causal and has no effect.
+        padded = seq + [0] * (self.engine.max_len - len(seq))
+        return np.asarray(self._family.reference.logits(
+            self._params, self._sizes, padded, rows, **how))
+
+    def check_reference(self, samples: list, tolerance: float,
+                        diagnose: int = 0) -> list:
+        """For each sample (`rid`, `prompt`, `output`): one teacher-forced
+        pass of the plain reference over prompt + generated, on these
+        weights; at every generated position k, how far the reference's
+        logit of the engine's token lies under the reference's own best.
+        Every gap over `tolerance` is returned with its k (`over`).
+
+        Where a sample has such gaps the reference is run again with the
+        roundings a bfloat16 server makes (`reference.ROUNDINGS`, one
+        more a pass): a position at which the reference's OWN best token
+        falls more than `tolerance` under the rounded pass's best is one
+        the served type cannot decide, and is set aside.  The engine's
+        tokens play no part in that.  `serve_common.judge` holds every
+        position kept to `tolerance` and caps the share set aside.
+        `diagnose` (BENCH_DIAGNOSE): make the rounded passes on that many
+        leading samples whatever they show, and the discriminating runs
+        of `harness/diagnose.py` on every sample with a gap."""
+        import numpy as np
 
         out = []
-        pad_to = self.engine.max_len     # one shape, one compile
-        for prompt, got in samples:
-            seq = list(prompt) + list(got[:-1])
-            rows = list(range(len(prompt) - 1, len(seq)))
-            padded = seq + [0] * (pad_to - len(seq))   # causal: no effect
-            lg = np.asarray(dense_decoder.logits(
-                self._params, self._sizes, padded, rows))
+        for i, sample in enumerate(samples):
+            prompt, got = sample["prompt"], sample["output"]
+            at = np.arange(len(got))
+            lg = self.reference_logits(prompt, got)
             top2 = np.partition(lg, -2, axis=-1)[:, -2:]
-            best = top2[:, 1]
-            gap = best - lg[np.arange(len(got)), np.asarray(got)]
-            out.append({"prompt_len": len(prompt), "tokens": len(got),
-                        "agree": int((gap == 0).sum()),
-                        "max_logit_gap": float(gap.max()),
-                        "gaps": sorted(float(g) for g in gap if g > 0)[-8:],
-                        "mean_top_logit": float(best.mean()),
-                        "median_top2_margin":
-                            float(np.median(top2[:, 1] - top2[:, 0]))})
+            best, margin = top2[:, 1], top2[:, 1] - top2[:, 0]
+            gap = best - lg[at, np.asarray(got)]
+            over = np.flatnonzero(gap > tolerance)
+            one = {"rid": sample.get("rid"), "prompt_len": len(prompt),
+                   "tokens": len(got), "agree": int((gap == 0).sum()),
+                   "max_logit_gap": float(gap.max()),
+                   "gaps": sorted(float(g) for g in gap if g > 0)[-8:],
+                   "over": [[int(k), float(gap[k]), float(best[k]),
+                             float(margin[k])] for k in over],
+                   "mean_top_logit": float(best.mean()),
+                   "median_top2_margin": float(np.median(margin)),
+                   "set_aside": None, "kept_max_gap": float(gap.max())}
+            if len(over) or i < diagnose:
+                own = lg.argmax(-1)
+                passes = [self.reference_logits(prompt, got, rounded=level)
+                          for level in
+                          range(1, len(self._family.reference.ROUNDINGS))]
+                moved = np.stack([p.max(-1) - p[at, own] for p in passes])
+                aside = (moved > tolerance).any(0)
+                one.update(
+                    set_aside=int(aside.sum()),
+                    set_aside_at=[int(k) for k in np.flatnonzero(aside)],
+                    set_aside_by_pass=[int((m > tolerance).sum())
+                                       for m in moved],
+                    kept_max_gap=float(gap[~aside].max(initial=0.0)))
+                if diagnose:
+                    from benchmarks.harness import diagnose as dg
+
+                    one["diagnosis"] = dg.sample(
+                        self, sample, lg, over, moved, passes, tolerance)
+            out.append(one)
         return out
 
 

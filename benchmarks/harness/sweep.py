@@ -27,7 +27,8 @@ def run(cell, seed, seconds, rates, t_start, platform, log) -> None:
             mix["rate_rps"] = rate
             t0 = time.monotonic()
             requests = traffic.serve_requests(
-                mix, seed, cell.config["vocab_size"], seconds)
+                mix, seed, cell.family.sizes(cell.config)["vocab_size"],
+                seconds)
             for r in requests:       # rids stay unique over the rates
                 r.rid += 100000 * (i + 1)
             ctx.requests.extend(requests)

@@ -12,27 +12,25 @@ import queue
 import threading
 import time
 
-from benchmarks.harness import cluster, trace_reduce, traffic
-from benchmarks.harness.model import llama_config
+from benchmarks.harness import cluster, loader, trace_reduce, traffic
 from benchmarks.harness.replica import JAX_SEED_MASK
 
 TRACE_AFTER_STEPS = 3
 TRACE_STEPS = 10
 
 
-def build_program(cfg, mesh, optimizer_spec: dict):
-    """The step by the repo's own SPMD path (as chip_smoke.py builds it)."""
-    import jax
+def build_program(family, cfg, mesh, optimizer_spec: dict):
+    """The step by the repo's own SPMD path (as chip_smoke.py builds it)
+    over the family's model and loss."""
     import jax.numpy as jnp
     import optax
     from jax.sharding import PartitionSpec as P
 
-    from ray_tpu.models.llama import LlamaModel, cross_entropy_loss
     from ray_tpu.train.spmd import make_train_step, shard_train_step
 
     if optimizer_spec["name"] != "adafactor":
         raise ValueError(f"unknown optimizer {optimizer_spec['name']!r}")
-    model = LlamaModel(cfg)
+    model = family.model(cfg)
     optimizer = optax.adafactor(float(optimizer_spec["learning_rate"]))
 
     def init_fn(key):
@@ -40,7 +38,7 @@ def build_program(cfg, mesh, optimizer_spec: dict):
 
     def loss_fn(params, batch):
         inp, tgt = batch
-        return cross_entropy_loss(model.apply(params, inp), tgt)
+        return family.loss(model.apply(params, inp), tgt)
 
     step = make_train_step(loss_fn, optimizer)
     batch_spec = (P(("dp", "fsdp"), None), P(("dp", "fsdp"), None))
@@ -130,12 +128,13 @@ def train_loop(config: dict) -> None:
     sizes, tr, seed = config["sizes"], config["train"], config["seed"]
     mix = config["traffic"]
     seconds, trace = config["seconds"], config["trace"]
-    cfg = llama_config(sizes, attention=tr["attention"], remat=True,
-                       remat_policy=tr["remat_policy"])
+    family = loader.load_family(config["family"], config["root"])
+    cfg = family.program_config(sizes, attention=tr["attention"],
+                                remat=True, remat_policy=tr["remat_policy"])
     devices = jax.devices()[:config["chips"]]
     mesh = make_mesh(MeshConfig(fsdp=len(devices)), devices=devices)
     init_fn, optimizer, batch_spec, sharded_step = build_program(
-        cfg, mesh, tr["optimizer"])
+        family, cfg, mesh, tr["optimizer"])
     key = jax.random.PRNGKey(seed & JAX_SEED_MASK)
     t0 = time.monotonic()
     state, specs, init_params = init_state(mesh, init_fn, optimizer, key)
@@ -210,13 +209,11 @@ def train_loop(config: dict) -> None:
     del state, step, batch
     reference_loss = None
     if config["reference_rows"]:
-        from benchmarks.reference import dense_decoder
-
         params = jax.block_until_ready(init_params(key))
         n = int(config["reference_rows"])
-        reference_loss = dense_decoder.mean_token_loss(
+        reference_loss = family.reference.mean_token_loss(
             params, sizes, first_data[:n, :-1], first_data[:n, 1:])
-        model_loss = _first_rows_loss(cfg, params, first_data[:n])
+        model_loss = _first_rows_loss(family, cfg, params, first_data[:n])
         del params
     else:
         model_loss = None
@@ -238,16 +235,14 @@ def train_loop(config: dict) -> None:
         "log_vocab": math.log(sizes["vocab_size"])})
 
 
-def _first_rows_loss(cfg, params, data) -> float:
+def _first_rows_loss(family, cfg, params, data) -> float:
     """The system's own loss (its model, its loss function, its dtype) on
     the rows the reference saw: the step's loss is the mean over the whole
     batch, the reference is given only some rows."""
     import jax
 
-    from ray_tpu.models.llama import LlamaModel, cross_entropy_loss
-
-    model = LlamaModel(cfg)
-    fn = jax.jit(lambda p, x, y: cross_entropy_loss(model.apply(p, x), y))
+    model = family.model(cfg)
+    fn = jax.jit(lambda p, x, y: family.loss(model.apply(p, x), y))
     return float(fn(params, data[:, :-1], data[:, 1:]))
 
 

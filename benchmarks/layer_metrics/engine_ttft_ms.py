@@ -5,7 +5,10 @@ from benchmarks.harness import stats
 
 LAYER = "engine"
 UNIT = "ms"
-MOVES = "ttft_p90_ms"
+# A prefill holds the device between two decode chunks, so what lengthens
+# this lengthens the slowest streams' time per token; the client's TTFT is
+# recorded per layer (`client_ttft_p90_ms`), too noisy to be judged.
+MOVES = "tpot_p90_ms"
 
 
 def read(obs):

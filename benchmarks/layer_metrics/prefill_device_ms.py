@@ -5,7 +5,10 @@ from benchmarks.harness import stats
 
 LAYER = "model step"
 UNIT = "ms"
-MOVES = "ttft_p90_ms"
+# A prefill holds the device between two decode chunks, so its length is in
+# the slowest streams' time per token; the client's TTFT is recorded per
+# layer (`client_ttft_p90_ms`), too noisy to be judged end to end.
+MOVES = "tpot_p90_ms"
 PROGRAMS = ("prefill_many", "prefill_one")
 
 

@@ -53,6 +53,9 @@ def run_cell(cell: loader.Cell, seed: int, seconds: float, trace: bool,
         # Also where a caller that keeps only the end of stderr finds it.
         print("benchmark: not correct: " + "; ".join(out["problems"]),
               file=sys.stderr, flush=True)
+    # Each number compared beside its limit, in every run.
+    for line in out["checked"]:
+        print("benchmark: compared: " + line, file=sys.stderr, flush=True)
     device = dict(out["device"])
     result = {"correct": out["correct"], "attempted": out["attempted"],
               "failed": out["failed"]}

@@ -9,6 +9,7 @@ import json
 import math
 import os
 import re
+import shutil
 import subprocess
 import sys
 import time
@@ -25,7 +26,8 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 from benchmarks import run as bench_run  # noqa: E402
 from benchmarks.harness import (kernel_costs, loader, stats,  # noqa: E402
                                 trace_reduce, traffic)
-from benchmarks.harness.model import llama_config, model_sizes  # noqa: E402
+from benchmarks.families.dense_decoder import (  # noqa: E402
+    program_config as llama_config, sizes as model_sizes)
 
 BENCH = loader.load_benchmark()
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -57,7 +59,7 @@ def made_up_root(tmp_path):
     with open(os.path.join(_HERE, "cells", "traffic", "tiny-open.json")) as f:
         (cells / "traffic" / "made-up-mix.json").write_text(f.read())
     (cells / "layer_metrics" / "requests_seen.py").write_text(
-        "LAYER = 'serve'\nUNIT = 'requests'\nMOVES = 'ttft_p90_ms'\n\n\n"
+        "LAYER = 'serve'\nUNIT = 'requests'\nMOVES = 'tpot_p90_ms'\n\n\n"
         "def read(obs):\n    return len(obs.get('client_spans', [])) or None\n")
     bench = {
         "command": BENCH["command"], "paths": ["extra"], "run_seconds": 2,
@@ -67,13 +69,13 @@ def made_up_root(tmp_path):
         "workloads": [{"name": "made-up.cell", "config": "made-up",
                        "traffic": "made-up-mix", "chips": 1, "why": "test"}],
         "end_to_end": [dict(m) for m in BENCH["end_to_end"]
-                       if m["name"] in ("ttft_p90_ms", "setup_s")],
+                       if m["name"] in ("tpot_p90_ms", "setup_s")],
         "per_layer": [{"name": "requests_seen", "unit": "requests",
                        "better": "higher", "source": "program_counter",
-                       "layer": "serve", "moves": "ttft_p90_ms"},
+                       "layer": "serve", "moves": "tpot_p90_ms"},
                       {"name": "engine_ttft_ms", "unit": "ms",
                        "better": "lower", "source": "program_span",
-                       "layer": "engine", "moves": "ttft_p90_ms",
+                       "layer": "engine", "moves": "tpot_p90_ms",
                        "workloads": ["another.cell"]}]}
     for m in bench["end_to_end"]:
         m.pop("workloads", None)
@@ -172,17 +174,69 @@ def test_benchmark_json_names_units_and_keys():
 @pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
 def test_configuration_files_say_where_they_come_from(entry):
     conf = _config(entry["name"])
-    for key in ("source", "reduced", "assumed", "deployment", "memory"):
-        assert key in conf, key
     assert conf["source"] == entry["source"]
     assert conf["reduced"] == entry["reduced"]
-    # What is cut is named with its published value; no width is.
-    for key in conf["reduced"]:
-        assert key == "num_hidden_layers"
-        assert conf["published"][key] > conf[key]
-    assert conf["memory"], "the compile's memory report is recorded"
-    cfg = llama_config(model_sizes(conf))
-    assert cfg.head_dim == conf["head_dim"] == 128
+    # The keys a file must have, what may be cut and how far (named with
+    # its published value; no width is), the compile's memory report, and
+    # what the file's family asks of it (`dense_decoder`: heads of 128
+    # that make up the hidden size, only the depth cut).
+    family = loader.load_family(conf.get("family", loader.DEFAULT_FAMILY))
+    loader.check_configuration(conf, family)
+
+
+def _expert_family(**kw):
+    """A family with experts and a layer pattern, as a later PR's might be."""
+    import types
+
+    return types.SimpleNamespace(**{
+        "REDUCIBLE": {"num_hidden_layers", "experts_held", "vocab_size"},
+        "EXPERTS_KEY": "experts_held",
+        "layer_pattern": lambda conf: (conf["leading_dense"],
+                                       conf["period"]),
+        "check_file": lambda conf: None, **kw})
+
+
+def _expert_file(**changes):
+    conf = {"source": "test", "assumed": {}, "memory": {"temporaries": 1},
+            "deployment": {"chips_sharing_a_layer": 8},
+            "hidden_size": 2048, "num_hidden_layers": 7, "leading_dense": 1,
+            "period": 3, "experts_held": 8, "vocab_size": 16384,
+            "reduced": ["num_hidden_layers", "experts_held", "vocab_size"],
+            "published": {"num_hidden_layers": 27, "experts_held": 64,
+                          "vocab_size": 131072}}
+    conf.update(changes)
+    return conf
+
+
+def test_a_cut_configuration_that_keeps_to_the_guide_passes():
+    loader.check_configuration(_expert_file(), _expert_family())
+
+
+@pytest.mark.parametrize("changes, why", [
+    ({"reduced": ["hidden_size"], "published": {"hidden_size": 4096}},
+     "lets only"),
+    ({"published": {"num_hidden_layers": 7, "experts_held": 64,
+                    "vocab_size": 131072}}, "not under its published"),
+    ({"num_hidden_layers": 4}, "no whole period"),
+    ({"num_hidden_layers": 6, "period": 6}, "no whole period"),
+    ({"experts_held": 4}, "the floor is 8"),
+    ({"vocab_size": 8192}, "under an eighth"),
+    ({"deployment": "one chip of a fleet"}, "chips_sharing_a_layer"),
+    ({"memory": {}}, "memory report"),
+], ids=["a-width-cut", "not-under-published", "under-four-layers",
+        "no-whole-period", "under-eight-experts", "thin-vocabulary",
+        "no-deployment-share", "no-memory-report"])
+def test_a_configuration_that_breaks_a_rule_is_refused(changes, why):
+    with pytest.raises(loader.BenchmarkError, match=why):
+        loader.check_configuration(_expert_file(**changes),
+                                   _expert_family())
+
+
+def test_a_file_is_held_to_its_familys_own_rule():
+    conf = dict(_config("mistral-7b-v0.3-l16"), head_dim=64,
+                num_attention_heads=64)
+    with pytest.raises(ValueError, match="heads of 64"):
+        loader.check_configuration(conf, loader.load_family("dense_decoder"))
 
 
 # ---- traffic ----------------------------------------------------------------
@@ -547,11 +601,51 @@ def test_the_command_refuses_to_measure_without_a_tpu():
 # ---- one CPU rehearsal: the drivers end to end at TINY widths -----------------
 
 
+# The rehearsal's cells by the kind of their mix: the TINY configuration
+# under each driver, and a made-up family's under two of them.
+TINY_OF_KIND = {"serve_open": ["tiny.open", "other.open", "miswired.open"],
+                "serve_closed": ["tiny.closed"],
+                "train_fit": ["tiny.train", "other.train"]}
+
+
+def _stand_in_for_the_cells(bench, cells=None):
+    """Each rehearsal cell reports what the cells of its kind report: every
+    cell of `cells` (BENCHMARK.json's `workloads`) is stood in for by the
+    tiny cells of its mix's kind, so a metric may list several cells of
+    one kind."""
+    stand_in = {}
+    for w in cells or BENCH["workloads"]:
+        with open(loader.find_file(BENCH, _REPO, "traffic",
+                                   w["traffic"] + ".json")) as f:
+            stand_in[w["name"]] = TINY_OF_KIND.get(json.load(f)["kind"], [])
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "workloads" in m:
+            m["workloads"] = sorted({t for w in m["workloads"]
+                                     for t in stand_in[w]})
+    return stand_in
+
+
+def test_a_metric_may_list_two_cells_of_one_kind():
+    cells = BENCH["workloads"] + [
+        dict(BENCH["workloads"][0], name="a-second-open-loop-cell")]
+    bench = json.loads(json.dumps(BENCH))
+    listed = [m for m in bench["end_to_end"] + bench["per_layer"]
+              if CELLS[0] in m.get("workloads", [])]
+    for m in listed:
+        m["workloads"].append("a-second-open-loop-cell")
+    stand_in = _stand_in_for_the_cells(bench, cells)
+    assert stand_in["a-second-open-loop-cell"] == stand_in[CELLS[0]]
+    assert listed and all(
+        m["workloads"] == sorted(stand_in[CELLS[0]]) for m in listed)
+
+
 @pytest.fixture(scope="module")
 def rehearsal(tmp_path_factory):
     """One in-process cluster that offers `TPU: 1` (conftest's seam gives
     such a lease-holder the CPU), and a benchmark whose cells are the
-    test-only TINY configuration under the harness's own three drivers."""
+    test-only TINY configuration under the harness's own three drivers,
+    and a made-up family's configuration (its files copied to a directory
+    of `paths` of their own) under two of them."""
     import ray_tpu
     from tests.conftest import _fast_config
 
@@ -559,22 +653,29 @@ def rehearsal(tmp_path_factory):
     mixes = {"open": "tiny-open", "closed": "tiny-closed",
              "train": "tiny-packed"}
     bench = json.loads(json.dumps(BENCH))
-    bench["paths"] = ["tests/benchmarks/cells"]
+    bench["paths"] = ["tests/benchmarks/cells", "extra"]
     bench["configs"] = [{"name": "tiny", "source": "test", "reduced": [],
                          "file": "tests/benchmarks/cells/configs/tiny.json",
-                         "why": "test"}]
+                         "why": "test"},
+                        {"name": "other-tiny", "source": "test",
+                         "reduced": ["n_layer"], "why": "test",
+                         "file": "extra/configs/other-tiny.json"},
+                        {"name": "miswired-tiny", "source": "test",
+                         "reduced": ["n_layer"], "why": "test",
+                         "file": "extra/configs/miswired-tiny.json"}]
     bench["workloads"] = [{"name": "tiny." + k, "config": "tiny",
                            "traffic": v, "chips": 1, "why": "rehearsal"}
                           for k, v in mixes.items()]
-    # Each rehearsal cell reports what the cell of its kind reports.
-    kinds = {loader.load_cell(w["name"]).traffic["kind"]: w["name"]
-             for w in BENCH["workloads"]}
-    stand_in = {kinds["serve_open"]: "tiny.open",
-                kinds["serve_closed"]: "tiny.closed",
-                kinds["train_fit"]: "tiny.train"}
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if "workloads" in m:
-            m["workloads"] = [stand_in[w] for w in m["workloads"]]
+    bench["workloads"] += [{"name": "other." + k, "config": "other-tiny",
+                            "traffic": "other-" + v, "chips": 1,
+                            "why": "a made-up family"}
+                           for k, v in (("open", "open"),
+                                        ("train", "packed"))]
+    bench["workloads"].append(
+        {"name": "miswired.open", "config": "miswired-tiny",
+         "traffic": "other-open", "chips": 1, "why": "a broken timed path"})
+    shutil.copytree(os.path.join(_HERE, "made_up"), root / "extra")
+    _stand_in_for_the_cells(bench)
     os.symlink(os.path.join(_REPO, "tests"), root / "tests")
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     ray_tpu.init(num_cpus=4, resources={"TPU": 1}, config=_fast_config())
@@ -606,7 +707,10 @@ def test_rehearsal_serve_traced(rehearsal):
     cell, result, lines = _rehearse(rehearsal, "tiny.open", True)
     # Per-layer metrics that need no device trace are read from the spans.
     assert {"worker_ready_s", "handle_overhead_ms", "engine_ttft_ms",
-            "batch_occupancy"} <= set(result["metrics"])
+            "client_ttft_p90_ms", "batch_occupancy"} <= set(result["metrics"])
+    # The client's TTFT is a per-layer reading, no longer judged end to end.
+    assert result["metrics"]["client_ttft_p90_ms"]["value"] > 0
+    assert "ttft_p90_ms" not in {m["name"] for m in cell.end_to_end}
     assert 0 < result["metrics"]["batch_occupancy"]["value"] <= 100
     load = next(ln for ln in lines if ln.get("phase") == "load")
     assert load["compiles_in_window"] == 0
@@ -631,6 +735,60 @@ def test_rehearsal_train(rehearsal):
     assert load["reports_received"] == load["steps"] == result["attempted"]
     assert load["reference_loss"] == pytest.approx(load["model_loss"],
                                                    abs=1e-3)
+
+
+def test_a_made_up_family_is_found_from_files_alone(rehearsal):
+    """A family that no file of `benchmarks/` knows: the same decoder under
+    other key names, heads of 64, a tied head; its family file, its
+    configuration, its reference and its mixes lie in a directory of
+    `paths` of their own."""
+    cell = loader.load_cell("other.open", rehearsal)
+    assert cell.family_name == cell.config["family"] == "other_decoder"
+    assert cell.family.__file__ == os.path.join(
+        rehearsal, "extra", "families", "other_decoder.py")
+    assert cell.family.reference.__file__ == os.path.join(
+        rehearsal, "extra", "reference", "other_decoder_ref.py")
+    sizes = cell.family.sizes(cell.config)
+    assert sizes["vocab_size"] == 256 and "hidden_size" not in sizes
+    assert cell.family.program_config(sizes).head_dim == 64
+    # The general rules hold of it, and its family's own: the harness's
+    # family refuses the same file (it has none of its keys).
+    loader.check_configuration(cell.config, cell.family)
+    with pytest.raises(KeyError):
+        loader.load_family("dense_decoder").check_file(cell.config)
+    with pytest.raises(loader.BenchmarkError, match="families/nowhere.py"):
+        loader.load_family("nowhere", rehearsal)
+
+
+@pytest.mark.parametrize("name", ["other.open", "other.train"])
+def test_a_made_up_family_rehearses(rehearsal, name):
+    cell, result, lines = _rehearse(rehearsal, name, False)
+    assert set(result["metrics"]) == {m["name"] for m in cell.end_to_end}
+    load = next(ln for ln in lines if ln.get("phase") == "load")
+    if name == "other.open":
+        assert load["reference"] and all(
+            c["max_logit_gap"] == 0.0 for c in load["reference"])
+    else:
+        assert load["reference_loss"] == pytest.approx(load["model_loss"],
+                                                       abs=1e-3)
+
+
+def test_a_run_whose_timed_path_is_broken_is_not_correct(rehearsal):
+    """The whole of a run but the look for a chip, over an engine built with
+    another rotary base than the model's: every request completes, the
+    streams are fluent, and `correct` comes out false with the gaps."""
+    lines = []
+    cell = loader.load_cell("miswired.open", rehearsal)
+    result = bench_run.run_cell(
+        cell, 2 ** 31 + 7, 2.0, False, time.monotonic(), platform="cpu",
+        log=lambda **kw: lines.append(kw))
+    assert result["correct"] is False and result["failed"] == 0
+    problems = next(ln for ln in lines if ln.get("phase") == "check")
+    assert any("under the reference's best logit" in p
+               for p in problems["problems"])
+    load = next(ln for ln in lines if ln.get("phase") == "load")
+    assert all(c["over"] and c["kept_max_gap"] > 3 * 0.125
+               for c in load["reference"])
 
 
 def test_rehearsal_refuses_another_platform(rehearsal):
