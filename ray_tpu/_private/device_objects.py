@@ -357,23 +357,24 @@ def export_device_object_gauges() -> dict:
 def tree_map(value, fn, is_leaf):
     """Minimal pytree map over dict/list/tuple/namedtuple containers:
     the ONE traversal shared by extraction, resolution, and consumers
-    (a fifth hand-rolled walker is how container-type fixes diverge)."""
+    (a fifth hand-rolled walker is how container-type fixes diverge).
 
-    def walk(v):
-        if is_leaf(v):
-            return fn(v)
-        if isinstance(v, dict):
-            return {k: walk(x) for k, x in v.items()}
-        if isinstance(v, tuple):
-            walked = tuple(walk(x) for x in v)
-            if type(v) is not tuple and hasattr(v, "_fields"):
-                return type(v)(*walked)  # namedtuple
-            return walked
-        if isinstance(v, list):
-            return [walk(x) for x in v]
-        return v
-
-    return walk(value)
+    It recurses through its own module-level name, not through an inner
+    function that closes over itself: such a closure is a reference
+    cycle, and through `fn` it kept every resolved array (a prefill's
+    whole KV) alive until Python's cycle collector happened to run."""
+    if is_leaf(value):
+        return fn(value)
+    if isinstance(value, dict):
+        return {k: tree_map(x, fn, is_leaf) for k, x in value.items()}
+    if isinstance(value, tuple):
+        walked = tuple(tree_map(x, fn, is_leaf) for x in value)
+        if type(value) is not tuple and hasattr(value, "_fields"):
+            return type(value)(*walked)  # namedtuple
+        return walked
+    if isinstance(value, list):
+        return [tree_map(x, fn, is_leaf) for x in value]
+    return value
 
 
 def extract_arrays(value, prefix: str, cw=None):

@@ -44,6 +44,12 @@ from ray_tpu.models.encoder import (
     seq2seq_loss,
 )
 from ray_tpu.models.generate import Generator, SamplingParams, generate
+from ray_tpu.models.sambay import (
+    PHI4_MINI_FLASH,
+    TINY_SAMBAY,
+    SambaYConfig,
+    SambaYModel,
+)
 from ray_tpu.models.ssm import (
     MAMBA_130M,
     MAMBA_790M,
@@ -51,6 +57,7 @@ from ray_tpu.models.ssm import (
     SSM_RULES,
     SSMConfig,
     SSMModel,
+    chunked_selective_scan,
     init_ssm_state,
     ssm_decode_step,
     ssm_prefill,
@@ -77,4 +84,6 @@ __all__ = [
     "TINY_ENCDEC", "seq2seq_loss",
     "SSMModel", "SSMConfig", "MAMBA_130M", "MAMBA_790M", "TINY_SSM",
     "SSM_RULES", "init_ssm_state", "ssm_decode_step", "ssm_prefill",
+    "chunked_selective_scan",
+    "SambaYModel", "SambaYConfig", "PHI4_MINI_FLASH", "TINY_SAMBAY",
 ]

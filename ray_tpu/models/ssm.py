@@ -4,10 +4,17 @@ Rounds out the model zoo with the SSM architecture class. The TPU-native
 angle: the recurrence h_t = a_t * h_{t-1} + b_t is evaluated with
 `jax.lax.associative_scan` — O(log S) depth parallel prefix instead of a
 sequential loop, which is the difference between MXU/VPU-friendly and
-latency-bound on TPU. Decode is O(1) per token: `init_ssm_state` /
-`ssm_decode_step` carry the per-layer SSM state (E,N) and the depthwise
-conv window (d_conv-1, E) — the SSM advantage over attention's O(S)
-KV cache.
+latency-bound on TPU (`_selective_scan`; it materialises (B, S, E, N)
+float32 decay, drive and state: 5.4 GB each for one 16k prompt at E
+5120). The models run `chunked_selective_scan` instead: a `lax.scan`
+over the sequence whose body is a chunk of steps unrolled, so that only
+the state (B, N, E) and a chunk's inputs are live, each step is
+contracted with C at once, and the compiler fuses a chunk into a few
+passes; on the v5e that was 3 to 15 times faster than the prefix, which
+is bound by the memory it writes. Decode
+is O(1) per token: `init_ssm_state` / `ssm_decode_step` carry the
+per-layer SSM state (E,N) and the depthwise conv window (d_conv-1, E) —
+the SSM advantage over attention's O(S) KV cache.
 
 Structure follows the Mamba block shape (Gu & Dao 2023, public
 architecture): in-proj to a gated pair, short depthwise causal conv,
@@ -61,6 +68,49 @@ def _selective_scan(a, b):
     return h
 
 
+# Positions one iteration of the scan's loop advances: its body is that many
+# steps unrolled, fused by the compiler into a few passes over the state. On
+# the v5e, E 5120 x N 16: 14.6 ms for 16,384 positions at 32, 21.8 ms at 8,
+# 46 ms at 1; a parallel prefix over chunks of 64 took 40 ms for one row and
+# 224 ms for eight rows of 2,048 (my chip runs, PR 28).
+_SCAN_CHUNK = 32
+
+
+def chunked_selective_scan(delta, u, b_sel, c_sel, a, h0=None, *,
+                           chunk: int = _SCAN_CHUNK):
+    """The selective scan of a Mamba-1 layer, a chunk of the sequence at a
+    time:  h_t = exp(delta_t * A) * h_{t-1} + (delta_t * u_t) (x) B_t,
+    y_t = h_t . C_t.
+
+    delta, u: (B, S, E); b_sel, c_sel: (B, S, N); a: (E, N), negative;
+    h0: (B, N, E) float32 or None (zeros). Returns y (B, S, E) float32
+    and the state after the last position, (B, N, E) float32. A position
+    whose delta is 0 leaves the state as it was (decay 1, drive 0): that
+    is how a right-padded row keeps the state of its own last token.
+
+    Only the state (B, N, E) and one chunk's inputs are live at a time:
+    each step is contracted with C as soon as it is made, and the state
+    crosses from chunk to chunk as the carry of a `lax.scan` whose body is
+    `chunk` steps. E is the minor axis (the lanes; N = 16 there would be
+    padded eightfold).
+    """
+    B, S, E = delta.shape
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    a_t = f32(a).T                                  # (N, E)
+    h0 = jnp.zeros((B, a.shape[1], E), jnp.float32) if h0 is None else f32(h0)
+
+    def step(h, xs):
+        d, x, bs, cs = (f32(v) for v in xs)
+        h = jnp.exp(d[:, None, :] * a_t) * h \
+            + (d * x)[:, None, :] * bs[:, :, None]
+        return h, jnp.einsum("bne,bn->be", h, cs)
+
+    h_last, y = jax.lax.scan(
+        step, h0, tuple(x.swapaxes(0, 1) for x in (delta, u, b_sel, c_sel)),
+        unroll=max(1, min(chunk, S)))
+    return y.swapaxes(0, 1), h_last
+
+
 class SSMBlock(nn.Module):
     cfg: SSMConfig
 
@@ -111,16 +161,18 @@ class SSMBlock(nn.Module):
                            jnp.float32)
         A = -jnp.exp(a_log)                                          # (E,N)
 
-        d32 = delta.astype(jnp.float32)
-        decay = jnp.exp(d32[..., None] * A[None, None])              # (B,S,E,N)
-        drive = (d32 * u.astype(jnp.float32))[..., None] * \
-            Bsel.astype(jnp.float32)[:, :, None, :]                  # (B,S,E,N)
         if state is None:
-            h = _selective_scan(decay, drive)                        # (B,S,E,N)
+            # (B,S,E,N) is never whole in memory: a chunk at a time.
+            y, h_last = chunked_selective_scan(delta, u, Bsel, Csel, A)
+            h_last = h_last.swapaxes(1, 2)                           # (B,E,N)
         else:
+            d32 = delta.astype(jnp.float32)
+            decay = jnp.exp(d32[..., None] * A[None, None])          # (B,1,E,N)
+            drive = (d32 * u.astype(jnp.float32))[..., None] * \
+                Bsel.astype(jnp.float32)[:, :, None, :]
             h_new = decay[:, 0] * h_prev + drive[:, 0]               # (B,E,N)
-            h = h_new[:, None]
-        y = jnp.einsum("bsen,bsn->bse", h, Csel.astype(jnp.float32))
+            y = jnp.einsum("bsen,bsn->bse", h_new[:, None],
+                           Csel.astype(jnp.float32))
         D = self.param("d_skip", nn.initializers.ones, (E,), jnp.float32)
         y = (y + D[None, None] * u.astype(jnp.float32)).astype(c.dtype)
 
@@ -129,7 +181,7 @@ class SSMBlock(nn.Module):
         if state is not None:
             return out, (window[:, 1:], h_new)
         if return_state:
-            return out, (window, h[:, -1])
+            return out, (window, h_last)
         return out
 
 

@@ -27,6 +27,13 @@ behind Serve deployments); this engine is native and TPU-shaped:
   defers requests when the pool is dry and pages return to the free
   list the moment a stream completes. page_size=0 keeps the dense
   per-slot max_len caches.
+- **One seam to the model** (`serve/llm_families.py`): what a sequence's
+  state is (which parts are paged, which are fixed per slot), prefill ->
+  state at each row's last token, one decode step over the state. The
+  engine compiles the family's functions under its own program names
+  and knows no architecture: a rotary GQA decoder (a pool a layer) and
+  a hybrid of state-space, window and shared-cache layers (pages of one
+  layer, rings, recurrent state) run through the same loop.
 """
 
 from __future__ import annotations
@@ -41,7 +48,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ray_tpu.models.generate import SamplingParams
-from ray_tpu.models.llama import LlamaConfig, LlamaModel, init_kv_caches
+from ray_tpu.serve.llm_families import family_of
 
 logger = logging.getLogger(__name__)
 
@@ -116,6 +123,11 @@ class RequestHandle:
     def backlog_full(self) -> bool:
         return self._q.full()
 
+    def room(self) -> int:
+        """Tokens the queue takes now without parking (it only grows:
+        the engine's loop is the one producer)."""
+        return self._q.maxsize - self._q.qsize()
+
     def __iter__(self):
         while True:
             try:
@@ -142,9 +154,10 @@ class RequestHandle:
 
 
 class LLMEngine:
-    """Slot-based continuous-batching engine over a Llama-family model."""
+    """Slot-based continuous-batching engine over any model family that
+    `serve/llm_families.py` knows (`cfg`: that family's configuration)."""
 
-    def __init__(self, cfg: LlamaConfig, params, *, max_batch: int = 4,
+    def __init__(self, cfg, params, *, max_batch: int = 4,
                  max_len: int = 1024, decode_chunk: int = 8,
                  prefill_chunk: int = 0, rng_seed: int = 0,
                  page_size: int = 0, kv_pool_tokens: int = 0,
@@ -175,6 +188,9 @@ class LLMEngine:
         # warm-up out) by difference.
         self.paged_pages_live = 0
         self.paged_pages_table = 0
+        # Slots whose fixed per-slot state (rings, recurrent state) an
+        # admission replaced with what its own prefill computed from zero.
+        self.state_slots_reset = 0
         # Paged KV mode (page_size > 0): admission is bounded by POOL
         # pages (resident tokens), not slot count x max_len.
         self.page_size = page_size
@@ -195,84 +211,63 @@ class LLMEngine:
         # decode ticks, so one long prompt cannot stall every in-flight
         # stream for its whole prefill. 0: whole-prompt bucketed prefill.
         self.prefill_chunk = prefill_chunk
-        self.model = LlamaModel(cfg)
+        # What the model tells the engine (serve/llm_families.py).
+        self.family = family = family_of(cfg, max_len)
+        if not page_size and not family.dense:
+            raise ValueError(
+                f"{type(cfg).__name__} keeps pages beside per-slot state: "
+                "it is served paged (page_size > 0)")
+        self.model = family.model
         self._jax, self._jnp = jax, jnp
         self._rng = jax.random.PRNGKey(rng_seed)
 
-        model = self.model
-
         # ---- compiled programs ------------------------------------------
-
-        max_len_ = max_len
-        cfg_ = cfg
-
-        @jax.jit
-        def prefill_one(params, tokens):
-            # tokens: (1, bucket) right-padded. Cache entries past the true
-            # prompt length hold garbage, but decode masks keys by position
-            # (kpos <= qpos) and overwrites index `cache_len` before each
-            # attention, so they are never attended.
-            positions = jnp.arange(tokens.shape[1])[None, :]
-            caches1 = init_kv_caches(cfg_, 1, max_len_)
-            logits, new = model.apply(params, tokens, positions,
-                                      kv_caches=caches1)
-            return logits[0], [(k[0], v[0]) for k, v, _l in new]
+        # The family's functions under the engine's own program names (a
+        # profile is read by them): `prefill_one` and `prefill_many` are
+        # one function at two widths.
 
         import functools
 
-        @functools.partial(jax.jit, donate_argnums=(3,))
-        def prefill_chunk(params, tokens, start, kv_full, slot):
-            # One CHUNK of a long prompt: tokens (1, chunk) at absolute
-            # positions start..start+chunk, KV written at the same offset
-            # of slot `slot`'s cache. Gather/scatter of the slot row stays
-            # INSIDE the jit with the full cache donated, so a chunk costs
-            # one row update, not a full multi-slot cache copy per tick.
-            C = tokens.shape[1]
-            positions = start + jnp.arange(C)[None, :]
-            caches1 = [
-                (jax.lax.dynamic_slice_in_dim(k, slot, 1, axis=0),
-                 jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=0), start)
-                for k, v in kv_full]
-            logits, new = model.apply(params, tokens, positions,
-                                      kv_caches=caches1)
-            out_kv = [
-                (jax.lax.dynamic_update_slice_in_dim(kf, kn, slot, axis=0),
-                 jax.lax.dynamic_update_slice_in_dim(vf, vn, slot, axis=0))
-                for (kf, vf), (kn, vn, _l) in zip(kv_full, new)]
-            return logits[0], out_kv
+        @jax.jit
+        def prefill_one(params, tokens, last_idx):
+            # tokens: (1, bucket) right-padded; last_idx: (1,)
+            return family.prefill(params, tokens, last_idx)
 
-        self._prefill_chunk = prefill_chunk
-
-        def _decode_one(params, token, pos, kv, lens):
-            # One sequence: token (), pos (), kv list of ((Hkv,L,D) k, v),
-            # lens () — the slot's private write offset.
-            caches1 = [(k[None], v[None], lens) for k, v in kv]
-            logits, new = model.apply(params, token[None, None],
-                                      pos[None, None], kv_caches=caches1)
-            return logits[0, 0], [(k[0], v[0]) for k, v, _l in new]
-
-        # vmap: slots advance at DIFFERENT offsets in the same program.
-        decode_step = jax.vmap(_decode_one, in_axes=(None, 0, 0, 0, 0))
+        if family.dense:
+            self._prefill_chunk = functools.partial(
+                jax.jit, donate_argnums=(3,))(family.prefill_chunk)
+            decode_step = family.decode_dense
 
         V = cfg.vocab_size
 
         def _sample(logits, temps, top_ks, top_ps, rng):
             # Per-slot temperature / top-k / top-p, fully vectorized
             # (matches models/generate.sample_logits semantics per row;
-            # top_ks==0 and top_ps==1 disable the truncations).
+            # top_ks==0 and top_ps==1 disable the truncations). A batch
+            # whose rows are all greedy is an argmax and nothing else:
+            # the sort over the whole vocabulary was 20% of the device's
+            # time at 32 x 200,064 (my chip run, PR 28), 3.4% at 32,768.
             greedy = jnp.argmax(logits, axis=-1)
-            scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-            sorted_l = jnp.sort(scaled, axis=-1)[:, ::-1]
-            k_idx = jnp.clip(jnp.where(top_ks > 0, top_ks, V) - 1, 0, V - 1)
-            kth = jnp.take_along_axis(sorted_l, k_idx[:, None], axis=-1)
-            scaled = jnp.where(scaled < kth, -1e30, scaled)
-            probs = jax.nn.softmax(sorted_l, axis=-1)
-            cum = jnp.cumsum(probs, axis=-1)
-            cut_idx = jnp.clip(jnp.sum(cum < top_ps[:, None], axis=-1), 0, V - 1)
-            cutoff = jnp.take_along_axis(sorted_l, cut_idx[:, None], axis=-1)
-            scaled = jnp.where(scaled < cutoff, -1e30, scaled)
-            sampled = jax.random.categorical(rng, scaled, axis=-1)
-            return jnp.where(temps <= 0.0, greedy, sampled)
+
+            def sampled():
+                scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+                sorted_l = jnp.sort(scaled, axis=-1)[:, ::-1]
+                k_idx = jnp.clip(jnp.where(top_ks > 0, top_ks, V) - 1, 0,
+                                 V - 1)
+                kth = jnp.take_along_axis(sorted_l, k_idx[:, None], axis=-1)
+                cut = jnp.where(scaled < kth, -1e30, scaled)
+                probs = jax.nn.softmax(sorted_l, axis=-1)
+                cum = jnp.cumsum(probs, axis=-1)
+                cut_idx = jnp.clip(jnp.sum(cum < top_ps[:, None], axis=-1),
+                                   0, V - 1)
+                cutoff = jnp.take_along_axis(sorted_l, cut_idx[:, None],
+                                             axis=-1)
+                cut = jnp.where(cut < cutoff, -1e30, cut)
+                drawn = jax.random.categorical(rng, cut, axis=-1)
+                return jnp.where(temps <= 0.0, greedy, drawn)
+
+            return jax.lax.cond(jnp.all(temps <= 0.0), lambda: greedy,
+                                sampled)
 
         K = self.decode_chunk
 
@@ -293,16 +288,15 @@ class LLMEngine:
 
         # Donating the caches makes each chunk update KV in place instead
         # of copying the full (B,Hkv,L,D)·2·layers working set through HBM.
-        self._decode_chunk_fn = jax.jit(decode_chunk_fn, donate_argnums=(3,))
+        if family.dense:
+            self._decode_chunk_fn = jax.jit(decode_chunk_fn,
+                                            donate_argnums=(3,))
         self._sample = jax.jit(_sample)
         self._prefill_one = prefill_one
 
         # ---- paged-mode programs ----------------------------------------
 
         if page_size:
-            from ray_tpu.models.llama import PagedKVCache
-            from ray_tpu.ops.paged_attention import PageAllocator
-
             # Overshoot margin: a chunk of K steps may run up to K-1
             # tokens past a stream's max_new before the host notices eos.
             pool_tokens = kv_pool_tokens or max_batch * (max_len + K)
@@ -312,17 +306,22 @@ class LLMEngine:
             self._init_paged_state()
 
             def decode_chunk_paged(params, token, pos, pools, tables, lens,
-                                   temps, top_ks, top_ps, base_rng):
+                                   temps, top_ks, top_ps, base_rng,
+                                   steps=None):
+                # `steps` (B,), where the family's state cannot be rewound:
+                # a slot advances that many steps of the chunk and is held
+                # still after them (a parked or an empty slot: 0).
                 def body(carry, i):
                     token, pos, pools, lens = carry
-                    caches = [PagedKVCache(k, v, tables, lens)
-                              for (k, v) in pools]
-                    logits, new = model.apply(params, token[:, None],
-                                              pos[:, None], kv_caches=caches)
-                    pools2 = [(c.k_pool, c.v_pool) for c in new]
-                    tok = _sample(logits[:, 0], temps, top_ks, top_ps,
+                    live = None if steps is None else i < steps
+                    logits, pools2 = family.decode(params, token, pos, pools,
+                                                   tables, lens, live)
+                    tok = _sample(logits, temps, top_ks, top_ps,
                                   jax.random.fold_in(base_rng, i))
-                    return (tok, pos + 1, pools2, lens + 1), tok
+                    if live is None:
+                        return (tok, pos + 1, pools2, lens + 1), tok
+                    return (jnp.where(live, tok, token), pos + live, pools2,
+                            lens + live), tok
 
                 (token, pos, pools, lens), toks = jax.lax.scan(
                     body, (token, pos, pools, lens), jnp.arange(K))
@@ -331,74 +330,31 @@ class LLMEngine:
             self._decode_chunk_paged = jax.jit(decode_chunk_paged,
                                                donate_argnums=(3,))
 
-            ps_ = page_size
-
-            @functools.partial(jax.jit, donate_argnums=(0,))
-            def write_prompt_pages(pools, kv_one, page_ids):
-                # Scatter a bucketed prefill's (Hkv, max_len, D) caches
-                # into pool pages (pool layout (P, Hkv, page, D)).
-                # page_ids rows past the prompt point at the dummy page
-                # (garbage there is fine).
-                out = []
-                for (kp, vp), (k1, v1) in zip(pools, kv_one):
-                    Hkv_, L_, D_ = k1.shape
-                    kpg = k1.reshape(Hkv_, L_ // ps_, ps_, D_).transpose(
-                        1, 0, 2, 3)
-                    vpg = v1.reshape(Hkv_, L_ // ps_, ps_, D_).transpose(
-                        1, 0, 2, 3)
-                    out.append((kp.at[page_ids].set(kpg),
-                                vp.at[page_ids].set(vpg)))
-                return out
-
-            self._write_prompt_pages = write_prompt_pages
-
             # ---- batched prefill admission --------------------------------
             # Sequential slot prefills dominate end-to-end serving at
             # large batch (each is a full program dispatch). When several
             # same-bucket
             # requests are pending, ONE (W, bucket) prefill serves all of
-            # them. W is FIXED (padding with rows that scatter into the
-            # dummy page) so exactly one extra program per bucket
-            # compiles, regardless of arrival pattern.
-            self._batch_prefill_width = min(8, max_batch)
+            # them. W is FIXED for a bucket (padding with rows that
+            # scatter into the dummy page) so exactly one extra program
+            # per bucket compiles, regardless of arrival pattern; the
+            # family says how many rows a bucket takes, at most this many.
+            self._batch_prefill_width = family.prefill_width(
+                page_size, max_batch)
 
             @jax.jit
             def prefill_many(params, tokens, last_idx):
                 # tokens: (W, bucket) right-padded; last_idx: (W,) index
                 # of each row's last prompt token. Returns the last-token
-                # logits row per sequence (gathered INSIDE jit: the full
-                # (W, bucket, vocab) logits never reach the host) and the
-                # per-layer (W, Hkv, L, D) caches.
-                positions = jnp.arange(tokens.shape[1])[None, :]
-                caches = init_kv_caches(cfg_, tokens.shape[0], max_len_)
-                logits, new = model.apply(params, tokens, positions,
-                                          kv_caches=caches)
-                last = jnp.take_along_axis(
-                    logits, last_idx[:, None, None], axis=1)[:, 0]
-                return last, [(k, v) for k, v, _l in new]
+                # logits row per sequence and the rows' fresh state.
+                return family.prefill(params, tokens, last_idx)
 
             self._prefill_many = prefill_many
 
-            @functools.partial(jax.jit, donate_argnums=(0,))
-            def write_prompt_pages_many(pools, kv_many, page_ids):
-                # Batched variant of write_prompt_pages: kv_many per
-                # layer (W, Hkv, L, D), page_ids (W, L/ps). Rows flatten
-                # into one scatter; padding rows target the dummy page.
-                out = []
-                flat = page_ids.reshape(-1)
-                for (kp, vp), (k1, v1) in zip(pools, kv_many):
-                    W_, Hkv_, L_, D_ = k1.shape
-                    kpg = k1.reshape(W_, Hkv_, L_ // ps_, ps_, D_) \
-                        .transpose(0, 2, 1, 3, 4) \
-                        .reshape(-1, Hkv_, ps_, D_)
-                    vpg = v1.reshape(W_, Hkv_, L_ // ps_, ps_, D_) \
-                        .transpose(0, 2, 1, 3, 4) \
-                        .reshape(-1, Hkv_, ps_, D_)
-                    out.append((kp.at[flat].set(kpg),
-                                vp.at[flat].set(vpg)))
-                return out
-
-            self._write_prompt_pages_many = write_prompt_pages_many
+            # The rows' fresh state into the engine's: paged parts to
+            # `page_ids` (W, n), fixed parts to `slots` (W,).
+            self._write_prompt_pages = functools.partial(
+                jax.jit, donate_argnums=(0,))(family.write_prompt)
             self._deferred: list = []  # pool-dry admissions, FIFO retry
 
         # ---- engine state (host-managed; device caches stacked by slot) --
@@ -406,8 +362,7 @@ class LLMEngine:
         if page_size:
             self._kv = None  # paged mode: pools above replace slot caches
         else:
-            proto = init_kv_caches(cfg, max_batch, max_len)
-            self._kv = [(k, v) for k, v, _l in proto]  # [(B,Hkv,L,D)] / layer
+            self._kv = family.init_dense(max_batch)  # [(B,Hkv,L,D)] / layer
         self._lens = np.zeros(max_batch, np.int32)
         self._token = np.zeros(max_batch, np.int32)
         self._pos = np.zeros(max_batch, np.int32)
@@ -459,6 +414,7 @@ class LLMEngine:
         prefill pool, or a drain-evacuated stream being resumed): the
         KV prefix lands in a free slot and decoding continues from
         `pack.token` without re-running prefill here."""
+        self._require_portable_cache("submit_prefilled")
         sp = sampling or SamplingParams()
         budget = sp.max_new_tokens - pack.generated
         if budget <= 0:
@@ -471,6 +427,13 @@ class LLMEngine:
                                max_buffered=self._stream_buffer, tag=tag)
         self._pending.put((pack, handle))
         return handle
+
+    def _require_portable_cache(self, what: str) -> None:
+        if not self.family.dense:
+            raise NotImplementedError(
+                f"{what}: a {type(self.cfg).__name__} stream's state is "
+                "pages of one layer, rings and recurrent state, not a "
+                "per-layer KV prefix; it is prefilled where it decodes")
 
     def generate(self, prompt_tokens,
                  sampling: SamplingParams | None = None) -> list[int]:
@@ -506,6 +469,17 @@ class LLMEngine:
             "paged_pages_table": float(self.paged_pages_table),
             "paged_live_share": (self.paged_pages_live
                                  / max(1, self.paged_pages_table)),
+            # Where several layers read ONE pool through one table row
+            # (a shared key-value cache): pages fetched by all of them.
+            "shared_pool_pages_live": float(
+                self.paged_pages_live * self.family.pool_readers),
+            "shared_pool_pages_table": float(
+                self.paged_pages_table * self.family.pool_readers),
+            # Fixed per-slot state: tokens held in the window layers'
+            # rings now, and slots an admission has reset so far.
+            "ring_tokens": float(self.family.ring_tokens(
+                self._lens[[s.request is not None for s in self._slots]])),
+            "state_slots_reset": float(self.state_slots_reset),
             "ttft_p50_ms": pick(0.5) * 1e3,
             "ttft_p99_ms": pick(0.99) * 1e3,
         }
@@ -525,6 +499,7 @@ class LLMEngine:
         quiesce first. Keyed by the handle's tag; each value holds the
         trimmed per-layer KV (numpy) and the full decode cursor, enough
         to rebuild the stream via submit_prefilled on another replica."""
+        self._require_portable_cache("snapshot_active_streams")
         out: dict = {}
         for i, st in enumerate(self._slots):
             h = st.request
@@ -637,15 +612,16 @@ class LLMEngine:
         bucket = self._bucket(len(prompt))
         padded = np.zeros((1, bucket), np.int32)
         padded[0, : len(prompt)] = prompt
-        logits, kv_one = self._prefill_one(self.params, jnp.asarray(padded))
+        logits, kv_one = self._prefill_one(
+            self.params, jnp.asarray(padded),
+            jnp.asarray([len(prompt) - 1], jnp.int32))
         kv_one = self._device_handoff(kv_one)
         # Write the slot row of every layer cache + first sampled token.
         for li, (k_full, v_full) in enumerate(self._kv):
             k_one, v_one = kv_one[li]
-            self._kv[li] = (k_full.at[slot].set(k_one),
-                            v_full.at[slot].set(v_one))
-        self._commit_first_token(slot, handle,
-                                 logits[len(prompt) - 1], len(prompt))
+            self._kv[li] = (k_full.at[slot].set(k_one[0]),
+                            v_full.at[slot].set(v_one[0]))
+        self._commit_first_token(slot, handle, logits[0], len(prompt))
 
     def _device_handoff(self, kv):
         """Hand the prefill KV cache to decode as a device object:
@@ -735,7 +711,7 @@ class LLMEngine:
                                pack: _Prefilled, handle: RequestHandle):
         """Scatter an external KV prefix into this sequence's reserved
         pages. The prefix is padded up to the engine bucket (a page
-        multiple) so write_prompt_pages compiles one variant per bucket,
+        multiple) so the page writer compiles one variant per bucket,
         not one per arbitrary kv length; pad rows scatter into the dummy
         page, never a page a live sequence owns."""
         jnp = self._jnp
@@ -754,10 +730,11 @@ class LLMEngine:
             vp = np.zeros((Hkv, Lb, D), v1.dtype)
             kp[:, :L] = k1
             vp[:, :L] = v1
-            kv_pad.append((jnp.asarray(kp, self.cfg.dtype),
-                           jnp.asarray(vp, self.cfg.dtype)))
+            kv_pad.append((jnp.asarray(kp[None], self.cfg.dtype),
+                           jnp.asarray(vp[None], self.cfg.dtype)))
         self._pools = self._write_prompt_pages(
-            self._pools, kv_pad, jnp.asarray(page_ids))
+            self._pools, kv_pad, jnp.asarray([slot], jnp.int32),
+            jnp.asarray(page_ids[None]))
         self._tables[slot] = row
         self._commit_prefilled(slot, handle, pack)
 
@@ -785,7 +762,6 @@ class LLMEngine:
         up to _batch_prefill_width streams). Singleton groups keep the
         single-sequence program. cands: (slot, seq_id, prompt, handle)
         with pages already reserved."""
-        jnp = self._jnp
         # Externally prefilled streams skip the prefill programs entirely:
         # their KV prefix scatters straight into the reserved pages.
         for slot, seq_id, pack, handle in \
@@ -801,117 +777,90 @@ class LLMEngine:
             bucket = max(self._bucket(len(c[2])), self.page_size)
             groups.setdefault(bucket, []).append(c)
         for bucket, group in groups.items():
+            width = self.family.prefill_width(bucket, self.max_batch)
             while group:
-                chunk = group[: self._batch_prefill_width]
+                chunk = group[:width]
                 group = group[len(chunk):]
-                if len(chunk) == 1:
-                    slot, seq_id, prompt, handle = chunk[0]
-                    try:
-                        logits = self._prefill_into_pages(slot, seq_id,
-                                                          prompt)
-                        # _commit_first_token dispatches _sample: it must
-                        # be covered too, or a transient device error
-                        # kills the engine thread and strands every
-                        # waiter (no sentinel ever lands).
-                        self._commit_first_token(slot, handle,
-                                                 logits[len(prompt) - 1],
-                                                 len(prompt))
-                    except BaseException as e:
-                        self._free_slot_pages(slot)
-                        handle._finish(e)
-                    continue
-                W = self._batch_prefill_width
-                npages_row = self.max_len // self.page_size
-                tokens = np.zeros((W, bucket), np.int32)
-                last_idx = np.zeros((W,), np.int32)
-                page_rows = np.full((W, npages_row), self._dummy_page,
-                                    np.int32)
-                rows = []
-                for r, (slot, seq_id, prompt, handle) in enumerate(chunk):
-                    tokens[r, : len(prompt)] = prompt
-                    last_idx[r] = len(prompt) - 1
-                    row = np.asarray(self._alloc.table(seq_id,
-                                                       self._np_pages))
-                    rows.append(row)
-                    npp = self._alloc.pages_needed(len(prompt))
-                    page_rows[r, :npp] = row[:npp]
-                # Sampling params padded to the FIXED width W: a partial
-                # group must not compile its own (n, V) _sample variant.
-                temps = np.zeros(W, np.float32)
-                topks = np.zeros(W, np.int32)
-                topps = np.ones(W, np.float32)
-                for r, c in enumerate(chunk):
-                    temps[r] = c[3].sampling.temperature
-                    topks[r] = c[3].sampling.top_k
-                    topps[r] = c[3].sampling.top_p
-                try:
-                    last_logits, kv_many = self._prefill_many(
-                        self.params, jnp.asarray(tokens),
-                        jnp.asarray(last_idx))
-                    kv_many = self._device_handoff(kv_many)
-                    self._pools = self._write_prompt_pages_many(
-                        self._pools, kv_many, jnp.asarray(page_rows))
-                    # ONE sampling dispatch + host sync for the whole
-                    # group (the sequential path pays one per request;
-                    # greedy stays bit-equal — argmax ignores the rng
-                    # mapping).
-                    self._rng, srng = self._jax.random.split(self._rng)
-                    toks = np.asarray(self._sample(
-                        last_logits, temps, topks, topps, srng))
-                except BaseException as e:
-                    # Device-level failure sinks the whole dispatch: fail
-                    # every member and return their pages.
-                    for slot, seq_id, prompt, handle in chunk:
-                        self._free_slot_pages(slot)
-                        handle._finish(e)
-                    continue
-                # Host-only from here: no device call can strand waiters.
-                for r, (slot, seq_id, prompt, handle) in enumerate(chunk):
-                    self._tables[slot] = rows[r]
-                    self._commit_token(slot, handle, int(toks[r]),
-                                       len(prompt))
+                # A request alone keeps the single-sequence program.
+                self._prefill_group(chunk, bucket,
+                                    1 if len(chunk) == 1 else width)
+
+    def _prefill_group(self, chunk: list, bucket: int, W: int) -> None:
+        """One prefill dispatch for `chunk` (at most W requests of one
+        bucket, pages reserved): the rows' state into pages and slots,
+        one sampling dispatch, then the host-side commit."""
+        jnp = self._jnp
+        npages_row = self.family.prompt_pages(bucket, self.page_size)
+        tokens = np.zeros((W, bucket), np.int32)
+        last_idx = np.zeros((W,), np.int32)
+        page_rows = np.full((W, npages_row), self._dummy_page, np.int32)
+        # A padding row's fixed state goes nowhere (dropped), its pages
+        # to the dummy page.
+        slots = np.full((W,), self.max_batch, np.int32)
+        # Sampling params padded to the FIXED width W: a partial
+        # group must not compile its own (n, V) _sample variant.
+        temps = np.zeros(W, np.float32)
+        topks = np.zeros(W, np.int32)
+        topps = np.ones(W, np.float32)
+        rows = []
+        for r, (slot, seq_id, prompt, handle) in enumerate(chunk):
+            tokens[r, : len(prompt)] = prompt
+            last_idx[r] = len(prompt) - 1
+            slots[r] = slot
+            row = np.asarray(self._alloc.table(seq_id, self._np_pages))
+            rows.append(row)
+            npp = self._alloc.pages_needed(len(prompt))
+            page_rows[r, :npp] = row[:npp]
+            temps[r] = handle.sampling.temperature
+            topks[r] = handle.sampling.top_k
+            topps[r] = handle.sampling.top_p
+        prefill = self._prefill_one if W == 1 else self._prefill_many
+        try:
+            last_logits, fresh = prefill(
+                self.params, jnp.asarray(tokens), jnp.asarray(last_idx))
+            fresh = self._device_handoff(fresh)
+            self._pools = self._write_prompt_pages(
+                self._pools, fresh, jnp.asarray(slots),
+                jnp.asarray(page_rows))
+            # ONE sampling dispatch + host sync for the whole group;
+            # it must be covered too, or a transient device error kills
+            # the engine thread and strands every waiter (no sentinel
+            # ever lands). Greedy stays bit-equal whatever the group:
+            # argmax ignores the rng mapping.
+            self._rng, srng = self._jax.random.split(self._rng)
+            toks = np.asarray(self._sample(
+                last_logits, temps, topks, topps, srng))
+        except BaseException as e:
+            # Device-level failure sinks the whole dispatch: fail
+            # every member and return their pages.
+            for slot, seq_id, prompt, handle in chunk:
+                self._free_slot_pages(slot)
+                handle._finish(e)
+            return
+        # Host-only from here: no device call can strand waiters.
+        if not self.family.rewinds:
+            self.state_slots_reset += len(chunk)
+        for r, (slot, seq_id, prompt, handle) in enumerate(chunk):
+            self._tables[slot] = rows[r]
+            self._commit_token(slot, handle, int(toks[r]), len(prompt))
 
     def _init_paged_state(self):
-        """(Re)build the page pool: allocator + dummy page + zeroed
-        per-layer pools + tables. Shared by __init__ and the
+        """(Re)build the page pool: allocator + dummy page + the family's
+        zeroed state + tables. Shared by __init__ and the
         decode-failure recovery path so the two can never drift."""
         from ray_tpu.ops.paged_attention import PageAllocator
 
-        jnp = self._jnp
         self._alloc = PageAllocator(self._num_pages, self.page_size)
         # Dummy page: inactive slots' garbage writes and table padding
         # land here, never in a page a live sequence owns.
         self._dummy_page = self._alloc.allocate("__dummy__", 1)[0]
-        Hkv, Dh = self.cfg.n_kv_heads, self.cfg.head_dim
-        self._pools = [
-            (jnp.zeros((self._num_pages, Hkv, self.page_size, Dh),
-                       self.cfg.dtype),
-             jnp.zeros((self._num_pages, Hkv, self.page_size, Dh),
-                       self.cfg.dtype))
-            for _ in range(self.cfg.n_layers)]
+        # The engine's whole decode state: what is paged and, where the
+        # family has it, what is fixed per slot (`_pools` for a family
+        # with nothing fixed: the list of its layers' pools).
+        self._pools = self.family.init_state(
+            self.max_batch, self._num_pages, self.page_size)
         self._tables = np.full((self.max_batch, self._np_pages),
                                self._dummy_page, np.int32)
-
-    def _prefill_into_pages(self, slot: int, seq_id: str,
-                            prompt: np.ndarray):
-        """Bucketed prefill through the dense program, scattering the
-        prompt's KV into this sequence's pages; returns the logits."""
-        jnp = self._jnp
-        bucket = max(self._bucket(len(prompt)), self.page_size)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, : len(prompt)] = prompt
-        logits, kv_one = self._prefill_one(self.params, jnp.asarray(padded))
-        kv_one = self._device_handoff(kv_one)
-        row = np.asarray(self._alloc.table(seq_id, self._np_pages))
-        n_prompt_pages = self._alloc.pages_needed(len(prompt))
-        prompt_pages = jnp.asarray(np.concatenate([
-            row[:n_prompt_pages],
-            np.full(self.max_len // self.page_size - n_prompt_pages,
-                    self._dummy_page, np.int32)]))
-        self._pools = self._write_prompt_pages(
-            self._pools, kv_one, prompt_pages)
-        self._tables[slot] = row
-        return logits
 
     def _free_slot_pages(self, slot: int):
         st = self._slots[slot]
@@ -932,6 +881,22 @@ class LLMEngine:
         tokens = self._lens[:, None].astype(np.int64) + steps
         self.paged_pages_live += int((-(-tokens // self.page_size)).sum())
         self.paged_pages_table += tokens.size * self._np_pages
+
+    def _steps_to_take(self) -> np.ndarray:
+        """Steps of the next chunk each slot may advance: as many as its
+        consumer's queue has room for and it is still owed, none for a
+        slot that is empty. (A full queue parks the slot, as a refused
+        offer does where steps can be re-run.)"""
+        steps = np.zeros(self.max_batch, np.int32)
+        for i, st in enumerate(self._slots):
+            h = st.request
+            if h is None or st.prefill_prompt is not None:
+                continue
+            owed = h.sampling.max_new_tokens - st.generated
+            steps[i] = max(0, min(self.decode_chunk, owed, h.room()))
+            if steps[i] < min(self.decode_chunk, owed):
+                self._parked_events += 1
+        return steps
 
     def _advance_prefill(self, slot: int):
         """Write ONE chunk of a long prompt into the slot's cache; on the
@@ -1079,17 +1044,23 @@ class LLMEngine:
             # One decode CHUNK for every slot (inactive slots compute
             # garbage on their stale state — discarded host-side; slots
             # finishing mid-chunk have their overshoot discarded too).
+            # State that cannot be rewound: every slot's steps are sized
+            # now, by what its consumer's queue takes and what it is still
+            # owed, and the program holds it still past them.
+            steps = None if self.family.rewinds else self._steps_to_take()
             try:
                 self._rng, srng = jax.random.split(self._rng)
                 if self.page_size:
                     self._count_paged_pages()
-                    toks, pools_out = self._decode_chunk_paged(
-                        self.params, jnp.asarray(self._token),
-                        jnp.asarray(self._pos), self._pools,
-                        jnp.asarray(self._tables), jnp.asarray(self._lens),
-                        jnp.asarray(self._temps), self._topks_arr(),
-                        self._topps_arr(), srng)
-                    self._pools = [(k, v) for k, v in pools_out]
+                    args = [self.params, jnp.asarray(self._token),
+                            jnp.asarray(self._pos), self._pools,
+                            jnp.asarray(self._tables),
+                            jnp.asarray(self._lens),
+                            jnp.asarray(self._temps), self._topks_arr(),
+                            self._topps_arr(), srng]
+                    if steps is not None:
+                        args.append(jnp.asarray(steps))
+                    toks, self._pools = self._decode_chunk_paged(*args)
                 else:
                     toks, kv_out = self._decode_chunk_fn(
                         self.params, jnp.asarray(self._token),
@@ -1107,14 +1078,13 @@ class LLMEngine:
                 if self.page_size:
                     self._init_paged_state()
                 else:
-                    proto = init_kv_caches(self.cfg, self.max_batch,
-                                           self.max_len)
-                    self._kv = [(k, v) for k, v, _l in proto]
+                    self._kv = self.family.init_dense(self.max_batch)
                 continue
             for i, st in enumerate(self._slots):
                 if st.request is None or st.prefill_prompt is not None:
                     continue
-                for kstep in range(toks.shape[0]):
+                take = toks.shape[0] if steps is None else int(steps[i])
+                for kstep in range(take):
                     tok = int(toks[kstep, i])
                     if not self._emit(i, tok):
                         # Consumer backlog full: park WITHOUT committing.
@@ -1152,7 +1122,7 @@ class LLMServer:
                                             "max_new_tokens": 32})
     """
 
-    def __init__(self, cfg: LlamaConfig, params, *, max_batch: int = 4,
+    def __init__(self, cfg, params, *, max_batch: int = 4,
                  max_len: int = 1024, decode_chunk: int = 8,
                  prefill_chunk: int = 0, page_size: int = 0,
                  kv_pool_tokens: int = 0, stream_buffer: int = 256):

@@ -1,0 +1,261 @@
+"""What a model tells the serving engine: the one seam between
+`LLMEngine` (slots, admission, pages, sampling, streams: host logic that
+knows no architecture) and a model family (what a sequence's state is and
+how the device advances it).
+
+A family object answers, for its configuration:
+
+    model                       the flax module (`engine.model`)
+    rewinds                     True where an uncommitted decode step may
+                                simply be run again (its cache writes are
+                                overwritten before they are read: attention
+                                caches).  False where a step changes state
+                                for good (recurrent state): the engine then
+                                sizes every slot's steps BEFORE a chunk is
+                                dispatched and the program holds a slot
+                                still past its count (`live`).
+    dense                       whether the family also has the page_size=0
+                                programs (per-slot max_len caches, chunked
+                                prefill) and a cache another engine can be
+                                handed (`submit_prefilled`, drain snapshots)
+    pool_readers                layers that read a sequence's pages in one
+                                decode step through ONE table row of ONE pool
+    ring_tokens(lens)           tokens held in fixed-size rings (0: none)
+    prefill_width(bucket, max_batch)   rows of the batched prefill program
+                                at a bucket (fixed, so one program a bucket)
+    prompt_pages(bucket, page_size)    page columns `write_prompt` takes
+    init_state(max_batch, num_pages, page_size)
+                                the engine's whole decode state, a pytree:
+                                what is paged (pools of `num_pages` pages)
+                                and what is fixed per slot (leading axis
+                                `max_batch`)
+    prefill(params, tokens (W, bucket), last_idx (W,))
+                                -> float32 logits (W, V) at each row's last
+                                token, and the rows' fresh state AT that
+                                token (right-padding must not leak into it)
+    write_prompt(state, fresh, slots (W,), page_ids (W, n))
+                                -> state with the rows' fresh state in it:
+                                paged parts scattered to `page_ids` (padding
+                                rows and columns name the dummy page), fixed
+                                parts written whole to `slots` (a padding
+                                row's slot is `max_batch`: dropped).  Writing
+                                a slot's fixed state whole IS how a slot is
+                                cleared: a reused slot starts from what its
+                                own prefill computed from zero.
+    decode(params, token, pos, state, tables, lens, live)
+                                -> float32 logits (B, V), state; one token
+                                a slot.  `live` (B,) bool or None.
+
+Freeing a slot is the engine's: its pages go back to the allocator, its
+table row to the dummy page, its length to 0.  Fixed state needs nothing
+then; it is replaced at the next admission.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.models.llama import (LlamaConfig, LlamaModel, PagedKVCache,
+                                  init_kv_caches)
+from ray_tpu.models.sambay import SambaYConfig, SambaYModel
+
+# Rows of the batched prefill program (fewer where the slots are fewer).
+BATCH_PREFILL_WIDTH = 8
+
+
+def _pages(a, page_size: int):
+    """(W, H, L, D) -> (W * L / page_size, H, page_size, D): the rows'
+    tokens cut into pool pages, in the order of a flattened (W, L / ps)."""
+    W, H, L, D = a.shape
+    return a.reshape(W, H, L // page_size, page_size, D) \
+        .transpose(0, 2, 1, 3, 4).reshape(-1, H, page_size, D)
+
+
+class LlamaServing:
+    """Rotary GQA decoders through `models/llama.py`: a (k, v) pool a
+    layer, nothing fixed."""
+
+    rewinds = True
+    dense = True
+    pool_readers = 1
+
+    def __init__(self, cfg: LlamaConfig, max_len: int):
+        self.cfg, self.max_len = cfg, max_len
+        self.model = LlamaModel(cfg)
+
+    def ring_tokens(self, lens) -> int:
+        return 0
+
+    def prefill_width(self, bucket: int, max_batch: int) -> int:
+        return min(BATCH_PREFILL_WIDTH, max_batch)
+
+    def prompt_pages(self, bucket: int, page_size: int) -> int:
+        # the prefill's caches are max_len long whatever the bucket
+        return self.max_len // page_size
+
+    def init_state(self, max_batch: int, num_pages: int, page_size: int):
+        shape = (num_pages, self.cfg.n_kv_heads, page_size,
+                 self.cfg.head_dim)
+        return [(jnp.zeros(shape, self.cfg.dtype),
+                 jnp.zeros(shape, self.cfg.dtype))
+                for _ in range(self.cfg.n_layers)]
+
+    def prefill(self, params, tokens, last_idx):
+        # tokens: (W, bucket) right-padded. Cache entries past the true
+        # prompt length hold garbage, but decode masks keys by position
+        # (kpos <= qpos) and overwrites index `cache_len` before each
+        # attention, so they are never attended. The last-token logits
+        # are gathered INSIDE the program: the full (W, bucket, vocab)
+        # logits never reach the host.
+        positions = jnp.arange(tokens.shape[1])[None, :]
+        caches = init_kv_caches(self.cfg, tokens.shape[0], self.max_len)
+        logits, new = self.model.apply(params, tokens, positions,
+                                       kv_caches=caches)
+        last = jnp.take_along_axis(
+            logits, last_idx[:, None, None], axis=1)[:, 0]
+        return last, [(k, v) for k, v, _l in new]
+
+    def write_prompt(self, pools, fresh, slots, page_ids):
+        # Scatter the rows' (W, Hkv, L, D) caches into pool pages (pool
+        # layout (P, Hkv, page, D)): rows flatten into one scatter;
+        # page_ids past a prompt point at the dummy page (garbage there
+        # is fine).
+        flat = page_ids.reshape(-1)
+        ps = pools[0][0].shape[2]
+        return [(kp.at[flat].set(_pages(k, ps)),
+                 vp.at[flat].set(_pages(v, ps)))
+                for (kp, vp), (k, v) in zip(pools, fresh)]
+
+    def decode(self, params, token, pos, pools, tables, lens, live):
+        caches = [PagedKVCache(k, v, tables, lens) for (k, v) in pools]
+        logits, new = self.model.apply(params, token[:, None], pos[:, None],
+                                       kv_caches=caches)
+        return logits[:, 0], [(c.k_pool, c.v_pool) for c in new]
+
+    # ---- dense mode (page_size == 0): a max_len cache a slot --------------
+
+    def init_dense(self, max_batch: int):
+        proto = init_kv_caches(self.cfg, max_batch, self.max_len)
+        return [(k, v) for k, v, _l in proto]   # [(B,Hkv,L,D)] / layer
+
+    def prefill_chunk(self, params, tokens, start, kv_full, slot):
+        # One CHUNK of a long prompt: tokens (1, chunk) at absolute
+        # positions start..start+chunk, KV written at the same offset
+        # of slot `slot`'s cache. Gather/scatter of the slot row stays
+        # INSIDE the jit with the full cache donated, so a chunk costs
+        # one row update, not a full multi-slot cache copy per tick.
+        C = tokens.shape[1]
+        positions = start + jnp.arange(C)[None, :]
+        caches1 = [
+            (jax.lax.dynamic_slice_in_dim(k, slot, 1, axis=0),
+             jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=0), start)
+            for k, v in kv_full]
+        logits, new = self.model.apply(params, tokens, positions,
+                                       kv_caches=caches1)
+        out_kv = [
+            (jax.lax.dynamic_update_slice_in_dim(kf, kn, slot, axis=0),
+             jax.lax.dynamic_update_slice_in_dim(vf, vn, slot, axis=0))
+            for (kf, vf), (kn, vn, _l) in zip(kv_full, new)]
+        return logits[0], out_kv
+
+    def decode_dense(self, params, token, pos, kv, lens):
+        def one(params, token, pos, kv, lens):
+            # One sequence: token (), pos (), kv list of ((Hkv,L,D) k, v),
+            # lens () — the slot's private write offset.
+            caches1 = [(k[None], v[None], lens) for k, v in kv]
+            logits, new = self.model.apply(params, token[None, None],
+                                           pos[None, None],
+                                           kv_caches=caches1)
+            return logits[0, 0], [(k[0], v[0]) for k, v, _l in new]
+
+        # vmap: slots advance at DIFFERENT offsets in the same program.
+        return jax.vmap(one, in_axes=(None, 0, 0, 0, 0))(
+            params, token, pos, kv, lens)
+
+
+# Tokens one prefill dispatch may hold: a row of the largest bucket alone,
+# eight rows of 2,048: the feed-forward's (tokens, 2 x d_ff) intermediate
+# is 0.67 GB at 16,384 tokens and d_ff 10,240.
+_PREFILL_TOKENS = 16384
+
+
+class SambaYServing:
+    """`models/sambay.py`: pages of ONE layer's K/V (read by every cross
+    layer), a ring of `window` tokens for each window layer, and (conv
+    window, scan state) for each Mamba layer."""
+
+    rewinds = False
+    dense = False
+
+    def __init__(self, cfg: SambaYConfig, max_len: int):
+        self.cfg, self.max_len = cfg, max_len
+        self.model = SambaYModel(cfg)
+        # the full layer and the cross layers behind it
+        self.pool_readers = 1 + len(cfg.layers_of("cross"))
+        self._window_layers = len(cfg.layers_of("window"))
+
+    def ring_tokens(self, lens) -> int:
+        return int(np.minimum(lens, self.cfg.window).sum()) \
+            * self._window_layers
+
+    def prefill_width(self, bucket: int, max_batch: int) -> int:
+        return max(1, min(BATCH_PREFILL_WIDTH, max_batch,
+                          _PREFILL_TOKENS // bucket))
+
+    def prompt_pages(self, bucket: int, page_size: int) -> int:
+        return bucket // page_size
+
+    def init_state(self, max_batch: int, num_pages: int, page_size: int):
+        c = self.cfg
+        B, D2 = max_batch, 2 * c.head_dim
+        # (every leaf a buffer of its own: the state is donated)
+        pool = lambda: jnp.zeros(  # noqa: E731
+            (num_pages, c.kv_pairs, page_size, D2), c.dtype)
+        ring = lambda: jnp.zeros(  # noqa: E731
+            (B, c.kv_pairs, c.window, D2), c.dtype)
+        return {
+            "pool": (pool(), pool()),
+            "rings": [(ring(), ring()) for _ in c.layers_of("window")],
+            "mamba": [(jnp.zeros((B, c.d_conv - 1, c.d_inner), c.dtype),
+                       jnp.zeros((B, c.d_state, c.d_inner), jnp.float32))
+                      for _ in c.layers_of("mamba")]}
+
+    def prefill(self, params, tokens, last_idx):
+        return self.model.apply(params, tokens, last_idx,
+                                method=SambaYModel.prefill)
+
+    def write_prompt(self, state, fresh, slots, page_ids):
+        flat = page_ids.reshape(-1)
+        kp, vp = state["pool"]
+        ps = kp.shape[2]
+        k, v = fresh["cache"]
+        put = lambda old, new: old.at[slots].set(  # noqa: E731
+            new, mode="drop")
+        return {
+            "pool": (kp.at[flat].set(_pages(k, ps)),
+                     vp.at[flat].set(_pages(v, ps))),
+            "rings": [tuple(map(put, old, new)) for old, new in
+                      zip(state["rings"], fresh["rings"])],
+            "mamba": [tuple(map(put, old, new)) for old, new in
+                      zip(state["mamba"], fresh["mamba"])]}
+
+    def decode(self, params, token, pos, state, tables, lens, live):
+        # no position embedding of any kind: `pos` is not read
+        return self.model.apply(params, token, state, tables, lens, live,
+                                method=SambaYModel.decode)
+
+
+_FAMILIES = {LlamaConfig: LlamaServing, SambaYConfig: SambaYServing}
+
+
+def family_of(cfg, max_len: int):
+    """The serving family of a model configuration."""
+    for kind, family in _FAMILIES.items():
+        if isinstance(cfg, kind):
+            return family(cfg, max_len)
+    raise TypeError(
+        f"LLMEngine serves {[k.__name__ for k in _FAMILIES]}; got "
+        f"{type(cfg).__name__} (a new family is a class in "
+        "ray_tpu/serve/llm_families.py)")

@@ -234,3 +234,69 @@ def test_train_step_sharded_over_four_chips(topo):
     whole = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                 for x in jax.tree_util.tree_leaves(state))
     assert compiled.memory_analysis().argument_size_in_bytes < 0.3 * whole
+
+
+# ---- the SambaY family at the widths of `phi4flash-serve-reason-closed` -----
+
+SAMBAY_ENGINE = dict(max_batch=32, max_len=17472, page_size=64,
+                     decode_chunk=8, kv_pool_tokens=303104)
+
+
+def test_sambay_kernels_at_the_cells_shapes(one_chip):
+    """Heads of 64 run as pairs: 40 zero-padded query heads and 10 KV
+    heads of 128, scale 1/8.  The paged kernel over the one shared pool
+    (4,737 pages of 64, a table of ceil((17472 + 8) / 64) = 274 columns,
+    float32 queries) and the flash kernel over a 16,384-token prompt."""
+    S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    pool = S((4737, 10, 64, 128), jnp.bfloat16)
+    compiled = jax.jit(lambda *a: paged_attention.paged_decode_attention_batch(
+        *a, sm_scale=0.125)).lower(
+        S((32, 40, 128), jnp.float32), pool, pool, S((32, 274), jnp.int32),
+        S((32,), jnp.int32)).compile()
+    assert KERNEL in compiled.as_text()
+    qkv = S((1, 40, 16384, 128), jnp.bfloat16)
+    compiled = jax.jit(lambda q, k, v: attention.flash_attention(
+        q, k, v, 0.125, True)).lower(qkv, qkv, qkv).compile()
+    assert KERNEL in compiled.as_text()
+
+
+@pytest.mark.slow      # 45 s of a many-threaded compile: by hand, not in tier-1
+@_WHOLE_STEP_LIMIT
+def test_sambay_engine_programs_fit_the_chip(one_chip):
+    """The cell's decode chunk (eight paged calls a step, rings and
+    recurrent state carried through the scan, a count of steps a slot)
+    and its largest prefill (one row of 16,384 tokens: the scan, eight
+    windowed layers in blocks, flash over the full layer) at published
+    widths, each beside the weights and the engine's whole state."""
+    from ray_tpu.models.sambay import PHI4_MINI_FLASH, SambaYModel
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg = PHI4_MINI_FLASH
+    params = jax.eval_shape(
+        lambda: SambaYModel(cfg).init(jax.random.PRNGKey(0),
+                                      jnp.zeros((1, 8), jnp.int32)))
+    eng = LLMEngine(cfg, params, **SAMBAY_ENGINE)
+    try:
+        B = eng.max_batch
+        S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=one_chip)
+        state = _on(one_chip, eng._pools)
+        state_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                          for x in jax.tree_util.tree_leaves(eng._pools))
+        decode = eng._decode_chunk_paged.lower(
+            _on(one_chip, params), S((B,), jnp.int32), S((B,), jnp.int32),
+            state, S(eng._tables.shape, jnp.int32), S((B,), jnp.int32),
+            S((B,), jnp.float32), S((B,), jnp.int32), S((B,), jnp.float32),
+            S((2,), jnp.uint32), S((B,), jnp.int32)).compile()
+        assert decode.as_text().count(KERNEL) >= 8
+        assert _peak_bytes(decode) < HBM_BYTES
+        assert eng.family.prefill_width(16384, B) == 1
+        prefill = eng._prefill_one.lower(
+            _on(one_chip, params), S((1, 16384), jnp.int32),
+            S((1,), jnp.int32)).compile()
+        assert KERNEL in prefill.as_text()
+        # (the state is not an argument of the prefill: it is resident)
+        assert _peak_bytes(prefill) + state_bytes < HBM_BYTES
+    finally:
+        eng.shutdown()

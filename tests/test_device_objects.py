@@ -307,6 +307,35 @@ def test_local_handoff_identity_and_gauges():
                    for e in device_objects.registry().entries())
 
 
+def test_local_handoff_leaves_no_cyclic_garbage():
+    """What a handoff held dies with the last reference to it, without
+    waiting for Python's cycle collector: a walker closing over itself
+    kept each prefill's whole KV alive until a collection happened to run
+    (1.2 GB a batched prefill, then RESOURCE_EXHAUSTED on the chip)."""
+    import gc
+    import weakref
+
+    class Leaf:     # stands in for an array: weakly referenceable
+        pass
+
+    gc.collect()
+    gc.disable()
+    try:
+        leaf = Leaf()
+        gone = weakref.ref(leaf)
+        tree = {"rings": [(leaf, 1)], "pool": (2, 3)}
+        out = device_objects.tree_map(
+            tree, lambda v: v, lambda v: isinstance(v, Leaf))
+        assert out["rings"][0][0] is leaf
+        kv = {"cache": (jnp.ones((2, 4)), jnp.zeros((2, 4)))}
+        held = weakref.ref(kv["cache"][0])
+        out2 = device_objects.local_handoff("test-cycle", kv)
+        del leaf, tree, out, kv, out2
+        assert gone() is None and held() is None
+    finally:
+        gc.enable()
+
+
 def test_train_broadcast_weights(ray_start_regular):
     """Train consumer: WorkerGroup.broadcast_weights ships one device
     object to every worker; each receives the full tree."""

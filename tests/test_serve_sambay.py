@@ -1,0 +1,183 @@
+"""`LLMEngine` over the SambaY family (`serve/llm_families.py`): pages of
+one layer, rings and recurrent state behind the same loop, slots and
+allocator that serve a Llama.  Tiny widths, float32, the benchmark's plain
+reference as the judge: in float32 on the CPU the engine's greedy tokens
+are the reference's argmax at every position (the top-2 margins of these
+logits, 1e-3 and up, are far above float32 reordering, 5e-7).
+"""
+
+import os
+import sys
+import time
+
+import numpy as np
+import pytest
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _REPO not in sys.path:
+    sys.path.insert(0, _REPO)
+
+from tests.test_models_sambay import SIZES  # noqa: E402
+
+ENGINE = dict(max_batch=4, max_len=128, page_size=16, decode_chunk=4)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    from ray_tpu.models.sambay import TINY_SAMBAY, init_params
+
+    return TINY_SAMBAY, init_params(TINY_SAMBAY, jax.random.PRNGKey(0))
+
+
+def _prompts(seed, lengths):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, 256, size=n).tolist() for n in lengths]
+
+
+def _reference_gap(params, prompt, output):
+    """How far the reference's logit of each engine token lies under the
+    reference's best, teacher-forced over prompt + output."""
+    from benchmarks.reference import sambay as ref
+
+    rows = list(range(len(prompt) - 1, len(prompt) + len(output) - 1))
+    lg = np.asarray(ref.logits(params, SIZES, prompt + output[:-1], rows))
+    return lg.max(-1) - lg[np.arange(len(output)), output]
+
+
+def test_engine_streams_are_the_references_greedy(tiny):
+    """Six requests over four slots: a batched prefill (two rows of one
+    bucket), singles, admission mid-flight and two slots used twice.  A
+    reused slot starts from what its own prefill computed from zero, or
+    the second stream in it would leave the reference."""
+    from ray_tpu.models.generate import SamplingParams
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, params = tiny
+    eng = LLMEngine(cfg, params, **ENGINE)
+    try:
+        prompts = _prompts(0, (5, 19, 33, 40, 17, 64))
+        eng.quiesce_for_drain()
+        handles = [eng.submit(p, SamplingParams(max_new_tokens=24))
+                   for p in prompts]
+        eng.resume()
+        outs = [h.tokens() for h in handles]
+        for p, o in zip(prompts, outs):
+            assert len(o) == 24
+            assert _reference_gap(params, p, o).max() == 0.0
+        got = eng.report_metrics()
+        assert got["state_slots_reset"] == 6
+        assert got["ring_tokens"] == 0 and eng.num_active() == 0
+        # the one paged layer is read by the full layer and the cross layer
+        assert got["shared_pool_pages_live"] == 2 * got["paged_pages_live"]
+        assert got["paged_pages_live"] > 0
+    finally:
+        eng.shutdown()
+
+
+def test_a_parked_and_an_empty_slot_keep_their_state(tiny):
+    """The decode program with a count of steps a slot: a slot given none
+    (parked, or empty) keeps rings, conv window and scan state bit for
+    bit while its neighbours decode; its token, position and length stay."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.generate import SamplingParams
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, params = tiny
+    eng = LLMEngine(cfg, params, **ENGINE)
+    try:
+        eng.quiesce_for_drain()
+        prompts = _prompts(1, (21, 30))
+        handles = [eng.submit(p, SamplingParams(max_new_tokens=40))
+                   for p in prompts]
+        eng.resume()
+        firsts = [next(iter(h)) for h in handles]   # both admitted
+        assert len(firsts) == 2
+        assert eng.quiesce_for_drain()
+        B, K = eng.max_batch, eng.decode_chunk
+        before = jax.tree_util.tree_map(np.array, eng._pools)   # copies
+        steps = np.zeros(B, np.int32)
+        steps[0] = K                    # slot 0 decodes, 1 is parked,
+        toks, after = eng._decode_chunk_paged(      # 2 and 3 are empty
+            eng.params, jnp.asarray(eng._token), jnp.asarray(eng._pos),
+            eng._pools, jnp.asarray(eng._tables), jnp.asarray(eng._lens),
+            jnp.asarray(eng._temps), eng._topks_arr(), eng._topps_arr(),
+            jax.random.PRNGKey(0), jnp.asarray(steps))
+        eng._pools = after              # (the old buffers were donated)
+        after = jax.tree_util.tree_map(np.asarray, after)
+        fixed = lambda s: jax.tree_util.tree_leaves(  # noqa: E731
+            {"rings": s["rings"], "mamba": s["mamba"]})
+        # (layer 0's conv window holds its last three INPUTS, a function
+        # of the tokens alone: a stream that repeats a token leaves it as
+        # it was, so it is not asked to move)
+        moved = [not np.array_equal(a[0], b[0])
+                 for a, b in zip(fixed(after), fixed(before))]
+        assert all(moved[1:]), "the decoding slot's state did not advance"
+        for a, b in zip(fixed(after), fixed(before)):
+            np.testing.assert_array_equal(a[1:], b[1:])
+    finally:
+        eng.shutdown()
+
+
+def test_a_slow_consumer_parks_its_slot_and_loses_nothing(tiny):
+    """A consumer that stops reading fills its queue (4 tokens); the slot
+    is held still, not re-run: when the consumer comes back the stream is
+    still the reference's, and the other stream never waited for it."""
+    from ray_tpu.models.generate import SamplingParams
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, params = tiny
+    eng = LLMEngine(cfg, params, stream_buffer=4, **ENGINE)
+    try:
+        slow_p, fast_p = _prompts(2, (18, 25))
+        slow = eng.submit(slow_p, SamplingParams(max_new_tokens=30))
+        fast = eng.submit(fast_p, SamplingParams(max_new_tokens=30))
+        fast_out = fast.tokens()                 # slow is not read meanwhile
+        assert eng.report_metrics()["parked_events"] > 0
+        time.sleep(0.2)
+        slow_out = slow.tokens()
+        assert len(slow_out) == len(fast_out) == 30
+        assert _reference_gap(params, slow_p, slow_out).max() == 0.0
+        assert _reference_gap(params, fast_p, fast_out).max() == 0.0
+    finally:
+        eng.shutdown()
+
+
+def test_what_the_family_cannot_do_is_refused_in_words(tiny):
+    from ray_tpu.serve.llm import LLMEngine, _Prefilled
+
+    cfg, params = tiny
+    with pytest.raises(ValueError, match="served paged"):
+        LLMEngine(cfg, params, max_batch=2, max_len=64)
+    with pytest.raises(TypeError, match="a new family is a class"):
+        LLMEngine(object(), params, max_batch=2, max_len=64, page_size=16)
+    eng = LLMEngine(cfg, params, **ENGINE)
+    try:
+        with pytest.raises(NotImplementedError, match="prefilled where"):
+            eng.submit_prefilled(_Prefilled([], 1, 4, 4, 0, [], True))
+        with pytest.raises(NotImplementedError, match="prefilled where"):
+            eng.snapshot_active_streams()
+    finally:
+        eng.shutdown()
+
+
+def test_the_family_sizes_the_batched_prefill_by_bucket(tiny):
+    """Rows of one prefill dispatch: eight at most, fewer where a bucket's
+    rows would pass 16,384 tokens, so the program of the largest bucket
+    holds one row; the Llama family keeps eight at every bucket."""
+    from ray_tpu.models.llama import TINY
+    from ray_tpu.serve.llm_families import family_of
+
+    cfg, _ = tiny
+    fam = family_of(cfg, 17472)
+    assert [fam.prefill_width(b, 32) for b in
+            (512, 2048, 4096, 8192, 16384, 17472)] == [8, 8, 4, 2, 1, 1]
+    assert fam.prefill_width(512, 4) == 4
+    assert fam.prompt_pages(2048, 64) == 32
+    llama = family_of(TINY, 2304)
+    assert [llama.prefill_width(b, 32) for b in (64, 2048)] == [8, 8]
+    assert llama.prompt_pages(64, 64) == 36
