@@ -22,7 +22,9 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import math
 import os
+import re
 import sys
 import threading
 import time
@@ -151,6 +153,66 @@ def _kernel_calls(lowered_text: str) -> int:
     return lowered_text.count("tpu_custom_call")
 
 
+STATE_MOVES = ("copy", "copy-start", "slice-start")
+
+
+def state_moves(text: str, state) -> dict:
+    """What the compiled program `text` does to buffers of the shape of a
+    leaf of `state`, inside its loops and outside them: {"loop": {...},
+    "outside": {...}}, each with the count of `copy` (a change of
+    layout), `copy-start` and `slice-start` (a move to another memory
+    space, whole or in slices) whose result has such a shape, and
+    `moved`, the copy-starts and the slices together in units of a whole
+    leaf.  A decode step should find its state where the last one left
+    it: every one of these is a pool read and written for nothing."""
+    import jax
+
+    hlo = {"bfloat16": "bf16", "float32": "f32"}
+    shapes = {f"{hlo[x.dtype.name]}[{','.join(map(str, x.shape))}]":
+              math.prod(x.shape) for x in jax.tree_util.tree_leaves(state)}
+    computations, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            computations[name] = []
+        elif name is not None:
+            computations[name].append(line)
+    calls = {n: set(re.findall(
+        r"(?:body|condition|calls|to_apply)=%([\w.\-]+)", "\n".join(ls)))
+        for n, ls in computations.items()}
+    in_loop = set(re.findall(r"body=%([\w.\-]+)", text))
+    grew = True
+    while grew:
+        more = set().union(*(calls[n] for n in in_loop if n in calls))
+        grew = not more <= in_loop
+        in_loop |= more
+    counts = {where: dict.fromkeys(STATE_MOVES + ("moved",), 0)
+              for where in ("loop", "outside")}
+    for n, lines in computations.items():
+        tally = counts["loop" if n in in_loop else "outside"]
+        for line in lines:
+            m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ("
+                         + "|".join(STATE_MOVES) + r")\(", line)
+            if not m:
+                continue
+            result, op = m.groups()
+            leaf = next((s for s in shapes if s in result), None)
+            if leaf is None:
+                continue
+            tally[op] += 1
+            if op == "copy-start":
+                tally["moved"] += 1
+            elif op == "slice-start":
+                lo_hi = re.findall(r"\[(\d+):(\d+)\]",
+                                   line.split("slice={")[1])
+                tally["moved"] += math.prod(
+                    int(b) - int(a) for a, b in lo_hi) / shapes[leaf]
+    for tally in counts.values():
+        tally["moved"] = round(tally["moved"], 3)
+    return counts
+
+
 # ===========================================================================
 # serve
 # ===========================================================================
@@ -199,7 +261,10 @@ class SmokeLLM(LLMServer):
 
     def kernels(self) -> dict:
         """tpu_custom_call sites in the engine's lowered decode program
-        (the paged Pallas kernel), so a drop to interpret mode shows."""
+        (the paged Pallas kernel), so a drop to interpret mode shows, and
+        what the compiled program does to buffers of a pool's shape
+        (`state_moves`), so a copy of the state that came back shows.
+        (The program ran: compiling it again is a load from the cache.)"""
         import jax
         import jax.numpy as jnp
 
@@ -208,13 +273,15 @@ class SmokeLLM(LLMServer):
         shape = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
         i32 = jax.ShapeDtypeStruct((B,), jnp.int32)
         f32 = jax.ShapeDtypeStruct((B,), jnp.float32)
-        text = eng._decode_chunk_paged.lower(
+        lowered = eng._decode_chunk_paged.lower(
             jax.tree_util.tree_map(shape, self._params), i32, i32,
             jax.tree_util.tree_map(shape, eng._pools),
             jax.ShapeDtypeStruct(eng._tables.shape, jnp.int32),
             i32, f32, i32, f32,
-            jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text()
-        return {"decode_chunk_paged": _kernel_calls(text)}
+            jax.ShapeDtypeStruct((2,), jnp.uint32))
+        return {"decode_chunk_paged": _kernel_calls(lowered.as_text()),
+                "decode_chunk_paged_state_moves": state_moves(
+                    lowered.compile().as_text(), eng._pools)}
 
     def reference(self, prompts: list, engine_tokens: list,
                   max_new: int) -> list:

@@ -140,24 +140,18 @@ class Attention(nn.Module):
         k = apply_rope(k, cos, sin, positions)
 
         if isinstance(kv_cache, PagedKVCache):
-            # Batched single-token decode over the shared page pool:
-            # scatter this step's K/V into each sequence's current page,
-            # then attend over its page table (GQA handled in-kernel; no
-            # head repetition, no per-slot max_len cache).
+            # Batched single-token decode over the shared page pool: the
+            # kernel puts this step's K/V into each sequence's current
+            # page, in place, and attends over its page table (GQA
+            # handled in-kernel; no head repetition, no per-slot max_len
+            # cache). The pools are (P, Hkv, page, D).
             from ray_tpu.ops.paged_attention import (
                 paged_decode_attention_batch)
 
             pc = kv_cache
-            ps = pc.k_pool.shape[2]
-            pages = jnp.take_along_axis(
-                pc.table, (pc.length // ps)[:, None], axis=1)[:, 0]
-            offs = pc.length % ps
-            # pool is (P, Hkv, page, D): [pages, :, offs] scatters one
-            # (B, Hkv, D) row set across the batch
-            k_pool = pc.k_pool.at[pages, :, offs].set(k[:, :, 0, :])
-            v_pool = pc.v_pool.at[pages, :, offs].set(v[:, :, 0, :])
-            out = paged_decode_attention_batch(
-                q[:, :, 0, :], k_pool, v_pool, pc.table, pc.length + 1)
+            out, k_pool, v_pool = paged_decode_attention_batch(
+                q[:, :, 0, :], pc.k_pool, pc.v_pool, pc.table,
+                pc.length + 1, k_new=k[:, :, 0, :], v_new=v[:, :, 0, :])
             out = out[:, :, None, :].astype(cfg.dtype)
             out = out.transpose(0, 2, 1, 3).reshape(B, S, Hq * Dh)
             out = dense(cfg.d_model, name="o_proj")(out)

@@ -643,7 +643,6 @@ class SambaYModel(nn.Module):
         x = self.embed(token).astype(jnp.float32)              # (B, d)
         mamba, rings = [], []
         k_pool, v_pool = state["pool"]
-        ps = k_pool.shape[2]
         memory = None
 
         def lift(f):        # a mixer over (B, d) as one over (B, 1, d)
@@ -651,12 +650,12 @@ class SambaYModel(nn.Module):
                 a[:, None] if j == 0 else a
                 for j, a in enumerate(f(h[:, 0])))
 
-        def paged(attn, q, k_pool, v_pool):
+        def paged(q, sm_scale, **new):
             # (float32 queries: the kernel computes in float32 whatever
             # they are, and hands back their type)
-            return attn.combine(paged_decode_attention_batch(
+            return paged_decode_attention_batch(
                 q[:, :, 0].astype(jnp.float32), k_pool, v_pool, table,
-                length + 1, sm_scale=attn.sm_scale)[:, :, None], True)
+                length + 1, sm_scale=sm_scale, **new)
 
         x = x[:, None]
         for i in range(c.n_layers):
@@ -681,13 +680,14 @@ class SambaYModel(nn.Module):
                         keep = live[:, None, None]
                         k = jnp.where(keep, k, rk[rows, :, slot])
                         v = jnp.where(keep, v, rv[rows, :, slot])
-                    # (A write of one token a row makes the compiler
-                    # give the buffer a layout of its own, (H, D) tiles
-                    # a token, and copy the whole of it in and out for
-                    # whoever reads it: the bfloat16 tiling packs two
-                    # tokens a word.  So with rings and pool, scatter or
-                    # slice updates alike: 10.6 ms of a 44.5-ms step;
-                    # my chip run, PR 28; PERF.md section 5.)
+                    # (A write of one token a row makes the compiler carry
+                    # the buffer through the decode loop token-major
+                    # (layout {3,1,2,0}) and copy it whole to the layout
+                    # of whoever reads it, every step.  The pool's write
+                    # moved into the kernel that reads it (PR 29); a
+                    # ring has no kernel, and held token-major it is
+                    # copied all the same, by the dot that reads it:
+                    # PERF.md section 6, PR 29.)
                     nk = rk.at[rows, :, slot].set(k)
                     nv = rv.at[rows, :, slot].set(v)
                     seen = (jnp.arange(c.window)[None, :]
@@ -700,22 +700,21 @@ class SambaYModel(nn.Module):
                 rings.append((nk, nv))
             elif kind == "full":
                 attn = layer.attn
-                page = jnp.take_along_axis(
-                    table, (length // ps)[:, None], axis=1)[:, 0]
 
                 def mixer(h):
-                    # the pool takes this token before any layer reads it
+                    # the kernel puts this token into the pool, in place,
+                    # before it or any later layer reads it
                     q, k, v = attn.project(h, True)
-                    kp = k_pool.at[page, :, length % ps].set(k[:, :, 0])
-                    vp = v_pool.at[page, :, length % ps].set(v[:, :, 0])
-                    return paged(attn, q, kp, vp), kp, vp
+                    o, kp, vp = paged(q, attn.sm_scale, k_new=k[:, :, 0],
+                                      v_new=v[:, :, 0])
+                    return attn.combine(o[:, :, None], True), kp, vp
 
                 x, k_pool, v_pool = layer.mix(x, mixer, True)
             else:
                 attn = layer.attn
-                x, = layer.mix(x, lambda h: (
-                    paged(attn, attn.project(h, True)[0], k_pool, v_pool),),
-                    True)
+                x, = layer.mix(x, lambda h: (attn.combine(paged(
+                    attn.project(h, True)[0], attn.sm_scale)[:, :, None],
+                    True),), True)
         logits = self._head(x[:, 0], True)
         return logits, {"mamba": mamba, "rings": rings,
                         "pool": (k_pool, v_pool)}

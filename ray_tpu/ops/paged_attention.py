@@ -7,19 +7,25 @@ not `max_len x slots` — the round-1 engine's admitted waste
 (reference: the reference serves LLMs through vLLM-style external
 engines whose core trick is exactly this block table).
 
-Both kernels attend over scattered pages without ever materializing a
+The kernel attends over scattered pages without ever materializing a
 contiguous per-sequence cache, accumulating an online softmax across
 pages (same recurrence as ops/attention.py's flash kernel), with page
 tables and lengths scalar-prefetched into SMEM
 (PrefetchScalarGridSpec).
 
-The batched kernel, the one the engine runs, costs what the resident
-tokens cost: one grid step a sequence, and inside it a loop that ends at
-the sequence's last live page. The pools stay in HBM; a live page is
-copied whole (all KV heads, one contiguous transfer) into a
-double-buffered VMEM scratch, the next block's copies in flight while
-the current block is computed. The single-sequence kernel (tests only)
-still has one page per grid step, dereferenced by the index_map.
+It costs what the resident tokens cost: one grid step a sequence, and
+inside it a loop that ends at the sequence's last live page. The pools
+stay in HBM; a live page is copied whole (all KV heads, one contiguous
+transfer) into a double-buffered VMEM scratch, the next block's copies
+in flight while the current block is computed.
+
+Handed the step's new K and V rows, the kernel also WRITES them: the
+pools are aliased from input to output, the row is put into the copy of
+the sequence's last live page in VMEM, and that one page goes back. A
+decode step's state then never changes form between the write and the
+read, so the compiler has no reason to copy a pool (a one-token scatter
+outside the kernel made it carry every pool token-major through the
+decode loop and copy it whole, both ways, every step; PERF.md, PR 29).
 
 On CPU (tests) the kernel runs in interpret mode.
 """
@@ -42,10 +48,9 @@ def _interpret_mode() -> bool:
 
 def _online_softmax_update(start, length, q, k, v, m_prev, l_prev, acc_prev,
                            *, sm_scale: float):
-    """One block of the online-softmax recurrence, shared by BOTH paged
-    kernels (single-sequence, batched) so a numerics change cannot
-    silently miss one of them. `k`/`v` hold the cached tokens `start`,
-    `start + 1`, ...; those at or past `length` are masked.
+    """One block of the online-softmax recurrence. `k`/`v` hold the
+    cached tokens `start`, `start + 1`, ...; those at or past `length`
+    are masked.
 
     Pure function of values: callers own the scratch-ref IO. Every dot is
     a plain 2D (G, D) x (tokens, D) matmul: Mosaic lowers 2D dots onto
@@ -74,103 +79,6 @@ def _normalized(l, acc):
     return acc / jnp.where(l == 0.0, 1.0, l)
 
 
-def _online_softmax_page_step(pi, num_page_steps, length, q, k, v,
-                              o_write, m_scratch, l_scratch, acc_scratch,
-                              *, page_size: int, sm_scale: float):
-    """One grid step of the single-sequence kernel over whole-scratch
-    refs. pi: page-step program id; q: (G, D); k/v: (page, D); o_write:
-    callback writing the normalized (G, D) output on the last step."""
-    @pl.when(pi == 0)
-    def _init():
-        m_scratch[...] = jnp.full_like(m_scratch, _NEG_INF)
-        l_scratch[...] = jnp.zeros_like(l_scratch)
-        acc_scratch[...] = jnp.zeros_like(acc_scratch)
-
-    m_new, l_new, acc_new = _online_softmax_update(
-        pi * page_size, length, q, k, v, m_scratch[...], l_scratch[...],
-        acc_scratch[...], sm_scale=sm_scale)
-    m_scratch[...] = m_new
-    l_scratch[...] = l_new
-    acc_scratch[...] = acc_new
-
-    @pl.when(pi == num_page_steps - 1)
-    def _finish():
-        o_write(_normalized(l_scratch[...], acc_scratch[...]))
-
-
-def _paged_decode_kernel(page_table_ref, length_ref,  # scalar prefetch
-                         q_ref, k_ref, v_ref, o_ref,
-                         m_scratch, l_scratch, acc_scratch,
-                         *, page_size: int, num_pages: int, groups: int,
-                         sm_scale: float):
-    # Grid: (Hkv, npages)
-    pi = pl.program_id(1)
-
-    def write(out):
-        o_ref[0] = out.astype(o_ref.dtype)
-
-    _online_softmax_page_step(
-        pi, pl.num_programs(1), length_ref[0],
-        q_ref[0].astype(jnp.float32),           # (G, D)
-        k_ref[0, 0].astype(jnp.float32),        # (page, D)
-        v_ref[0, 0].astype(jnp.float32),
-        write, m_scratch, l_scratch, acc_scratch,
-        page_size=page_size, sm_scale=sm_scale)
-
-
-def paged_decode_attention(q, k_pool, v_pool, page_table, length,
-                           *, sm_scale: float | None = None):
-    """Single-token decode attention over paged KV.
-
-    q:          (H, D) query for ONE sequence's current token
-    k_pool/v_pool: (P, Hkv, page_size, D) shared pools — head-then-page
-                minor layout so each (head, page) block is a contiguous
-                (page, D) tile (Mosaic requires the last two block dims
-                to tile as (sublane, lane))
-    page_table: (NP,) int32 pool indices owned by this sequence (entries
-                past the live length may be arbitrary valid indices)
-    length:     () int32 valid token count (incl. the current token,
-                whose K/V must already be written to the pool)
-    Returns (H, D). vmap over sequences for a batch.
-    """
-    H, D = q.shape
-    P, Hkv, page_size, _ = k_pool.shape
-    groups = H // Hkv
-    npages = page_table.shape[0]
-    if sm_scale is None:
-        sm_scale = 1.0 / (D ** 0.5)
-
-    q3 = q.reshape(Hkv, groups, D)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(Hkv, npages),
-        in_specs=[
-            pl.BlockSpec((1, groups, D), lambda h, i, pt, ln: (h, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, D),
-                         lambda h, i, pt, ln: (pt[i], h, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, D),
-                         lambda h, i, pt, ln: (pt[i], h, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, groups, D),
-                               lambda h, i, pt, ln: (h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((groups, 1), jnp.float32),
-            pltpu.VMEM((groups, 1), jnp.float32),
-            pltpu.VMEM((groups, D), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, page_size=page_size,
-                          num_pages=npages, groups=groups,
-                          sm_scale=sm_scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Hkv, groups, D), q.dtype),
-        interpret=_interpret_mode(),
-    )(page_table.astype(jnp.int32), length.reshape(1).astype(jnp.int32),
-      q3, k_pool, v_pool)
-    return out.reshape(H, D)
-
-
 # VMEM the K and V page buffers of the batched kernel may take together
 # (two slots each, so that one block is copied while one is computed).
 _KV_VMEM_BYTES = 4 * 1024 * 1024
@@ -183,11 +91,9 @@ def _pages_per_block(page_bytes: int, table_pages: int) -> int:
 
 
 def _paged_decode_batch_kernel(length_ref, page_table_ref,  # scalar prefetch
-                               q_ref, k_hbm, v_hbm, o_ref,
-                               k_buf, v_buf, sems, slot_ref,
-                               m_scratch, l_scratch, acc_scratch,
-                               *, page_size: int, pages_per_block: int,
-                               table_pages: int, sm_scale: float):
+                               *refs, page_size: int, pages_per_block: int,
+                               table_pages: int, sm_scale: float,
+                               writes: bool):
     # Grid: (B,), one step a sequence. The pools stay in HBM; the step
     # loops over the sequence's LIVE blocks of `pages_per_block` pages,
     # copying each live page (all KV heads: one contiguous transfer in
@@ -196,18 +102,33 @@ def _paged_decode_batch_kernel(length_ref, page_table_ref,  # scalar prefetch
     # first block of the next one, so only the very first copy of a call
     # is waited for with nothing to compute. `slot_ref` (SMEM) carries the
     # slot that copy went to from one grid step to the next.
+    if writes:
+        # The pools are outputs aliased to the inputs: read and written
+        # through the one (output) ref, so that a read after the write
+        # sees it on the chip and in interpret mode alike.
+        (q_ref, k_new_ref, v_new_ref, _, _, o_ref, k_hbm, v_hbm,
+         k_buf, v_buf, sems, slot_ref,
+         m_scratch, l_scratch, acc_scratch) = refs
+    else:
+        (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref,
+         m_scratch, l_scratch, acc_scratch) = refs
     b = pl.program_id(0)
     num_seqs = pl.num_programs(0)
     num_heads = q_ref.shape[1]
     block_tokens = page_size * pages_per_block
-    length = length_ref[b]
+    table_tokens = table_pages * page_size
+
+    def length_of(seq):
+        return jnp.minimum(length_ref[seq], table_tokens)
+
+    length = length_of(b)
     num_blocks = pl.cdiv(length, block_tokens)
 
     def for_live_pages(seq, blk, slot, act):
         """`act` on the K and the V copy of every live page of block
         `blk` of sequence `seq`, into buffer `slot`."""
         first = blk * pages_per_block
-        live = jnp.clip(pl.cdiv(length_ref[seq], page_size) - first,
+        live = jnp.clip(pl.cdiv(length_of(seq), page_size) - first,
                         0, pages_per_block)
 
         def page(j, carry):
@@ -225,6 +146,23 @@ def _paged_decode_batch_kernel(length_ref, page_table_ref,  # scalar prefetch
 
     def wait(seq, blk, slot):
         for_live_pages(seq, blk, slot, lambda copy: copy.wait())
+
+    if writes:
+        # The new token: position `length - 1` of the sequence, in its
+        # last live page. A position outside the table (the clamp above)
+        # or a length of 0 writes nothing.
+        new_pos = length_ref[b] - 1
+        new_page = new_pos // page_size
+        puts = jnp.logical_and(new_pos >= 0, new_pos < table_tokens)
+
+        def for_new_page(slot, j, act):
+            """`act` on the copies of page `j` of buffer `slot` back to
+            the pools, as the page of the new token."""
+            dst = page_table_ref[b * table_pages + new_page]
+            act(pltpu.make_async_copy(
+                k_buf.at[slot, j], k_hbm.at[dst], sems.at[0, 2]))
+            act(pltpu.make_async_copy(
+                v_buf.at[slot, j], v_hbm.at[dst], sems.at[1, 2]))
 
     @pl.when(b == 0)
     def _first():
@@ -253,6 +191,23 @@ def _paged_decode_batch_kernel(length_ref, page_table_ref,  # scalar prefetch
                   1 - slot)
 
         wait(b, blk, slot)
+        if writes:
+            new_j = new_page - blk * pages_per_block
+
+            @pl.when(jnp.logical_and(last, puts))
+            def _put():
+                # The row goes into the page's copy by a select on the
+                # token's index (no store of part of a packed tile), and
+                # the page goes back while the block is computed.
+                here = jax.lax.broadcasted_iota(
+                    jnp.int32, k_buf.shape[-2:], 0) == new_pos % page_size
+                for h in range(num_heads):
+                    for buf, new in ((k_buf, k_new_ref), (v_buf, v_new_ref)):
+                        buf[slot, new_j, h] = jnp.where(
+                            here, new[0, pl.ds(h, 1), :],
+                            buf[slot, new_j, h])
+                for_new_page(slot, new_j, lambda copy: copy.start())
+
         # Dots over the whole block, dead pages too (masked): a dot for
         # every 1, 2 or 4 pages spared those and was still slower in all
         # three shapes measured, its fixed cost a head is most of it.
@@ -269,6 +224,12 @@ def _paged_decode_batch_kernel(length_ref, page_table_ref,  # scalar prefetch
             m_scratch[h] = m_new
             l_scratch[h] = l_new
             acc_scratch[h] = acc_new
+
+        if writes:
+            # before the slot is filled again, and before the call ends
+            @pl.when(jnp.logical_and(last, puts))
+            def _put_done():
+                for_new_page(slot, new_j, lambda copy: copy.wait())
         return carry
 
     jax.lax.fori_loop(0, num_blocks, block, None)
@@ -285,7 +246,8 @@ def _paged_decode_batch_kernel(length_ref, page_table_ref,  # scalar prefetch
 
 
 def paged_decode_attention_batch(q, k_pool, v_pool, page_tables, lengths,
-                                 *, sm_scale: float | None = None):
+                                 *, k_new=None, v_new=None,
+                                 sm_scale: float | None = None):
     """Batched single-token decode attention over paged KV.
 
     One kernel for every slot of a continuous-batching engine. Its work
@@ -296,20 +258,30 @@ def paged_decode_attention_batch(q, k_pool, v_pool, page_tables, lengths,
 
     q:           (B, H, D) one query per sequence
     k/v_pool:    (P, Hkv, page_size, D) pools SHARED by all sequences:
-                 page p is one contiguous Hkv x page_size x D block, and
-                 is copied whole (see paged_decode_attention for the
-                 layout)
+                 head-then-page minor layout, so page p is one contiguous
+                 Hkv x page_size x D block, copied whole, and each (head,
+                 page) a (page_size, D) tile
     page_tables: (B, NP) int32 pool indices per sequence (entries past
                  the live length are never read)
-    lengths:     (B,) int32 valid token counts (incl. current tokens),
-                 at most NP * page_size; a row of length 0 returns zeros
-    Returns (B, H, D).
+    lengths:     (B,) int32 valid token counts (incl. current tokens);
+                 a row of length 0 returns zeros, one past NP * page_size
+                 attends over the table's NP * page_size
+    k/v_new:     (B, Hkv, D), or none: the current tokens' K and V, which
+                 the call then writes before it attends: row b to page
+                 `page_tables[b, (lengths[b] - 1) // page_size]`, offset
+                 `(lengths[b] - 1) % page_size` (nothing where that is
+                 outside the table), rounded to the pools' type. That
+                 page must be sequence b's own.
+    Returns (B, H, D); with k/v_new, (out, k_pool, v_pool): the pools
+    updated in place (donate them, or the caller pays a copy).
     """
     _, Hkv, page_size, D = k_pool.shape
     if sm_scale is None:
         sm_scale = 1.0 / (D ** 0.5)
+    new = None if k_new is None else (k_new.astype(k_pool.dtype),
+                                      v_new.astype(v_pool.dtype))
     return _paged_decode_batch_call(
-        q, k_pool, v_pool, page_tables, lengths, sm_scale=sm_scale,
+        q, k_pool, v_pool, page_tables, lengths, new, sm_scale=sm_scale,
         pages_per_block=_pages_per_block(
             Hkv * page_size * D * k_pool.dtype.itemsize,
             page_tables.shape[1]),
@@ -319,50 +291,59 @@ def paged_decode_attention_batch(q, k_pool, v_pool, page_tables, lengths,
 # jit, so that the layers of a model share ONE lowering of the kernel:
 # its body (the heads are unrolled) takes a second or two to lower on the
 # chip's host, and a decode program lowered it once a layer, in every
-# process's set-up (30-39 s for 16 layers; my chip runs, PR 25).
+# process's set-up (30-39 s for 16 layers; my chip runs, PR 25). `new` is
+# the rows to write or None: one lowering for each of the two.
 @functools.partial(jax.jit, static_argnames=("sm_scale", "pages_per_block",
                                              "interpret"))
-def _paged_decode_batch_call(q, k_pool, v_pool, page_tables, lengths, *,
+def _paged_decode_batch_call(q, k_pool, v_pool, page_tables, lengths, new, *,
                              sm_scale: float, pages_per_block: int,
                              interpret: bool):
     B, H, D = q.shape
     P, Hkv, page_size, _ = k_pool.shape
     groups = H // Hkv
     table_pages = page_tables.shape[1]
+    writes = new is not None
     buf = (2, pages_per_block, Hkv, page_size, D)
     row = pl.BlockSpec((1, Hkv, groups, D), lambda b, ln, pt: (b, 0, 0, 0))
+    new_row = pl.BlockSpec((1, Hkv, D), lambda b, ln, pt: (b, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out = jax.ShapeDtypeStruct((B, Hkv, groups, D), q.dtype)
+    pool = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B,),
-        in_specs=[row,
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=row,
+        in_specs=[row] + [new_row] * (2 * writes) + [in_hbm, in_hbm],
+        out_specs=[row, in_hbm, in_hbm] if writes else row,
         scratch_shapes=[
             pltpu.VMEM(buf, k_pool.dtype),
             pltpu.VMEM(buf, v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),        # (K | V, slot)
+            # (K | V, the two slots' reads and the new page's write)
+            pltpu.SemaphoreType.DMA((2, 3)),
             pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((Hkv, groups, 1), jnp.float32),
             pltpu.VMEM((Hkv, groups, 1), jnp.float32),
             pltpu.VMEM((Hkv, groups, D), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    result = pl.pallas_call(
         functools.partial(_paged_decode_batch_kernel, page_size=page_size,
                           pages_per_block=pages_per_block,
-                          table_pages=table_pages, sm_scale=sm_scale),
+                          table_pages=table_pages, sm_scale=sm_scale,
+                          writes=writes),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, groups, D), q.dtype),
+        out_shape=[out, pool(k_pool), pool(v_pool)] if writes else out,
+        # (operands count from the scalar-prefetched two)
+        input_output_aliases={5: 1, 6: 2} if writes else {},
         # The slot handed from one sequence to the next makes the grid a
         # sequence, not a set.
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(jnp.minimum(lengths.astype(jnp.int32), table_pages * page_size),
-      page_tables.astype(jnp.int32).reshape(-1),
-      q.reshape(B, Hkv, groups, D), k_pool, v_pool)
-    return out.reshape(B, H, D)
+    )(lengths.astype(jnp.int32), page_tables.astype(jnp.int32).reshape(-1),
+      q.reshape(B, Hkv, groups, D), *(new or ()), k_pool, v_pool)
+    if writes:
+        return (result[0].reshape(B, H, D), *result[1:])
+    return result.reshape(B, H, D)
 
 
 class PageAllocator:

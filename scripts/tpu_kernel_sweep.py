@@ -83,7 +83,10 @@ def check_paged(Hkv: int = 8, page: int = 16, npages_seq: int = 8,
     """Hkv == H exercises MHA; Hkv < H exercises the GQA grouped-query
     q-block path (groups > 1), which must be validated on-chip too.
     `lengths` may be ragged and may hold 0 (an empty slot: its row is
-    ignored, as the engine ignores it)."""
+    ignored, as the engine ignores it).  Then the same call with the
+    current tokens' rows handed in: the pools it returns are the pools
+    with those rows scattered in, bit for bit, and its output is the
+    read-only call's on them."""
     from ray_tpu.ops.paged_attention import paged_decode_attention_batch
     H, D = 8, 128
     B = len(lengths)
@@ -132,11 +135,32 @@ def check_paged(Hkv: int = 8, page: int = 16, npages_seq: int = 8,
         ref = np.einsum("hl,lhd->hd", p_, vb.astype(np.float32))
         err = max(err, float(np.max(np.abs(
             np.asarray(out[b], np.float32) - ref))))
-    ok = finite and err < 0.05
+    # the write: rows at position length - 1 (a length of 0 has none)
+    k_new = jax.random.normal(jax.random.PRNGKey(2), (B, Hkv, D),
+                              jnp.bfloat16)
+    v_new = jax.random.normal(jax.random.PRNGKey(3), (B, Hkv, D),
+                              jnp.bfloat16)
+    at = np.maximum(lengths - 1, 0)
+    rows = np.flatnonzero(lengths > 0)
+    pages = np.asarray(tables)[rows, at[rows] // page]
+    k_ref = k_pool.at[pages, :, at[rows] % page].set(k_new[rows])
+    v_ref = v_pool.at[pages, :, at[rows] % page].set(v_new[rows])
+    out_ref = paged_decode_attention_batch(q, k_ref, v_ref, tables,
+                                           lengths_j)
+    out_w, k_got, v_got = paged_decode_attention_batch(
+        q, k_pool, v_pool, tables, lengths_j, k_new=k_new, v_new=v_new)
+    bits = lambda a: np.asarray(a).view(np.uint16)  # noqa: E731
+    pools_equal = bool((bits(k_got) == bits(k_ref)).all()
+                       and (bits(v_got) == bits(v_ref)).all())
+    live = lengths > 0
+    out_equal = bool((bits(out_w)[live] == bits(out_ref)[live]).all())
+    ok = finite and err < 0.05 and pools_equal and out_equal
     print(json.dumps({"check": "paged_decode_onchip", "Hkv": Hkv,
                       "groups": groups, "page": page,
                       "lengths": lengths.tolist(), "finite": finite,
-                      "max_abs_err": round(err, 5), "ok": ok}))
+                      "max_abs_err": round(err, 5),
+                      "written_pools_bit_equal": pools_equal,
+                      "written_output_bit_equal": out_equal, "ok": ok}))
     return ok
 
 

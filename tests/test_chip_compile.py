@@ -90,6 +90,23 @@ def _peak_bytes(compiled) -> int:
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
+_NOTHING = dict.fromkeys(chip_smoke.STATE_MOVES + ("moved",), 0)
+
+
+def _compiled_decode_chunk(eng, params, one_chip):
+    """The engine's decode chunk compiled for the chip at the engine's
+    own shapes (a count of steps a slot where the family needs one)."""
+    B = eng.max_batch
+    S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    steps = () if eng.family.rewinds else (S((B,), jnp.int32),)
+    return eng._decode_chunk_paged.lower(
+        _on(one_chip, params), S((B,), jnp.int32), S((B,), jnp.int32),
+        _on(one_chip, eng._pools), S(eng._tables.shape, jnp.int32),
+        S((B,), jnp.int32), S((B,), jnp.float32), S((B,), jnp.int32),
+        S((B,), jnp.float32), S((2,), jnp.uint32), *steps).compile()
+
+
 def _qkv(one_chip, seq, hkv=32):
     q = jax.ShapeDtypeStruct((1, 32, seq, 128), jnp.bfloat16,
                              sharding=one_chip)
@@ -118,20 +135,39 @@ def test_flash_forward_backward(one_chip):
     assert KERNEL in compiled.as_text()
 
 
+def _paged_call(one_chip, B, H, Hkv, pool_pages, table_pages, q_dtype,
+                writes, sm_scale=None):
+    """The paged kernel compiled alone: pages of 64 tokens, heads of 128,
+    bfloat16 pools; with the step's rows handed in (`writes`: the pools
+    donated, as the engine donates them) or read-only."""
+    S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    pool = S((pool_pages, Hkv, 64, 128), jnp.bfloat16)
+    new = S((B, Hkv, 128), q_dtype)
+
+    def call(q, k_pool, v_pool, tables, lengths, *rows):
+        return paged_attention.paged_decode_attention_batch(
+            q, k_pool, v_pool, tables, lengths, sm_scale=sm_scale,
+            **dict(zip(("k_new", "v_new"), rows)))
+
+    return jax.jit(call, donate_argnums=(1, 2) if writes else ()).lower(
+        S((B, H, 128), q_dtype), pool, pool, S((B, table_pages), jnp.int32),
+        S((B,), jnp.int32), *((new, new) if writes else ())).compile()
+
+
 @pytest.mark.parametrize("B, pool_pages", [(32, 385), (4, 193)],
                          ids=["chat_open_b32", "docs_closed_b4"])
 def test_paged_decode_batch(one_chip, B, pool_pages):
     """The two serve cells' shapes (`benchmarks/configs/mistral-7b-v0.3-
     l16*.json`: 32/8 heads of 128, pages of 64, a table of ceil((2304 + 8)
-    / 64) = 37 columns, the pool with its dummy page)."""
-    H, Hkv, D, page, table_pages = 32, 8, 128, 64, 37
-    S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
-        shape, dt, sharding=one_chip)
-    pool = S((pool_pages, Hkv, page, D), jnp.bfloat16)
-    compiled = jax.jit(paged_attention.paged_decode_attention_batch).lower(
-        S((B, H, D), jnp.bfloat16), pool, pool,
-        S((B, table_pages), jnp.int32), S((B,), jnp.int32)).compile()
+    / 64) = 37 columns, the pool with its dummy page), as a decode step
+    calls the kernel: with the step's rows, the pools aliased in place."""
+    compiled = _paged_call(one_chip, B, 32, 8, pool_pages, 37, jnp.bfloat16,
+                           writes=True)
     assert KERNEL in compiled.as_text()
+    pool_bytes = pool_pages * 8 * 64 * 128 * 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * pool_bytes
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
 @pytest.fixture(scope="module")
@@ -151,16 +187,77 @@ def engine_programs(one_chip):
 
 def test_engine_decode_step(engine_programs, one_chip):
     cfg, eng, params = engine_programs
-    B = eng.max_batch
-    S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
-        shape, dt, sharding=one_chip)
-    compiled = eng._decode_chunk_paged.lower(
-        params, S((B,), jnp.int32), S((B,), jnp.int32),
-        _on(one_chip, eng._pools), S(eng._tables.shape, jnp.int32),
-        S((B,), jnp.int32), S((B,), jnp.float32), S((B,), jnp.int32),
-        S((B,), jnp.float32), S((2,), jnp.uint32)).compile()
+    compiled = _compiled_decode_chunk(eng, params, one_chip)
     assert KERNEL in compiled.as_text()
     assert _peak_bytes(compiled) < HBM_BYTES
+
+
+# `benchmarks/configs/mistral-7b-v0.3-l16.json`: the engine of
+# `mistral7b-serve-chat-open`.
+CHAT_OPEN_ENGINE = dict(max_batch=32, max_len=2304, page_size=64,
+                        decode_chunk=8, kv_pool_tokens=24576)
+
+
+def test_decode_chunk_leaves_the_pools_where_they_lie(one_chip):
+    """The decode chunk at chat-open's shapes (published widths, 385
+    pages of 8 x 64 x 128 a pool: 50 MB), cut to 2 layers: no pool is
+    copied to another layout or moved to another memory space, in the
+    loop or around it.  The kernel writes the step's token itself, in
+    place; a one-token scatter outside it made the compiler carry every
+    pool token-major through the loop: at 2 layers 4 layout copies a
+    step and 8 copies + 4 pools prefetched a chunk, at 16 layers 32
+    copies + 31 pools moved a step and 64 copies + 40 pools moved a
+    chunk (the parent of PR 29, this helper).  At the smoke's 34-page
+    pool the compiler prefetches whole pools whatever form the write
+    has, so that size tells nothing."""
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg = LlamaConfig(vocab_size=32768, d_model=4096, n_layers=2,
+                      n_heads=32, n_kv_heads=8, d_ff=14336,
+                      rope_theta=1e6)
+    params = jax.eval_shape(
+        lambda: LlamaModel(cfg).init(jax.random.PRNGKey(0),
+                                     jnp.zeros((1, 8), jnp.int32)))
+    eng = LLMEngine(cfg, params, **CHAT_OPEN_ENGINE)
+    try:
+        text = _compiled_decode_chunk(eng, params, one_chip).as_text()
+        assert text.count(KERNEL) == cfg.n_layers
+        moves = chip_smoke.state_moves(text, eng._pools)
+        assert moves == {"loop": _NOTHING, "outside": _NOTHING}
+    finally:
+        eng.shutdown()
+
+
+def test_state_moves_counts_what_a_compiled_program_holds():
+    """The counter on a text with one of each: a layout copy in a loop's
+    body, half a leaf prefetched in slices there, a whole leaf moved
+    outside, and copies of other shapes, which do not count."""
+    pool = "bf16[6,8,64,128]"
+    text = f"""
+%fused (p: {pool}) -> {pool} {{
+  ROOT %copy.9 = {pool}{{3,2,1,0}} copy(%p)
+}}
+
+%body (arg: ({pool})) -> ({pool}) {{
+  %copy.1 = {pool}{{3,1,2,0:T(8,128)(2,1)}} copy(%x)
+  %copy.2 = bf16[32,128]{{1,0}} copy(%y)
+  %slice-start.1 = (({pool}{{3,2,1,0}}), bf16[3,8,64,128]{{3,2,1,0:S(1)}}, s32[]) slice-start(%x), slice={{[0:3], [0:8], [0:64], [0:128]}}
+  %f = {pool} fusion(%x), kind=kLoop, calls=%fused
+}}
+
+ENTRY %main (a: {pool}) -> {pool} {{
+  %w = ({pool}) while(%t), condition=%cond, body=%body
+  %copy-start.1 = ({pool}{{3,2,1,0:S(1)}}, {pool}{{3,2,1,0}}, u32[]) copy-start(%a)
+  ROOT %copy.3 = f32[6,8,64,128]{{3,2,1,0}} copy(%b)
+}}
+"""
+    state = [jax.ShapeDtypeStruct((6, 8, 64, 128), jnp.bfloat16)]
+    assert chip_smoke.state_moves(text, state) == {
+        "loop": {"copy": 2, "copy-start": 0, "slice-start": 1,
+                 "moved": 0.5},
+        "outside": {"copy": 0, "copy-start": 1, "slice-start": 0,
+                    "moved": 1}}
 
 
 def test_engine_batched_prefill(engine_programs, one_chip):
@@ -246,19 +343,62 @@ def test_sambay_kernels_at_the_cells_shapes(one_chip):
     """Heads of 64 run as pairs: 40 zero-padded query heads and 10 KV
     heads of 128, scale 1/8.  The paged kernel over the one shared pool
     (4,737 pages of 64, a table of ceil((17472 + 8) / 64) = 274 columns,
-    float32 queries) and the flash kernel over a 16,384-token prompt."""
+    float32 queries and rows) and the flash kernel over a 16,384-token
+    prompt."""
     S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
-    pool = S((4737, 10, 64, 128), jnp.bfloat16)
-    compiled = jax.jit(lambda *a: paged_attention.paged_decode_attention_batch(
-        *a, sm_scale=0.125)).lower(
-        S((32, 40, 128), jnp.float32), pool, pool, S((32, 274), jnp.int32),
-        S((32,), jnp.int32)).compile()
-    assert KERNEL in compiled.as_text()
+    for writes in (True, False):    # the full layer's call, a cross layer's
+        compiled = _paged_call(one_chip, 32, 40, 10, 4737, 274, jnp.float32,
+                               writes, sm_scale=0.125)
+        assert KERNEL in compiled.as_text()
     qkv = S((1, 40, 16384, 128), jnp.bfloat16)
     compiled = jax.jit(lambda q, k, v: attention.flash_attention(
         q, k, v, 0.125, True)).lower(qkv, qkv, qkv).compile()
     assert KERNEL in compiled.as_text()
+
+
+def _sambay_engine(cfg):
+    from ray_tpu.models.sambay import SambaYModel
+    from ray_tpu.serve.llm import LLMEngine
+
+    params = jax.eval_shape(
+        lambda: SambaYModel(cfg).init(jax.random.PRNGKey(0),
+                                      jnp.zeros((1, 8), jnp.int32)))
+    return LLMEngine(cfg, params, **SAMBAY_ENGINE), params
+
+
+def _assert_the_pool_stays(text, eng):
+    """No copy or move of the one shared pool (775 MB a side), in the
+    loop or around it: the full layer's kernel call writes the token in
+    place and the cross layers read what it returned.  (The rings are
+    still written by a scatter outside any kernel, and still copied: one
+    layout copy a ring and step, two a ring and chunk.  Held token-major,
+    (B, window, Hkv/2, 2 Dh), the write needs none, but the attention's
+    dot then takes its operand through a transposing copy of the same
+    size, every step: PERF.md section 6, PR 29.)"""
+    moves = chip_smoke.state_moves(text, eng._pools["pool"])
+    assert moves == {"loop": _NOTHING, "outside": _NOTHING}
+
+
+def test_sambay_decode_chunk_leaves_the_pool_where_it_lies(one_chip):
+    """Published widths and the cell's engine, cut to 8 layers: every
+    kind of layer occurs (three Mamba, two window, the full one, a GMU
+    and a cross layer that reads the pool the full layer's call
+    returned)."""
+    import dataclasses
+
+    from ray_tpu.models.sambay import PHI4_MINI_FLASH
+
+    cfg = dataclasses.replace(PHI4_MINI_FLASH, n_layers=8)
+    assert {cfg.kind(i) for i in range(8)} == {
+        "mamba", "window", "full", "gmu", "cross"}
+    eng, params = _sambay_engine(cfg)
+    try:
+        text = _compiled_decode_chunk(eng, params, one_chip).as_text()
+        assert text.count(KERNEL) == 2
+        _assert_the_pool_stays(text, eng)
+    finally:
+        eng.shutdown()
 
 
 @pytest.mark.slow      # 45 s of a many-threaded compile: by hand, not in tier-1
@@ -269,27 +409,18 @@ def test_sambay_engine_programs_fit_the_chip(one_chip):
     and its largest prefill (one row of 16,384 tokens: the scan, eight
     windowed layers in blocks, flash over the full layer) at published
     widths, each beside the weights and the engine's whole state."""
-    from ray_tpu.models.sambay import PHI4_MINI_FLASH, SambaYModel
-    from ray_tpu.serve.llm import LLMEngine
+    from ray_tpu.models.sambay import PHI4_MINI_FLASH
 
-    cfg = PHI4_MINI_FLASH
-    params = jax.eval_shape(
-        lambda: SambaYModel(cfg).init(jax.random.PRNGKey(0),
-                                      jnp.zeros((1, 8), jnp.int32)))
-    eng = LLMEngine(cfg, params, **SAMBAY_ENGINE)
+    eng, params = _sambay_engine(PHI4_MINI_FLASH)
     try:
         B = eng.max_batch
         S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
             shape, dt, sharding=one_chip)
-        state = _on(one_chip, eng._pools)
         state_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                           for x in jax.tree_util.tree_leaves(eng._pools))
-        decode = eng._decode_chunk_paged.lower(
-            _on(one_chip, params), S((B,), jnp.int32), S((B,), jnp.int32),
-            state, S(eng._tables.shape, jnp.int32), S((B,), jnp.int32),
-            S((B,), jnp.float32), S((B,), jnp.int32), S((B,), jnp.float32),
-            S((2,), jnp.uint32), S((B,), jnp.int32)).compile()
-        assert decode.as_text().count(KERNEL) >= 8
+        decode = _compiled_decode_chunk(eng, params, one_chip)
+        assert decode.as_text().count(KERNEL) == 8
+        _assert_the_pool_stays(decode.as_text(), eng)
         assert _peak_bytes(decode) < HBM_BYTES
         assert eng.family.prefill_width(16384, B) == 1
         prefill = eng._prefill_one.lower(

@@ -178,3 +178,47 @@ def test_freed_slot_has_length_zero_and_live_pages_are_counted(tiny_model):
         assert got["paged_live_share"] == pytest.approx(live / table)
     finally:
         eng.shutdown()
+
+
+def test_one_slot_used_again_and_again(tiny_model):
+    """Three requests through the one slot of an engine, none a multiple
+    of the chunk long: each stream's last chunk runs past its end into
+    pages that go back to the pool, and the next stream takes slot and
+    pages over. Every stream is the Generator's."""
+    cfg, params = tiny_model
+    eng = LLMEngine(cfg, params, max_batch=1, max_len=96, page_size=16,
+                    decode_chunk=4, kv_pool_tokens=64)
+    try:
+        prompts = [[1 + (3 * i) % 90 for i in range(20)], [5, 6, 7],
+                   [9 + i for i in range(33)]]
+        handles = [eng.submit(p, SamplingParams(max_new_tokens=n))
+                   for p, n in zip(prompts, (14, 7, 10))]
+        for p, n, h in zip(prompts, (14, 7, 10), handles):
+            assert h.tokens() == _reference_greedy(cfg, params, p, n)
+        assert eng._alloc.free_pages == eng._num_pages - 1
+    finally:
+        eng.shutdown()
+
+
+def test_a_rerun_chunk_overwrites_what_it_wrote(tiny_model):
+    """A consumer that stops reading fills its queue; the engine drops
+    the steps it could not hand over and runs them again from the
+    committed position (`LlamaServing.rewinds`). The kernel writes a
+    rerun step's K/V over the uncommitted ones before reading them, so
+    the stream, and the stream beside it, stay the Generator's."""
+    import time
+
+    cfg, params = tiny_model
+    eng = LLMEngine(cfg, params, max_batch=2, max_len=96, page_size=16,
+                    decode_chunk=4, stream_buffer=3)
+    try:
+        slow_p, fast_p = [2, 4, 6, 8, 10, 12, 14], [3 + i for i in range(19)]
+        slow = eng.submit(slow_p, SamplingParams(max_new_tokens=26))
+        fast = eng.submit(fast_p, SamplingParams(max_new_tokens=26))
+        fast_out = fast.tokens()            # slow is not read meanwhile
+        assert eng.report_metrics()["parked_events"] > 0
+        time.sleep(0.2)
+        assert slow.tokens() == _reference_greedy(cfg, params, slow_p, 26)
+        assert fast_out == _reference_greedy(cfg, params, fast_p, 26)
+    finally:
+        eng.shutdown()
