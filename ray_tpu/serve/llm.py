@@ -10,23 +10,19 @@ behind Serve deployments); this engine is native and TPU-shaped:
 - **Slot-based continuous batching.** The decode batch is a fixed set of
   `max_batch` slots; new requests prefill into a free slot mid-flight
   while other slots keep decoding (the continuous-batching idea:
-  admission does not wait for the batch to drain).
-- **Per-slot KV caches with per-slot write offsets** via `jax.vmap` of
-  the single-sequence decode step — each slot advances at its own
-  position, which a plain batched `dynamic_update_slice` (one offset for
-  all rows) cannot express.
+  admission does not wait for the batch to drain). Each slot advances
+  at its own position: its length and its row of the page table say
+  where its next token is written and what it attends over.
 - **Streaming.** `submit()` returns a handle whose iterator yields tokens
   as they are produced; `LLMDeployment` plugs that into Serve's
   generator-streaming path (`handle.options(stream=True)` / `?stream=1`).
-
-- **Paged KV (page_size > 0).** Slots share one pool of fixed-size KV
-  pages per layer (vLLM block tables, TPU-shaped: the scalar-prefetch
-  pallas kernel in ops/paged_attention.py attends over scattered pages;
-  PageAllocator manages the free list host-side). HBM is bounded by
-  `kv_pool_tokens` RESIDENT tokens, not max_len x slots — admission
-  defers requests when the pool is dry and pages return to the free
-  list the moment a stream completes. page_size=0 keeps the dense
-  per-slot max_len caches.
+- **Paged KV.** Slots share one pool of fixed-size KV pages per layer
+  (vLLM block tables, TPU-shaped: the scalar-prefetch pallas kernel in
+  ops/paged_attention.py attends over scattered pages; PageAllocator
+  manages the free list host-side). HBM is bounded by `kv_pool_tokens`
+  RESIDENT tokens, not max_len x slots — admission defers requests when
+  the pool is dry and pages return to the free list the moment a stream
+  completes. `page_size` is a size like `max_len`, which it divides.
 - **One seam to the model** (`serve/llm_families.py`): what a sequence's
   state is (which parts are paged, which are fixed per slot), prefill ->
   state at each row's last token, one decode step over the state. The
@@ -57,11 +53,7 @@ logger = logging.getLogger(__name__)
 class _Slot:
     request: "RequestHandle | None" = None
     generated: int = 0
-    # Chunked prefill in progress: the full prompt and how much of it has
-    # been written into this slot's KV cache so far. None = decoding.
-    prefill_prompt: "object" = None
-    prefill_pos: int = 0
-    # Paged mode: allocator key owning this slot's pages.
+    # Allocator key owning this slot's pages.
     seq_id: str = ""
     # Every token this stream has generated (including ones still queued
     # in the handle). A drain snapshot ships this so a resumed stream can
@@ -159,9 +151,8 @@ class LLMEngine:
 
     def __init__(self, cfg, params, *, max_batch: int = 4,
                  max_len: int = 1024, decode_chunk: int = 8,
-                 prefill_chunk: int = 0, rng_seed: int = 0,
-                 page_size: int = 0, kv_pool_tokens: int = 0,
-                 use_device_plane: bool = True, stream_buffer: int = 256):
+                 rng_seed: int = 0, page_size: int = 64,
+                 kv_pool_tokens: int = 0, stream_buffer: int = 256):
         import jax
         import jax.numpy as jnp
 
@@ -180,9 +171,8 @@ class LLMEngine:
         # through the plane's gauges. Fails open: any plane error falls
         # back to the direct in-memory handoff, counted in
         # handoff_fallbacks.
-        self.use_device_plane = use_device_plane
         self.handoff_fallbacks = 0
-        # Cumulative over decode dispatches (paged mode): the table pages
+        # Cumulative over decode dispatches: the table pages
         # the kernel had to visit, and the pages the tables hold. Counters,
         # so that a reader takes the share over its own window (and leaves
         # warm-up out) by difference.
@@ -191,32 +181,24 @@ class LLMEngine:
         # Slots whose fixed per-slot state (rings, recurrent state) an
         # admission replaced with what its own prefill computed from zero.
         self.state_slots_reset = 0
-        # Paged KV mode (page_size > 0): admission is bounded by POOL
-        # pages (resident tokens), not slot count x max_len.
+        # Tokens a KV page holds: admission is bounded by POOL pages
+        # (resident tokens), not slot count x max_len.
+        if page_size <= 0:
+            raise ValueError(
+                f"page_size={page_size} must be positive: the engine "
+                "keeps every stream's KV in pages")
+        if max_len % page_size:
+            raise ValueError(
+                f"max_len={max_len} must be a multiple of "
+                f"page_size={page_size}")
         self.page_size = page_size
-        if page_size:
-            if max_len % page_size:
-                raise ValueError(
-                    f"max_len={max_len} must be a multiple of "
-                    f"page_size={page_size}")
-            if prefill_chunk:
-                raise ValueError(
-                    "chunked prefill is not supported in paged mode")
         # Steps per compiled decode call: one host sync per CHUNK, not per
         # token (dispatch/fetch latency dominates single-token decode).
         # Admission waits at most one chunk; tokens stream with chunk
         # granularity.
         self.decode_chunk = max(1, decode_chunk)
-        # >0: prompts longer than this prefill in chunks INTERLEAVED with
-        # decode ticks, so one long prompt cannot stall every in-flight
-        # stream for its whole prefill. 0: whole-prompt bucketed prefill.
-        self.prefill_chunk = prefill_chunk
         # What the model tells the engine (serve/llm_families.py).
         self.family = family = family_of(cfg, max_len)
-        if not page_size and not family.dense:
-            raise ValueError(
-                f"{type(cfg).__name__} keeps pages beside per-slot state: "
-                "it is served paged (page_size > 0)")
         self.model = family.model
         self._jax, self._jnp = jax, jnp
         self._rng = jax.random.PRNGKey(rng_seed)
@@ -232,11 +214,6 @@ class LLMEngine:
         def prefill_one(params, tokens, last_idx):
             # tokens: (1, bucket) right-padded; last_idx: (1,)
             return family.prefill(params, tokens, last_idx)
-
-        if family.dense:
-            self._prefill_chunk = functools.partial(
-                jax.jit, donate_argnums=(3,))(family.prefill_chunk)
-            decode_step = family.decode_dense
 
         V = cfg.vocab_size
 
@@ -269,100 +246,72 @@ class LLMEngine:
             return jax.lax.cond(jnp.all(temps <= 0.0), lambda: greedy,
                                 sampled)
 
-        K = self.decode_chunk
-
-        def decode_chunk_fn(params, token, pos, kv, lens, temps, top_ks,
-                            top_ps, base_rng):
-            # K decode steps in one program (lax.scan): sampling happens
-            # in-device, so only the (K, B) token block crosses to host.
-            def body(carry, i):
-                token, pos, kv, lens = carry
-                logits, kv = decode_step(params, token, pos, kv, lens)
-                tok = _sample(logits, temps, top_ks, top_ps,
-                              jax.random.fold_in(base_rng, i))
-                return (tok, pos + 1, kv, lens + 1), tok
-
-            (token, pos, kv, lens), toks = jax.lax.scan(
-                body, (token, pos, kv, lens), jnp.arange(K))
-            return toks, kv  # toks: (K, B)
-
-        # Donating the caches makes each chunk update KV in place instead
-        # of copying the full (B,Hkv,L,D)·2·layers working set through HBM.
-        if family.dense:
-            self._decode_chunk_fn = jax.jit(decode_chunk_fn,
-                                            donate_argnums=(3,))
         self._sample = jax.jit(_sample)
         self._prefill_one = prefill_one
 
-        # ---- paged-mode programs ----------------------------------------
+        K = self.decode_chunk
+        # Overshoot margin: a chunk of K steps may run up to K-1
+        # tokens past a stream's max_new before the host notices eos.
+        pool_tokens = kv_pool_tokens or max_batch * (max_len + K)
+        self._np_pages = -(-(max_len + K) // page_size)  # table width
+        self._num_pages = -(-pool_tokens // page_size) + 1  # + dummy
+        self._init_paged_state()  # allocator, `_pools`, `_tables`
 
-        if page_size:
-            # Overshoot margin: a chunk of K steps may run up to K-1
-            # tokens past a stream's max_new before the host notices eos.
-            pool_tokens = kv_pool_tokens or max_batch * (max_len + K)
-            self._np_pages = -(-(max_len + K) // page_size)  # table width
-            self._num_pages = -(-pool_tokens // page_size) + 1  # + dummy
-            self._tables = None  # created by _init_paged_state
-            self._init_paged_state()
+        def decode_chunk_paged(params, token, pos, pools, tables, lens,
+                               temps, top_ks, top_ps, base_rng, steps=None):
+            # K decode steps in one program (lax.scan): sampling happens
+            # in-device, so only the (K, B) token block crosses to host.
+            # `steps` (B,), where the family's state cannot be rewound:
+            # a slot advances that many steps of the chunk and is held
+            # still after them (a parked or an empty slot: 0).
+            def body(carry, i):
+                token, pos, pools, lens = carry
+                live = None if steps is None else i < steps
+                logits, pools2 = family.decode(params, token, pos, pools,
+                                               tables, lens, live)
+                tok = _sample(logits, temps, top_ks, top_ps,
+                              jax.random.fold_in(base_rng, i))
+                if live is None:
+                    return (tok, pos + 1, pools2, lens + 1), tok
+                return (jnp.where(live, tok, token), pos + live, pools2,
+                        lens + live), tok
 
-            def decode_chunk_paged(params, token, pos, pools, tables, lens,
-                                   temps, top_ks, top_ps, base_rng,
-                                   steps=None):
-                # `steps` (B,), where the family's state cannot be rewound:
-                # a slot advances that many steps of the chunk and is held
-                # still after them (a parked or an empty slot: 0).
-                def body(carry, i):
-                    token, pos, pools, lens = carry
-                    live = None if steps is None else i < steps
-                    logits, pools2 = family.decode(params, token, pos, pools,
-                                                   tables, lens, live)
-                    tok = _sample(logits, temps, top_ks, top_ps,
-                                  jax.random.fold_in(base_rng, i))
-                    if live is None:
-                        return (tok, pos + 1, pools2, lens + 1), tok
-                    return (jnp.where(live, tok, token), pos + live, pools2,
-                            lens + live), tok
+            (token, pos, pools, lens), toks = jax.lax.scan(
+                body, (token, pos, pools, lens), jnp.arange(K))
+            return toks, pools  # toks: (K, B)
 
-                (token, pos, pools, lens), toks = jax.lax.scan(
-                    body, (token, pos, pools, lens), jnp.arange(K))
-                return toks, pools  # toks: (K, B)
+        # Donating the state makes each chunk update it in place.
+        self._decode_chunk_paged = jax.jit(decode_chunk_paged,
+                                           donate_argnums=(3,))
 
-            self._decode_chunk_paged = jax.jit(decode_chunk_paged,
-                                               donate_argnums=(3,))
+        # ---- batched prefill admission ----------------------------------
+        # Sequential slot prefills dominate end-to-end serving at large
+        # batch (each is a full program dispatch). When several
+        # same-bucket requests are pending, ONE (W, bucket) prefill serves
+        # all of them. W is FIXED for a bucket (padding with rows that
+        # scatter into the dummy page) so exactly one extra program per
+        # bucket compiles, regardless of arrival pattern; the family says
+        # how many rows a bucket takes, at most this many.
+        self._batch_prefill_width = family.prefill_width(
+            page_size, max_batch)
 
-            # ---- batched prefill admission --------------------------------
-            # Sequential slot prefills dominate end-to-end serving at
-            # large batch (each is a full program dispatch). When several
-            # same-bucket
-            # requests are pending, ONE (W, bucket) prefill serves all of
-            # them. W is FIXED for a bucket (padding with rows that
-            # scatter into the dummy page) so exactly one extra program
-            # per bucket compiles, regardless of arrival pattern; the
-            # family says how many rows a bucket takes, at most this many.
-            self._batch_prefill_width = family.prefill_width(
-                page_size, max_batch)
+        @jax.jit
+        def prefill_many(params, tokens, last_idx):
+            # tokens: (W, bucket) right-padded; last_idx: (W,) index
+            # of each row's last prompt token. Returns the last-token
+            # logits row per sequence and the rows' fresh state.
+            return family.prefill(params, tokens, last_idx)
 
-            @jax.jit
-            def prefill_many(params, tokens, last_idx):
-                # tokens: (W, bucket) right-padded; last_idx: (W,) index
-                # of each row's last prompt token. Returns the last-token
-                # logits row per sequence and the rows' fresh state.
-                return family.prefill(params, tokens, last_idx)
+        self._prefill_many = prefill_many
 
-            self._prefill_many = prefill_many
+        # The rows' fresh state into the engine's: paged parts to
+        # `page_ids` (W, n), fixed parts to `slots` (W,).
+        self._write_prompt_pages = functools.partial(
+            jax.jit, donate_argnums=(0,))(family.write_prompt)
+        self._deferred: list = []  # pool-dry admissions, FIFO retry
 
-            # The rows' fresh state into the engine's: paged parts to
-            # `page_ids` (W, n), fixed parts to `slots` (W,).
-            self._write_prompt_pages = functools.partial(
-                jax.jit, donate_argnums=(0,))(family.write_prompt)
-            self._deferred: list = []  # pool-dry admissions, FIFO retry
+        # ---- engine state (host-managed; `_pools` and `_tables` above) ---
 
-        # ---- engine state (host-managed; device caches stacked by slot) --
-
-        if page_size:
-            self._kv = None  # paged mode: pools above replace slot caches
-        else:
-            self._kv = family.init_dense(max_batch)  # [(B,Hkv,L,D)] / layer
         self._lens = np.zeros(max_batch, np.int32)
         self._token = np.zeros(max_batch, np.int32)
         self._pos = np.zeros(max_batch, np.int32)
@@ -370,7 +319,6 @@ class LLMEngine:
         self._topks = np.zeros(max_batch, np.int32)
         self._topps = np.ones(max_batch, np.float32)
         self._slots = [_Slot() for _ in range(max_batch)]
-        self._prefill_rr = 0  # round-robin cursor over prefilling slots
         self._pending: queue.Queue = queue.Queue()
         self._stop = threading.Event()
         # Drain quiesce handshake: _quiesce asks the loop to pause at a
@@ -395,13 +343,12 @@ class LLMEngine:
             raise ValueError(
                 f"prompt({len(prompt)}) + max_new_tokens({sp.max_new_tokens})"
                 f" exceeds engine max_len={self.max_len}")
-        if self.page_size:
-            need = self._alloc.pages_needed(
-                len(prompt) + sp.max_new_tokens + self.decode_chunk)
-            if need > self._alloc.num_pages - 1:  # -1: dummy page
-                raise ValueError(
-                    f"request needs {need} KV pages but the pool holds "
-                    f"{self._alloc.num_pages - 1}; raise kv_pool_tokens")
+        need = self._alloc.pages_needed(
+            len(prompt) + sp.max_new_tokens + self.decode_chunk)
+        if need > self._alloc.num_pages - 1:  # -1: dummy page
+            raise ValueError(
+                f"request needs {need} KV pages but the pool holds "
+                f"{self._alloc.num_pages - 1}; raise kv_pool_tokens")
         handle = RequestHandle(len(prompt), sp,
                                max_buffered=self._stream_buffer, tag=tag)
         self._pending.put((prompt, handle))
@@ -412,9 +359,9 @@ class LLMEngine:
                          tag: str = "") -> RequestHandle:
         """Admit a request whose prefill ran elsewhere (disaggregated
         prefill pool, or a drain-evacuated stream being resumed): the
-        KV prefix lands in a free slot and decoding continues from
+        KV prefix lands in the slot's pages and decoding continues from
         `pack.token` without re-running prefill here."""
-        self._require_portable_cache("submit_prefilled")
+        self._require_portable_kv("submit_prefilled")
         sp = sampling or SamplingParams()
         budget = sp.max_new_tokens - pack.generated
         if budget <= 0:
@@ -428,8 +375,8 @@ class LLMEngine:
         self._pending.put((pack, handle))
         return handle
 
-    def _require_portable_cache(self, what: str) -> None:
-        if not self.family.dense:
+    def _require_portable_kv(self, what: str) -> None:
+        if not self.family.portable_kv:
             raise NotImplementedError(
                 f"{what}: a {type(self.cfg).__name__} stream's state is "
                 "pages of one layer, rings and recurrent state, not a "
@@ -453,7 +400,7 @@ class LLMEngine:
         return total
 
     def queue_depth(self) -> int:
-        return self._pending.qsize() + len(getattr(self, "_deferred", []))
+        return self._pending.qsize() + len(self._deferred)
 
     def report_metrics(self) -> dict:
         ttft = sorted(self._ttft)
@@ -499,29 +446,26 @@ class LLMEngine:
         quiesce first. Keyed by the handle's tag; each value holds the
         trimmed per-layer KV (numpy) and the full decode cursor, enough
         to rebuild the stream via submit_prefilled on another replica."""
-        self._require_portable_cache("snapshot_active_streams")
+        self._require_portable_kv("snapshot_active_streams")
         out: dict = {}
+        ps = self.page_size
         for i, st in enumerate(self._slots):
             h = st.request
-            if h is None or st.prefill_prompt is not None:
+            if h is None:
                 continue
+            # The stream's pages, by its table row, back into a per-layer
+            # prefix of its L tokens.
             L = int(self._lens[i])
+            n = -(-L // ps)
+            row = self._tables[i][:n]
             kv = []
-            if self.page_size:
-                ps = self.page_size
-                n = -(-L // ps)
-                row = self._tables[i][:n]
-                for kp, vp in self._pools:
-                    Hkv, D = kp.shape[1], kp.shape[3]
-                    k = np.asarray(kp[row]).transpose(1, 0, 2, 3).reshape(
-                        Hkv, n * ps, D)[:, :L]
-                    v = np.asarray(vp[row]).transpose(1, 0, 2, 3).reshape(
-                        Hkv, n * ps, D)[:, :L]
-                    kv.append((k, v))
-            else:
-                for kf, vf in self._kv:
-                    kv.append((np.asarray(kf[i, :, :L]),
-                               np.asarray(vf[i, :, :L])))
+            for kp, vp in self._pools:
+                Hkv, D = kp.shape[1], kp.shape[3]
+                k = np.asarray(kp[row]).transpose(1, 0, 2, 3).reshape(
+                    Hkv, n * ps, D)[:, :L]
+                v = np.asarray(vp[row]).transpose(1, 0, 2, 3).reshape(
+                    Hkv, n * ps, D)[:, :L]
+                kv.append((k, v))
             sp = h.sampling
             out[h.tag or f"slot{i}"] = {
                 "kv": kv,
@@ -548,12 +492,10 @@ class LLMEngine:
             if st.request is not None:
                 st.request._finish(err)
                 st.request = None
-            st.prefill_prompt = None
             self._free_slot_pages(i)
-        for _prompt, handle in getattr(self, "_deferred", []):
+        for _prompt, handle in self._deferred:
             handle._finish(err)
-        if self.page_size:
-            self._deferred.clear()
+        self._deferred.clear()
         while True:
             try:
                 _prompt, handle = self._pending.get_nowait()
@@ -575,61 +517,11 @@ class LLMEngine:
             b *= 2
         return min(b, self.max_len)
 
-    def _admit(self, prompt: np.ndarray, handle: RequestHandle):
-        """DENSE-mode admission. Paged admissions go through
-        _reserve_paged + _admit_paged_group in the loop instead."""
-        assert not self.page_size
-        jnp = self._jnp
-        slot = next(i for i, s in enumerate(self._slots) if s.request is None)
-        if isinstance(prompt, _Prefilled):
-            self._admit_prefilled_dense(slot, prompt, handle)
-            return
-        # Chunked only when the chunk GRID fits the cache: the final
-        # chunk's write window [start, start+C) must not run past max_len,
-        # where dynamic_update_slice clamping would silently relocate it
-        # over already-prefilled KV. Otherwise the bucketed whole-prompt
-        # path (whose write window is exactly the bucket) handles it.
-        C = self.prefill_chunk
-        grid_fits = C and -(-len(prompt) // C) * C <= self.max_len
-        if C and len(prompt) > C and grid_fits:
-            # Chunked path: bookkeeping only; the loop advances one chunk
-            # per tick. Point the slot's decode-write offset at the last
-            # cache index so the shared decode program's garbage writes
-            # for this still-prefilling slot cannot land inside the
-            # region being prefilled (that index is overwritten before
-            # any legitimate attention reaches it).
-            st = self._slots[slot]
-            st.request = handle
-            st.generated = 0
-            st.prefill_prompt = prompt
-            st.prefill_pos = 0
-            st.history = []
-            self._lens[slot] = self.max_len - 1
-            self._temps[slot] = handle.sampling.temperature
-            self._topks[slot] = handle.sampling.top_k
-            self._topps[slot] = handle.sampling.top_p
-            return
-        bucket = self._bucket(len(prompt))
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, : len(prompt)] = prompt
-        logits, kv_one = self._prefill_one(
-            self.params, jnp.asarray(padded),
-            jnp.asarray([len(prompt) - 1], jnp.int32))
-        kv_one = self._device_handoff(kv_one)
-        # Write the slot row of every layer cache + first sampled token.
-        for li, (k_full, v_full) in enumerate(self._kv):
-            k_one, v_one = kv_one[li]
-            self._kv[li] = (k_full.at[slot].set(k_one[0]),
-                            v_full.at[slot].set(v_one[0]))
-        self._commit_first_token(slot, handle, logits[0], len(prompt))
-
     def _device_handoff(self, kv):
         """Hand the prefill KV cache to decode as a device object:
         same-process resolution returns the SAME live arrays (zero copy)
         while ticking the plane's pinned-HBM gauge and in_process
         counter — the serve hot path's first device-plane consumer."""
-        if not self.use_device_plane:
-            return kv
         try:
             from ray_tpu._private import device_objects
 
@@ -640,21 +532,11 @@ class LLMEngine:
                            "arrays over directly", exc_info=True)
             return kv
 
-    def _commit_first_token(self, slot: int, handle: RequestHandle,
-                            first_logits, prompt_len: int):
-        """Shared prefill->decode handoff: sample the first token and
-        commit all per-slot decode state (one protocol, dense AND paged)."""
-        self._rng, srng = self._jax.random.split(self._rng)
-        sp = handle.sampling
-        tok = int(np.asarray(self._sample(
-            first_logits[None], np.float32([sp.temperature]),
-            np.int32([sp.top_k]), np.float32([sp.top_p]), srng))[0])
-        self._commit_token(slot, handle, tok, prompt_len)
-
     def _commit_token(self, slot: int, handle: RequestHandle, tok: int,
                       prompt_len: int):
-        """Commit an already-sampled first token + per-slot decode state
-        (batched admission samples a whole group in one dispatch)."""
+        """Prefill->decode handoff: commit an already-sampled first token
+        + per-slot decode state (admission samples a whole group in one
+        dispatch)."""
         sp = handle.sampling
         self._lens[slot] = prompt_len
         self._pos[slot] = prompt_len
@@ -665,7 +547,6 @@ class LLMEngine:
         st = self._slots[slot]
         st.request = handle
         st.generated = 0
-        st.prefill_prompt = None
         st.history = []
         self._ttft.append(time.monotonic() - handle._submit_ts)
         self._emit(slot, tok)
@@ -686,26 +567,10 @@ class LLMEngine:
         st = self._slots[slot]
         st.request = handle
         st.generated = pack.generated
-        st.prefill_prompt = None
         st.history = list(pack.history)
         if pack.emit_first:
             self._ttft.append(time.monotonic() - handle._submit_ts)
             self._emit(slot, pack.token)
-
-    def _admit_prefilled_dense(self, slot: int, pack: _Prefilled,
-                               handle: RequestHandle):
-        """Land an external KV prefix in a dense slot row. Cache entries
-        past `pack.lens` keep whatever garbage they hold — decode masks
-        kpos<=qpos and overwrites index lens before attending."""
-        jnp = self._jnp
-        L = pack.lens
-        for li, (k_full, v_full) in enumerate(self._kv):
-            k1, v1 = pack.kv_layers[li]
-            k1 = jnp.asarray(np.asarray(k1)[:, :L], self.cfg.dtype)
-            v1 = jnp.asarray(np.asarray(v1)[:, :L], self.cfg.dtype)
-            self._kv[li] = (k_full.at[slot, :, :L, :].set(k1),
-                            v_full.at[slot, :, :L, :].set(v1))
-        self._commit_prefilled(slot, handle, pack)
 
     def _admit_prefilled_paged(self, slot: int, seq_id: str,
                                pack: _Prefilled, handle: RequestHandle):
@@ -864,7 +729,7 @@ class LLMEngine:
 
     def _free_slot_pages(self, slot: int):
         st = self._slots[slot]
-        if self.page_size and st.seq_id:
+        if st.seq_id:
             self._alloc.free(st.seq_id)
             self._tables[slot, :] = self._dummy_page
             # The kernel's work follows `_lens`: an empty slot costs the
@@ -890,44 +755,13 @@ class LLMEngine:
         steps = np.zeros(self.max_batch, np.int32)
         for i, st in enumerate(self._slots):
             h = st.request
-            if h is None or st.prefill_prompt is not None:
+            if h is None:
                 continue
             owed = h.sampling.max_new_tokens - st.generated
             steps[i] = max(0, min(self.decode_chunk, owed, h.room()))
             if steps[i] < min(self.decode_chunk, owed):
                 self._parked_events += 1
         return steps
-
-    def _advance_prefill(self, slot: int):
-        """Write ONE chunk of a long prompt into the slot's cache; on the
-        final chunk, sample the first token and switch to decoding."""
-        jnp = self._jnp
-        st = self._slots[slot]
-        prompt = st.prefill_prompt
-        C = self.prefill_chunk
-        start = st.prefill_pos
-        chunk = np.zeros((1, C), np.int32)
-        n = min(C, len(prompt) - start)
-        chunk[0, :n] = prompt[start: start + n]
-        logits, kv_out = self._prefill_chunk(
-            self.params, jnp.asarray(chunk), jnp.int32(start), self._kv,
-            jnp.int32(slot))
-        self._kv = [(k, v) for k, v in kv_out]
-        st.prefill_pos = start + n
-        if st.prefill_pos < len(prompt):
-            return
-        # Prompt complete: first token from the last REAL position's logits.
-        self._rng, srng = self._jax.random.split(self._rng)
-        sp = st.request.sampling
-        tok = int(np.asarray(self._sample(
-            logits[n - 1][None], np.float32([sp.temperature]),
-            np.int32([sp.top_k]), np.float32([sp.top_p]), srng))[0])
-        self._lens[slot] = len(prompt)
-        self._pos[slot] = len(prompt)
-        self._token[slot] = tok
-        st.prefill_prompt = None
-        self._ttft.append(time.monotonic() - st.request._submit_ts)
-        self._emit(slot, tok)
 
     def _emit(self, slot: int, tok: int) -> bool:
         """Offer one token to the stream. False = the consumer's bounded
@@ -945,9 +779,9 @@ class LLMEngine:
                 st.generated >= sp.max_new_tokens:
             st.request._finish()
             st.request = None
-            # Paged mode: the stream's pages return to the pool the
-            # moment it completes — this is what lets a deferred request
-            # admit on the next loop pass.
+            # The stream's pages return to the pool the moment it
+            # completes — this is what lets a deferred request admit on
+            # the next loop pass.
             self._free_slot_pages(slot)
         return True
 
@@ -962,36 +796,26 @@ class LLMEngine:
                 self._stop.wait(0.01)
                 continue
             # Admit as many pending requests as there are free slots —
-            # without stalling slots that are mid-decode. Paged mode also
+            # without stalling slots that are mid-decode. Admission also
             # gates on pool pages: a dry pool defers the request (FIFO)
-            # until completions free pages. Paged admissions gathered in
-            # one pass PREFILL TOGETHER (see _admit_paged_group) —
-            # sequential slot prefills were the measured end-to-end
-            # serving bottleneck at large batch.
-            paged_cands: list = []
+            # until completions free pages. Admissions gathered in one
+            # pass PREFILL TOGETHER (see _admit_paged_group) — sequential
+            # slot prefills were the measured end-to-end serving
+            # bottleneck at large batch.
+            cands: list = []
             picked: set = set()
             while any(i not in picked and s.request is None
                       for i, s in enumerate(self._slots)):
-                from_deferred = bool(self.page_size and self._deferred)
+                from_deferred = bool(self._deferred)
                 if from_deferred:
                     prompt, handle = self._deferred[0]
                 else:
                     try:
                         prompt, handle = self._pending.get(
                             block=(self.num_active() == 0
-                                   and not paged_cands), timeout=0.05)
+                                   and not cands), timeout=0.05)
                     except queue.Empty:
                         break
-                if not self.page_size:
-                    try:
-                        self._admit(prompt, handle)
-                        if from_deferred:
-                            self._deferred.pop(0)
-                    except Exception as e:  # surfacing beats a dead stream
-                        if from_deferred:
-                            self._deferred.pop(0)
-                        handle._finish(e)
-                    continue
                 slot = next(i for i, s in enumerate(self._slots)
                             if s.request is None and i not in picked)
                 try:
@@ -1002,7 +826,7 @@ class LLMEngine:
                     if not from_deferred:
                         self._deferred.append((prompt, handle))
                     break
-                except Exception as e:
+                except Exception as e:  # surfacing beats a dead stream
                     if from_deferred:
                         self._deferred.pop(0)
                     handle._finish(e)
@@ -1010,29 +834,10 @@ class LLMEngine:
                 if from_deferred:
                     self._deferred.pop(0)
                 picked.add(slot)
-                paged_cands.append((slot, seq_id, prompt, handle))
-            if paged_cands:
-                self._admit_paged_group(paged_cands)
-            if self.num_active() == 0:
-                continue
-            # Advance ONE chunk of ONE prefilling slot per tick — long
-            # prompts interleave with decoding instead of stalling it.
-            prefilling = [i for i, s in enumerate(self._slots)
-                          if s.request is not None
-                          and s.prefill_prompt is not None]
-            if prefilling:
-                idx = prefilling[self._prefill_rr % len(prefilling)]
-                self._prefill_rr += 1
-                try:
-                    self._advance_prefill(idx)
-                except Exception as e:
-                    st = self._slots[idx]
-                    if st.request is not None:
-                        st.request._finish(e)
-                        st.request = None
-                        st.prefill_prompt = None
-            decoding = [s for s in self._slots
-                        if s.request is not None and s.prefill_prompt is None]
+                cands.append((slot, seq_id, prompt, handle))
+            if cands:
+                self._admit_paged_group(cands)
+            decoding = [s for s in self._slots if s.request is not None]
             if not decoding:
                 continue
             # Backpressure: if EVERY decoding stream's consumer queue is
@@ -1050,38 +855,25 @@ class LLMEngine:
             steps = None if self.family.rewinds else self._steps_to_take()
             try:
                 self._rng, srng = jax.random.split(self._rng)
-                if self.page_size:
-                    self._count_paged_pages()
-                    args = [self.params, jnp.asarray(self._token),
-                            jnp.asarray(self._pos), self._pools,
-                            jnp.asarray(self._tables),
-                            jnp.asarray(self._lens),
-                            jnp.asarray(self._temps), self._topks_arr(),
-                            self._topps_arr(), srng]
-                    if steps is not None:
-                        args.append(jnp.asarray(steps))
-                    toks, self._pools = self._decode_chunk_paged(*args)
-                else:
-                    toks, kv_out = self._decode_chunk_fn(
-                        self.params, jnp.asarray(self._token),
-                        jnp.asarray(self._pos), self._kv,
-                        jnp.asarray(self._lens),
+                self._count_paged_pages()
+                args = [self.params, jnp.asarray(self._token),
+                        jnp.asarray(self._pos), self._pools,
+                        jnp.asarray(self._tables), jnp.asarray(self._lens),
                         jnp.asarray(self._temps), self._topks_arr(),
-                        self._topps_arr(), srng)
-                    self._kv = [(k, v) for k, v in kv_out]
+                        self._topps_arr(), srng]
+                if steps is not None:
+                    args.append(jnp.asarray(steps))
+                toks, self._pools = self._decode_chunk_paged(*args)
                 toks = np.asarray(toks)  # (K, B)
             except Exception as e:
                 # A decode failure (device OOM, donated-buffer misuse, ...)
                 # must not strand waiters on a dead thread: fail loudly and
                 # keep serving subsequent requests on fresh state.
                 self._fail_all(e)
-                if self.page_size:
-                    self._init_paged_state()
-                else:
-                    self._kv = self.family.init_dense(self.max_batch)
+                self._init_paged_state()
                 continue
             for i, st in enumerate(self._slots):
-                if st.request is None or st.prefill_prompt is not None:
+                if st.request is None:
                     continue
                 take = toks.shape[0] if steps is None else int(steps[i])
                 for kstep in range(take):
@@ -1124,11 +916,10 @@ class LLMServer:
 
     def __init__(self, cfg, params, *, max_batch: int = 4,
                  max_len: int = 1024, decode_chunk: int = 8,
-                 prefill_chunk: int = 0, page_size: int = 0,
-                 kv_pool_tokens: int = 0, stream_buffer: int = 256):
+                 page_size: int = 64, kv_pool_tokens: int = 0,
+                 stream_buffer: int = 256):
         self.engine = LLMEngine(cfg, params, max_batch=max_batch,
                                 max_len=max_len, decode_chunk=decode_chunk,
-                                prefill_chunk=prefill_chunk,
                                 page_size=page_size,
                                 kv_pool_tokens=kv_pool_tokens,
                                 stream_buffer=stream_buffer)

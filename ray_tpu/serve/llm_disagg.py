@@ -343,7 +343,7 @@ class DecodeServer:
 
     def __init__(self, cfg: LlamaConfig, params, *, max_batch: int = 4,
                  max_len: int = 1024, decode_chunk: int = 8,
-                 page_size: int = 0, kv_pool_tokens: int = 0,
+                 page_size: int = 64, kv_pool_tokens: int = 0,
                  stream_buffer: int = 256):
         self.cfg = cfg
         self.engine = LLMEngine(cfg, params, max_batch=max_batch,
@@ -677,7 +677,7 @@ class DisaggHandle:
 def deploy_disagg(cfg: LlamaConfig, params, *, name: str = "llm",
                   prefill_replicas: int = 2, decode_replicas: int = 2,
                   max_batch: int = 4, max_len: int = 512,
-                  decode_chunk: int = 4, page_size: int = 0,
+                  decode_chunk: int = 4, page_size: int = 64,
                   kv_pool_tokens: int = 0, prefix_cache_size: int = 32,
                   stream_buffer: int = 256,
                   prefill_autoscaling: dict | None = None,

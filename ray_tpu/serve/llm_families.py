@@ -14,10 +14,10 @@ A family object answers, for its configuration:
                                 sizes every slot's steps BEFORE a chunk is
                                 dispatched and the program holds a slot
                                 still past its count (`live`).
-    dense                       whether the family also has the page_size=0
-                                programs (per-slot max_len caches, chunked
-                                prefill) and a cache another engine can be
-                                handed (`submit_prefilled`, drain snapshots)
+    portable_kv                 True where a stream's whole state is a
+                                per-layer KV prefix, which another engine
+                                can be handed (`submit_prefilled`) and a
+                                drain snapshot can carry
     pool_readers                layers that read a sequence's pages in one
                                 decode step through ONE table row of ONE pool
     ring_tokens(lens)           tokens held in fixed-size rings (0: none)
@@ -53,7 +53,6 @@ then; it is replaced at the next admission.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -78,7 +77,7 @@ class LlamaServing:
     layer, nothing fixed."""
 
     rewinds = True
-    dense = True
+    portable_kv = True
     pool_readers = 1
 
     def __init__(self, cfg: LlamaConfig, max_len: int):
@@ -134,46 +133,6 @@ class LlamaServing:
                                        kv_caches=caches)
         return logits[:, 0], [(c.k_pool, c.v_pool) for c in new]
 
-    # ---- dense mode (page_size == 0): a max_len cache a slot --------------
-
-    def init_dense(self, max_batch: int):
-        proto = init_kv_caches(self.cfg, max_batch, self.max_len)
-        return [(k, v) for k, v, _l in proto]   # [(B,Hkv,L,D)] / layer
-
-    def prefill_chunk(self, params, tokens, start, kv_full, slot):
-        # One CHUNK of a long prompt: tokens (1, chunk) at absolute
-        # positions start..start+chunk, KV written at the same offset
-        # of slot `slot`'s cache. Gather/scatter of the slot row stays
-        # INSIDE the jit with the full cache donated, so a chunk costs
-        # one row update, not a full multi-slot cache copy per tick.
-        C = tokens.shape[1]
-        positions = start + jnp.arange(C)[None, :]
-        caches1 = [
-            (jax.lax.dynamic_slice_in_dim(k, slot, 1, axis=0),
-             jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=0), start)
-            for k, v in kv_full]
-        logits, new = self.model.apply(params, tokens, positions,
-                                       kv_caches=caches1)
-        out_kv = [
-            (jax.lax.dynamic_update_slice_in_dim(kf, kn, slot, axis=0),
-             jax.lax.dynamic_update_slice_in_dim(vf, vn, slot, axis=0))
-            for (kf, vf), (kn, vn, _l) in zip(kv_full, new)]
-        return logits[0], out_kv
-
-    def decode_dense(self, params, token, pos, kv, lens):
-        def one(params, token, pos, kv, lens):
-            # One sequence: token (), pos (), kv list of ((Hkv,L,D) k, v),
-            # lens () — the slot's private write offset.
-            caches1 = [(k[None], v[None], lens) for k, v in kv]
-            logits, new = self.model.apply(params, token[None, None],
-                                           pos[None, None],
-                                           kv_caches=caches1)
-            return logits[0, 0], [(k[0], v[0]) for k, v, _l in new]
-
-        # vmap: slots advance at DIFFERENT offsets in the same program.
-        return jax.vmap(one, in_axes=(None, 0, 0, 0, 0))(
-            params, token, pos, kv, lens)
-
 
 # Tokens one prefill dispatch may hold: a row of the largest bucket alone,
 # eight rows of 2,048: the feed-forward's (tokens, 2 x d_ff) intermediate
@@ -187,7 +146,7 @@ class SambaYServing:
     window, scan state) for each Mamba layer."""
 
     rewinds = False
-    dense = False
+    portable_kv = False
 
     def __init__(self, cfg: SambaYConfig, max_len: int):
         self.cfg, self.max_len = cfg, max_len

@@ -367,7 +367,7 @@ def test_llm_engine_kv_handoff_uses_plane():
     model = LlamaModel(cfg)
     params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
     before = device_objects.counters()
-    eng = LLMEngine(cfg, params, max_batch=2, max_len=48)
+    eng = LLMEngine(cfg, params, max_batch=2, max_len=48, page_size=16)
     try:
         toks = eng.generate([1, 2, 3], SamplingParams(max_new_tokens=4))
         assert len(toks) >= 1

@@ -1,7 +1,8 @@
-"""Paged-KV LLM engine: greedy output must match the dense engine and
-the one-shot Generator bit-for-bit, and admission must be bounded by
-POOL pages (resident tokens), not slot count (the vLLM block-table
-property the dense engine lacked — VERDICT r2 weak #5)."""
+"""The engine's pages: admission bounded by POOL pages (resident
+tokens), not slot count, pages recycled as streams leave, the batched
+prefill, and what the kernel's counters read; greedy output bit-equal to
+the one-shot Generator throughout (the plain cases of that are in
+tests/test_serve_llm.py)."""
 
 import numpy as np
 import pytest
@@ -28,31 +29,6 @@ def _reference_greedy(cfg, params, prompt, n_new):
     gen = Generator(cfg, params, batch=1, max_len=len(prompt) + n_new)
     return gen.generate(np.asarray([prompt], np.int32),
                         SamplingParams(max_new_tokens=n_new))[0].tolist()
-
-
-def test_paged_engine_matches_generator(tiny_model):
-    cfg, params = tiny_model
-    eng = LLMEngine(cfg, params, max_batch=3, max_len=96, page_size=16)
-    try:
-        prompt = [1, 5, 9, 2, 7]
-        expected = _reference_greedy(cfg, params, prompt, 12)
-        got = eng.generate(prompt, SamplingParams(max_new_tokens=12))
-        assert got == expected
-    finally:
-        eng.shutdown()
-
-
-def test_paged_engine_concurrent_requests(tiny_model):
-    cfg, params = tiny_model
-    eng = LLMEngine(cfg, params, max_batch=3, max_len=96, page_size=16)
-    try:
-        prompts = [[1, 2, 3], [4, 5, 6, 7, 8, 9, 10], [11, 12]]
-        expected = [_reference_greedy(cfg, params, p, 10) for p in prompts]
-        handles = [eng.submit(p, SamplingParams(max_new_tokens=10))
-                   for p in prompts]
-        assert [h.tokens() for h in handles] == expected
-    finally:
-        eng.shutdown()
 
 
 def test_paged_admission_bounded_by_pool_not_slots(tiny_model):

@@ -89,7 +89,7 @@ def test_prefix_cache_full_hit_skips_prefill(tiny_model):
     cfg, params = tiny_model
     pe = PrefillEngine(cfg, params, max_len=96)
     cache = PrefixCache(8)
-    eng = LLMEngine(cfg, params, max_batch=2, max_len=96)
+    eng = LLMEngine(cfg, params, max_batch=2, max_len=96, page_size=16)
     calls = {"one": 0, "suffix": 0}
     real_one, real_suffix = pe._prefill_one, pe._prefill_suffix
 
@@ -170,7 +170,7 @@ def test_disagg_two_pools_collective_route(tiny_model, collective_env,
     cfg, params = tiny_model
     h = llm_disagg.deploy_disagg(
         cfg, params, prefill_replicas=2, decode_replicas=2,
-        max_batch=2, max_len=96,
+        max_batch=2, max_len=96, page_size=16,
         prefill_actor_options={"num_cpus": 0},
         decode_actor_options={"num_cpus": 0})
     try:
@@ -218,7 +218,7 @@ def test_disagg_per_pool_autoscaling(tiny_model, ray_start_regular):
     cfg, params = tiny_model
     h = llm_disagg.deploy_disagg(
         cfg, params, prefill_replicas=1, decode_replicas=1,
-        max_batch=4, max_len=96,
+        max_batch=4, max_len=96, page_size=16,
         # TTFT includes queue wait + first-touch compile, and the
         # replica's TTFT deque keeps it observable after the burst —
         # queue_depth on a tiny CPU model drains between controller

@@ -9,7 +9,7 @@ import pytest
 
 from ray_tpu.models.generate import Generator, SamplingParams
 from ray_tpu.models.llama import LlamaConfig, LlamaModel
-from ray_tpu.serve.llm import LLMEngine
+from ray_tpu.serve.llm import LLMEngine, _Prefilled
 
 
 @pytest.fixture(scope="module")
@@ -28,7 +28,7 @@ def tiny_model():
 @pytest.fixture()
 def engine(tiny_model):
     cfg, params = tiny_model
-    eng = LLMEngine(cfg, params, max_batch=3, max_len=96)
+    eng = LLMEngine(cfg, params, max_batch=3, max_len=96, page_size=16)
     yield eng
     eng.shutdown()
 
@@ -39,23 +39,38 @@ def _reference_greedy(cfg, params, prompt, n_new):
                         SamplingParams(max_new_tokens=n_new))[0].tolist()
 
 
-def test_engine_matches_generator_greedy(tiny_model, engine):
+@pytest.fixture(params=[0, 64], ids=["default-pool", "small-pool"])
+def pooled_engine(request, tiny_model):
+    """The engine with room for every slot's longest stream (the default
+    pool), and with fewer tokens than three requests need at once, so
+    that admission waits for pages."""
+    cfg, params = tiny_model
+    eng = LLMEngine(cfg, params, max_batch=3, max_len=96, page_size=16,
+                    kv_pool_tokens=request.param)
+    yield eng
+    eng.shutdown()
+
+
+def test_engine_matches_generator_greedy(tiny_model, pooled_engine):
     cfg, params = tiny_model
     prompt = [1, 5, 9, 2, 7]
     expected = _reference_greedy(cfg, params, prompt, 12)
-    got = engine.generate(prompt, SamplingParams(max_new_tokens=12))
+    got = pooled_engine.generate(prompt, SamplingParams(max_new_tokens=12))
     assert got == expected
 
 
-def test_engine_concurrent_requests_interleave(tiny_model, engine):
+def test_engine_concurrent_requests_interleave(tiny_model, pooled_engine):
     cfg, params = tiny_model
     prompts = [[1, 2, 3], [4, 5, 6, 7, 8, 9, 10], [11, 12]]
     expected = [_reference_greedy(cfg, params, p, 10) for p in prompts]
-    # Submit all three concurrently: slots decode in one batched program.
-    handles = [engine.submit(p, SamplingParams(max_new_tokens=10))
+    # Submit all three concurrently: slots decode in one batched program
+    # (two pages a request: the small pool holds two of them at a time).
+    handles = [pooled_engine.submit(p, SamplingParams(max_new_tokens=10))
                for p in prompts]
     results = [h.tokens() for h in handles]
     assert results == expected
+    alloc = pooled_engine._alloc
+    assert alloc.free_pages == alloc.num_pages - 1  # all but the dummy
 
 
 def test_engine_admission_mid_flight(tiny_model, engine):
@@ -107,7 +122,8 @@ def test_llm_server_streams_through_serve(tiny_model, ray_start_regular):
     @serve.deployment
     class TinyLLM(LLMServer):
         def __init__(self):
-            super().__init__(cfg, params, max_batch=2, max_len=64)
+            super().__init__(cfg, params, max_batch=2, max_len=64,
+                             page_size=16)
 
     serve.run(TinyLLM.bind())
     try:
@@ -119,24 +135,55 @@ def test_llm_server_streams_through_serve(tiny_model, ray_start_regular):
         serve.shutdown()
 
 
-def test_chunked_prefill_matches_generator(tiny_model):
-    """Long prompts prefilled in chunks interleaved with decoding still
-    produce exactly the reference greedy output, and a short in-flight
-    request keeps decoding while the long prompt prefills."""
+def test_a_long_prompt_admitted_mid_decode_keeps_every_stream_exact(
+        tiny_model):
+    """A 60-token prompt (four pages, the 64-token bucket) is prefilled
+    whole between two chunks of two streams that are mid-decode: its
+    pages and first token land beside theirs, and all three streams are
+    the Generator's."""
     cfg, params = tiny_model
-    eng = LLMEngine(cfg, params, max_batch=2, max_len=96, decode_chunk=4,
-                    prefill_chunk=8)
+    eng = LLMEngine(cfg, params, max_batch=3, max_len=96, page_size=16,
+                    decode_chunk=4, stream_buffer=8)
     try:
-        long_prompt = [(i * 7 + 3) % 120 for i in range(27)]  # 4 chunks
-        short_prompt = [5, 6]
-        h_short = eng.submit(short_prompt, SamplingParams(max_new_tokens=20))
-        h_long = eng.submit(long_prompt, SamplingParams(max_new_tokens=10))
-        out_short = h_short.tokens()
+        prompts = [[5, 6], [1, 5, 9, 2, 7, 3, 8]]
+        long_prompt = [(i * 11 + 2) % 120 for i in range(60)]
+        hs = [eng.submit(p, SamplingParams(max_new_tokens=30))
+              for p in prompts]
+        its = [iter(h) for h in hs]
+        outs = [[next(it) for _ in range(3)] for it in its]
+        # Both are mid-decode whatever the machine's speed: a stream runs
+        # at most its 8-token buffer ahead of what was read.
+        assert eng.quiesce_for_drain()
+        assert eng.num_active() == 2
+        h_long = eng.submit(long_prompt, SamplingParams(max_new_tokens=8))
+        eng.resume()
         out_long = h_long.tokens()
-        assert out_long == _reference_greedy(cfg, params, long_prompt, 10)
-        assert out_short == _reference_greedy(cfg, params, short_prompt, 20)
+        for out, it in zip(outs, its):
+            out.extend(it)
+        assert out_long == _reference_greedy(cfg, params, long_prompt, 8)
+        assert outs == [_reference_greedy(cfg, params, p, 30)
+                        for p in prompts]
     finally:
         eng.shutdown()
+
+
+def test_engine_is_paged_by_default_and_refuses_page_size_zero(tiny_model):
+    """No size given: pages of 64 tokens and a pool with room for every
+    slot's longest stream, `max_batch * (max_len + decode_chunk)` tokens,
+    plus the dummy page. A page size that is no size is refused in words."""
+    cfg, params = tiny_model
+    eng = LLMEngine(cfg, params)
+    try:
+        assert (eng.max_batch, eng.max_len, eng.decode_chunk,
+                eng.page_size) == (4, 1024, 8, 64)
+        assert eng._alloc.num_pages == -(-4 * (1024 + 8) // 64) + 1
+        assert eng.queue_depth() == 0
+    finally:
+        eng.shutdown()
+    with pytest.raises(ValueError, match="page_size=0 must be positive"):
+        LLMEngine(cfg, params, page_size=0)
+    with pytest.raises(ValueError, match="multiple of page_size=64"):
+        LLMEngine(cfg, params, max_len=96)
 
 
 def test_stream_backpressure_parks_and_resumes(tiny_model):
@@ -146,7 +193,7 @@ def test_stream_backpressure_parks_and_resumes(tiny_model):
     reference exactly."""
     cfg, params = tiny_model
     eng = LLMEngine(cfg, params, max_batch=2, max_len=96, decode_chunk=4,
-                    stream_buffer=4)
+                    page_size=16, stream_buffer=4)
     try:
         prompts = [[1, 5, 9, 2, 7], [4, 4, 6]]
         expected = [_reference_greedy(cfg, params, p, 24) for p in prompts]
@@ -190,7 +237,7 @@ def test_decode_drain_midstream_zero_loss(tiny_model, ray_start_cluster_head):
     before = dict(device_objects.counters())
     h = llm_disagg.deploy_disagg(
         cfg, params, prefill_replicas=1, decode_replicas=2,
-        max_batch=2, max_len=96, stream_buffer=4,
+        max_batch=2, max_len=96, page_size=16, stream_buffer=4,
         prefill_actor_options={"num_cpus": 0},
         decode_actor_options={"num_cpus": 0, "resources": {"decode": 1}})
     try:
@@ -226,16 +273,43 @@ def test_decode_drain_midstream_zero_loss(tiny_model, ray_start_cluster_head):
         serve.shutdown()
 
 
-def test_chunked_prefill_grid_overrun_falls_back(tiny_model):
-    """A chunk grid that would overrun max_len (clamped writes would
-    corrupt prefilled KV) falls back to whole-prompt prefill — output
-    still matches the reference exactly."""
+def test_snapshot_of_a_paged_engine_resumes_on_another(tiny_model):
+    """What a drained replica does, without the cluster: two streams
+    mid-decode are snapshotted (their pages gathered by table row back
+    into a per-layer prefix) and handed to an engine with another page
+    size and another pool; what was generated before plus what the
+    second engine generates is the Generator's stream."""
     cfg, params = tiny_model
-    eng = LLMEngine(cfg, params, max_batch=1, max_len=96, decode_chunk=4,
-                    prefill_chunk=50)  # ceil(60/50)*50 = 100 > 96
+    src = LLMEngine(cfg, params, max_batch=2, max_len=96, page_size=16,
+                    decode_chunk=4, stream_buffer=4)
+    dst = LLMEngine(cfg, params, max_batch=2, max_len=96, page_size=32,
+                    decode_chunk=4, kv_pool_tokens=192)
     try:
-        prompt = [(i * 11 + 2) % 120 for i in range(60)]
-        got = eng.generate(prompt, SamplingParams(max_new_tokens=8))
-        assert got == _reference_greedy(cfg, params, prompt, 8)
+        # One stream on one page, the other over three.
+        prompts = {"short": [1, 5, 9, 2, 7],
+                   "long": [(i * 7 + 3) % 120 for i in range(37)]}
+        sp = SamplingParams(max_new_tokens=30)
+        hs = {tag: src.submit(p, sp, tag=tag) for tag, p in prompts.items()}
+        for h in hs.values():
+            it = iter(h)
+            for _ in range(3):
+                next(it)
+        assert src.quiesce_for_drain()
+        snap = src.snapshot_active_streams()
+        assert set(snap) == set(prompts)
+        for tag, s in snap.items():
+            # mid-decode: at most the 4-token buffer ahead of the reader
+            assert 3 <= s["generated"] < 30
+            assert s["lens"] == len(prompts[tag]) + s["generated"] - 1
+            assert all(k.shape == (cfg.n_kv_heads, s["lens"], cfg.head_dim)
+                       for k, _v in s["kv"])
+            pack = _Prefilled(s["kv"], s["token"], s["prompt_len"],
+                              s["lens"], s["generated"], s["history"],
+                              emit_first=False)
+            rest = dst.submit_prefilled(
+                pack, SamplingParams(**s["sampling"])).tokens()
+            assert s["history"] + rest == _reference_greedy(
+                cfg, params, prompts[tag], 30)
     finally:
-        eng.shutdown()
+        src.shutdown()
+        dst.shutdown()

@@ -151,8 +151,6 @@ def test_what_the_family_cannot_do_is_refused_in_words(tiny):
     from ray_tpu.serve.llm import LLMEngine, _Prefilled
 
     cfg, params = tiny
-    with pytest.raises(ValueError, match="served paged"):
-        LLMEngine(cfg, params, max_batch=2, max_len=64)
     with pytest.raises(TypeError, match="a new family is a class"):
         LLMEngine(object(), params, max_batch=2, max_len=64, page_size=16)
     eng = LLMEngine(cfg, params, **ENGINE)
