@@ -4,11 +4,14 @@ The reference ships NO attention kernels — its compute plane is torch
 (SURVEY.md §2.4: sequence/context parallelism "absent in reference"; §5
 names Pallas ring/flash attention as the rebuild's native additions).
 
-- `flash_attention`: TPU Pallas kernel, online-softmax forward with the
+- `flash_attention`: TPU Pallas kernels. Online-softmax forward with the
   canonical (batch, heads, q-block, k-block) grid; k is the innermost
   sequential grid dimension so VMEM scratch accumulators persist across k
-  steps. Backward is a blockwise lax.scan recomputation using the saved
-  logsumexp (memory O(S·block) not O(S²)).
+  steps. The backward is one kernel on the grid turned round (q innermost:
+  dk and dv sum over q-blocks, dq over k-blocks in a VMEM accumulator of
+  the whole query length) that recomputes p from the saved logsumexp, so
+  no block of scores ever lies in HBM; causal blocks above the diagonal
+  are skipped in both.
 - `ring_attention`: sequence-parallel attention inside `shard_map` — each
   device holds a sequence shard of Q/K/V; K/V shards rotate around the mesh
   axis via `lax.ppermute` while a running (out, max, denom) merge keeps
@@ -124,25 +127,26 @@ def _operand_vma(*arrays) -> frozenset:
     return vma
 
 
+def _fit_block(block: int, seq: int) -> int:
+    """Largest block ≤ requested that divides the sequence (halving first:
+    stays MXU-aligned for the common power-of-two lengths; a length that
+    no halving divides degrades to one block)."""
+    block = min(block, seq)
+    while block > 1 and seq % block:
+        block //= 2
+    if seq % block:
+        block = seq
+    return block
+
+
 def _flash_forward(q, k, v, sm_scale: float, causal: bool,
                    block_q: int, block_k: int,
                    kv_valid_len: int | None = None):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
 
-    def fit_block(block, seq):
-        # Largest block ≤ requested that divides the sequence (halving
-        # first — stays MXU-aligned for the common power-of-two seqs —
-        # then any divisor; a prime length degrades to one block).
-        block = min(block, seq)
-        while block > 1 and seq % block:
-            block //= 2
-        if seq % block:
-            block = seq
-        return block
-
-    block_q = fit_block(block_q, Sq)
-    block_k = fit_block(block_k, Sk)
+    block_q = _fit_block(block_q, Sq)
+    block_k = _fit_block(block_k, Sk)
     grid = (B, H, Sq // block_q, Sk // block_k)
 
     if causal:
@@ -195,61 +199,131 @@ def _flash_forward(q, k, v, sm_scale: float, causal: bool,
 
 
 # ---------------------------------------------------------------------------
-# Backward: blockwise recomputation with saved logsumexp
+# Pallas flash attention (backward): one kernel, p recomputed from the saved
+# logsumexp, nothing of size Sq x Sk outside VMEM
 # ---------------------------------------------------------------------------
+#
+# With p = exp(s - lse), dp = dO v^T, delta = rowsum(o * dO) and
+# ds = p * (dp - delta) * sm_scale:   dv = p^T dO,  dk = ds^T q,  dq = ds k.
+# The grid is (batch, heads, k-block, q-block), q innermost: dk and dv of a
+# key block sum over the query blocks in VMEM scratch, and dq sums over the
+# key blocks in a float32 accumulator that holds the whole query length (1 MB
+# at 2048 x 128), so every block of scores is computed once and feeds all
+# five matmuls.  Operands go to the MXU in their storage dtype with float32
+# accumulation; p and ds are rounded to the storage dtype before their
+# matmuls.  Causal: blocks wholly above the diagonal are skipped by the
+# forward's rule, and their index maps clamped onto the first block that is
+# needed so that a skipped step moves nothing.
+#
+# Why one kernel and not the usual two (dk/dv summed over q-blocks, dq over
+# k-blocks, the scores computed twice): on a TPU v5 lite at (4, 16, 2048, 128)
+# bf16 causal, blocks of 512, 1.64 ms against 2.63 ms, results equal bit for
+# bit (PR 31).  The price is the accumulator: 4 * Sq * D bytes of VMEM.
 
 
-def _flash_backward(sm_scale, causal, block_q, block_k, kv_valid_len, res, do):
-    # Operands stay in their storage dtype (bf16 on TPU — full-rate MXU);
-    # every einsum accumulates in f32 via preferred_element_type, and the
-    # dk/dv accumulators are f32.
-    q, k, v, out, lse = res
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
+                      *, sm_scale: float, causal: bool,
+                      block_q: int, block_k: int):
+    """Scores are held transposed, (block_k, block_q): then p^T dO and ds^T q
+    are plain matmuls, only dq's contracts over the leading axis, and lse and
+    delta come in as lane-dense rows."""
+    ki, qi = pl.program_id(2), pl.program_id(3)
+    last_k, last_q = pl.num_programs(2) - 1, pl.num_programs(3) - 1
+
+    @pl.when((ki == 0) & (qi == 0))
+    def _init_dq():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    @pl.when(qi == 0)
+    def _init_dkv():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    needed = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
+
+    @pl.when(needed)
+    def _compute():
+        q = q_ref[0, 0]                                # (block_q, d)
+        k = k_ref[0, 0]                                # (block_k, d)
+        v = v_ref[0, 0]
+        do = do_ref[0, 0]                              # (block_q, d)
+        f32 = functools.partial(lax.dot_general,
+                                preferred_element_type=jnp.float32)
+        a_bt = (((1,), (1,)), ((), ()))
+        a_b = (((1,), (0,)), ((), ()))
+        at_b = (((0,), (0,)), ((), ()))
+        s_t = f32(k, q, a_bt) * sm_scale               # (block_k, block_q)
+        if causal:
+            k_off = lax.broadcasted_iota(jnp.int32, s_t.shape, 0)
+            q_off = lax.broadcasted_iota(jnp.int32, s_t.shape, 1)
+            s_t = jnp.where(q_off - k_off >= ki * block_k - qi * block_q,
+                            s_t, _NEG_INF)
+        p_t = jnp.exp(s_t - lse_ref[0, 0, 0])
+        dv_acc[:] += f32(p_t.astype(do.dtype), do, a_b)
+        dp_t = f32(v, do, a_bt)
+        ds_t = (p_t * (dp_t - delta_ref[0, 0, 0]) * sm_scale).astype(q.dtype)
+        dk_acc[:] += f32(ds_t, q, a_b)
+        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        dq_acc[rows, :] += f32(ds_t, k, at_b)
+
+    @pl.when(qi == last_q)
+    def _finish_dkv():
+        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when((ki == last_k) & (qi == last_q))
+    def _finish_dq():
+        dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
+
+
+# One jitted function, so that a model's layers share one lowering of the
+# kernel body (a body lowered once a layer cost +50% set-up; PR 25).
+@functools.partial(jax.jit, static_argnames=("sm_scale", "causal", "block_q",
+                                             "block_k", "interpret"))
+def _flash_backward(q, k, v, out, lse, do, *, sm_scale: float, causal: bool,
+                    block_q: int, block_k: int, interpret: bool):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    f32 = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
-    delta = f32("bhsd,bhsd->bhs", out, do)                   # (B,H,Sq)
+    block_q = _fit_block(block_q, Sq)
+    block_k = _fit_block(block_k, Sk)
+    nq = Sq // block_q
+    delta = jnp.einsum("bhsd,bhsd->bhs", out, do,
+                       preferred_element_type=jnp.float32)
 
-    bq = min(block_q, Sq)
-    if Sq % bq:
-        bq = Sq
+    def q_index(b, h, ki, qi):
+        if causal:  # a query block wholly before the key block is skipped
+            qi = jnp.maximum(qi, (ki * block_k) // block_q)
+        return (b, h, qi, 0)
 
-    def p_block(qi_start, q_blk, lse_blk):
-        s = f32("bhqd,bhkd->bhqk", q_blk, k) * sm_scale
-        if causal:
-            q_pos = qi_start + jnp.arange(q_blk.shape[2])[:, None]
-            k_pos = jnp.arange(Sk)[None, :]
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        if kv_valid_len is not None and kv_valid_len < Sk:
-            # Same padded-key mask as the forward: without it the
-            # recomputed p would leak gradient into padding keys.
-            s = jnp.where(jnp.arange(Sk)[None, :] < kv_valid_len, s,
-                          _NEG_INF)
-        return jnp.exp(s - lse_blk[..., None])
-
-    def scan_body(carry, idx):
-        dk_acc, dv_acc = carry
-        qs = idx * bq
-        q_blk = lax.dynamic_slice_in_dim(q, qs, bq, axis=2)
-        do_blk = lax.dynamic_slice_in_dim(do, qs, bq, axis=2)
-        lse_blk = lax.dynamic_slice_in_dim(lse, qs, bq, axis=2)
-        dl_blk = lax.dynamic_slice_in_dim(delta, qs, bq, axis=2)
-        p = p_block(qs, q_blk, lse_blk)                      # (B,H,bq,Sk) f32
-        pb = p.astype(v.dtype)
-        dv_acc = dv_acc + f32("bhqk,bhqd->bhkd", pb, do_blk)
-        dp = f32("bhqd,bhkd->bhqk", do_blk, v)
-        ds = (p * (dp - dl_blk[..., None]) * sm_scale).astype(v.dtype)
-        dq_blk = f32("bhqk,bhkd->bhqd", ds, k)
-        dk_acc = dk_acc + f32("bhqk,bhqd->bhkd", ds, q_blk)
-        return (dk_acc, dv_acc), dq_blk
-
-    init = (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32))
-    vma = tuple(_operand_vma(q, k, v, do))
-    if vma:  # under shard_map the carries vary like the operands
-        init = tuple(lax.pcast(x, vma, to="varying") for x in init)
-    (dk, dv), dq_blocks = lax.scan(scan_body, init, jnp.arange(Sq // bq))
-    # dq_blocks: (nq, B, H, bq, D) → (B, H, Sq, D)
-    dq = jnp.moveaxis(dq_blocks, 0, 2).reshape(B, H, Sq, D)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    q_rows = pl.BlockSpec((1, 1, block_q, D), q_index)
+    q_stat = pl.BlockSpec((1, 1, 1, 1, block_q),
+                          lambda b, h, ki, qi: q_index(b, h, ki, qi) + (0,))
+    k_rows = pl.BlockSpec((1, 1, block_k, D), lambda b, h, ki, qi: (b, h, ki, 0))
+    q_whole = pl.BlockSpec((1, 1, Sq, D), lambda b, h, ki, qi: (b, h, 0, 0))
+    as_rows = lambda x: x.reshape(B, H, nq, 1, block_q)  # noqa: E731
+    vma = _operand_vma(q, k, v, do)
+    # What stays in VMEM for a whole (batch, head): dq's accumulator and its
+    # output block, twice (the pipeline's two buffers), beside the blocks,
+    # which have fitted the compiler's default limit of 16 MiB so far.
+    resident = Sq * D * (4 + 2 * q.dtype.itemsize)
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, sm_scale=sm_scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
+        grid=(B, H, Sk // block_k, nq),
+        in_specs=[q_rows, k_rows, k_rows, q_rows, q_stat, q_stat],
+        out_specs=[q_whole, k_rows, k_rows],
+        out_shape=tuple(jax.ShapeDtypeStruct(x.shape, x.dtype, vma=vma)
+                        for x in (q, k, v)),
+        scratch_shapes=[pltpu.VMEM((Sq, D), jnp.float32),
+                        pltpu.VMEM((block_k, D), jnp.float32),
+                        pltpu.VMEM((block_k, D), jnp.float32)],
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=resident + 16 * 1024 * 1024),
+        interpret=interpret,
+    )(q, k, v, do, as_rows(lse), as_rows(delta))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -258,14 +332,19 @@ def flash_attention(q, k, v, sm_scale: float | None = None,
                     block_k: int = 512):
     """Flash attention. q,k,v: (batch, heads, seq, head_dim).
 
-    Default (block_q, block_k) = (512, 512): chosen by IN-MODEL A/B on
-    a real v5e chip (1.2B decoder bench, B2 S2048): 249.6-250.1 ms/step
-    vs 254.1-254.3 for (1024, 1024), reproducibly — even though the
-    standalone kernel sweep (scripts/tpu_kernel_sweep.py) ranks 1024^2
-    faster in isolation (7.18 vs 11.16 ms fwd+bwd). Trust end-to-end
-    timings over microbenchmarks here; re-sweep in-model if the
-    flagship shape changes. Blocks are clamped to the sequence length
-    for shorter inputs.
+    `block_q` and `block_k` are the blocks of the forward and of the
+    backward kernel alike, each fitted to its sequence (`_fit_block`).
+    Default (512, 512), by in-model A/B on a TPU v5 lite, one chip (PR 31):
+    the train step of `internlm2-train-packed2k` (InternLM2-1.8B whole, 24
+    layers, remat "full", q/k/v bf16 (4, 16, 2048, 128), causal), forward at
+    (512, 512), median of 8 steps with the backward at (512, 512) 767.5 ms,
+    (1024, 1024) 767.4, (1024, 512) 769.1, (512, 1024) 770.0; the backward
+    alone at those shapes 1.64, 1.70, 1.76, 1.76 ms, and 2.08 / 1.99 ms at
+    (256, 512) / (512, 256). So the backward takes the caller's blocks and
+    has none of its own. (The forward alone ranks (1024, 1024) first, 1.34
+    against 1.84 ms; an earlier in-model A/B on a 1.2B decoder had the step
+    2% slower with it. Trust end-to-end timings over the kernel alone, and
+    re-run the A/B in the model if the flagship shape changes.)
     """
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     out, _ = _flash_forward(q, k, v, scale, causal, block_q, block_k)
@@ -280,7 +359,9 @@ def _fa_fwd(q, k, v, sm_scale, causal, block_q, block_k):
 
 def _fa_bwd(sm_scale, causal, block_q, block_k, res, do):
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(res[0].shape[-1])
-    return _flash_backward(scale, causal, block_q, block_k, None, res, do)
+    return _flash_backward(*res, do, sm_scale=scale, causal=causal,
+                           block_q=block_q, block_k=block_k,
+                           interpret=_interpret_mode())
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)

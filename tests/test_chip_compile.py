@@ -126,13 +126,47 @@ def test_flash_forward(one_chip, seq):
     assert KERNEL in compiled.as_text()
 
 
-def test_flash_forward_backward(one_chip):
+def _compiled_flash_grad(q, k, v):
     def loss(q, k, v):
         return _flash(q, k, v).astype(jnp.float32).sum()
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        *_qkv(one_chip, 2048)).compile()
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, v).compile()
+
+
+def test_flash_forward_backward(one_chip):
+    compiled = _compiled_flash_grad(*_qkv(one_chip, 2048))
     assert KERNEL in compiled.as_text()
+
+
+def test_flash_backward_keeps_no_score_block_in_hbm(one_chip):
+    """The gradient at the train cell's shapes (`internlm2-train-packed2k`:
+    4 rows of 2048 tokens, 16 heads of 128, K and V already repeated, bf16,
+    causal): the forward kernel and the backward's one, and no buffer of a
+    block of scores.  The scan of einsums this replaced held
+    f32[4,16,512,2048] (268 MB) three times over and its bf16 copy twice:
+    537 MB of temporaries; delta and lse, as rows, are all that is left."""
+    B, H, S, D, block = 4, 16, 2048, 128, 512
+    x = jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16, sharding=one_chip)
+    compiled = _compiled_flash_grad(x, x, x)
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 2
+    for scores in (f"[{B},{H},{block},{S}]", f"[{B},{H},{S},{S}]"):
+        assert scores not in text
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries < 0.2e9 < B * H * block * S * 4
+
+
+@pytest.mark.parametrize("seq, dtype", [(32768, jnp.bfloat16),
+                                        (200, jnp.bfloat16),
+                                        (197, jnp.float32)],
+                         ids=["seq32768", "bucket200_not_pow2", "vit197_f32"])
+def test_flash_backward_other_lengths(one_chip, seq, dtype):
+    """The backward holds dq's float32 accumulator for the whole query
+    length in VMEM (16.8 MB at 32,768 x 128, beside the output block twice:
+    over the compiler's default limit, which the call raises by what it
+    holds); and lengths that are one block, no multiple of the tile."""
+    x = jax.ShapeDtypeStruct((1, 2, seq, 128), dtype, sharding=one_chip)
+    assert _compiled_flash_grad(x, x, x).as_text().count(KERNEL) == 2
 
 
 def _paged_call(one_chip, B, H, Hkv, pool_pages, table_pages, q_dtype,
@@ -306,7 +340,9 @@ def test_train_step_fits_one_chip(topo):
     step, state, batch = _abstract_train(cfg, mesh, chip_smoke.TRAIN_BATCH,
                                          chip_smoke.TRAIN_SEQ)
     compiled = step.lower(state, batch).compile()
-    assert KERNEL in compiled.as_text()
+    # Every layer's forward kernel twice (remat "full") was all the step
+    # held before the backward was a kernel: that makes it three.
+    assert compiled.as_text().count(KERNEL) == 3 * chip_smoke.TRAIN_LAYERS
     assert _peak_bytes(compiled) < HBM_BYTES
 
 
