@@ -533,6 +533,7 @@ def _run_steps(cfg: LlamaConfig, devices, mesh_axes: dict, batch: int,
         n_devices=len(devices), batch=batch, seq=seq, losses=losses,
         step_seconds=step_s, init_state_s=round(init_s, 2),
         compile_s=round(compile_s, 2), kernel_calls=kernel_calls,
+        compiled_kernel_calls=_kernel_calls(compiled.as_text()),
         state_bytes_per_device_after_step_1=resident,
         peak_bytes_in_use=[s.get("peak_bytes_in_use") for s in stats],
         compiled_bytes_per_device=int(

@@ -22,14 +22,27 @@ from typing import Any, NamedTuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops.attention import (
+    FLASH_LSE,
+    FLASH_OUT,
     flash_attention,
     mha_reference,
     ring_attention,
     ulysses_attention,
 )
+
+
+# What a rematerialised layer keeps from its first forward under
+# `remat_policy == "full"` (see `LlamaConfig.remat_policy`): the flash
+# kernel's two results, q, k and v after rope and before the GQA repeat
+# (the repeat is re-done from the kept KV heads) and the attention block's
+# output. Each was kept because it shortened the train step on the chip
+# (PERF.md section 6, PR 34: 767 -> 734 -> 709 -> 698 ms).
+_KEPT_UNDER_FULL = (FLASH_OUT, FLASH_LSE, "attn_q", "attn_k", "attn_v",
+                    "attn_block_out")
 
 
 class PagedKVCache(NamedTuple):
@@ -63,10 +76,16 @@ class LlamaConfig:
     # shard_map over axis sp), "reference" (plain jnp)
     attention: str = "flash"
     remat: bool = True
-    # "full": recompute everything (nothing_saveable — min memory);
-    # "dots": save matmul outputs, recompute elementwise (far less
-    # recompute per backward at slightly more memory — usually the right
-    # speed/memory point on TPU).
+    # "full": a layer is recomputed from its input in the backward, all but
+    # its attention: the flash kernel's `out` and `lse`, the q, k, v that
+    # feed it and the attention block's output are kept from the first
+    # forward (`_KEPT_UNDER_FULL`; 135 MB a layer at 8,192 tokens of
+    # InternLM2-1.8B's widths), so the re-run forward holds norms and the
+    # MLP's two input matmuls only. The kernel is the dearest thing a layer
+    # can run twice (a quarter of its compute floor where the matmuls
+    # around it reach 80%).
+    # "dots": save matmul outputs, recompute elementwise and the kernel
+    # (far less recompute per backward at more memory).
     remat_policy: str = "dots"
     tie_embeddings: bool = False
 
@@ -159,7 +178,11 @@ class Attention(nn.Module):
                                      pc.length + 1)
 
         new_cache = None
-        if kv_cache is not None:
+        if kv_cache is None:
+            q = checkpoint_name(q, "attn_q")
+            k = checkpoint_name(k, "attn_k")
+            v = checkpoint_name(v, "attn_v")
+        else:
             # Decode step: append to cache (S == new tokens, typically 1).
             ck, cv, cache_len = kv_cache
             k = jax.lax.dynamic_update_slice_in_dim(ck, k, cache_len, axis=2)
@@ -196,7 +219,7 @@ class Attention(nn.Module):
         out = dense(cfg.d_model, name="o_proj")(out)
         if kv_cache is not None:
             return out, new_cache
-        return out
+        return checkpoint_name(out, "attn_block_out")
 
 
 def _flash_on_mesh(q, k, v):
@@ -264,7 +287,8 @@ class LlamaModel(nn.Module):
         x = embed(tokens)
         layer_cls = DecoderLayer
         if cfg.remat and kv_caches is None:
-            policy = (jax.checkpoint_policies.nothing_saveable
+            policy = (jax.checkpoint_policies.save_only_these_names(
+                          *_KEPT_UNDER_FULL)
                       if cfg.remat_policy == "full" else
                       jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
             layer_cls = nn.remat(DecoderLayer, policy=policy)

@@ -217,3 +217,68 @@ def test_dit_param_count_matches():
     actual = sum(int(np.prod(x.shape))
                  for x in jax.tree_util.tree_leaves(params))
     assert count_dit_params(cfg) == actual
+
+
+# ---- what a rematerialised layer keeps under remat_policy "full" ----------
+
+
+def _flash_decoder(n_kv_heads, dtype=jnp.bfloat16, **overrides):
+    cfg = LlamaConfig(vocab_size=64, d_model=128, n_layers=2, n_heads=4,
+                      n_kv_heads=n_kv_heads, d_ff=256, max_seq_len=128,
+                      dtype=dtype, attention="flash", **overrides)
+    model = LlamaModel(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 129), 0,
+                                cfg.vocab_size)
+    params = model.init(jax.random.PRNGKey(0), tokens[:, :8])
+
+    def loss(params):
+        return cross_entropy_loss(model.apply(params, tokens[:, :-1]),
+                                  tokens[:, 1:])
+
+    return cfg, params, loss
+
+
+def _kernel_call_sites(jaxpr, counts=None):
+    """Pallas calls by kernel name, every call site of every sub-jaxpr
+    counted (a jitted function called by two layers counts twice)."""
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["jaxpr"].debug_info.func_name
+            counts[name] = counts.get(name, 0) + 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _kernel_call_sites(sub, counts)
+    return counts
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("n_kv_heads", [2, 4], ids=["gqa", "mha"])
+def test_full_remat_gradients_equal_no_remat(n_kv_heads, dtype):
+    """What "full" keeps are the arrays the first forward produced, so the
+    backward kernel is handed the same residuals bit for bit, and every
+    gradient is the one the model gives with no rematerialisation.  (Not
+    jitted: the CPU compiler fuses bf16 arithmetic differently in
+    different programs, with or without a checkpoint.)"""
+    _, params, plain = _flash_decoder(n_kv_heads, dtype, remat=False)
+    _, _, kept = _flash_decoder(n_kv_heads, dtype, remat=True,
+                                remat_policy="full")
+    want = jax.grad(plain)(params)
+    got = jax.grad(kept)(params)
+    for path, g in jax.tree_util.tree_leaves_with_path(got):
+        assert np.isfinite(np.asarray(g, np.float32)).all(), path
+    jax.tree_util.tree_map(np.testing.assert_array_equal, got, want)
+
+
+@pytest.mark.parametrize("policy, forwards_a_layer", [("full", 1),
+                                                      ("dots", 2)])
+def test_full_remat_runs_the_flash_forward_once_a_layer(policy,
+                                                        forwards_a_layer):
+    """The gradient's program holds the forward kernel once a layer under
+    "full" (its results are kept; the re-run forward of the layer holds no
+    kernel) and the backward's kernel once a layer.  "dots" keeps matmul
+    outputs only, so its re-run forward still holds the kernel."""
+    cfg, params, loss = _flash_decoder(2, remat=True, remat_policy=policy)
+    calls = _kernel_call_sites(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+    assert calls == {"_flash_fwd_kernel": forwards_a_layer * cfg.n_layers,
+                     "_flash_bwd_kernel": cfg.n_layers}

@@ -2,6 +2,7 @@
 8-device CPU mesh (the SPMD fake backend, SURVEY.md §4)."""
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -265,3 +266,24 @@ def test_flash_non_block_multiple_seq():
     ref3 = mha_reference(q3, q3, q3, causal=False)
     np.testing.assert_allclose(np.asarray(out3), np.asarray(ref3),
                                atol=2e-5, rtol=2e-5)
+
+
+def test_residual_names_change_no_program_outside_a_checkpoint(monkeypatch):
+    """`_fa_fwd` names the kernel's results for a `jax.checkpoint` policy
+    around the caller; where there is none, the forward and the gradient
+    lower to the text they lower to without the names."""
+    from ray_tpu.ops import attention
+
+    q, k, v = _rand_qkv(jax.random.PRNGKey(11), B=1, H=2, S=128, D=32)
+    do = jnp.ones_like(q)
+
+    def texts():
+        call = lambda q, k, v: flash_attention(q, k, v, None, True)  # noqa: E731
+        lowered = (jax.jit(call).lower(q, k, v),
+                   jax.jit(lambda *x: _grads(call, *x)).lower(q, k, v, do))
+        # (private functions are numbered by a counter the process keeps)
+        return [re.sub(r"(@\w+?)_\d+\b", r"\1", x.as_text()) for x in lowered]
+
+    named = texts()
+    monkeypatch.setattr(attention, "checkpoint_name", lambda x, name: x)
+    assert texts() == named
