@@ -3,6 +3,8 @@
 The reference ships no model implementations (its release gates pull
 GPT-J/vicuna through external torch engines); here the flagship decoder,
 an expert-parallel MoE, and the generation path are part of the framework.
+`serve.LLMEngine` serves three of them (`serve/llm_families.py`):
+`LlamaConfig`, `SambaYConfig` and `GraniteHybridConfig`.
 """
 
 from ray_tpu.models.llama import (
@@ -44,6 +46,12 @@ from ray_tpu.models.encoder import (
     seq2seq_loss,
 )
 from ray_tpu.models.generate import Generator, SamplingParams, generate
+from ray_tpu.models.granite_hybrid import (
+    GRANITE_4_H_MICRO,
+    TINY_GRANITE,
+    GraniteHybridConfig,
+    GraniteHybridModel,
+)
 from ray_tpu.models.sambay import (
     PHI4_MINI_FLASH,
     TINY_SAMBAY,
@@ -86,4 +94,6 @@ __all__ = [
     "SSM_RULES", "init_ssm_state", "ssm_decode_step", "ssm_prefill",
     "chunked_selective_scan",
     "SambaYModel", "SambaYConfig", "PHI4_MINI_FLASH", "TINY_SAMBAY",
+    "GraniteHybridModel", "GraniteHybridConfig", "GRANITE_4_H_MICRO",
+    "TINY_GRANITE",
 ]

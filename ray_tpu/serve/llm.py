@@ -181,6 +181,10 @@ class LLMEngine:
         # Slots whose fixed per-slot state (rings, recurrent state) an
         # admission replaced with what its own prefill computed from zero.
         self.state_slots_reset = 0
+        # Live slots summed over the decode steps dispatched: with the
+        # family's `state_bytes_per_slot`, what the fixed state cost a
+        # window (each live slot's state is read and written once a step).
+        self.state_slot_steps = 0
         # Tokens a KV page holds: admission is bounded by POOL pages
         # (resident tokens), not slot count x max_len.
         if page_size <= 0:
@@ -379,8 +383,9 @@ class LLMEngine:
         if not self.family.portable_kv:
             raise NotImplementedError(
                 f"{what}: a {type(self.cfg).__name__} stream's state is "
-                "pages of one layer, rings and recurrent state, not a "
-                "per-layer KV prefix; it is prefilled where it decodes")
+                "pages and fixed per-slot state (rings, recurrent state), "
+                "not a per-layer KV prefix; it is prefilled where it "
+                "decodes")
 
     def generate(self, prompt_tokens,
                  sampling: SamplingParams | None = None) -> list[int]:
@@ -427,6 +432,8 @@ class LLMEngine:
             "ring_tokens": float(self.family.ring_tokens(
                 self._lens[[s.request is not None for s in self._slots]])),
             "state_slots_reset": float(self.state_slots_reset),
+            "state_bytes_per_slot": float(self.family.state_bytes_per_slot),
+            "state_slot_steps": float(self.state_slot_steps),
             "ttft_p50_ms": pick(0.5) * 1e3,
             "ttft_p99_ms": pick(0.99) * 1e3,
         }
@@ -853,6 +860,8 @@ class LLMEngine:
             # now, by what its consumer's queue takes and what it is still
             # owed, and the program holds it still past them.
             steps = None if self.family.rewinds else self._steps_to_take()
+            self.state_slot_steps += len(decoding) * self.decode_chunk \
+                if steps is None else int(steps.sum())
             try:
                 self._rng, srng = jax.random.split(self._rng)
                 self._count_paged_pages()

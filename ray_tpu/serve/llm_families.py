@@ -20,6 +20,9 @@ A family object answers, for its configuration:
                                 drain snapshot can carry
     pool_readers                layers that read a sequence's pages in one
                                 decode step through ONE table row of ONE pool
+    state_bytes_per_slot        bytes of the fixed per-slot state of one
+                                sequence, from shapes alone (0: all of
+                                its state is pages)
     ring_tokens(lens)           tokens held in fixed-size rings (0: none)
     prefill_width(bucket, max_batch)   rows of the batched prefill program
                                 at a bucket (fixed, so one program a bucket)
@@ -53,15 +56,28 @@ then; it is replaced at the next admission.
 
 from __future__ import annotations
 
+import math
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.models.granite_hybrid import (GraniteHybridConfig,
+                                           GraniteHybridModel)
 from ray_tpu.models.llama import (LlamaConfig, LlamaModel, PagedKVCache,
                                   init_kv_caches)
 from ray_tpu.models.sambay import SambaYConfig, SambaYModel
 
 # Rows of the batched prefill program (fewer where the slots are fewer).
 BATCH_PREFILL_WIDTH = 8
+
+
+def _fixed_bytes_per_slot(family, fixed) -> int:
+    """Bytes one slot holds of the per-slot part of a family's state
+    (`fixed(state)` picks it out), from shapes alone."""
+    state = jax.eval_shape(lambda: family.init_state(1, 1, 64))
+    return sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(fixed(state)))
 
 
 def _pages(a, page_size: int):
@@ -79,6 +95,7 @@ class LlamaServing:
     rewinds = True
     portable_kv = True
     pool_readers = 1
+    state_bytes_per_slot = 0
 
     def __init__(self, cfg: LlamaConfig, max_len: int):
         self.cfg, self.max_len = cfg, max_len
@@ -134,10 +151,15 @@ class LlamaServing:
         return logits[:, 0], [(c.k_pool, c.v_pool) for c in new]
 
 
-# Tokens one prefill dispatch may hold: a row of the largest bucket alone,
-# eight rows of 2,048: the feed-forward's (tokens, 2 x d_ff) intermediate
-# is 0.67 GB at 16,384 tokens and d_ff 10,240.
+# Tokens one prefill dispatch of a hybrid family may hold: a row of the
+# largest bucket alone, eight rows of 2,048: the feed-forward's (tokens,
+# 2 x d_ff) intermediate is 0.67 GB at 16,384 tokens and d_ff 10,240.
 _PREFILL_TOKENS = 16384
+
+
+def _rows_under_the_token_cap(bucket: int, max_batch: int) -> int:
+    return max(1, min(BATCH_PREFILL_WIDTH, max_batch,
+                      _PREFILL_TOKENS // bucket))
 
 
 class SambaYServing:
@@ -154,14 +176,15 @@ class SambaYServing:
         # the full layer and the cross layers behind it
         self.pool_readers = 1 + len(cfg.layers_of("cross"))
         self._window_layers = len(cfg.layers_of("window"))
+        self.state_bytes_per_slot = _fixed_bytes_per_slot(
+            self, lambda s: (s["rings"], s["mamba"]))
 
     def ring_tokens(self, lens) -> int:
         return int(np.minimum(lens, self.cfg.window).sum()) \
             * self._window_layers
 
     def prefill_width(self, bucket: int, max_batch: int) -> int:
-        return max(1, min(BATCH_PREFILL_WIDTH, max_batch,
-                          _PREFILL_TOKENS // bucket))
+        return _rows_under_the_token_cap(bucket, max_batch)
 
     def prompt_pages(self, bucket: int, page_size: int) -> int:
         return bucket // page_size
@@ -206,7 +229,71 @@ class SambaYServing:
                                 method=SambaYModel.decode)
 
 
-_FAMILIES = {LlamaConfig: LlamaServing, SambaYConfig: SambaYServing}
+class GraniteHybridServing:
+    """`models/granite_hybrid.py`: a (k, v) pool for each attention layer,
+    all under one table row a sequence (as `LlamaServing`), and (conv
+    window, state) for each Mamba-2 layer, fixed per slot (as
+    `SambaYServing.mamba`; 2 MB of state a layer at the published sizes,
+    so the slots an engine can hold are bounded by `state_bytes_per_slot`,
+    not by `kv_pool_tokens`)."""
+
+    rewinds = False
+    portable_kv = False
+    pool_readers = 1
+
+    def __init__(self, cfg: GraniteHybridConfig, max_len: int):
+        self.cfg, self.max_len = cfg, max_len
+        self.model = GraniteHybridModel(cfg)
+        self.state_bytes_per_slot = _fixed_bytes_per_slot(
+            self, lambda s: s["ssm"])
+
+    def ring_tokens(self, lens) -> int:
+        return 0
+
+    def prefill_width(self, bucket: int, max_batch: int) -> int:
+        return _rows_under_the_token_cap(bucket, max_batch)
+
+    def prompt_pages(self, bucket: int, page_size: int) -> int:
+        return bucket // page_size
+
+    def init_state(self, max_batch: int, num_pages: int, page_size: int):
+        c = self.cfg
+        B = max_batch
+        # (every leaf a buffer of its own: the state is donated)
+        pool = lambda: jnp.zeros(  # noqa: E731
+            (num_pages, c.n_kv_heads // 2, page_size, 2 * c.head_dim),
+            c.dtype)
+        return {
+            "pools": [(pool(), pool()) for _ in c.layers_of("attention")],
+            "ssm": [(jnp.zeros((B, c.d_conv - 1, c.conv_dim), c.dtype),
+                     jnp.zeros((B, c.mamba_heads, c.mamba_head_dim,
+                                c.d_state), jnp.float32))
+                    for _ in c.layers_of("mamba")]}
+
+    def prefill(self, params, tokens, last_idx):
+        return self.model.apply(params, tokens, last_idx,
+                                method=GraniteHybridModel.prefill)
+
+    def write_prompt(self, state, fresh, slots, page_ids):
+        flat = page_ids.reshape(-1)
+        ps = state["pools"][0][0].shape[2]
+        put = lambda old, new: old.at[slots].set(  # noqa: E731
+            new, mode="drop")
+        return {
+            "pools": [(kp.at[flat].set(_pages(k, ps)),
+                       vp.at[flat].set(_pages(v, ps)))
+                      for (kp, vp), (k, v) in zip(state["pools"],
+                                                  fresh["kv"])],
+            "ssm": [tuple(map(put, old, new)) for old, new in
+                    zip(state["ssm"], fresh["ssm"])]}
+
+    def decode(self, params, token, pos, state, tables, lens, live):
+        return self.model.apply(params, token, pos, state, tables, lens,
+                                live, method=GraniteHybridModel.decode)
+
+
+_FAMILIES = {LlamaConfig: LlamaServing, SambaYConfig: SambaYServing,
+             GraniteHybridConfig: GraniteHybridServing}
 
 
 def family_of(cfg, max_len: int):

@@ -1,0 +1,230 @@
+"""`LLMEngine` over the Granite hybrid family (`serve/llm_families.py`): a
+(k, v) pool for each attention layer and Mamba-2 state fixed per slot,
+behind the same loop, slots and allocator that serve a Llama.  Tiny
+widths, float32, the benchmark's plain reference as the judge: in float32
+on the CPU the engine's greedy tokens are the reference's argmax at every
+position (the top-2 margins of these logits, 1e-3 and up, are far above
+float32 reordering, 5e-6).
+"""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _REPO not in sys.path:
+    sys.path.insert(0, _REPO)
+
+from tests.test_models_granite_hybrid import SIZES, make  # noqa: E402
+
+ENGINE = dict(max_batch=4, max_len=128, page_size=16, decode_chunk=4)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    from ray_tpu.models.granite_hybrid import TINY_GRANITE
+
+    return TINY_GRANITE, make(TINY_GRANITE)
+
+
+def _prompts(seed, lengths):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, 256, size=n).tolist() for n in lengths]
+
+
+def _reference_gap(params, prompt, output):
+    """How far the reference's logit of each engine token lies under the
+    reference's best, teacher-forced over prompt + output (the rule of the
+    benchmark's `correct`), and the share of positions at which the
+    reference's best is the token just read."""
+    from benchmarks.reference import granite_hybrid as ref
+
+    seq = prompt + output[:-1]
+    rows = list(range(len(prompt) - 1, len(seq)))
+    lg = np.asarray(ref.logits(params, SIZES, seq, rows))
+    repeats = (lg.argmax(-1) == np.asarray(seq)[rows]).mean()
+    return lg.max(-1) - lg[np.arange(len(output)), output], repeats
+
+
+def _serve(eng, prompts, new=24):
+    from ray_tpu.models.generate import SamplingParams
+
+    eng.quiesce_for_drain()
+    handles = [eng.submit(p, SamplingParams(max_new_tokens=new))
+               for p in prompts]
+    eng.resume()
+    return [h.tokens() for h in handles]
+
+
+LENGTHS = (5, 19, 33, 40, 17, 64, 28, 3, 50)
+
+
+def test_engine_streams_are_the_references_greedy(tiny):
+    """Nine requests over four slots: batched prefills (rows of very
+    different lengths in one padded bucket), singles, admission
+    mid-flight, and every slot used at least twice.  A reused slot starts
+    from what its own prefill computed from zero, or the second stream in
+    it would leave the reference."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, params = tiny
+    eng = LLMEngine(cfg, params, **ENGINE)
+    try:
+        prompts = _prompts(0, LENGTHS)
+        outs = _serve(eng, prompts)
+        repeats = []
+        for p, o in zip(prompts, outs):
+            assert len(o) == 24
+            gap, repeat = _reference_gap(params, p, o)
+            assert gap.max() == 0.0
+            repeats.append(repeat)
+        # the streams are not echoes of their input: the layers decide
+        assert np.mean(repeats) < 0.2
+        got = eng.report_metrics()
+        assert got["state_slots_reset"] == len(prompts)
+        assert got["ring_tokens"] == 0 and eng.num_active() == 0
+        assert got["paged_pages_live"] > 0
+        # what the fixed state costs, for a reader that knows no model:
+        # six Mamba-2 layers of (3, 160) conv inputs and (4, 32, 16) state
+        assert got["state_bytes_per_slot"] == 6 * (3 * 160 + 4 * 32 * 16) * 4
+        # every token but a stream's first came from a decode step of a
+        # live slot (a chunk may run past a stream's end by less than 4)
+        decoded = len(prompts) * 23
+        assert decoded <= got["state_slot_steps"] < decoded + 4 * len(prompts)
+    finally:
+        eng.shutdown()
+
+
+def test_a_reused_slot_that_keeps_its_last_streams_state_is_seen(tiny,
+                                                                 monkeypatch):
+    """The planted fault: an admission that does not clear S, so that
+    the new stream's state is added to what the slot's last stream left
+    there.  The first stream of each slot finds zeros and is sound; the
+    streams that reuse a slot are not the reference's."""
+    import jax.numpy as jnp
+
+    from ray_tpu.serve import llm_families
+    from ray_tpu.serve.llm import LLMEngine
+
+    sound = llm_families.GraniteHybridServing.write_prompt
+
+    def write_prompt(self, state, fresh, slots, page_ids):
+        new = sound(self, state, fresh, slots, page_ids)
+        B = state["ssm"][0][1].shape[0]
+        written = jnp.zeros((B, 1, 1, 1)).at[slots].set(1.0, mode="drop")
+        return dict(new, ssm=[(conv, s + written * left) for (conv, s),
+                              (_, left) in zip(new["ssm"], state["ssm"])])
+
+    monkeypatch.setattr(llm_families.GraniteHybridServing, "write_prompt",
+                        write_prompt)
+    cfg, params = tiny
+    eng = LLMEngine(cfg, params, **ENGINE)
+    try:
+        prompts = _prompts(0, LENGTHS)
+        outs = _serve(eng, prompts)
+        gaps = [_reference_gap(params, p, o)[0].max()
+                for p, o in zip(prompts, outs)]
+        # (at these widths a state's trace in the logits is small: two of
+        # the five reused streams leave the reference's greedy path, by
+        # 0.004; the sound engine's streams above are held to 0.0)
+        assert max(gaps[:4]) == 0.0
+        assert max(gaps[4:]) > 1e-3
+    finally:
+        eng.shutdown()
+
+
+def test_a_parked_and_an_empty_slot_keep_their_state(tiny):
+    """The decode program with a count of steps a slot: a slot given none
+    (parked, or empty) keeps conv window and state bit for bit while its
+    neighbour decodes; its token, position and length stay."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.generate import SamplingParams
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, params = tiny
+    eng = LLMEngine(cfg, params, **ENGINE)
+    try:
+        eng.quiesce_for_drain()
+        prompts = _prompts(1, (21, 30))
+        handles = [eng.submit(p, SamplingParams(max_new_tokens=40))
+                   for p in prompts]
+        eng.resume()
+        firsts = [next(iter(h)) for h in handles]   # both admitted
+        assert len(firsts) == 2
+        assert eng.quiesce_for_drain()
+        B, K = eng.max_batch, eng.decode_chunk
+        before = jax.tree_util.tree_map(np.array, eng._pools)   # copies
+        steps = np.zeros(B, np.int32)
+        steps[0] = K                    # slot 0 decodes, 1 is parked,
+        toks, after = eng._decode_chunk_paged(      # 2 and 3 are empty
+            eng.params, jnp.asarray(eng._token), jnp.asarray(eng._pos),
+            eng._pools, jnp.asarray(eng._tables), jnp.asarray(eng._lens),
+            jnp.asarray(eng._temps), eng._topks_arr(), eng._topps_arr(),
+            jax.random.PRNGKey(0), jnp.asarray(steps))
+        eng._pools = after              # (the old buffers were donated)
+        after = jax.tree_util.tree_map(np.asarray, after)
+        fixed = lambda s: jax.tree_util.tree_leaves(s["ssm"])  # noqa: E731
+        # (layer 0's conv window holds its last three INPUTS, a function
+        # of the tokens alone: a stream that repeats a token leaves it as
+        # it was, so it is not asked to move)
+        moved = [not np.array_equal(a[0], b[0])
+                 for a, b in zip(fixed(after), fixed(before))]
+        assert all(moved[1:]), "the decoding slot's state did not advance"
+        for a, b in zip(fixed(after), fixed(before)):
+            np.testing.assert_array_equal(a[1:], b[1:])
+    finally:
+        eng.shutdown()
+
+
+def test_what_the_family_cannot_do_is_refused_in_words(tiny):
+    """A stream's state is pages AND fixed per-slot state: it cannot be
+    handed to another engine (disaggregated prefill), nor carried by a
+    drain snapshot."""
+    from ray_tpu.serve.llm import LLMEngine, _Prefilled
+    from ray_tpu.serve.llm_families import family_of
+
+    cfg, params = tiny
+    with pytest.raises(TypeError, match="GraniteHybridConfig.*a new family "
+                                        "is a class"):
+        family_of(object(), 64)
+    eng = LLMEngine(cfg, params, **ENGINE)
+    try:
+        for refused in (
+                lambda: eng.submit_prefilled(
+                    _Prefilled([], 1, 4, 4, 0, [], True)),
+                eng.snapshot_active_streams):
+            with pytest.raises(NotImplementedError,
+                               match="fixed per-slot state.*prefilled where"):
+                refused()
+    finally:
+        eng.shutdown()
+
+
+def test_the_family_sizes_state_and_prefill_from_shapes():
+    """At the published sizes: 36 layers of (64, 64, 128) float32 state
+    and a (3, 4352) bfloat16 conv window a sequence, 8,192 bytes of K and
+    V a token (four pools of 4 paired heads of 128), eight rows a batched
+    prefill at every bucket the cell has."""
+    import jax
+
+    from ray_tpu.models.granite_hybrid import GRANITE_4_H_MICRO
+    from ray_tpu.serve.llm_families import family_of
+
+    fam = family_of(GRANITE_4_H_MICRO, 1600)
+    assert fam.state_bytes_per_slot == 75_497_472 + 36 * 3 * 4352 * 2
+    assert not fam.rewinds and not fam.portable_kv
+    assert [fam.prefill_width(b, 64) for b in (64, 1024, 4096)] == [8, 8, 4]
+    assert fam.prompt_pages(1024, 64) == 16
+    state = jax.eval_shape(lambda: fam.init_state(2, 5, 64))
+    assert [tuple(x.shape) for x in state["pools"][0]] == [(5, 4, 64, 128)] * 2
+    assert len(state["pools"]) == 4 and len(state["ssm"]) == 36
+    per_token = sum(x.size // 5 // 64 * x.dtype.itemsize
+                    for x in jax.tree_util.tree_leaves(state["pools"]))
+    assert per_token == 8192
