@@ -28,6 +28,7 @@ from jax.sharding import PartitionSpec as P
 from ray_tpu.ops.attention import (
     FLASH_LSE,
     FLASH_OUT,
+    causal_over_itself,
     flash_attention,
     mha_reference,
     ring_attention,
@@ -58,6 +59,18 @@ class PagedKVCache(NamedTuple):
     v_pool: Any    # (P, Hkv, page_size, D)   (see ops/paged_attention.py)
     table: Any     # (B, NP) int32 pool indices per sequence
     length: Any    # (B,) int32 tokens already cached (= write offset)
+
+
+class FreshKV:
+    """In place of a cache: there are no earlier keys, and the caller wants
+    each layer's K and V of the tokens it hands in (a prefill whose K/V go
+    into pages). Attention is then causal attention of the prompt over
+    itself, through the flash forward kernel whatever `cfg.attention`
+    says of the cache-less path, and what comes back a layer is (k, v) of
+    shape (B, Hkv, S, D): the prompt's length, not a cache's."""
+
+
+FRESH_KV = FreshKV()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -177,13 +190,21 @@ class Attention(nn.Module):
             return out, PagedKVCache(k_pool, v_pool, pc.table,
                                      pc.length + 1)
 
+        if isinstance(kv_cache, FreshKV):
+            # Right-padded rows: a position past a row's last token gives
+            # K/V nobody attends (causal here, masked by length in decode).
+            out = causal_over_itself(q, k, v)
+            out = out.transpose(0, 2, 1, 3).reshape(B, S, Hq * Dh)
+            return dense(cfg.d_model, name="o_proj")(out), (k, v)
+
         new_cache = None
         if kv_cache is None:
             q = checkpoint_name(q, "attn_q")
             k = checkpoint_name(k, "attn_k")
             v = checkpoint_name(v, "attn_v")
         else:
-            # Decode step: append to cache (S == new tokens, typically 1).
+            # There are earlier keys: append to the cache (a decode step, a
+            # suffix behind a cached prefix) and attend over all of it.
             ck, cv, cache_len = kv_cache
             k = jax.lax.dynamic_update_slice_in_dim(ck, k, cache_len, axis=2)
             v = jax.lax.dynamic_update_slice_in_dim(cv, v, cache_len, axis=2)
@@ -293,6 +314,8 @@ class LlamaModel(nn.Module):
                       jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
             layer_cls = nn.remat(DecoderLayer, policy=policy)
         new_caches = []
+        if isinstance(kv_caches, FreshKV):
+            kv_caches = [kv_caches] * cfg.n_layers
         for i in range(cfg.n_layers):
             layer = layer_cls(cfg, name=f"layers_{i}")
             if kv_caches is not None:

@@ -64,8 +64,8 @@ import numpy as np
 
 from ray_tpu.models.granite_hybrid import (GraniteHybridConfig,
                                            GraniteHybridModel)
-from ray_tpu.models.llama import (LlamaConfig, LlamaModel, PagedKVCache,
-                                  init_kv_caches)
+from ray_tpu.models.llama import (FRESH_KV, LlamaConfig, LlamaModel,
+                                  PagedKVCache)
 from ray_tpu.models.sambay import SambaYConfig, SambaYModel
 
 # Rows of the batched prefill program (fewer where the slots are fewer).
@@ -108,8 +108,9 @@ class LlamaServing:
         return min(BATCH_PREFILL_WIDTH, max_batch)
 
     def prompt_pages(self, bucket: int, page_size: int) -> int:
-        # the prefill's caches are max_len long whatever the bucket
-        return self.max_len // page_size
+        # the prefill's K/V are as long as the bucket (a page multiple:
+        # a power of two no smaller than a page, or max_len itself)
+        return bucket // page_size
 
     def init_state(self, max_batch: int, num_pages: int, page_size: int):
         shape = (num_pages, self.cfg.n_kv_heads, page_size,
@@ -119,22 +120,24 @@ class LlamaServing:
                 for _ in range(self.cfg.n_layers)]
 
     def prefill(self, params, tokens, last_idx):
-        # tokens: (W, bucket) right-padded. Cache entries past the true
-        # prompt length hold garbage, but decode masks keys by position
-        # (kpos <= qpos) and overwrites index `cache_len` before each
-        # attention, so they are never attended. The last-token logits
-        # are gathered INSIDE the program: the full (W, bucket, vocab)
-        # logits never reach the host.
+        # tokens: (W, bucket) right-padded. A slot's pages hold no
+        # earlier keys, so the prompt attends over itself (`FRESH_KV`:
+        # the flash kernel, no cache of max_len) and each layer's K/V
+        # come back (W, Hkv, bucket, D). Those past a row's true length
+        # are garbage, but they land on the dummy page or are overwritten
+        # at index `length` before the decode kernel, which reads no
+        # further than that, attends them. The last-token logits are
+        # gathered INSIDE the program: the full (W, bucket, vocab) logits
+        # never reach the host.
         positions = jnp.arange(tokens.shape[1])[None, :]
-        caches = init_kv_caches(self.cfg, tokens.shape[0], self.max_len)
-        logits, new = self.model.apply(params, tokens, positions,
-                                       kv_caches=caches)
+        logits, fresh = self.model.apply(params, tokens, positions,
+                                         kv_caches=FRESH_KV)
         last = jnp.take_along_axis(
             logits, last_idx[:, None, None], axis=1)[:, 0]
-        return last, [(k, v) for k, v, _l in new]
+        return last, fresh
 
     def write_prompt(self, pools, fresh, slots, page_ids):
-        # Scatter the rows' (W, Hkv, L, D) caches into pool pages (pool
+        # Scatter the rows' (W, Hkv, L, D) K/V into pool pages (pool
         # layout (P, Hkv, page, D)): rows flatten into one scatter;
         # page_ids past a prompt point at the dummy page (garbage there
         # is fine).

@@ -13,6 +13,7 @@ the TPU library.  Keep these cases in this one file.
 """
 
 import os
+import re
 import signal
 import sys
 
@@ -294,18 +295,107 @@ ENTRY %main (a: {pool}) -> {pool} {{
                     "moved": 1}}
 
 
+def _compiled_prefill(eng, params, one_chip, W, bucket):
+    """The engine's prefill of W rows of a bucket (`prefill_one` for a
+    row alone, as the engine chooses), lowered and compiled for the chip."""
+    program = eng._prefill_one if W == 1 else eng._prefill_many
+    lowered = program.lower(
+        params,
+        jax.ShapeDtypeStruct((W, bucket), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((W,), jnp.int32, sharding=one_chip))
+    return lowered, lowered.compile()
+
+
 def test_engine_batched_prefill(engine_programs, one_chip):
-    """The engine prefills through the dense masked path over its KV
-    cache (no Pallas kernel on it); what is checked is that the whole
-    (W, bucket) program compiles and fits beside the weights."""
+    """The whole (W, bucket) program of the smoke's engine compiles and
+    fits beside the weights; since PR 39 the prompt attends over itself
+    through the flash forward kernel, once a layer."""
     cfg, eng, params = engine_programs
     W = eng._batch_prefill_width
     bucket = max(eng._bucket(chip_smoke.PROMPT_LENGTHS[-1]), eng.page_size)
-    compiled = eng._prefill_many.lower(
-        params,
-        jax.ShapeDtypeStruct((W, bucket), jnp.int32, sharding=one_chip),
-        jax.ShapeDtypeStruct((W,), jnp.int32, sharding=one_chip)).compile()
+    _, compiled = _compiled_prefill(eng, params, one_chip, W, bucket)
+    assert compiled.as_text().count(KERNEL) == cfg.n_layers
     assert _peak_bytes(compiled) < HBM_BYTES
+
+
+# `benchmarks/configs/mistral-7b-v0.3-l16-b4.json`: the engine of
+# `mistral7b-serve-docs-closed`; the model of both dense serve cells as the
+# harness builds it (`families/dense_decoder.program_config`: "reference"
+# is what the cache-less path would run, a prefill does not ask it).
+DOCS_CLOSED_ENGINE = dict(max_batch=4, max_len=2304, page_size=64,
+                          decode_chunk=8, kv_pool_tokens=12288)
+MISTRAL_L16 = LlamaConfig(vocab_size=32768, d_model=4096, n_layers=16,
+                          n_heads=32, n_kv_heads=8, d_ff=14336,
+                          rope_theta=1e6, attention="reference", remat=False)
+
+
+@pytest.fixture(scope="module")
+def mistral_params(one_chip):
+    return _on(one_chip, jax.eval_shape(
+        lambda: LlamaModel(MISTRAL_L16).init(jax.random.PRNGKey(0),
+                                             jnp.zeros((1, 8), jnp.int32))))
+
+
+@pytest.mark.parametrize("engine, W, bucket", [
+    (CHAT_OPEN_ENGINE, 8, 1024), (CHAT_OPEN_ENGINE, 1, 64),
+    (DOCS_CLOSED_ENGINE, 4, 2048), (DOCS_CLOSED_ENGINE, 1, 2304)],
+    ids=["chat-8x1024", "chat-1x64", "docs-4x2048", "docs-1x2304"])
+def test_dense_prefill_is_the_prompt_over_itself(one_chip, mistral_params,
+                                                 engine, W, bucket):
+    """The two dense cells' prefill programs at their real widths and
+    depth: the batched program at its largest bucket, the single one at
+    the smallest and at `max_len` itself (2304 = 9 x 256: no power of
+    two, so the kernel's blocks are fitted). Each holds the flash forward
+    kernel once a layer, no float32 scores of bucket x `max_len` a head
+    (nor of bucket x bucket), no cache of `max_len` where the bucket is
+    shorter, and returns K/V as long as the bucket."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, max_len = MISTRAL_L16, engine["max_len"]
+    eng = LLMEngine(cfg, mistral_params, **engine)
+    try:
+        lowered, compiled = _compiled_prefill(eng, mistral_params, one_chip,
+                                              W, bucket)
+    finally:
+        eng.shutdown()
+    logits, fresh = lowered.out_info
+    assert logits.shape == (W, cfg.vocab_size)
+    assert {x.shape for x in jax.tree_util.tree_leaves(fresh)} == \
+        {(W, cfg.n_kv_heads, bucket, cfg.head_dim)}
+    assert len(fresh) == cfg.n_layers
+    text = compiled.as_text()
+    assert text.count(KERNEL) == cfg.n_layers
+    # (rope's float32 halves are (W, heads, bucket, 64): 64 keys tell nothing)
+    keys = "|".join(str(n) for n in {max_len, bucket} - {cfg.head_dim // 2})
+    assert not re.search(rf"f32\[\d+,\d+,{bucket},({keys})\]", text)
+    if bucket < max_len:
+        assert f",{max_len},{cfg.head_dim}]" not in text
+    assert _peak_bytes(compiled) < HBM_BYTES
+
+
+def test_docs_closed_prefill_needs_less_than_over_the_dense_cache(
+        one_chip, mistral_params, capsys):
+    """4 x 2048 tokens, docs-closed's largest prefill, needed 10.83 GB
+    (weights, temporaries and outputs) while it attended over a float32
+    (2048, 2304) block a head and returned caches of `max_len`; 8 x 2048,
+    what `max_batch` 8 would compile, needed 14.13 GB beside no pool at
+    all, which is why the cell has 4 slots. Printed: what both need now."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    peaks = {}
+    for slots in (4, 8):
+        eng = LLMEngine(MISTRAL_L16, mistral_params,
+                        **{**DOCS_CLOSED_ENGINE, "max_batch": slots})
+        try:
+            peaks[slots] = _peak_bytes(_compiled_prefill(
+                eng, mistral_params, one_chip, slots, 2048)[1])
+        finally:
+            eng.shutdown()
+    with capsys.disabled():
+        print("\nprefill_many peak bytes (weights 7.52 GB among them): "
+              f"4 x 2048 {peaks[4]:,}, 8 x 2048 {peaks[8]:,}")
+    assert peaks[4] < 10.83e9
+    assert peaks[8] < 14.13e9
 
 
 def _abstract_train(cfg, mesh, batch, seq):

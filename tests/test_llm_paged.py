@@ -4,6 +4,8 @@ prefill, and what the kernel's counters read; greedy output bit-equal to
 the one-shot Generator throughout (the plain cases of that are in
 tests/test_serve_llm.py)."""
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -29,6 +31,26 @@ def _reference_greedy(cfg, params, prompt, n_new):
     gen = Generator(cfg, params, batch=1, max_len=len(prompt) + n_new)
     return gen.generate(np.asarray([prompt], np.int32),
                         SamplingParams(max_new_tokens=n_new))[0].tolist()
+
+
+@contextlib.contextmanager
+def _page_writes(eng):
+    """What `eng` hands its page writer, a call: the shapes of the fresh
+    K/V leaves and the page columns. Shuts the engine down on the way out."""
+    seen = []
+    real = eng._write_prompt_pages
+
+    def spy(pools, fresh, slots, page_ids):
+        seen.append(({x.shape for kv in fresh for x in kv},
+                     np.asarray(page_ids)))
+        return real(pools, fresh, slots, page_ids)
+
+    eng._write_prompt_pages = spy
+    try:
+        yield seen
+    finally:
+        eng._write_prompt_pages = real
+        eng.shutdown()
 
 
 def test_paged_admission_bounded_by_pool_not_slots(tiny_model):
@@ -109,6 +131,52 @@ def test_batched_prefill_used_and_bit_equal(tiny_model):
     finally:
         eng._prefill_many, eng._prefill_one = real_many, real_one
         eng.shutdown()
+
+
+@pytest.mark.parametrize("prompt_len, bucket",
+                         [(5, 16), (16, 16), (17, 32), (40, 64), (70, 96)],
+                         ids=["in-a-page", "a-page-full", "two-pages",
+                              "mid", "max_len"])
+def test_prefill_hands_over_kv_as_long_as_the_bucket(tiny_model, prompt_len,
+                                                     bucket):
+    """A prompt attends over itself, so what its prefill hands the page
+    writer is K/V of the bucket's length with as many page columns, and
+    not a cache of `max_len` (6 columns here, whatever the bucket): a
+    bucket of one page, a prompt that fills its page to the last row, the
+    bucket that is `max_len` itself and no power of two. The greedy
+    stream is the Generator's, as it was over the dense cache."""
+    cfg, params = tiny_model
+    eng = LLMEngine(cfg, params, max_batch=2, max_len=96, page_size=16)
+    with _page_writes(eng) as seen:
+        prompt = [(i * 11 + 5) % 120 + 1 for i in range(prompt_len)]
+        n_new = min(12, 96 - prompt_len)
+        assert eng.generate(prompt, SamplingParams(max_new_tokens=n_new)) \
+            == _reference_greedy(cfg, params, prompt, n_new)
+    (shapes, page_ids), = seen
+    assert shapes == {(1, cfg.n_kv_heads, bucket, cfg.head_dim)}
+    assert page_ids.shape == (1, bucket // 16)
+
+
+def test_batched_prefill_of_unequal_rows_fills_only_their_own_pages(
+        tiny_model):
+    """Rows of one bucket and unequal length through `prefill_many`: a
+    row's pages past its prompt are the dummy page's columns, a padding
+    row's all of them, and every stream is the Generator's."""
+    cfg, params = tiny_model
+    eng = LLMEngine(cfg, params, max_batch=4, max_len=96, page_size=16)
+    dummy = eng._dummy_page
+    with _page_writes(eng) as seen:
+        prompts = [[(i * 7 + r) % 120 + 1 for i in range(n)]
+                   for r, n in enumerate((33, 64, 50))]
+        expected = [_reference_greedy(cfg, params, p, 8) for p in prompts]
+        eng.quiesce_for_drain()
+        handles = [eng.submit(p, SamplingParams(max_new_tokens=8))
+                   for p in prompts]
+        eng.resume()
+        assert [h.tokens() for h in handles] == expected
+    (shapes, page_ids), = seen
+    assert shapes == {(4, cfg.n_kv_heads, 64, cfg.head_dim)}
+    assert [(row != dummy).sum() for row in page_ids] == [3, 4, 4, 0]
 
 
 def test_freed_slot_has_length_zero_and_live_pages_are_counted(tiny_model):

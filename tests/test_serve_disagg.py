@@ -151,6 +151,33 @@ def test_prefilled_handoff_into_paged_engine(tiny_model):
         eng.shutdown()
 
 
+@pytest.mark.parametrize("prompt_len", [7, 16, 40, 70],
+                         ids=["in-a-page", "a-page-full", "mid", "max_len"])
+def test_prefilled_kv_equals_what_the_engine_prefills_itself(tiny_model,
+                                                             prompt_len):
+    """The prefill pool still attends over a dense cache (it holds earlier
+    keys when a prefix hits); the decode engine's own prefill is the
+    prompt over itself. Both put the same K/V into pages: a stream handed
+    over through `submit_prefilled` and the same prompt submitted plainly
+    give the same tokens, the Generator's."""
+    cfg, params = tiny_model
+    pe = PrefillEngine(cfg, params, max_len=96)
+    eng = LLMEngine(cfg, params, max_batch=2, max_len=96, page_size=16)
+    try:
+        prompt = [(i * 13 + 2) % 120 + 1 for i in range(prompt_len)]
+        sp = SamplingParams(max_new_tokens=10)
+        out = pe.prefill(np.asarray(prompt), sp, None)
+        assert all(k.shape == (cfg.n_kv_heads, prompt_len, cfg.head_dim)
+                   for k, _v in out["kv"])
+        pack = _Prefilled(out["kv"], out["first_token"], out["prompt_len"],
+                          out["kv_len"], 0, [], emit_first=True)
+        want = _reference_greedy(cfg, params, prompt, 10)
+        assert eng.submit_prefilled(pack, sp).tokens() == want
+        assert eng.generate(prompt, sp) == want
+    finally:
+        eng.shutdown()
+
+
 # ---------------------------------------------------------------------------
 # Two-pool e2e
 # ---------------------------------------------------------------------------

@@ -59,6 +59,31 @@ def test_engine_matches_generator_greedy(tiny_model, pooled_engine):
     assert got == expected
 
 
+# What the engine of PR 36 (prefill over a dense cache of `max_len`, masked
+# softmax in float32) gave for `_PARENT_PROMPTS` on this model, 16 tokens
+# each, greedy, three streams at once.
+_PARENT_PROMPTS = [[(i * 5 + 3 * n) % 120 + 1 for i in range(n)]
+                   for n in (5, 37, 70)]
+_PARENT_TOKENS = [
+    [52, 113, 113, 39, 39, 39, 39, 39, 39, 39, 39, 39, 39, 39, 39, 39],
+    [113, 113, 119, 37, 29, 6, 102, 37, 15, 121, 113, 10, 124, 84, 21, 90],
+    [65, 32, 117, 110, 65, 86, 61, 98, 86, 27, 65, 86, 106, 23, 106, 34]]
+
+
+@pytest.mark.parametrize("which", [0, 1, 2],
+                         ids=["one-page", "three-pages", "max_len-bucket"])
+def test_greedy_tokens_are_what_the_dense_cache_prefill_gave(tiny_model,
+                                                             engine, which):
+    """The prefill is the prompt over itself through the flash kernel
+    now; the tokens are those the engine gave before, and the
+    Generator's (which still prefills over a dense cache)."""
+    cfg, params = tiny_model
+    prompt = _PARENT_PROMPTS[which]
+    got = engine.generate(prompt, SamplingParams(max_new_tokens=16))
+    assert got == _PARENT_TOKENS[which]
+    assert got == _reference_greedy(cfg, params, prompt, 16)
+
+
 def test_engine_concurrent_requests_interleave(tiny_model, pooled_engine):
     cfg, params = tiny_model
     prompts = [[1, 2, 3], [4, 5, 6, 7, 8, 9, 10], [11, 12]]

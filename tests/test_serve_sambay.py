@@ -178,4 +178,7 @@ def test_the_family_sizes_the_batched_prefill_by_bucket(tiny):
     assert fam.prompt_pages(2048, 64) == 32
     llama = family_of(TINY, 2304)
     assert [llama.prefill_width(b, 32) for b in (64, 2048)] == [8, 8]
-    assert llama.prompt_pages(64, 64) == 36
+    # K/V of the bucket's length since the prompt attends over itself
+    # (36, the pages of max_len, while the prefill held a dense cache)
+    assert [llama.prompt_pages(b, 64) for b in (64, 2048, 2304)] == \
+        [1, 32, 36]
