@@ -32,21 +32,22 @@ class SamplingParams:
 
 def sample_logits(logits, rng, params: SamplingParams):
     """logits: (B, V) → tokens (B,)."""
-    if params.temperature <= 0.0:
-        return jnp.argmax(logits, axis=-1)
-    logits = logits / params.temperature
-    if params.top_k > 0:
-        kth = jax.lax.top_k(logits, params.top_k)[0][..., -1:]
-        logits = jnp.where(logits < kth, -1e30, logits)
-    if params.top_p < 1.0:
-        sorted_logits = jnp.sort(logits, axis=-1)[..., ::-1]
-        probs = jax.nn.softmax(sorted_logits, axis=-1)
-        cum = jnp.cumsum(probs, axis=-1)
-        # Smallest set whose mass ≥ top_p; keep at least one.
-        cutoff_idx = jnp.sum(cum < params.top_p, axis=-1, keepdims=True)
-        cutoff = jnp.take_along_axis(sorted_logits, cutoff_idx, axis=-1)
-        logits = jnp.where(logits < cutoff, -1e30, logits)
-    return jax.random.categorical(rng, logits, axis=-1)
+    with jax.named_scope("sample"):
+        if params.temperature <= 0.0:
+            return jnp.argmax(logits, axis=-1)
+        logits = logits / params.temperature
+        if params.top_k > 0:
+            kth = jax.lax.top_k(logits, params.top_k)[0][..., -1:]
+            logits = jnp.where(logits < kth, -1e30, logits)
+        if params.top_p < 1.0:
+            sorted_logits = jnp.sort(logits, axis=-1)[..., ::-1]
+            probs = jax.nn.softmax(sorted_logits, axis=-1)
+            cum = jnp.cumsum(probs, axis=-1)
+            # Smallest set whose mass ≥ top_p; keep at least one.
+            cutoff_idx = jnp.sum(cum < params.top_p, axis=-1, keepdims=True)
+            cutoff = jnp.take_along_axis(sorted_logits, cutoff_idx, axis=-1)
+            logits = jnp.where(logits < cutoff, -1e30, logits)
+        return jax.random.categorical(rng, logits, axis=-1)
 
 
 class Generator:

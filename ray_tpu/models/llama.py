@@ -324,12 +324,16 @@ class LlamaModel(nn.Module):
             else:
                 x = layer(x, positions)
         x = RMSNorm(cfg.rms_eps, name="norm")(x)
-        if cfg.tie_embeddings:
-            logits = embed.attend(x.astype(cfg.dtype))
-        else:
-            logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                              param_dtype=cfg.dtype, name="lm_head")(x)
-        logits = logits.astype(jnp.float32)
+        # (Attention and MLP are named by their modules, `layers_<i>/attn`
+        # and `/mlp`; a tied head has no module of its own.)
+        with jax.named_scope("head"):
+            if cfg.tie_embeddings:
+                logits = embed.attend(x.astype(cfg.dtype))
+            else:
+                logits = nn.Dense(cfg.vocab_size, use_bias=False,
+                                  dtype=cfg.dtype, param_dtype=cfg.dtype,
+                                  name="lm_head")(x)
+            logits = logits.astype(jnp.float32)
         if kv_caches is not None:
             return logits, new_caches
         return logits

@@ -282,9 +282,10 @@ def window_attention(q, k, v, window: int, sm_scale: float,
         mask = seen & ((blk > 0) | (kpos >= window))
         return masked_attention(qb, kb, vb, mask, sm_scale, precise)
 
-    out = jax.lax.map(one, (jnp.arange(nb), blocks(q),
-                            with_previous(blocks(k)),
-                            with_previous(blocks(v))))
+    with jax.named_scope("window_attention"):
+        out = jax.lax.map(one, (jnp.arange(nb), blocks(q),
+                                with_previous(blocks(k)),
+                                with_previous(blocks(v))))
     out = out.transpose(1, 2, 0, 3, 4).reshape(B, Hq, nb * window, D)
     return out[:, :, :S]
 
@@ -436,8 +437,9 @@ class Mamba(nn.Module):
         # A position past a row's last token leaves the state alone.
         delta = jnp.where(jnp.arange(S)[None, :, None]
                           <= last_idx[:, None, None], delta, 0.0)
-        y, s_last = chunked_selective_scan(
-            delta, u, b_sel, c_sel, -jnp.exp(self.a_log))
+        with jax.named_scope("selective_scan"):
+            y, s_last = chunked_selective_scan(
+                delta, u, b_sel, c_sel, -jnp.exp(self.a_log))
         y = keep(y + self.d_skip * u.astype(jnp.float32))
         # padded position p holds input p - (K - 1): the K - 1 inputs that
         # end at last_idx are padded positions last_idx + 1 .. + K - 1
@@ -459,10 +461,12 @@ class Mamba(nn.Module):
         u = jax.nn.silu(jnp.sum(window * f32(self.conv_w), axis=1)
                         + f32(self.conv_b))
         delta, b_sel, c_sel = self._selective(u, precise)
-        a_t = -jnp.exp(self.a_log).T                              # (N, E)
-        s = jnp.exp(delta[:, None, :] * a_t) * s_prev \
-            + (delta * f32(u))[:, None, :] * f32(b_sel)[:, :, None]
-        y = jnp.einsum("bne,bn->be", s, f32(c_sel)) + self.d_skip * f32(u)
+        with jax.named_scope("state_step"):
+            a_t = -jnp.exp(self.a_log).T                          # (N, E)
+            s = jnp.exp(delta[:, None, :] * a_t) * s_prev \
+                + (delta * f32(u))[:, None, :] * f32(b_sel)[:, :, None]
+            y = jnp.einsum("bne,bn->be", s, f32(c_sel)) \
+                + self.d_skip * f32(u)
         new = (window[:, 1:].astype(conv.dtype), s)
         if live is not None:
             new = (jnp.where(live[:, None, None], new[0], conv),
@@ -578,8 +582,9 @@ class SambaYModel(nn.Module):
         return x, memory, cache, {"mamba": mamba, "rings": rings}
 
     def _head(self, x, precise: bool):
-        return matmul(self.norm(x), self.embed.embedding.T,
-                      precise).astype(jnp.float32)
+        with jax.named_scope("head"):
+            return matmul(self.norm(x), self.embed.embedding.T,
+                          precise).astype(jnp.float32)
 
     def _cross_decoder(self, x, memory, k, v, mask, precise: bool):
         """Layers L/2+2 .. over x (B, S, d) with the memory at the same
@@ -688,12 +693,13 @@ class SambaYModel(nn.Module):
                     # ring has no kernel, and held token-major it is
                     # copied all the same, by the dot that reads it:
                     # PERF.md section 6, PR 29.)
-                    nk = rk.at[rows, :, slot].set(k)
-                    nv = rv.at[rows, :, slot].set(v)
-                    seen = (jnp.arange(c.window)[None, :]
-                            <= length[:, None])[:, None, None, None, :]
-                    o = masked_attention(q, nk, nv, seen, attn.sm_scale,
-                                         True)
+                    with jax.named_scope("ring"):
+                        nk = rk.at[rows, :, slot].set(k)
+                        nv = rv.at[rows, :, slot].set(v)
+                        seen = (jnp.arange(c.window)[None, :]
+                                <= length[:, None])[:, None, None, None, :]
+                        o = masked_attention(q, nk, nv, seen,
+                                             attn.sm_scale, True)
                     return attn.combine(o, True), nk, nv
 
                 x, nk, nv = layer.mix(x, mixer, True)

@@ -446,7 +446,11 @@ def cmd_timeline(args):
     ray_tpu = _connect_from_state(args)
     from ray_tpu.util.timeline import dump_timeline
 
-    path = dump_timeline(args.output)
+    session_dir = None
+    if ray_tpu._cli_owns_session:  # a head started by `ray_tpu start`
+        with open(args.state_file) as f:
+            session_dir = json.load(f).get("session_dir")
+    path = dump_timeline(args.output, session_dir=session_dir)
     print(f"chrome trace written to {path} (open in chrome://tracing "
           "or https://ui.perfetto.dev)")
     _shutdown_if_owned(ray_tpu)

@@ -34,6 +34,7 @@ behind Serve deployments); this engine is native and TPU-shaped:
 
 from __future__ import annotations
 
+import itertools
 import logging
 import queue
 import threading
@@ -45,6 +46,7 @@ import numpy as np
 
 from ray_tpu.models.generate import SamplingParams
 from ray_tpu.serve.llm_families import family_of
+from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -87,14 +89,20 @@ class RequestHandle:
     draining while decode keeps producing parks the producing slot
     (backpressure) instead of growing host memory without limit."""
 
+    _rids = itertools.count(1)
+
     def __init__(self, prompt_len: int, sampling: SamplingParams,
                  max_buffered: int = 256, tag: str = ""):
         self.prompt_len = prompt_len
         self.sampling = sampling
         self.tag = tag  # router-visible stream key (disagg resume)
+        # Process-unique: what the spans of this request share
+        # (`request.queue`, `request.first_token`, `engine.admit`'s `rids`).
+        self.rid = next(RequestHandle._rids)
         self._q: queue.Queue = queue.Queue(maxsize=max(1, max_buffered))
         self._done = threading.Event()
-        self._submit_ts = time.monotonic()
+        self._submit_ns = time.monotonic_ns()
+        self._submit_ts = self._submit_ns / 1e9
         self.error: Exception | None = None
 
     def _offer(self, tok: int) -> bool:
@@ -143,6 +151,16 @@ class RequestHandle:
     def tokens(self) -> list[int]:
         """Block until completion; all tokens as a list."""
         return list(self)
+
+
+def _idle_span(open_span, why: str):
+    """The engine's loop has nothing to do for a while (`engine.idle`,
+    outside any pass): the open span of that kind, begun if there is none."""
+    if open_span is not None and open_span.attrs["why"] == why:
+        return open_span
+    if open_span is not None:
+        open_span.end()
+    return tracing.span("engine.idle", why=why).begin()
 
 
 class LLMEngine:
@@ -221,6 +239,7 @@ class LLMEngine:
 
         V = cfg.vocab_size
 
+        @jax.named_scope("sample")
         def _sample(logits, temps, top_ks, top_ps, rng):
             # Per-slot temperature / top-k / top-p, fully vectorized
             # (matches models/generate.sample_logits semantics per row;
@@ -555,8 +574,13 @@ class LLMEngine:
         st.request = handle
         st.generated = 0
         st.history = []
+        self._first_token(slot, handle, tok)
+
+    def _first_token(self, slot: int, handle: RequestHandle, tok: int):
         self._ttft.append(time.monotonic() - handle._submit_ts)
         self._emit(slot, tok)
+        tracing.record_span("request.first_token", handle._submit_ns,
+                            rid=handle.rid)
 
     def _commit_prefilled(self, slot: int, handle: RequestHandle,
                           pack: _Prefilled):
@@ -576,8 +600,7 @@ class LLMEngine:
         st.generated = pack.generated
         st.history = list(pack.history)
         if pack.emit_first:
-            self._ttft.append(time.monotonic() - handle._submit_ts)
-            self._emit(slot, pack.token)
+            self._first_token(slot, handle, pack.token)
 
     def _admit_prefilled_paged(self, slot: int, seq_id: str,
                                pack: _Prefilled, handle: RequestHandle):
@@ -654,8 +677,10 @@ class LLMEngine:
                 chunk = group[:width]
                 group = group[len(chunk):]
                 # A request alone keeps the single-sequence program.
-                self._prefill_group(chunk, bucket,
-                                    1 if len(chunk) == 1 else width)
+                W = 1 if len(chunk) == 1 else width
+                with tracing.span("engine.prefill", bucket=bucket,
+                                  rows=len(chunk), width=W):
+                    self._prefill_group(chunk, bucket, W)
 
     def _prefill_group(self, chunk: list, bucket: int, W: int) -> None:
         """One prefill dispatch for `chunk` (at most W requests of one
@@ -700,8 +725,9 @@ class LLMEngine:
             # ever lands). Greedy stays bit-equal whatever the group:
             # argmax ignores the rng mapping.
             self._rng, srng = self._jax.random.split(self._rng)
-            toks = np.asarray(self._sample(
-                last_logits, temps, topks, topps, srng))
+            with tracing.span("engine.prefill.wait"):
+                toks = np.asarray(self._sample(
+                    last_logits, temps, topks, topps, srng))
         except BaseException as e:
             # Device-level failure sinks the whole dispatch: fail
             # every member and return their pages.
@@ -748,11 +774,15 @@ class LLMEngine:
     def _count_paged_pages(self):
         """Table pages the paged kernel visits in the chunk about to be
         dispatched, against those the table holds: step k of the chunk
-        attends over `_lens + k + 1` tokens of every slot."""
+        attends over `_lens + k + 1` tokens of every slot. Returns this
+        chunk's own two counts (they ride on its `engine.decode.wait`)."""
         steps = 1 + np.arange(self.decode_chunk)
         tokens = self._lens[:, None].astype(np.int64) + steps
-        self.paged_pages_live += int((-(-tokens // self.page_size)).sum())
-        self.paged_pages_table += tokens.size * self._np_pages
+        live = int((-(-tokens // self.page_size)).sum())
+        table = tokens.size * self._np_pages
+        self.paged_pages_live += live
+        self.paged_pages_table += table
+        return live, table
 
     def _steps_to_take(self) -> np.ndarray:
         """Steps of the next chunk each slot may advance: as many as its
@@ -794,14 +824,44 @@ class LLMEngine:
 
     def _loop(self):
         jax, jnp = self._jax, self._jnp
+        # What the host does here leaves spans (`util/tracing.py`; PERF.md
+        # section 3 has the names): `engine.pass` for an iteration that
+        # admits or dispatches, its phases inside it, `engine.idle` between.
+        idle = None
         while not self._stop.is_set():
             # Drain quiesce: ack and idle at a tick boundary — every
             # admitted token is committed, so slot/KV state is a
             # consistent snapshot for the evacuation path.
             if self._quiesce.is_set():
+                idle = _idle_span(idle, "quiesce")
                 self._quiet.set()
                 self._stop.wait(0.01)
                 continue
+            decoding = [s for s in self._slots if s.request is not None]
+            arrived = None
+            admitting = len(decoding) < self.max_batch and (
+                bool(self._deferred) or not self._pending.empty())
+            if not admitting:
+                if not decoding:
+                    # Nothing in flight: block for a request.
+                    idle = _idle_span(idle, "no_request")
+                    try:
+                        arrived = self._pending.get(timeout=0.05)
+                    except queue.Empty:
+                        continue
+                    admitting = True
+                elif all(s.request.backlog_full() for s in decoding):
+                    # Backpressure: if EVERY decoding stream's consumer
+                    # queue is full, a decode chunk would produce only
+                    # parked tokens — skip the dispatch and give the
+                    # consumers time to drain.
+                    idle = _idle_span(idle, "backpressure")
+                    self._stop.wait(0.002)
+                    continue
+            if idle is not None:
+                idle.end()
+                idle = None
+            loop_pass = tracing.span("engine.pass").begin()
             # Admit as many pending requests as there are free slots —
             # without stalling slots that are mid-decode. Admission also
             # gates on pool pages: a dry pool defers the request (FIFO)
@@ -811,16 +871,18 @@ class LLMEngine:
             # bottleneck at large batch.
             cands: list = []
             picked: set = set()
-            while any(i not in picked and s.request is None
-                      for i, s in enumerate(self._slots)):
+            admit = tracing.span("engine.admit").begin() if admitting \
+                else None
+            while admitting and any(i not in picked and s.request is None
+                                    for i, s in enumerate(self._slots)):
                 from_deferred = bool(self._deferred)
                 if from_deferred:
                     prompt, handle = self._deferred[0]
+                elif arrived is not None:
+                    (prompt, handle), arrived = arrived, None
                 else:
                     try:
-                        prompt, handle = self._pending.get(
-                            block=(self.num_active() == 0
-                                   and not cands), timeout=0.05)
+                        prompt, handle = self._pending.get_nowait()
                     except queue.Empty:
                         break
                 slot = next(i for i, s in enumerate(self._slots)
@@ -842,15 +904,24 @@ class LLMEngine:
                     self._deferred.pop(0)
                 picked.add(slot)
                 cands.append((slot, seq_id, prompt, handle))
+                # Slot and pages are this request's: its wait is over.
+                tracing.record_span("request.queue", handle._submit_ns,
+                                    rid=handle.rid,
+                                    prompt_len=handle.prompt_len,
+                                    deferred=from_deferred)
             if cands:
                 self._admit_paged_group(cands)
+            if admit is not None:
+                admit.end(admitted=len(cands), deferred=len(self._deferred),
+                          rids=[c[3].rid for c in cands])
             decoding = [s for s in self._slots if s.request is not None]
             if not decoding:
+                loop_pass.end()
                 continue
-            # Backpressure: if EVERY decoding stream's consumer queue is
-            # full, a decode chunk would produce only parked tokens —
-            # skip the dispatch and give the consumers time to drain.
             if all(s.request.backlog_full() for s in decoding):
+                # (Backpressure again: what was admitted filled its queue.)
+                loop_pass.end()
+                idle = _idle_span(idle, "backpressure")
                 self._stop.wait(0.002)
                 continue
             # One decode CHUNK for every slot (inactive slots compute
@@ -859,12 +930,14 @@ class LLMEngine:
             # State that cannot be rewound: every slot's steps are sized
             # now, by what its consumer's queue takes and what it is still
             # owed, and the program holds it still past them.
+            build = tracing.span("engine.decode.build").begin()
             steps = None if self.family.rewinds else self._steps_to_take()
-            self.state_slot_steps += len(decoding) * self.decode_chunk \
+            slot_steps = len(decoding) * self.decode_chunk \
                 if steps is None else int(steps.sum())
+            self.state_slot_steps += slot_steps
             try:
                 self._rng, srng = jax.random.split(self._rng)
-                self._count_paged_pages()
+                pages_live, pages_table = self._count_paged_pages()
                 args = [self.params, jnp.asarray(self._token),
                         jnp.asarray(self._pos), self._pools,
                         jnp.asarray(self._tables), jnp.asarray(self._lens),
@@ -872,15 +945,25 @@ class LLMEngine:
                         self._topps_arr(), srng]
                 if steps is not None:
                     args.append(jnp.asarray(steps))
-                toks, self._pools = self._decode_chunk_paged(*args)
-                toks = np.asarray(toks)  # (K, B)
+                build.end()
+                with tracing.span("engine.decode.dispatch"):
+                    toks, self._pools = self._decode_chunk_paged(*args)
+                with tracing.span("engine.decode.wait",
+                                  active=len(decoding), steps=slot_steps,
+                                  pages_live=pages_live,
+                                  pages_table=pages_table):
+                    toks = np.asarray(toks)  # (K, B)
             except Exception as e:
                 # A decode failure (device OOM, donated-buffer misuse, ...)
                 # must not strand waiters on a dead thread: fail loudly and
                 # keep serving subsequent requests on fresh state.
                 self._fail_all(e)
                 self._init_paged_state()
+                loop_pass.end(error=type(e).__name__)
                 continue
+            walk = tracing.span("engine.walk").begin()
+            emitted = finished = 0
+            parked = self._parked_events
             for i, st in enumerate(self._slots):
                 if st.request is None:
                     continue
@@ -895,11 +978,18 @@ class LLMEngine:
                         # the uncommitted steps' writes are garbage that
                         # is simply rewritten.
                         break
+                    emitted += 1
                     if st.request is None:  # eos/max_new hit mid-chunk:
-                        break               # _emit freed the slot
+                        finished += 1       # _emit freed the slot
+                        break
                     self._lens[i] += 1
                     self._pos[i] += 1
                     self._token[i] = tok
+            walk.end(emitted=emitted, finished=finished,
+                     parked=self._parked_events - parked)
+            loop_pass.end()
+        if idle is not None:
+            idle.end()
 
 
 # ---------------------------------------------------------------------------

@@ -6,14 +6,19 @@ chrome://tracing JSON. Here the GCS task-event table provides the full
 lifecycle ladder: one "X" complete event per task execution on (node,
 worker) rows, plus per-STAGE sub-spans (queue, lease negotiation,
 dispatch, arg fetch) on dedicated "stage:<name>" rows so where a slow
-task spent its pre-execution time is visible at a glance.
+task spent its pre-execution time is visible at a glance. The session's
+program spans (`util/tracing.py`: what an engine's loop was doing, a
+request's waits) join it as rows of their own, one a process and thread.
 """
 
 from __future__ import annotations
 
 import json
+import os
 
+import ray_tpu
 from ray_tpu._private.api_internal import get_core_worker
+from ray_tpu.util import tracing
 
 # Pre-execution ladder segments rendered as their own rows (everything
 # up to and including RUNNING — one shared definition with the state
@@ -82,11 +87,40 @@ def build_trace_events(events: list[dict]) -> list[dict]:
     return trace
 
 
+def span_trace_events(session_dir: str) -> list[dict]:
+    """The session's span files as chrome trace 'X' events (category
+    `span`), on the task events' clock: a file's header pairs a wall-clock
+    reading with the monotonic one its spans are stamped in."""
+    trace = []
+    for header, spans in tracing.read_span_files(session_dir):
+        if not header:
+            continue
+        for s in spans:
+            wall_s = header["time_s"] + (s["t0_ns"] - header["mono_ns"]) / 1e9
+            args = {"id": s["id"], "parent": s["parent"],
+                    **s.get("attrs", {})}
+            if "rid" in s:
+                args["rid"] = s["rid"]
+            trace.append({"name": s["name"], "cat": "span", "ph": "X",
+                          "ts": wall_s * 1e6, "dur": s["dur_ns"] / 1e3,
+                          "pid": f"spans:{header['label']}",
+                          "tid": s["thread"], "args": args})
+    return trace
+
+
 def dump_timeline(path: str = "/tmp/ray_tpu_timeline.json",
-                  limit: int = 100000) -> str:
+                  limit: int = 100000, session_dir: str | None = None) -> str:
+    """`session_dir`: whose span files to merge in; this process's own
+    session where None (a driver that started the cluster, or a worker)."""
     cw = get_core_worker()
     events = cw._run(cw.gcs.call("ListTaskEvents", {"limit": limit}))["events"]
     trace = build_trace_events(events)
+    if session_dir is None:
+        node = ray_tpu._runtime_node
+        session_dir = node.session_dir if node is not None \
+            else os.environ.get("RAY_TPU_SESSION_DIR")
+    if session_dir:
+        trace.extend(span_trace_events(session_dir))
     with open(path, "w") as f:
         json.dump({"traceEvents": trace, "displayTimeUnit": "ms"}, f)
     return path

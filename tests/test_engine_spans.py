@@ -1,0 +1,293 @@
+"""Spans inside `LLMEngine._loop` (`ray_tpu/util/tracing.py`'s recorder): a
+tiny engine serves three requests and what it did is read back from the
+recorder, from the session's span file, from `ray_tpu timeline`'s rows and
+from a `jax.profiler` trace's host plane.
+"""
+
+import glob
+import json
+import os
+import time
+
+import pytest
+
+from ray_tpu.util import timeline, tracing
+
+LOOP_THREAD = "llm-engine"
+# Where each loop-scoped span may sit (PERF.md, section 3).
+PARENT_OF = {"engine.admit": "engine.pass", "engine.prefill": "engine.admit",
+             "engine.prefill.wait": "engine.prefill",
+             "engine.decode.build": "engine.pass",
+             "engine.decode.dispatch": "engine.pass",
+             "engine.decode.wait": "engine.pass",
+             "engine.walk": "engine.pass"}
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    import jax
+    import jax.numpy as jnp
+
+    jax.config.update("jax_platforms", "cpu")
+    from ray_tpu.models.llama import LlamaConfig, LlamaModel
+
+    cfg = LlamaConfig(vocab_size=128, d_model=64, n_layers=2, n_heads=4,
+                      n_kv_heads=2, d_ff=128, max_seq_len=64)
+    params = LlamaModel(cfg).init(jax.random.PRNGKey(0),
+                                  jnp.zeros((1, 8), jnp.int32))
+    return cfg, params
+
+
+def _engine(tiny):
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, params = tiny
+    return LLMEngine(cfg, params, max_batch=2, max_len=64, page_size=16,
+                     decode_chunk=4)
+
+
+@pytest.fixture
+def served(tiny, tmp_path, monkeypatch):
+    """Three requests over two slots, inside a session directory of the
+    test's own: (handles, counters before, counters after, this run's
+    spans, the session directory)."""
+    from ray_tpu.models.generate import SamplingParams
+
+    session = tmp_path / "session-test"
+    monkeypatch.setenv("RAY_TPU_SESSION_DIR", str(session))
+    eng = _engine(tiny)
+    try:
+        seen = {s["id"] for s in tracing.recent_spans()}
+        before = eng.report_metrics()
+        handles = [eng.submit(list(range(1, n)),
+                              SamplingParams(max_new_tokens=10))
+                   for n in (4, 7, 21)]
+        outs = [h.tokens() for h in handles]
+        after = eng.report_metrics()
+    finally:
+        eng.shutdown()
+    assert [len(o) for o in outs] == [10, 10, 10]
+    spans = [s for s in tracing.recent_spans() if s["id"] not in seen]
+    return handles, before, after, spans, session
+
+
+def test_children_lie_inside_their_parents_on_one_thread(served):
+    _, _, _, spans, _ = served
+    by_id = {s["id"]: s for s in spans}
+    loop = [s for s in spans if s["name"].startswith("engine.")]
+    assert {s["name"] for s in loop} >= set(PARENT_OF) | {"engine.pass"}
+    for s in loop:
+        assert s["thread"] == LOOP_THREAD
+        if s["name"] in ("engine.pass", "engine.idle"):
+            assert s["parent"] is None      # idle lies outside any pass
+            continue
+        parent = by_id[s["parent"]]
+        assert parent["name"] == PARENT_OF[s["name"]]
+        assert parent["tid"] == s["tid"]
+        assert parent["t0_ns"] <= s["t0_ns"]
+        assert s["t0_ns"] + s["dur_ns"] <= parent["t0_ns"] + parent["dur_ns"]
+
+
+def test_a_pass_is_its_host_only_time_and_its_waits(served):
+    _, _, _, spans, _ = served
+    children: dict = {}
+    for s in spans:
+        children.setdefault(s["parent"], []).append(s)
+
+    def descendants(s):
+        for c in children.get(s["id"], []):
+            yield c
+            yield from descendants(c)
+
+    passes = [s for s in spans if s["name"] == "engine.pass"]
+    assert passes
+    for p in passes:
+        below = list(descendants(p))
+        waits = sum(d["dur_ns"] for d in below if d["name"].endswith(".wait"))
+        phases = sum(d["dur_ns"] for d in children.get(p["id"], []))
+        # The waits are part of the pass, and the host-only rest is made
+        # of the phases outside them plus what lies between two phases.
+        assert 0 <= waits <= phases <= p["dur_ns"]
+        host_only = p["dur_ns"] - waits
+        assert host_only + waits == p["dur_ns"] and host_only > 0
+        # Every pass admitted or dispatched.
+        assert {d["name"] for d in below} & {"engine.admit",
+                                            "engine.decode.wait"}
+
+
+def test_a_requests_spans_share_its_rid_and_its_admit_names_it(served):
+    handles, _, _, spans, _ = served
+    rids = [h.rid for h in handles]
+    assert len(set(rids)) == 3
+    for h in handles:
+        mine = {s["name"]: s for s in spans if s.get("rid") == h.rid}
+        assert set(mine) == {"request.queue", "request.first_token"}
+        queue, first = mine["request.queue"], mine["request.first_token"]
+        # Both begin at `submit`; the first token follows the admission.
+        assert queue["t0_ns"] == first["t0_ns"] == h._submit_ns
+        assert queue["dur_ns"] <= first["dur_ns"]
+        assert queue["attrs"] == {"prompt_len": h.prompt_len,
+                                  "deferred": False}
+        took = [s for s in spans if s["name"] == "engine.admit"
+                and h.rid in s["attrs"]["rids"]]
+        assert len(took) == 1
+        admit = took[0]
+        assert admit["attrs"]["admitted"] == len(admit["attrs"]["rids"])
+        # The wait ends inside the admission that ended it.
+        ended = queue["t0_ns"] + queue["dur_ns"]
+        assert admit["t0_ns"] <= ended <= admit["t0_ns"] + admit["dur_ns"]
+    prefills = [s for s in spans if s["name"] == "engine.prefill"]
+    assert sum(s["attrs"]["rows"] for s in prefills) == 3
+    assert all(s["attrs"]["bucket"] in (16, 32) for s in prefills)
+
+
+def test_page_counts_on_the_spans_are_the_engines_counters(served):
+    _, before, after, spans, _ = served
+    chunks = [s["attrs"] for s in spans if s["name"] == "engine.decode.wait"]
+    assert chunks
+    for counter, attr in (("paged_pages_live", "pages_live"),
+                          ("paged_pages_table", "pages_table"),
+                          ("state_slot_steps", "steps")):
+        assert sum(c[attr] for c in chunks) == after[counter] - before[counter]
+    walks = [s["attrs"] for s in spans if s["name"] == "engine.walk"]
+    # Each request's first token comes from its prefill, the rest from walks.
+    assert sum(w["emitted"] for w in walks) == 3 * 10 - 3
+    assert sum(w["finished"] for w in walks) == 3
+
+
+def test_the_span_file_appears_in_the_session_and_stays_under_its_cap(
+        served, monkeypatch):
+    _, _, _, spans, session = served
+    deadline = time.monotonic() + 2.0
+    pattern = os.path.join(session, "logs", "spans-*.jsonl")
+    while time.monotonic() < deadline and not glob.glob(pattern):
+        time.sleep(0.05)
+    assert len(glob.glob(pattern)) == 1
+    tracing.flush_spans()
+    (header, written), = tracing.read_span_files(str(session))
+    assert header["pid"] == os.getpid()
+    # The header pairs the two clocks at one instant.
+    assert abs((time.time() - header["time_s"])
+               - (time.monotonic_ns() - header["mono_ns"]) / 1e9) < 0.5
+    by_id = {s["id"]: s for s in written}
+    for s in spans:
+        assert by_id[s["id"]] == json.loads(json.dumps(s))
+    # A flight recorder: two files of a fixed size, the older dropped.
+    cap = 4096
+    monkeypatch.setattr(tracing, "SPAN_FILE_BYTES", cap)
+    for round_ in range(6):
+        for i in range(20):
+            with tracing.span("filler", round=round_, i=i):
+                pass
+        tracing.flush_spans()
+        files = sorted(glob.glob(pattern + "*"))
+        assert len(files) <= 2
+        # (The file that was there before the cap shrank is the older one.)
+        assert os.path.getsize(files[0]) <= cap
+    assert len(files) == 2 and files[1].endswith(".1")
+    newest = tracing.read_span_files(str(session))[-1][1]
+    assert newest[-1]["attrs"] == {"round": 5, "i": 19}
+
+
+def test_timeline_draws_the_span_files_as_rows(served):
+    _, _, _, spans, session = served
+    tracing.flush_spans()
+    rows = timeline.span_trace_events(str(session))
+    assert {r["cat"] for r in rows} == {"span"} and \
+        {r["ph"] for r in rows} == {"X"}
+    mine = [r for r in rows if r["args"].get("id") in
+            {s["id"] for s in spans}]
+    assert len(mine) == len(spans)
+    # One row a process and thread; wall-clock microseconds.
+    assert {(r["pid"], r["tid"]) for r in mine} == \
+        {(f"spans:pid{os.getpid()}", LOOP_THREAD)}
+    wait = next(r for r in mine if r["name"] == "engine.decode.wait")
+    assert abs(wait["ts"] / 1e6 - time.time()) < 600
+    assert wait["args"]["pages_table"] > 0
+
+
+def test_spans_lie_on_the_profilers_host_plane(tiny, tmp_path):
+    import jax
+    from ray_tpu.models.generate import SamplingParams
+
+    eng = _engine(tiny)
+    try:
+        eng.generate([1, 2, 3], SamplingParams(max_new_tokens=2))  # compile
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            eng.generate([1, 2, 3, 4], SamplingParams(max_new_tokens=9))
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        eng.shutdown()
+    path, = glob.glob(os.path.join(tmp_path, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    names = {}
+    for plane in data.planes:
+        if not plane.name.startswith("/host"):
+            continue
+        for line in plane.lines:
+            for event in line.events:
+                if event.name.startswith("engine."):
+                    names.setdefault(event.name, []).append(
+                        dict(event.stats))
+    assert {"engine.pass", "engine.decode.build", "engine.decode.dispatch",
+            "engine.decode.wait", "engine.walk"} <= set(names)
+    # Scalar attrs ride on the annotation: a chunk's own page counts.
+    assert all(int(st["pages_table"]) > 0
+               for st in names["engine.decode.wait"])
+
+
+def test_an_unclosed_child_does_not_become_later_spans_parent():
+    outer = tracing.span("outer").begin()
+    tracing.span("left-open").begin()      # an exception passed its end()
+    outer.end()
+    with tracing.span("next") as nxt:
+        pass
+    assert nxt.parent == 0
+    assert [s["name"] for s in tracing.recent_spans()[-2:]] == ["outer",
+                                                                "next"]
+
+
+def test_timeline_of_a_session_that_served_shows_the_engines_rows(
+        ray_start_regular, tmp_path):
+    """An engine inside a worker of a real session (forked from the
+    zygote, its span file named by its worker id), then `ray_tpu
+    timeline`'s dump from the driver."""
+    import ray_tpu
+
+    @ray_tpu.remote
+    class Replica:
+        def serve_one(self):
+            import jax
+            import jax.numpy as jnp
+
+            from ray_tpu.models.generate import SamplingParams
+            from ray_tpu.models.llama import LlamaConfig, LlamaModel
+            from ray_tpu.serve.llm import LLMEngine
+            from ray_tpu.util import tracing as worker_tracing
+
+            cfg = LlamaConfig(vocab_size=128, d_model=64, n_layers=1,
+                              n_heads=4, n_kv_heads=2, d_ff=128,
+                              max_seq_len=64)
+            params = LlamaModel(cfg).init(jax.random.PRNGKey(0),
+                                          jnp.zeros((1, 8), jnp.int32))
+            eng = LLMEngine(cfg, params, max_batch=2, max_len=64,
+                            page_size=16, decode_chunk=4)
+            try:
+                out = eng.generate([1, 2, 3], SamplingParams(max_new_tokens=6))
+            finally:
+                eng.shutdown()
+            worker_tracing.flush_spans()
+            return len(out), os.environ["RAY_TPU_WORKER_ID"][:12]
+
+    n, worker = ray_tpu.get(Replica.remote().serve_one.remote(), timeout=170)
+    assert n == 6
+    path = timeline.dump_timeline(str(tmp_path / "timeline.json"))
+    with open(path) as f:
+        events = json.load(f)["traceEvents"]
+    rows = [e for e in events if e["cat"] == "span"]
+    assert {"engine.pass", "engine.decode.wait", "request.queue"} <= \
+        {e["name"] for e in rows if e["pid"] == f"spans:{worker}"}
+    assert any(e["cat"] == "task" for e in events)  # beside the task rows
