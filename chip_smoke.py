@@ -278,7 +278,8 @@ class SmokeLLM(LLMServer):
             jax.tree_util.tree_map(shape, eng._pools),
             jax.ShapeDtypeStruct(eng._tables.shape, jnp.int32),
             i32, f32, i32, f32,
-            jax.ShapeDtypeStruct((2,), jnp.uint32))
+            jax.ShapeDtypeStruct((2,), jnp.uint32),
+            jax.ShapeDtypeStruct((), jnp.int32))
         return {"decode_chunk_paged": _kernel_calls(lowered.as_text()),
                 "decode_chunk_paged_state_moves": state_moves(
                     lowered.compile().as_text(), eng._pools)}

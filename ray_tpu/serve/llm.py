@@ -50,6 +50,17 @@ from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
 
+# The decode chunk's per-slot arguments, each beside the engine's numpy
+# mirror of it. The mirrors are the truth; the device keeps a copy that
+# the chunk program advances itself, and the host sends a mirror again
+# only after it wrote to it in a way the program did not (`_dirty`).
+_MIRRORS = {"token": "_token", "pos": "_pos", "lens": "_lens",
+            "tables": "_tables", "temps": "_temps", "top_ks": "_topks",
+            "top_ps": "_topps", "chunk_no": "_chunk_no", "steps": "_steps"}
+_CURSOR = ("token", "pos", "lens")
+# What a slot changing hands (an admission, a finish) leaves stale.
+_SLOT = _CURSOR + ("tables",)
+
 
 @dataclass
 class _Slot:
@@ -203,6 +214,11 @@ class LLMEngine:
         # family's `state_bytes_per_slot`, what the fixed state cost a
         # window (each live slot's state is read and written once a step).
         self.state_slot_steps = 0
+        # Decode chunks dispatched, and those of them for which the host
+        # sent none of the chunk's resident arguments again (a pass that
+        # follows no admission, finish or park).
+        self.decode_passes = 0
+        self.decode_passes_clean = 0
         # Tokens a KV page holds: admission is bounded by POOL pages
         # (resident tokens), not slot count x max_len.
         if page_size <= 0:
@@ -224,6 +240,9 @@ class LLMEngine:
         self.model = family.model
         self._jax, self._jnp = jax, jnp
         self._rng = jax.random.PRNGKey(rng_seed)
+        # Decode draws from `fold_in(fold_in(base, chunk), step)`: the
+        # base stays on the device, the chunk's number rides in the carry.
+        self._rng_base = self._rng
 
         # ---- compiled programs ------------------------------------------
         # The family's functions under the engine's own program names (a
@@ -281,27 +300,36 @@ class LLMEngine:
         self._init_paged_state()  # allocator, `_pools`, `_tables`
 
         def decode_chunk_paged(params, token, pos, pools, tables, lens,
-                               temps, top_ks, top_ps, base_rng, steps=None):
+                               temps, top_ks, top_ps, rng_base, chunk_no,
+                               steps=None):
             # K decode steps in one program (lax.scan): sampling happens
             # in-device, so only the (K, B) token block crosses to host.
+            # The cursor (`token`, `pos`, `lens`) and the chunk's number
+            # come back as the next chunk's own: the engine hands them in
+            # again as they are unless the host moved a cursor meanwhile.
             # `steps` (B,), where the family's state cannot be rewound:
             # a slot advances that many steps of the chunk and is held
-            # still after them (a parked or an empty slot: 0).
+            # still after them (a parked or an empty slot: 0). Without
+            # it a slot that holds no stream (`lens` 0: a live one has
+            # its prompt) is held still, or its length would creep up a
+            # chunk at a time and the kernel's work with it.
+            chunk_rng = jax.random.fold_in(rng_base, chunk_no)
+            occupied = lens > 0
+
             def body(carry, i):
                 token, pos, pools, lens = carry
                 live = None if steps is None else i < steps
                 logits, pools2 = family.decode(params, token, pos, pools,
                                                tables, lens, live)
                 tok = _sample(logits, temps, top_ks, top_ps,
-                              jax.random.fold_in(base_rng, i))
-                if live is None:
-                    return (tok, pos + 1, pools2, lens + 1), tok
-                return (jnp.where(live, tok, token), pos + live, pools2,
-                        lens + live), tok
+                              jax.random.fold_in(chunk_rng, i))
+                moves = occupied if live is None else live
+                return (jnp.where(moves, tok, token), pos + moves, pools2,
+                        lens + moves), tok
 
             (token, pos, pools, lens), toks = jax.lax.scan(
                 body, (token, pos, pools, lens), jnp.arange(K))
-            return toks, pools  # toks: (K, B)
+            return toks, pools, token, pos, lens, chunk_no + 1  # toks: (K, B)
 
         # Donating the state makes each chunk update it in place.
         self._decode_chunk_paged = jax.jit(decode_chunk_paged,
@@ -341,6 +369,7 @@ class LLMEngine:
         self._temps = np.zeros(max_batch, np.float32)
         self._topks = np.zeros(max_batch, np.int32)
         self._topps = np.ones(max_batch, np.float32)
+        self._chunk_no = np.zeros((), np.int32)
         self._slots = [_Slot() for _ in range(max_batch)]
         self._pending: queue.Queue = queue.Queue()
         self._stop = threading.Event()
@@ -453,6 +482,8 @@ class LLMEngine:
             "state_slots_reset": float(self.state_slots_reset),
             "state_bytes_per_slot": float(self.family.state_bytes_per_slot),
             "state_slot_steps": float(self.state_slot_steps),
+            "decode_passes": float(self.decode_passes),
+            "decode_passes_clean": float(self.decode_passes_clean),
             "ttft_p50_ms": pick(0.5) * 1e3,
             "ttft_p99_ms": pick(0.99) * 1e3,
         }
@@ -531,12 +562,6 @@ class LLMEngine:
 
     # ---- engine loop -----------------------------------------------------
 
-    def _topks_arr(self):
-        return self._jnp.asarray(self._topks)
-
-    def _topps_arr(self):
-        return self._jnp.asarray(self._topps)
-
     def _bucket(self, n: int) -> int:
         b = 16
         while b < n:
@@ -563,18 +588,32 @@ class LLMEngine:
         """Prefill->decode handoff: commit an already-sampled first token
         + per-slot decode state (admission samples a whole group in one
         dispatch)."""
-        sp = handle.sampling
-        self._lens[slot] = prompt_len
-        self._pos[slot] = prompt_len
-        self._token[slot] = tok
-        self._temps[slot] = sp.temperature
-        self._topks[slot] = sp.top_k
-        self._topps[slot] = sp.top_p
+        self._commit_cursor(slot, handle, tok, prompt_len)
         st = self._slots[slot]
         st.request = handle
         st.generated = 0
         st.history = []
         self._first_token(slot, handle, tok)
+
+    def _commit_cursor(self, slot: int, handle: RequestHandle, tok: int,
+                       lens: int):
+        """The slot's decode cursor and sampling into the host mirrors
+        (its table row is already written). The device's copies of the
+        cursor and the tables are stale now; of a sampling array only
+        where the slot's value changed, so an all-greedy run never sends
+        those again."""
+        sp = handle.sampling
+        self._lens[slot] = lens
+        self._pos[slot] = lens
+        self._token[slot] = tok
+        self._dirty.update(_SLOT)
+        for name, value in (("temps", sp.temperature), ("top_ks", sp.top_k),
+                            ("top_ps", sp.top_p)):
+            mirror = getattr(self, _MIRRORS[name])
+            was = mirror[slot]
+            mirror[slot] = value
+            if mirror[slot] != was:
+                self._dirty.add(name)
 
     def _first_token(self, slot: int, handle: RequestHandle, tok: int):
         self._ttft.append(time.monotonic() - handle._submit_ts)
@@ -588,13 +627,7 @@ class LLMEngine:
         fresh handoff (emit_first=True) behaves like _commit_token with
         the prefill pool's sampled first token; a resume carries the
         full history/cursor and emits nothing until decode advances."""
-        sp = handle.sampling
-        self._lens[slot] = pack.lens
-        self._pos[slot] = pack.lens
-        self._token[slot] = pack.token
-        self._temps[slot] = sp.temperature
-        self._topks[slot] = sp.top_k
-        self._topps[slot] = sp.top_p
+        self._commit_cursor(slot, handle, pack.token, pack.lens)
         st = self._slots[slot]
         st.request = handle
         st.generated = pack.generated
@@ -759,6 +792,13 @@ class LLMEngine:
             self.max_batch, self._num_pages, self.page_size)
         self._tables = np.full((self.max_batch, self._np_pages),
                                self._dummy_page, np.int32)
+        # Nothing of the decode chunk's arguments is on the device (yet,
+        # or any more: a failed chunk may have taken its carry with it).
+        self._dev: dict = {}
+        self._dirty = set(_MIRRORS) - {"steps"}
+        # What the device holds of each slot's steps (a family that cannot
+        # rewind; `_loop` sends them when they change): nothing.
+        self._steps = None
 
     def _free_slot_pages(self, slot: int):
         st = self._slots[slot]
@@ -769,6 +809,8 @@ class LLMEngine:
             # one dummy page its garbage write lands in, not the pages
             # of the stream that left.
             self._lens[slot] = 0
+            # (The device's cursor of this slot ran on to the chunk's end.)
+            self._dirty.update(_SLOT)
             st.seq_id = ""
 
     def _count_paged_pages(self):
@@ -786,16 +828,19 @@ class LLMEngine:
 
     def _steps_to_take(self) -> np.ndarray:
         """Steps of the next chunk each slot may advance: as many as its
-        consumer's queue has room for and it is still owed, none for a
-        slot that is empty. (A full queue parks the slot, as a refused
-        offer does where steps can be re-run.)"""
+        consumer's queue has room for, none for a slot that is empty. (A
+        full queue parks the slot, as a refused offer does where steps can
+        be re-run.) A stream owed fewer runs past its end, as one that
+        meets its eos does: into pages reserved for that and state its
+        slot's next prefill replaces. So a slot's steps change with who
+        holds it and with its consumer's pace, not chunk by chunk."""
         steps = np.zeros(self.max_batch, np.int32)
         for i, st in enumerate(self._slots):
             h = st.request
             if h is None:
                 continue
             owed = h.sampling.max_new_tokens - st.generated
-            steps[i] = max(0, min(self.decode_chunk, owed, h.room()))
+            steps[i] = min(self.decode_chunk, h.room())
             if steps[i] < min(self.decode_chunk, owed):
                 self._parked_events += 1
         return steps
@@ -823,7 +868,7 @@ class LLMEngine:
         return True
 
     def _loop(self):
-        jax, jnp = self._jax, self._jnp
+        jnp = self._jnp
         # What the host does here leaves spans (`util/tracing.py`; PERF.md
         # section 3 has the names): `engine.pass` for an iteration that
         # admits or dispatches, its phases inside it, `engine.idle` between.
@@ -928,26 +973,47 @@ class LLMEngine:
             # garbage on their stale state — discarded host-side; slots
             # finishing mid-chunk have their overshoot discarded too).
             # State that cannot be rewound: every slot's steps are sized
-            # now, by what its consumer's queue takes and what it is still
-            # owed, and the program holds it still past them.
+            # now, by what its consumer's queue takes, and the program
+            # holds it still past them.
             build = tracing.span("engine.decode.build").begin()
             steps = None if self.family.rewinds else self._steps_to_take()
             slot_steps = len(decoding) * self.decode_chunk \
                 if steps is None else int(steps.sum())
             self.state_slot_steps += slot_steps
+            if steps is not None and not np.array_equal(steps, self._steps):
+                self._steps = steps
+                self._dirty.add("steps")
             try:
-                self._rng, srng = jax.random.split(self._rng)
                 pages_live, pages_table = self._count_paged_pages()
-                args = [self.params, jnp.asarray(self._token),
-                        jnp.asarray(self._pos), self._pools,
-                        jnp.asarray(self._tables), jnp.asarray(self._lens),
-                        jnp.asarray(self._temps), self._topks_arr(),
-                        self._topps_arr(), srng]
-                if steps is not None:
-                    args.append(jnp.asarray(steps))
-                build.end()
+                dev = self._dev
+                uploaded = len(self._dirty)
+                self.decode_passes += 1
+                self.decode_passes_clean += not uploaded
+                if uploaded:
+                    # (`jnp.array`: a copy, where the CPU backend would
+                    # otherwise keep reading the mirror the walk writes.)
+                    with tracing.span("engine.decode.upload"):
+                        for name in self._dirty:
+                            dev[name] = jnp.array(
+                                getattr(self, _MIRRORS[name]))
+                        self._dirty.clear()
+                args = (self.params, dev["token"], dev["pos"], self._pools,
+                        dev["tables"], dev["lens"], dev["temps"],
+                        dev["top_ks"], dev["top_ps"], self._rng_base,
+                        dev["chunk_no"],
+                        *(() if steps is None else (dev["steps"],)))
+                build.end(uploaded=uploaded)
                 with tracing.span("engine.decode.dispatch"):
-                    toks, self._pools = self._decode_chunk_paged(*args)
+                    (toks, self._pools, dev["token"], dev["pos"],
+                     dev["lens"], dev["chunk_no"]) = \
+                        self._decode_chunk_paged(*args)
+                    # The inputs the carry has replaced die here, with the
+                    # device at work: an array's destructor gives the
+                    # interpreter lock away, and between the walk (which
+                    # woke every consumer) and the dispatch that puts the
+                    # loop behind all of them (5 ms a pass at 32 streams).
+                    del args
+                    self._chunk_no += 1
                 with tracing.span("engine.decode.wait",
                                   active=len(decoding), steps=slot_steps,
                                   pages_live=pages_live,
@@ -976,7 +1042,9 @@ class LLMEngine:
                         # chunk — safe because decode writes KV at index
                         # lens before attending and masks kpos<=qpos, so
                         # the uncommitted steps' writes are garbage that
-                        # is simply rewritten.
+                        # is simply rewritten. The device's cursor ran on:
+                        # the host's, now behind it, is sent again.
+                        self._dirty.update(_CURSOR)
                         break
                     emitted += 1
                     if st.request is None:  # eos/max_new hit mid-chunk:

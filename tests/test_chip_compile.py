@@ -106,7 +106,8 @@ def _compiled_decode_chunk(eng, params, one_chip):
         _on(one_chip, params), S((B,), jnp.int32), S((B,), jnp.int32),
         _on(one_chip, eng._pools), S(eng._tables.shape, jnp.int32),
         S((B,), jnp.int32), S((B,), jnp.float32), S((B,), jnp.int32),
-        S((B,), jnp.float32), S((2,), jnp.uint32), *steps).compile()
+        S((B,), jnp.float32), S((2,), jnp.uint32), S((), jnp.int32),
+        *steps).compile()
 
 
 def _qkv(one_chip, seq, hkv=32):

@@ -18,6 +18,7 @@ LOOP_THREAD = "llm-engine"
 PARENT_OF = {"engine.admit": "engine.pass", "engine.prefill": "engine.admit",
              "engine.prefill.wait": "engine.prefill",
              "engine.decode.build": "engine.pass",
+             "engine.decode.upload": "engine.decode.build",
              "engine.decode.dispatch": "engine.pass",
              "engine.decode.wait": "engine.pass",
              "engine.walk": "engine.pass"}
@@ -153,6 +154,24 @@ def test_page_counts_on_the_spans_are_the_engines_counters(served):
     # Each request's first token comes from its prefill, the rest from walks.
     assert sum(w["emitted"] for w in walks) == 3 * 10 - 3
     assert sum(w["finished"] for w in walks) == 3
+
+
+def test_a_build_says_what_it_uploaded_and_holds_the_transfers(served):
+    _, before, after, spans, _ = served
+    builds = [s for s in spans if s["name"] == "engine.decode.build"]
+    uploads = {s["parent"] for s in spans
+               if s["name"] == "engine.decode.upload"}
+    assert len(builds) == after["decode_passes"] - before["decode_passes"]
+    # A dense model: a pass that follows no admission, finish or park
+    # sends nothing, so it has no `upload` child; the others have one.
+    assert {b["id"] for b in builds if b["attrs"]["uploaded"]} == uploads
+    clean = [b for b in builds if not b["attrs"]["uploaded"]]
+    assert len(clean) == (after["decode_passes_clean"]
+                          - before["decode_passes_clean"])
+    # Ten tokens a request in chunks of four: each stream has a chunk in
+    # its middle that nothing disturbs; the first pass sends everything.
+    assert clean and len(clean) < len(builds)
+    assert builds[0]["attrs"]["uploaded"] == 8
 
 
 def test_the_span_file_appears_in_the_session_and_stays_under_its_cap(

@@ -163,12 +163,19 @@ def test_a_parked_and_an_empty_slot_keep_their_state(tiny):
         before = jax.tree_util.tree_map(np.array, eng._pools)   # copies
         steps = np.zeros(B, np.int32)
         steps[0] = K                    # slot 0 decodes, 1 is parked,
-        toks, after = eng._decode_chunk_paged(      # 2 and 3 are empty
+        toks, after, token, pos, lens, chunk_no = eng._decode_chunk_paged(
             eng.params, jnp.asarray(eng._token), jnp.asarray(eng._pos),
             eng._pools, jnp.asarray(eng._tables), jnp.asarray(eng._lens),
-            jnp.asarray(eng._temps), eng._topks_arr(), eng._topps_arr(),
-            jax.random.PRNGKey(0), jnp.asarray(steps))
+            jnp.asarray(eng._temps), jnp.asarray(eng._topks),
+            jnp.asarray(eng._topps), jax.random.PRNGKey(0), jnp.int32(5),
+            jnp.asarray(steps))          # 2 and 3 are empty
         eng._pools = after              # (the old buffers were donated)
+        # The carry the program hands back: slot 0's cursor after K steps
+        # and its last token, the others' as they were; the chunk's number.
+        np.testing.assert_array_equal(lens, eng._lens + steps)
+        np.testing.assert_array_equal(pos, eng._pos + steps)
+        np.testing.assert_array_equal(token[1:], eng._token[1:])
+        assert int(token[0]) == int(toks[K - 1, 0]) and int(chunk_no) == 6
         after = jax.tree_util.tree_map(np.asarray, after)
         fixed = lambda s: jax.tree_util.tree_leaves(s["ssm"])  # noqa: E731
         # (layer 0's conv window holds its last three INPUTS, a function
