@@ -1,0 +1,55 @@
+"""model step: one whole decode step of the `lfm2_moe` family against its
+roofline.  Least time of a step, max(ops / peak FLOP/s, bytes / peak
+bytes/s) by `lfm2_moe_costs.decode_step_cost`: every weight that is no
+expert's once, each expert that the step's live rows TOUCHED once (the
+program's own count, `experts_touched` on the `engine.decode.wait` spans of
+the traced slot, a step's mean), each resident token's K and V and each
+live slot's conv windows once, at the streams that are decoding and their
+resident tokens as the replica sampled them over the slot; over the median
+device time of the decode program (`decode_chunk_paged`) divided by the
+steps of a chunk.  The count of work is the routing's, whatever implements
+the layer: a program that streams every expert reads low, none can read
+over 100.  None for another family, and on a program that counts nothing."""
+
+from benchmarks.harness import kernel_costs, stats
+from benchmarks.harness.loader import sibling_reader
+
+LAYER = "model step"
+UNIT = "%"
+MOVES = "batch_tokens_per_s"
+PROGRAM = "decode_chunk_paged"
+
+costs = sibling_reader(__file__, "lfm2_moe_costs")
+program_spans = sibling_reader(__file__, "program_spans")
+
+
+def touched_per_step(obs, chunk: int):
+    """Experts touched in a decode step of the traced slot, summed over
+    the routed layers: the chunks' counts over their steps."""
+    spans = program_spans.session(program_spans.traced_slot(obs))
+    chunks = [r.get("attrs", {}) for r in spans.named("engine.decode.wait")] \
+        if spans else []
+    chunks = [a for a in chunks if "experts_touched" in a]
+    if not chunks:
+        return None
+    return sum(a["experts_touched"] for a in chunks) / (len(chunks) * chunk)
+
+
+def read(obs):
+    trace, peak = obs.get("trace"), obs.get("peaks")
+    if not trace or not peak or "window_mono_s" not in trace \
+            or obs.get("family") != "lfm2_moe":
+        return None
+    runs = trace["program_ns"].get(PROGRAM, [])
+    t0, t1 = trace["window_mono_s"]
+    inside = [s for s in obs.get("samples", []) if t0 <= s[0] <= t1]
+    chunk = obs["config"]["serve"]["engine"]["decode_chunk"]
+    touched = touched_per_step(obs, chunk)
+    if not runs or not inside or touched is None:
+        return None
+    live = sum(s[3] for s in inside) / len(inside)
+    resident = sum(s[4] for s in inside) / len(inside)
+    flops, nbytes = costs.decode_step_cost(obs["sizes"], live, resident,
+                                           touched)
+    least, _bound = kernel_costs.roofline_seconds(flops, nbytes, peak)
+    return 100.0 * least / (stats.median(runs) / 1e9 / chunk)

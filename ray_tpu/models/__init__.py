@@ -3,10 +3,18 @@
 The reference ships no model implementations (its release gates pull
 GPT-J/vicuna through external torch engines); here the flagship decoder,
 an expert-parallel MoE, and the generation path are part of the framework.
-`serve.LLMEngine` serves three of them (`serve/llm_families.py`):
-`LlamaConfig`, `SambaYConfig` and `GraniteHybridConfig`.
+`serve.LLMEngine` serves four of them (`serve/llm_families.py`):
+`LlamaConfig`, `SambaYConfig`, `GraniteHybridConfig` and `Lfm2MoeConfig`
+(routed experts with no token dropped and a cached decode path; `moe.py`'s
+capacity-bounded layer trains at toy sizes and is not served).
 """
 
+from ray_tpu.models.lfm2_moe import (
+    LFM2_24B_A2B,
+    TINY_LFM2_MOE,
+    Lfm2MoeConfig,
+    Lfm2MoeModel,
+)
 from ray_tpu.models.llama import (
     LLAMA2_7B,
     LLAMA2_13B,
@@ -96,4 +104,5 @@ __all__ = [
     "SambaYModel", "SambaYConfig", "PHI4_MINI_FLASH", "TINY_SAMBAY",
     "GraniteHybridModel", "GraniteHybridConfig", "GRANITE_4_H_MICRO",
     "TINY_GRANITE",
+    "Lfm2MoeModel", "Lfm2MoeConfig", "LFM2_24B_A2B", "TINY_LFM2_MOE",
 ]

@@ -238,6 +238,15 @@ class LLMEngine:
         # What the model tells the engine (serve/llm_families.py).
         self.family = family = family_of(cfg, max_len)
         self.model = family.model
+        # Small integers a family's device programs count beside their
+        # logits (`llm_families.py`: `step_counters`, `prefill_counters`):
+        # fetched with the tokens, put on the spans, kept here in all.
+        self._step_counters = tuple(getattr(family, "step_counters", ()))
+        self._prefill_counters = tuple(
+            getattr(family, "prefill_counters", ()))
+        self.family_counters = {
+            name: 0 for name, _ in
+            self._step_counters + self._prefill_counters}
         self._jax, self._jnp = jax, jnp
         self._rng = jax.random.PRNGKey(rng_seed)
         # Decode draws from `fold_in(fold_in(base, chunk), step)`: the
@@ -289,6 +298,9 @@ class LLMEngine:
                                 sampled)
 
         self._sample = jax.jit(_sample)
+        # A prefill's counts leave the device WITH its first tokens.
+        self._sample_counted = jax.jit(
+            lambda counts, *args: jnp.concatenate([_sample(*args), counts]))
         self._prefill_one = prefill_one
 
         K = self.decode_chunk
@@ -319,17 +331,21 @@ class LLMEngine:
             def body(carry, i):
                 token, pos, pools, lens = carry
                 live = None if steps is None else i < steps
-                logits, pools2 = family.decode(params, token, pos, pools,
-                                               tables, lens, live)
+                logits, pools2, *counts = family.decode(
+                    params, token, pos, pools, tables, lens, live)
                 tok = _sample(logits, temps, top_ks, top_ps,
                               jax.random.fold_in(chunk_rng, i))
                 moves = occupied if live is None else live
+                # (a family that counts: its step's counts as further
+                # columns of the step's row of tokens, one fetch for both)
                 return (jnp.where(moves, tok, token), pos + moves, pools2,
-                        lens + moves), tok
+                        lens + moves), \
+                    jnp.concatenate([tok, *counts]) if counts else tok
 
             (token, pos, pools, lens), toks = jax.lax.scan(
                 body, (token, pos, pools, lens), jnp.arange(K))
-            return toks, pools, token, pos, lens, chunk_no + 1  # toks: (K, B)
+            # toks: (K, B), or (K, B + counters)
+            return toks, pools, token, pos, lens, chunk_no + 1
 
         # Donating the state makes each chunk update it in place.
         self._decode_chunk_paged = jax.jit(decode_chunk_paged,
@@ -484,6 +500,8 @@ class LLMEngine:
             "state_slot_steps": float(self.state_slot_steps),
             "decode_passes": float(self.decode_passes),
             "decode_passes_clean": float(self.decode_passes_clean),
+            # what the family's programs counted (e.g. experts touched)
+            **{k: float(v) for k, v in self.family_counters.items()},
             "ttft_p50_ms": pick(0.5) * 1e3,
             "ttft_p99_ms": pick(0.99) * 1e3,
         }
@@ -711,14 +729,27 @@ class LLMEngine:
                 group = group[len(chunk):]
                 # A request alone keeps the single-sequence program.
                 W = 1 if len(chunk) == 1 else width
-                with tracing.span("engine.prefill", bucket=bucket,
-                                  rows=len(chunk), width=W):
-                    self._prefill_group(chunk, bucket, W)
+                span = tracing.span("engine.prefill", bucket=bucket,
+                                    rows=len(chunk), width=W).begin()
+                span.end(**self._prefill_group(chunk, bucket, W))
 
-    def _prefill_group(self, chunk: list, bucket: int, W: int) -> None:
+    def _count(self, counters: tuple, values: np.ndarray) -> dict:
+        """What a family's program counted, by name: `values` (..., n)
+        reduced over its leading axes as each counter says ("sum" or
+        "max"), and taken into `family_counters`."""
+        out = {}
+        for j, (name, how) in enumerate(counters):
+            out[name] = got = int(getattr(np, how)(values[..., j]))
+            kept = self.family_counters[name]
+            self.family_counters[name] = max(kept, got) if how == "max" \
+                else kept + got
+        return out
+
+    def _prefill_group(self, chunk: list, bucket: int, W: int) -> dict:
         """One prefill dispatch for `chunk` (at most W requests of one
         bucket, pages reserved): the rows' state into pages and slots,
-        one sampling dispatch, then the host-side commit."""
+        one sampling dispatch, then the host-side commit.  Returns what
+        the family's program counted (`prefill_counters`), by name."""
         jnp = self._jnp
         npages_row = self.family.prompt_pages(bucket, self.page_size)
         tokens = np.zeros((W, bucket), np.int32)
@@ -746,7 +777,7 @@ class LLMEngine:
             topps[r] = handle.sampling.top_p
         prefill = self._prefill_one if W == 1 else self._prefill_many
         try:
-            last_logits, fresh = prefill(
+            last_logits, fresh, *counts = prefill(
                 self.params, jnp.asarray(tokens), jnp.asarray(last_idx))
             fresh = self._device_handoff(fresh)
             self._pools = self._write_prompt_pages(
@@ -759,21 +790,25 @@ class LLMEngine:
             # argmax ignores the rng mapping.
             self._rng, srng = self._jax.random.split(self._rng)
             with tracing.span("engine.prefill.wait"):
-                toks = np.asarray(self._sample(
-                    last_logits, temps, topks, topps, srng))
+                toks = np.asarray(
+                    self._sample_counted(*counts, last_logits, temps, topks,
+                                         topps, srng) if counts else
+                    self._sample(last_logits, temps, topks, topps, srng))
         except BaseException as e:
             # Device-level failure sinks the whole dispatch: fail
             # every member and return their pages.
             for slot, seq_id, prompt, handle in chunk:
                 self._free_slot_pages(slot)
                 handle._finish(e)
-            return
+            return {}
         # Host-only from here: no device call can strand waiters.
+        counted = self._count(self._prefill_counters, toks[W:])
         if not self.family.rewinds:
             self.state_slots_reset += len(chunk)
         for r, (slot, seq_id, prompt, handle) in enumerate(chunk):
             self._tables[slot] = rows[r]
             self._commit_token(slot, handle, int(toks[r]), len(prompt))
+        return counted
 
     def _init_paged_state(self):
         """(Re)build the page pool: allocator + dummy page + the family's
@@ -1014,11 +1049,14 @@ class LLMEngine:
                     # loop behind all of them (5 ms a pass at 32 streams).
                     del args
                     self._chunk_no += 1
-                with tracing.span("engine.decode.wait",
-                                  active=len(decoding), steps=slot_steps,
-                                  pages_live=pages_live,
-                                  pages_table=pages_table):
-                    toks = np.asarray(toks)  # (K, B)
+                wait = tracing.span("engine.decode.wait",
+                                    active=len(decoding), steps=slot_steps,
+                                    pages_live=pages_live,
+                                    pages_table=pages_table).begin()
+                toks = np.asarray(toks)  # (K, B), the step's counts after
+                B = self.max_batch
+                wait.end(**self._count(self._step_counters, toks[:, B:]))
+                toks = toks[:, :B]
             except Exception as e:
                 # A decode failure (device OOM, donated-buffer misuse, ...)
                 # must not strand waiters on a dead thread: fail loudly and

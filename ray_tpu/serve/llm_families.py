@@ -48,6 +48,15 @@ A family object answers, for its configuration:
     decode(params, token, pos, state, tables, lens, live)
                                 -> float32 logits (B, V), state; one token
                                 a slot.  `live` (B,) bool or None.
+    step_counters, prefill_counters     (optional) what `decode` and
+                                `prefill` count on the device: ((name,
+                                "sum" | "max"), ...).  Where a family names
+                                some, the function returns one more value,
+                                an int32 array with one count each; the
+                                engine fetches it with the tokens, reduces
+                                a chunk's steps as the pair says, and puts
+                                the result on `engine.decode.wait` or
+                                `engine.prefill` and into `report_metrics()`.
 
 Freeing a slot is the engine's: its pages go back to the allocator, its
 table row to the dummy page, its length to 0.  Fixed state needs nothing
@@ -64,6 +73,7 @@ import numpy as np
 
 from ray_tpu.models.granite_hybrid import (GraniteHybridConfig,
                                            GraniteHybridModel)
+from ray_tpu.models.lfm2_moe import Lfm2MoeConfig, Lfm2MoeModel
 from ray_tpu.models.llama import (FRESH_KV, LlamaConfig, LlamaModel,
                                   PagedKVCache)
 from ray_tpu.models.sambay import SambaYConfig, SambaYModel
@@ -160,9 +170,9 @@ class LlamaServing:
 _PREFILL_TOKENS = 16384
 
 
-def _rows_under_the_token_cap(bucket: int, max_batch: int) -> int:
-    return max(1, min(BATCH_PREFILL_WIDTH, max_batch,
-                      _PREFILL_TOKENS // bucket))
+def _rows_under_the_token_cap(bucket: int, max_batch: int,
+                              tokens: int = _PREFILL_TOKENS) -> int:
+    return max(1, min(BATCH_PREFILL_WIDTH, max_batch, tokens // bucket))
 
 
 class SambaYServing:
@@ -295,8 +305,81 @@ class GraniteHybridServing:
                                 live, method=GraniteHybridModel.decode)
 
 
+class Lfm2MoeServing:
+    """`models/lfm2_moe.py`: a (k, v) pool for each attention layer, all
+    under one table row a sequence (as `GraniteHybridServing`), and the
+    short conv's window for each conv layer, float32 and fixed per slot
+    (16 KB a layer at the published sizes: slots are nearly free, and what a decode step
+    costs is the experts its live rows touch, which the programs count)."""
+
+    rewinds = False
+    portable_kv = False
+    pool_readers = 1
+    # `models/lfm2_moe.EXPERT_COUNTS`: the first three of a decode step
+    # (over its live rows), the last two of a prefill (over real tokens)
+    step_counters = (("experts_touched", "sum"), ("expert_slots", "sum"),
+                     ("expert_rows_max", "max"))
+    prefill_counters = (("expert_rows_max", "max"), ("expert_rows", "sum"))
+
+    def __init__(self, cfg: Lfm2MoeConfig, max_len: int):
+        self.cfg, self.max_len = cfg, max_len
+        self.model = Lfm2MoeModel(cfg)
+        self.state_bytes_per_slot = _fixed_bytes_per_slot(
+            self, lambda s: s["conv"])
+
+    def ring_tokens(self, lens) -> int:
+        return 0
+
+    def prefill_width(self, bucket: int, max_batch: int) -> int:
+        # Half the other hybrids' tokens a dispatch: a token is four
+        # (row, expert) pairs of two terms each in the grouped products,
+        # 2.4 GB of temporaries at 8,192 tokens and 4.8 at 16,384, beside
+        # 10.4 GB of weights (compiled for a described v5e, PR 42).
+        return _rows_under_the_token_cap(bucket, max_batch,
+                                         _PREFILL_TOKENS // 2)
+
+    def prompt_pages(self, bucket: int, page_size: int) -> int:
+        return bucket // page_size
+
+    def init_state(self, max_batch: int, num_pages: int, page_size: int):
+        c = self.cfg
+        # (every leaf a buffer of its own: the state is donated)
+        pool = lambda: jnp.zeros(  # noqa: E731
+            (num_pages, c.n_kv_heads // 2, page_size, 2 * c.head_dim),
+            c.dtype)
+        return {
+            "pools": [(pool(), pool())
+                      for _ in c.layers_of("full_attention")],
+            "conv": [jnp.zeros((max_batch, c.conv_L - 1, c.d_model),
+                               jnp.float32)
+                     for _ in c.layers_of("conv")]}
+
+    def prefill(self, params, tokens, last_idx):
+        logits, fresh, counts = self.model.apply(
+            params, tokens, last_idx, method=Lfm2MoeModel.prefill)
+        return logits, fresh, counts[2:]
+
+    def write_prompt(self, state, fresh, slots, page_ids):
+        flat = page_ids.reshape(-1)
+        ps = state["pools"][0][0].shape[2]
+        return {
+            "pools": [(kp.at[flat].set(_pages(k, ps)),
+                       vp.at[flat].set(_pages(v, ps)))
+                      for (kp, vp), (k, v) in zip(state["pools"],
+                                                  fresh["kv"])],
+            "conv": [old.at[slots].set(new, mode="drop")
+                     for old, new in zip(state["conv"], fresh["conv"])]}
+
+    def decode(self, params, token, pos, state, tables, lens, live):
+        logits, state, counts = self.model.apply(
+            params, token, pos, state, tables, lens, live,
+            method=Lfm2MoeModel.decode)
+        return logits, state, counts[:3]
+
+
 _FAMILIES = {LlamaConfig: LlamaServing, SambaYConfig: SambaYServing,
-             GraniteHybridConfig: GraniteHybridServing}
+             GraniteHybridConfig: GraniteHybridServing,
+             Lfm2MoeConfig: Lfm2MoeServing}
 
 
 def family_of(cfg, max_len: int):

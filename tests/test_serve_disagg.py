@@ -217,6 +217,15 @@ def test_disagg_two_pools_collective_route(tiny_model, collective_env,
         for t in threads:
             t.join(timeout=180)
         assert results == expected
+        # Which prefill replica the four concurrent prompts reach, and in
+        # what order, is the router's and the scheduler's: the repeats can
+        # each meet a replica that has not seen their prefix yet (one run
+        # of the whole suite did).  Three more of one prompt, one after
+        # the other, over two replicas: one of them is a hit whatever the
+        # routing.
+        for _ in range(3):
+            assert h.generate({"prompt_tokens": prompts[0],
+                               "max_new_tokens": 10}) == expected[0]
         pm = h.pool_metrics()
         hits = sum(m.get("prefix_cache_hits", 0) for m in pm["prefill"])
         assert hits > 0, pm["prefill"]
@@ -226,7 +235,7 @@ def test_disagg_two_pools_collective_route(tiny_model, collective_env,
                    for m in pm["decode"])
         assert collective > 0, pm["decode"]
         assert host == 0, pm["decode"]
-        assert h.stats["completed"] == len(prompts)
+        assert h.stats["completed"] == len(prompts) + 3
         assert h.stats["resumes"] == 0
     finally:
         serve.shutdown()
