@@ -36,6 +36,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -383,10 +384,13 @@ class PageAllocator:
         """Fixed-width page table (padded with a valid dummy index so the
         kernel's out-of-range grid steps stay in bounds; masking by
         `length` makes their scores irrelevant)."""
+        return jnp.asarray(self.table_row(seq_id, npages))
+
+    def table_row(self, seq_id: str, npages: int) -> np.ndarray:
+        """`table`, on the host (the engine's mirror of a slot's row)."""
         owned = self._owned.get(seq_id, [])
         pad = owned[-1] if owned else 0
-        rows = (owned + [pad] * npages)[:npages]
-        return jnp.asarray(rows, jnp.int32)
+        return np.asarray((owned + [pad] * npages)[:npages], np.int32)
 
     def free(self, seq_id: str) -> None:
         self._free.extend(reversed(self._owned.pop(seq_id, [])))

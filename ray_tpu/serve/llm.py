@@ -16,6 +16,11 @@ behind Serve deployments); this engine is native and TPU-shaped:
 - **Streaming.** `submit()` returns a handle whose iterator yields tokens
   as they are produced; `LLMDeployment` plugs that into Serve's
   generator-streaming path (`handle.options(stream=True)` / `?stream=1`).
+- **The host in the chip's shadow.** Between a chunk's fetch and the next
+  dispatch the loop does only what that dispatch needs. A chunk's tokens
+  reach their streams, a stream's end with them, after the next chunk is
+  queued; a request that finds a slot empty is admitted and its prefill
+  queued behind the chunk that is on the chip (`_loop`).
 - **Paged KV.** Slots share one pool of fixed-size KV pages per layer
   (vLLM block tables, TPU-shaped: the scalar-prefetch pallas kernel in
   ops/paged_attention.py attends over scattered pages; PageAllocator
@@ -74,6 +79,18 @@ class _Slot:
     history: list = field(default_factory=list)
 
 
+@dataclass
+class _Flight:
+    """A prefill dispatched behind a program that is on the chip, its
+    first tokens not fetched yet."""
+
+    requests: list      # (slot, seq_id, prompt, handle), pages reserved
+    width: int          # rows of the program
+    rows: list          # each request's row of the page table
+    toks: object        # first tokens (and counts), on the device
+    held: int           # bytes of the program's outputs, see `_next_fits`
+
+
 class _Prefilled:
     """Admission payload for a request whose prefill ran in ANOTHER
     engine (the disaggregated prefill pool, or a resume after a drain
@@ -96,9 +113,17 @@ class _Prefilled:
 class RequestHandle:
     """Client-side stream of generated tokens for one request.
 
-    The token queue is BOUNDED (`max_buffered`): a consumer that stops
+    The stream is BOUNDED (`max_buffered`): a consumer that stops
     draining while decode keeps producing parks the producing slot
-    (backpressure) instead of growing host memory without limit."""
+    (backpressure) instead of growing host memory without limit.
+
+    The engine's loop BOOKS a token (`_offer`) the moment it has decided
+    it, and hands what it booked to the consumer later, whole and with
+    the stream's end if there is one (`_hand_over`): one turn of the
+    stream's lock for a chunk of tokens, taken when the loop has queued
+    the chip's next program and not while the chip waits for it. A booked
+    token takes its place in the bound at once, so what is booked always
+    fits when it is handed over."""
 
     _rids = itertools.count(1)
 
@@ -110,68 +135,69 @@ class RequestHandle:
         # Process-unique: what the spans of this request share
         # (`request.queue`, `request.first_token`, `engine.admit`'s `rids`).
         self.rid = next(RequestHandle._rids)
-        self._q: queue.Queue = queue.Queue(maxsize=max(1, max_buffered))
-        self._done = threading.Event()
+        self._max_buffered = max(1, max_buffered)
+        # Booked by the engine's loop (that thread alone), in order.
+        self._booked: list = []
+        # Handed over and not yet taken; `_wake` guards it and the end.
+        self._handed: deque = deque()
+        self._ended = False
+        self._wake = threading.Condition(threading.Lock())
         self._submit_ns = time.monotonic_ns()
         self._submit_ts = self._submit_ns / 1e9
         self.error: Exception | None = None
 
     def _offer(self, tok: int) -> bool:
-        """Non-blocking enqueue; False = consumer backlog full. The
-        engine parks the slot on False — it must never block its loop
-        on a slow consumer."""
-        try:
-            self._q.put_nowait(tok)
-            return True
-        except queue.Full:
+        """Book one token; False = consumer backlog full. The engine
+        parks the slot on False: it must never block its loop on a slow
+        consumer."""
+        if self.room() <= 0:
             return False
+        self._booked.append(tok)
+        return True
+
+    def _hand_over(self, end: bool = False,
+                   error: Exception | None = None) -> int:
+        """What is booked to the consumer, and the stream's end with it,
+        in one turn of the lock; the consumer wakes once, and at its end
+        returns at once. Returns the tokens handed over."""
+        with self._wake:
+            n = len(self._booked)
+            self._handed.extend(self._booked)
+            self._booked.clear()
+            if error is not None and self.error is None:
+                self.error = error
+            self._ended = self._ended or end or error is not None
+            self._wake.notify_all()
+        return n
 
     def _finish(self, error: Exception | None = None) -> None:
-        if error is not None and self.error is None:
-            self.error = error
-        self._done.set()
+        self._hand_over(end=True, error=error)
 
     def backlog_full(self) -> bool:
-        return self._q.full()
+        return self.room() <= 0
 
     def room(self) -> int:
-        """Tokens the queue takes now without parking (it only grows:
-        the engine's loop is the one producer)."""
-        return self._q.maxsize - self._q.qsize()
+        """Tokens the stream takes now without parking. What is booked
+        and not yet handed over counts as taken: the consumer cannot
+        drain it, so between two hand-overs room only shrinks, and a
+        slot is never given steps whose tokens would not fit."""
+        return self._max_buffered - len(self._handed) - len(self._booked)
 
     def __iter__(self):
         while True:
-            try:
-                yield self._q.get(timeout=0.05)
-                continue
-            except queue.Empty:
-                pass
-            if self._done.is_set():
-                # Drain tokens that raced the done flag: _finish is
-                # ordered after the final _offer, but this iterator may
-                # observe the event before emptying the queue.
-                while True:
-                    try:
-                        yield self._q.get_nowait()
-                    except queue.Empty:
-                        break
-                if self.error is not None:
-                    raise self.error
-                return
+            with self._wake:
+                while not self._handed:
+                    if self._ended:
+                        if self.error is not None:
+                            raise self.error
+                        return
+                    self._wake.wait()
+                tok = self._handed.popleft()
+            yield tok
 
     def tokens(self) -> list[int]:
         """Block until completion; all tokens as a list."""
         return list(self)
-
-
-def _idle_span(open_span, why: str):
-    """The engine's loop has nothing to do for a while (`engine.idle`,
-    outside any pass): the open span of that kind, begun if there is none."""
-    if open_span is not None and open_span.attrs["why"] == why:
-        return open_span
-    if open_span is not None:
-        open_span.end()
-    return tracing.span("engine.idle", why=why).begin()
 
 
 class LLMEngine:
@@ -219,6 +245,10 @@ class LLMEngine:
         # follows no admission, finish or park).
         self.decode_passes = 0
         self.decode_passes_clean = 0
+        # Requests given a slot and pages, and those of them whose prefill
+        # was dispatched behind a decode chunk that was on the chip.
+        self.admissions = 0
+        self.admissions_under_chunk = 0
         # Tokens a KV page holds: admission is bounded by POOL pages
         # (resident tokens), not slot count x max_len.
         if page_size <= 0:
@@ -387,7 +417,21 @@ class LLMEngine:
         self._topps = np.ones(max_batch, np.float32)
         self._chunk_no = np.zeros((), np.int32)
         self._slots = [_Slot() for _ in range(max_batch)]
-        self._pending: queue.Queue = queue.Queue()
+        # Submitted and not yet taken by the loop, first come first; under
+        # `_arrivals`, which wakes the loop for an arrival and, while it
+        # waits behind a program on the chip, for that program's end
+        # (`_chip_done`, the watcher thread's word).
+        self._pending: deque = deque()
+        self._arrivals = threading.Condition(threading.Lock())
+        self._chip_done = False
+        self._watched: queue.SimpleQueue = queue.SimpleQueue()
+        # Streams with tokens booked and not yet handed over -> whether
+        # the stream ended with them (`_hand_off`).
+        self._booked: dict = {}
+        # Prefills dispatched behind a program on the chip (`_Flight`), and
+        # the bytes of a prefill's outputs by (width, bucket), as seen.
+        self._in_flight: list = []
+        self._prefill_bytes: dict = {}
         self._stop = threading.Event()
         # Drain quiesce handshake: _quiesce asks the loop to pause at a
         # tick boundary; the loop acks via _quiet, after which slot/KV
@@ -400,6 +444,8 @@ class LLMEngine:
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="llm-engine")
         self._thread.start()
+        threading.Thread(target=self._watch, daemon=True,
+                         name="llm-engine-watch").start()
 
     # ---- public API ------------------------------------------------------
 
@@ -419,8 +465,13 @@ class LLMEngine:
                 f"{self._alloc.num_pages - 1}; raise kv_pool_tokens")
         handle = RequestHandle(len(prompt), sp,
                                max_buffered=self._stream_buffer, tag=tag)
-        self._pending.put((prompt, handle))
+        self._arrive(prompt, handle)
         return handle
+
+    def _arrive(self, what, handle: RequestHandle) -> None:
+        with self._arrivals:
+            self._pending.append((what, handle))
+            self._arrivals.notify()
 
     def submit_prefilled(self, pack: _Prefilled,
                          sampling: SamplingParams | None = None,
@@ -440,7 +491,7 @@ class LLMEngine:
                 f"engine max_len={self.max_len}")
         handle = RequestHandle(pack.prompt_len, sp,
                                max_buffered=self._stream_buffer, tag=tag)
-        self._pending.put((pack, handle))
+        self._arrive(pack, handle)
         return handle
 
     def _require_portable_kv(self, what: str) -> None:
@@ -469,7 +520,10 @@ class LLMEngine:
         return total
 
     def queue_depth(self) -> int:
-        return self._pending.qsize() + len(self._deferred)
+        """Requests that hold no slot yet, or hold one and await their
+        first token behind the chunk on the chip."""
+        return len(self._pending) + len(self._deferred) \
+            + sum(len(f.requests) for f in list(self._in_flight))
 
     def report_metrics(self) -> dict:
         ttft = sorted(self._ttft)
@@ -500,6 +554,8 @@ class LLMEngine:
             "state_slot_steps": float(self.state_slot_steps),
             "decode_passes": float(self.decode_passes),
             "decode_passes_clean": float(self.decode_passes_clean),
+            "admissions": float(self.admissions),
+            "admissions_under_chunk": float(self.admissions_under_chunk),
             # what the family's programs counted (e.g. experts touched)
             **{k: float(v) for k, v in self.family_counters.items()},
             "ttft_p50_ms": pick(0.5) * 1e3,
@@ -559,23 +615,31 @@ class LLMEngine:
     def shutdown(self):
         self._stop.set()
         self._thread.join(5.0)
+        self._watched.put(None)
         self._fail_all(RuntimeError("engine shut down"))
 
     def _fail_all(self, err: Exception):
-        """Unblock every waiter: active slots, deferred and queued requests."""
+        """Unblock every waiter: active slots, admissions whose first
+        tokens are in flight (in no slot yet; their pages go with their
+        slots'), deferred and queued requests. A stream that had ended
+        before ends as it did."""
+        self._hand_off()
         for i, st in enumerate(self._slots):
             if st.request is not None:
                 st.request._finish(err)
                 st.request = None
             self._free_slot_pages(i)
+        for flight in self._in_flight:
+            for _slot, _seq_id, _prompt, handle in flight.requests:
+                handle._finish(err)
+        self._in_flight.clear()
         for _prompt, handle in self._deferred:
             handle._finish(err)
         self._deferred.clear()
-        while True:
-            try:
-                _prompt, handle = self._pending.get_nowait()
-            except queue.Empty:
-                break
+        with self._arrivals:
+            queued = list(self._pending)
+            self._pending.clear()
+        for _prompt, handle in queued:
             handle._finish(err)
 
     # ---- engine loop -----------------------------------------------------
@@ -664,7 +728,7 @@ class LLMEngine:
         ps = self.page_size
         Lb = -(-max(self._bucket(pack.lens), ps) // ps) * ps
         n_real = -(-pack.lens // ps)
-        row = np.asarray(self._alloc.table(seq_id, self._np_pages))
+        row = self._alloc.table_row(seq_id, self._np_pages)
         page_ids = np.full(Lb // ps, self._dummy_page, np.int32)
         page_ids[:n_real] = row[:n_real]
         kv_pad = []
@@ -702,12 +766,17 @@ class LLMEngine:
         st.seq_id = seq_id
         return seq_id
 
-    def _admit_paged_group(self, cands: list) -> None:
+    def _admit_paged_group(self, cands: list, under_chunk: bool) -> None:
         """Prefill reserved candidates, batching same-bucket requests
         through the fixed-width prefill_many program (one dispatch for
         up to _batch_prefill_width streams). Singleton groups keep the
         single-sequence program. cands: (slot, seq_id, prompt, handle)
-        with pages already reserved."""
+        with pages already reserved. `under_chunk`: a program is on the
+        chip, so each group is only dispatched behind it; its first
+        tokens are fetched and committed after the chunk's walk
+        (`_finish_prefill`, from `_in_flight`)."""
+        self.admissions += len(cands)
+        self.admissions_under_chunk += under_chunk * len(cands)
         # Externally prefilled streams skip the prefill programs entirely:
         # their KV prefix scatters straight into the reserved pages.
         for slot, seq_id, pack, handle in \
@@ -729,9 +798,15 @@ class LLMEngine:
                 group = group[len(chunk):]
                 # A request alone keeps the single-sequence program.
                 W = 1 if len(chunk) == 1 else width
-                span = tracing.span("engine.prefill", bucket=bucket,
-                                    rows=len(chunk), width=W).begin()
-                span.end(**self._prefill_group(chunk, bucket, W))
+                with tracing.span("engine.prefill", bucket=bucket,
+                                  rows=len(chunk), width=W):
+                    flight = self._dispatch_prefill(chunk, bucket, W)
+                    if flight is None:
+                        continue
+                    if under_chunk:
+                        self._in_flight.append(flight)
+                    else:
+                        self._finish_prefill(flight)
 
     def _count(self, counters: tuple, values: np.ndarray) -> dict:
         """What a family's program counted, by name: `values` (..., n)
@@ -745,11 +820,19 @@ class LLMEngine:
                 else kept + got
         return out
 
-    def _prefill_group(self, chunk: list, bucket: int, W: int) -> dict:
+    def _fail_group(self, chunk: list, err: BaseException) -> None:
+        """A device-level failure sinks a prefill's whole dispatch: fail
+        every member and return their pages."""
+        for slot, _seq_id, _prompt, handle in chunk:
+            self._free_slot_pages(slot)
+            handle._finish(err)
+
+    def _dispatch_prefill(self, chunk: list, bucket: int, W: int):
         """One prefill dispatch for `chunk` (at most W requests of one
-        bucket, pages reserved): the rows' state into pages and slots,
-        one sampling dispatch, then the host-side commit.  Returns what
-        the family's program counted (`prefill_counters`), by name."""
+        bucket, pages reserved): the rows' state into pages and slots and
+        one sampling dispatch, nothing fetched. Returns what
+        `_finish_prefill` takes, or None where the dispatch failed (and
+        its requests with it)."""
         jnp = self._jnp
         npages_row = self.family.prompt_pages(bucket, self.page_size)
         tokens = np.zeros((W, bucket), np.int32)
@@ -768,7 +851,7 @@ class LLMEngine:
             tokens[r, : len(prompt)] = prompt
             last_idx[r] = len(prompt) - 1
             slots[r] = slot
-            row = np.asarray(self._alloc.table(seq_id, self._np_pages))
+            row = self._alloc.table_row(seq_id, self._np_pages)
             rows.append(row)
             npp = self._alloc.pages_needed(len(prompt))
             page_rows[r, :npp] = row[:npp]
@@ -783,32 +866,48 @@ class LLMEngine:
             self._pools = self._write_prompt_pages(
                 self._pools, fresh, jnp.asarray(slots),
                 jnp.asarray(page_rows))
-            # ONE sampling dispatch + host sync for the whole group;
-            # it must be covered too, or a transient device error kills
-            # the engine thread and strands every waiter (no sentinel
-            # ever lands). Greedy stays bit-equal whatever the group:
-            # argmax ignores the rng mapping.
+            # ONE sampling dispatch for the whole group; it must be
+            # covered too, or a transient device error kills the engine
+            # thread and strands every waiter (no sentinel ever lands).
+            # Greedy stays bit-equal whatever the group: argmax ignores
+            # the rng mapping.
             self._rng, srng = self._jax.random.split(self._rng)
-            with tracing.span("engine.prefill.wait"):
-                toks = np.asarray(
-                    self._sample_counted(*counts, last_logits, temps, topks,
-                                         topps, srng) if counts else
-                    self._sample(last_logits, temps, topks, topps, srng))
+            toks = self._sample_counted(*counts, last_logits, temps, topks,
+                                        topps, srng) if counts else \
+                self._sample(last_logits, temps, topks, topps, srng)
         except BaseException as e:
-            # Device-level failure sinks the whole dispatch: fail
-            # every member and return their pages.
-            for slot, seq_id, prompt, handle in chunk:
-                self._free_slot_pages(slot)
-                handle._finish(e)
-            return {}
+            self._fail_group(chunk, e)
+            return None
+        held = self._prefill_bytes.get((W, bucket))
+        if held is None:
+            held = self._prefill_bytes[W, bucket] = sum(
+                x.nbytes for x in self._jax.tree_util.tree_leaves(
+                    (last_logits, fresh)))
+        return _Flight(chunk, W, rows, toks, held)
+
+    def _finish_prefill(self, flight: _Flight, free=()) -> None:
+        """A dispatched prefill's first tokens (and what the family's
+        program counted, `prefill_counters`, which ride on the fetch's
+        span): ONE host sync for the whole group, then the host-side
+        commit. The chip is at work on the prefill, so first what is
+        booked is handed over and arrivals are served into `free`."""
+        chunk, W, rows = flight.requests, flight.width, flight.rows
+        self._hand_off()
+        self._admit_behind(flight.toks, free)
+        wait = tracing.span("engine.prefill.wait").begin()
+        try:
+            toks = np.asarray(flight.toks)
+        except BaseException as e:
+            wait.end()
+            self._fail_group(chunk, e)
+            return
+        wait.end(**self._count(self._prefill_counters, toks[W:]))
         # Host-only from here: no device call can strand waiters.
-        counted = self._count(self._prefill_counters, toks[W:])
         if not self.family.rewinds:
             self.state_slots_reset += len(chunk)
         for r, (slot, seq_id, prompt, handle) in enumerate(chunk):
             self._tables[slot] = rows[r]
             self._commit_token(slot, handle, int(toks[r]), len(prompt))
-        return counted
 
     def _init_paged_state(self):
         """(Re)build the page pool: allocator + dummy page + the family's
@@ -881,20 +980,23 @@ class LLMEngine:
         return steps
 
     def _emit(self, slot: int, tok: int) -> bool:
-        """Offer one token to the stream. False = the consumer's bounded
-        queue is full: the caller must NOT commit the token — the slot
-        parks (its decode cursor stays put) and the same token is
-        re-produced next chunk once the consumer drains."""
+        """Book one token for the stream (`_hand_off` wakes its consumer,
+        not this). False = the consumer's bounded queue is full: the
+        caller must NOT commit the token — the slot parks (its decode
+        cursor stays put) and the same token is re-produced next chunk
+        once the consumer drains."""
         st = self._slots[slot]
-        if not st.request._offer(tok):
+        handle = st.request
+        if not handle._offer(tok):
             self._parked_events += 1
             return False
         st.generated += 1
         st.history.append(tok)
-        sp = st.request.sampling
-        if (sp.eos_token is not None and tok == sp.eos_token) or \
-                st.generated >= sp.max_new_tokens:
-            st.request._finish()
+        sp = handle.sampling
+        ended = (sp.eos_token is not None and tok == sp.eos_token) or \
+            st.generated >= sp.max_new_tokens
+        self._booked[handle] = ended
+        if ended:
             st.request = None
             # The stream's pages return to the pool the moment it
             # completes — this is what lets a deferred request admit on
@@ -902,106 +1004,222 @@ class LLMEngine:
             self._free_slot_pages(slot)
         return True
 
+    def _idle(self, open_span, why: str):
+        """The loop has nothing to do for a while (`engine.idle`, outside
+        any pass): what is booked is handed over first; then the open
+        span of that kind, begun if there is none."""
+        self._hand_off()
+        if open_span is not None and open_span.attrs["why"] == why:
+            return open_span
+        if open_span is not None:
+            open_span.end()
+        return tracing.span("engine.idle", why=why).begin()
+
+    def _hand_off(self) -> None:
+        """Every stream's booked tokens to its consumer, a stream that
+        ended its end with them: one turn of its lock a stream. The loop
+        calls this when it has queued the chip's next program and is about
+        to wait for the chip (after a chunk's dispatch, before a prefill's
+        fetch), or when it has nothing to queue (idle, quiesce, a failure,
+        the loop's end): a woken consumer competes for the interpreter
+        lock, so none is woken between a fetch and the next dispatch, and
+        nothing booked is held over a wait."""
+        if not self._booked:
+            return
+        span = tracing.span("engine.handoff").begin()
+        tokens = sum(handle._hand_over(end=ended)
+                     for handle, ended in self._booked.items())
+        span.end(streams=len(self._booked), tokens=tokens,
+                 ended=sum(self._booked.values()))
+        self._booked.clear()
+
+    def _watch(self) -> None:
+        """(Its own thread.) Tells the loop, which may be waiting for an
+        arrival, that the program it was handed has left the chip."""
+        while (toks := self._watched.get()) is not None:
+            try:
+                toks.block_until_ready()
+            except Exception:  # noqa: S110 (the loop's own fetch raises it)
+                pass
+            with self._arrivals:
+                self._chip_done = True
+                self._arrivals.notify()
+
+    def _next_fits(self) -> bool:
+        """(Under `_arrivals`.) The next request in line is one to prefill
+        here (no `_Prefilled` pack), and its prefill's outputs (logits and
+        fresh state: allocated at its dispatch, released when its write
+        and sampling have run) fit beside those of the prefills in flight:
+        together no more than the most that ONE prefill of this engine
+        has held, which is what an admission at the top of a pass holds.
+        Sizes are the programs' own, as dispatched; a shape not yet seen
+        has none and goes alone."""
+        if not self._pending or isinstance(self._pending[0][0], _Prefilled):
+            return False
+        if not self._in_flight:
+            return True
+        bucket = max(self._bucket(len(self._pending[0][0])), self.page_size)
+        held = self._prefill_bytes.get((1, bucket))
+        return held is not None and held + sum(
+            f.held for f in self._in_flight) \
+            <= max(self._prefill_bytes.values())
+
+    def _empty_slots(self) -> list:
+        """Slots that hold no stream and are not spoken for by a prefill
+        in flight."""
+        return [i for i, st in enumerate(self._slots)
+                if st.request is None and not st.seq_id]
+
+    def _gather(self, free: list, under_chunk: bool = False) -> list:
+        """Waiting requests, first come first, into the slots of `free`
+        (shortened by those taken), pages reserved for each: (slot,
+        seq_id, prompt, handle). Admission gates on pool pages: a dry pool
+        defers the request (FIFO) until completions free pages, and
+        nothing behind it is taken. `under_chunk`: one request at most,
+        and only one that `_next_fits`."""
+        cands: list = []
+        while free and not (under_chunk and cands):
+            from_deferred = bool(self._deferred)
+            if from_deferred:
+                prompt, handle = self._deferred[0]
+            else:
+                with self._arrivals:
+                    if not (self._next_fits() if under_chunk
+                            else self._pending):
+                        break
+                    prompt, handle = self._pending.popleft()
+            slot = free[0]
+            try:
+                seq_id = self._reserve_paged(slot, prompt, handle)
+            except MemoryError:
+                # Pool dry: keep FIFO order and stop admitting until
+                # a completion frees pages.
+                if not from_deferred:
+                    self._deferred.append((prompt, handle))
+                break
+            except Exception as e:  # surfacing beats a dead stream
+                if from_deferred:
+                    self._deferred.pop(0)
+                handle._finish(e)
+                continue
+            if from_deferred:
+                self._deferred.pop(0)
+            free.pop(0)
+            cands.append((slot, seq_id, prompt, handle))
+            # Slot and pages are this request's: its wait is over.
+            tracing.record_span("request.queue", handle._submit_ns,
+                                rid=handle.rid,
+                                prompt_len=handle.prompt_len,
+                                deferred=from_deferred)
+        return cands
+
+    def _admit(self, free: list, under_chunk: bool) -> None:
+        """One `engine.admit`: the requests `_gather` takes, prefilled
+        together (see _admit_paged_group: sequential slot prefills were
+        the measured end-to-end serving bottleneck at large batch)."""
+        admit = tracing.span("engine.admit", under_chunk=under_chunk).begin()
+        cands = self._gather(free, under_chunk)
+        if cands:
+            self._admit_paged_group(cands, under_chunk)
+        admit.end(admitted=len(cands), deferred=len(self._deferred),
+                  rids=[c[3].rid for c in cands])
+
+    def _admit_behind(self, toks, free: list) -> None:
+        """The program that returns `toks` (a decode chunk, or a prefill
+        queued behind one) is on the chip and the slots of `free` are
+        empty (at the chunk's dispatch; after its walk, those it freed as
+        well) and not spoken for: until the program is done, a plain
+        request that arrives (or had arrived) is given one of them and
+        its prefill queued behind what is on the chip, one request a
+        prefill, so the chip goes from one program to the next while the
+        host is still fetching and walking. Nothing here needs the
+        chunk's tokens; the first tokens are fetched after its walk. One
+        wait serves both ends: an arrival and the watcher's word that the
+        program is done wake the same condition, so the fetch that follows
+        finds its tokens there and nothing polls. Where no slot is empty
+        (or a request waits for pages: nothing passes it) the loop goes
+        straight to that fetch, as it always did."""
+        if not free or self._deferred:
+            return
+        self._chip_done = False
+        self._watched.put(toks)
+        while True:
+            with tracing.span("engine.chip.wait"), self._arrivals:
+                self._arrivals.wait_for(
+                    lambda: self._chip_done or (
+                        free and not self._deferred and self._next_fits()))
+                if self._chip_done:
+                    return
+            self._admit(free, under_chunk=True)
+
     def _loop(self):
         jnp = self._jnp
+        # One rule orders a pass: between the fetch of chunk N and the
+        # dispatch of chunk N+1 this thread does only what that dispatch
+        # needs (the walk's bookkeeping, admissions into slots the walk
+        # freed, the commit of first tokens, the build); everything else
+        # it does while a program is queued on the chip. So a chunk's
+        # tokens are booked in the walk and handed to their streams after
+        # the next dispatch (`_hand_off`), and an arrival that finds a slot
+        # empty is admitted and prefilled behind the chunk on the chip, or
+        # behind a prefill already queued there (`_admit_behind`). The
+        # chip's order of programs is what it was: chunk N, prefills with
+        # their writes and sampling, chunk N+1.
+        #
         # What the host does here leaves spans (`util/tracing.py`; PERF.md
         # section 3 has the names): `engine.pass` for an iteration that
         # admits or dispatches, its phases inside it, `engine.idle` between.
         idle = None
         while not self._stop.is_set():
             # Drain quiesce: ack and idle at a tick boundary — every
-            # admitted token is committed, so slot/KV state is a
-            # consistent snapshot for the evacuation path.
+            # admitted token is committed and handed over, so slot/KV
+            # state is a consistent snapshot for the evacuation path.
             if self._quiesce.is_set():
-                idle = _idle_span(idle, "quiesce")
+                idle = self._idle(idle, "quiesce")
                 self._quiet.set()
                 self._stop.wait(0.01)
                 continue
             decoding = [s for s in self._slots if s.request is not None]
-            arrived = None
             admitting = len(decoding) < self.max_batch and (
-                bool(self._deferred) or not self._pending.empty())
+                bool(self._deferred) or bool(self._pending))
             if not admitting:
                 if not decoding:
                     # Nothing in flight: block for a request.
-                    idle = _idle_span(idle, "no_request")
-                    try:
-                        arrived = self._pending.get(timeout=0.05)
-                    except queue.Empty:
-                        continue
+                    idle = self._idle(idle, "no_request")
+                    with self._arrivals:
+                        if not self._arrivals.wait_for(
+                                lambda: self._pending, timeout=0.05):
+                            continue
                     admitting = True
                 elif all(s.request.backlog_full() for s in decoding):
                     # Backpressure: if EVERY decoding stream's consumer
                     # queue is full, a decode chunk would produce only
                     # parked tokens — skip the dispatch and give the
                     # consumers time to drain.
-                    idle = _idle_span(idle, "backpressure")
+                    idle = self._idle(idle, "backpressure")
                     self._stop.wait(0.002)
                     continue
             if idle is not None:
                 idle.end()
                 idle = None
             loop_pass = tracing.span("engine.pass").begin()
-            # Admit as many pending requests as there are free slots —
-            # without stalling slots that are mid-decode. Admission also
-            # gates on pool pages: a dry pool defers the request (FIFO)
-            # until completions free pages. Admissions gathered in one
-            # pass PREFILL TOGETHER (see _admit_paged_group) — sequential
-            # slot prefills were the measured end-to-end serving
-            # bottleneck at large batch.
-            cands: list = []
-            picked: set = set()
-            admit = tracing.span("engine.admit").begin() if admitting \
-                else None
-            while admitting and any(i not in picked and s.request is None
-                                    for i, s in enumerate(self._slots)):
-                from_deferred = bool(self._deferred)
-                if from_deferred:
-                    prompt, handle = self._deferred[0]
-                elif arrived is not None:
-                    (prompt, handle), arrived = arrived, None
-                else:
-                    try:
-                        prompt, handle = self._pending.get_nowait()
-                    except queue.Empty:
-                        break
-                slot = next(i for i, s in enumerate(self._slots)
-                            if s.request is None and i not in picked)
-                try:
-                    seq_id = self._reserve_paged(slot, prompt, handle)
-                except MemoryError:
-                    # Pool dry: keep FIFO order and stop admitting until
-                    # a completion frees pages.
-                    if not from_deferred:
-                        self._deferred.append((prompt, handle))
-                    break
-                except Exception as e:  # surfacing beats a dead stream
-                    if from_deferred:
-                        self._deferred.pop(0)
-                    handle._finish(e)
-                    continue
-                if from_deferred:
-                    self._deferred.pop(0)
-                picked.add(slot)
-                cands.append((slot, seq_id, prompt, handle))
-                # Slot and pages are this request's: its wait is over.
-                tracing.record_span("request.queue", handle._submit_ns,
-                                    rid=handle.rid,
-                                    prompt_len=handle.prompt_len,
-                                    deferred=from_deferred)
-            if cands:
-                self._admit_paged_group(cands)
-            if admit is not None:
-                admit.end(admitted=len(cands), deferred=len(self._deferred),
-                          rids=[c[3].rid for c in cands])
+            # Admit as many waiting requests as there are free slots,
+            # without stalling slots that are mid-decode: here, together,
+            # the ones that waited for a slot the last walk freed (or for
+            # pages, behind a pack, for room beside the prefills in flight,
+            # or with nothing on the chip); the others were admitted
+            # behind the last chunk.
+            if admitting:
+                self._admit(self._empty_slots(), under_chunk=False)
             decoding = [s for s in self._slots if s.request is not None]
             if not decoding:
+                self._hand_off()
                 loop_pass.end()
                 continue
             if all(s.request.backlog_full() for s in decoding):
                 # (Backpressure again: what was admitted filled its queue.)
                 loop_pass.end()
-                idle = _idle_span(idle, "backpressure")
+                idle = self._idle(idle, "backpressure")
                 self._stop.wait(0.002)
                 continue
             # One decode CHUNK for every slot (inactive slots compute
@@ -1044,11 +1262,18 @@ class LLMEngine:
                         self._decode_chunk_paged(*args)
                     # The inputs the carry has replaced die here, with the
                     # device at work: an array's destructor gives the
-                    # interpreter lock away, and between the walk (which
-                    # woke every consumer) and the dispatch that puts the
-                    # loop behind all of them (5 ms a pass at 32 streams).
+                    # interpreter lock away, and before the dispatch that
+                    # would put the loop behind every consumer awake.
                     del args
                     self._chunk_no += 1
+                # The chip has its next program: now the streams get what
+                # the last walk and the commits booked.
+                self._hand_off()
+                free = self._empty_slots()
+                self._admit_behind(toks, free)
+                # The chunk's ONE `engine.decode.wait` (its counts ride on
+                # it): the whole wait where no slot was empty, else what
+                # is left of it after `engine.chip.wait`.
                 wait = tracing.span("engine.decode.wait",
                                     active=len(decoding), steps=slot_steps,
                                     pages_live=pages_live,
@@ -1093,7 +1318,23 @@ class LLMEngine:
                     self._token[i] = tok
             walk.end(emitted=emitted, finished=finished,
                      parked=self._parked_events - parked)
+            if self._in_flight:
+                # First tokens of what was admitted behind the chunk: the
+                # chip has been at those prefills since the chunk ended,
+                # and arrivals are served behind them as behind the chunk.
+                commit = tracing.span("engine.commit").begin()
+                rows = 0
+                # (the walk's slots too: what arrives from here on was not
+                # waiting when they were freed)
+                free = self._empty_slots()
+                while self._in_flight:
+                    flight = self._in_flight[0]
+                    rows += len(flight.requests)
+                    self._finish_prefill(flight, free)
+                    self._in_flight.pop(0)
+                commit.end(rows=rows)
             loop_pass.end()
+        self._hand_off()
         if idle is not None:
             idle.end()
 

@@ -14,14 +14,23 @@ import pytest
 from ray_tpu.util import timeline, tracing
 
 LOOP_THREAD = "llm-engine"
-# Where each loop-scoped span may sit (PERF.md, section 3).
-PARENT_OF = {"engine.admit": "engine.pass", "engine.prefill": "engine.admit",
-             "engine.prefill.wait": "engine.prefill",
-             "engine.decode.build": "engine.pass",
-             "engine.decode.upload": "engine.decode.build",
-             "engine.decode.dispatch": "engine.pass",
-             "engine.decode.wait": "engine.pass",
-             "engine.walk": "engine.pass"}
+# Where each loop-scoped span may sit (PERF.md, section 3). The hand-off
+# stands wherever the loop is about to wait: after a chunk's dispatch,
+# before a prefill's fetch, or outside any pass before an idle wait.
+PARENT_OF = {"engine.admit": {"engine.pass", "engine.commit"},
+             "engine.prefill": {"engine.admit"},
+             "engine.prefill.wait": {"engine.prefill", "engine.commit"},
+             "engine.decode.build": {"engine.pass"},
+             "engine.decode.upload": {"engine.decode.build"},
+             "engine.decode.dispatch": {"engine.pass"},
+             "engine.chip.wait": {"engine.pass", "engine.commit"},
+             "engine.decode.wait": {"engine.pass"},
+             "engine.walk": {"engine.pass"},
+             "engine.commit": {"engine.pass"},
+             "engine.handoff": {"engine.pass", "engine.prefill",
+                                "engine.commit", None}}
+# What three requests over two slots always leave.
+ALWAYS = set(PARENT_OF) - {"engine.chip.wait", "engine.commit"}
 
 
 @pytest.fixture(scope="module")
@@ -76,14 +85,17 @@ def test_children_lie_inside_their_parents_on_one_thread(served):
     _, _, _, spans, _ = served
     by_id = {s["id"]: s for s in spans}
     loop = [s for s in spans if s["name"].startswith("engine.")]
-    assert {s["name"] for s in loop} >= set(PARENT_OF) | {"engine.pass"}
+    assert {s["name"] for s in loop} >= ALWAYS | {"engine.pass"}
     for s in loop:
         assert s["thread"] == LOOP_THREAD
         if s["name"] in ("engine.pass", "engine.idle"):
             assert s["parent"] is None      # idle lies outside any pass
             continue
+        if s["parent"] is None:
+            assert None in PARENT_OF[s["name"]]
+            continue
         parent = by_id[s["parent"]]
-        assert parent["name"] == PARENT_OF[s["name"]]
+        assert parent["name"] in PARENT_OF[s["name"]]
         assert parent["tid"] == s["tid"]
         assert parent["t0_ns"] <= s["t0_ns"]
         assert s["t0_ns"] + s["dur_ns"] <= parent["t0_ns"] + parent["dur_ns"]

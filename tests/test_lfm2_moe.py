@@ -378,7 +378,7 @@ def test_engine_streams_are_the_references_greedy(tiny):
                              a["experts_touched"] <= a["expert_slots"] and
                              1 <= a["expert_rows_max"] <= 4 for a in waits)
         fills = [s["attrs"] for s in tracing.recent_spans()
-                 if s["name"] == "engine.prefill"
+                 if s["name"] == "engine.prefill.wait"
                  and "expert_rows" in s.get("attrs", {})]
         assert sum(a["expert_rows"] for a in fills) == got["expert_rows"]
     finally:

@@ -244,8 +244,12 @@ def test_the_engine_recovers_from_a_failed_chunk(model):
         lost = eng.submit(_prompt(10, 11), SamplingParams(max_new_tokens=30))
         with pytest.raises(RuntimeError, match="planted decode failure"):
             lost.tokens()
+        # (The consumer hears of the failure at once, the state is made
+        # anew right after it: the pass has ended when the loop is quiet.)
+        assert eng.quiesce_for_drain()
         assert eng._dirty == set(_MIRRORS) - {"steps"} and eng._dev == {}
         assert eng._steps is None
+        eng.resume()
         prompts = [_prompt(11, 17), _prompt(12, 40)]
         handles = [eng.submit(p, SamplingParams(max_new_tokens=18))
                    for p in prompts]
