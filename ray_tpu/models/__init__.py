@@ -3,10 +3,12 @@
 The reference ships no model implementations (its release gates pull
 GPT-J/vicuna through external torch engines); here the flagship decoder,
 an expert-parallel MoE, and the generation path are part of the framework.
-`serve.LLMEngine` serves four of them (`serve/llm_families.py`):
-`LlamaConfig`, `SambaYConfig`, `GraniteHybridConfig` and `Lfm2MoeConfig`
+`serve.LLMEngine` serves five of them (`serve/llm_families.py`):
+`LlamaConfig`, `SambaYConfig`, `GraniteHybridConfig`, `Lfm2MoeConfig`
 (routed experts with no token dropped and a cached decode path; `moe.py`'s
-capacity-bounded layer trains at toy sizes and is not served).
+capacity-bounded layer trains at toy sizes and is not served) and
+`MlaMoeConfig` (latent attention over a latent paged cache, the same
+routed layer with shared experts beside it).
 """
 
 from ray_tpu.models.lfm2_moe import (
@@ -24,6 +26,12 @@ from ray_tpu.models.llama import (
     LlamaModel,
     cross_entropy_loss,
     init_kv_caches,
+)
+from ray_tpu.models.mla_moe import (
+    KIMI_VL_A3B,
+    TINY_MLA_MOE,
+    MlaMoeConfig,
+    MlaMoeModel,
 )
 from ray_tpu.models.moe import (
     MIXTRAL_8X7B,
@@ -105,4 +113,5 @@ __all__ = [
     "GraniteHybridModel", "GraniteHybridConfig", "GRANITE_4_H_MICRO",
     "TINY_GRANITE",
     "Lfm2MoeModel", "Lfm2MoeConfig", "LFM2_24B_A2B", "TINY_LFM2_MOE",
+    "MlaMoeModel", "MlaMoeConfig", "KIMI_VL_A3B", "TINY_MLA_MOE",
 ]

@@ -137,15 +137,16 @@ def router_logits(u, w):
     return jnp.dot(u, w, precision=jax.lax.Precision.HIGHEST)
 
 
-def route(logits, bias, top_k: int):
+def route(logits, bias, top_k: int, eps: float = 1e-6):
     """Router logits (T, E) float32 and the selection bias (E,) -> the
     chosen experts (T, k) and their gates (T, k), float32: sigmoid scores,
     the k largest of score + bias, the UNBIASED scores of the chosen
-    renormalised to sum to one."""
+    renormalised to sum to one (over their sum + `eps`: each family's
+    published constant)."""
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
     _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
-    return idx, chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-6)
+    return idx, chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + eps)
 
 
 def _grouped(x, w, sizes):
@@ -205,7 +206,14 @@ def add_counts(a, b):
 
 
 class RoutedExperts(nn.Module):
-    cfg: Lfm2MoeConfig
+    """`cfg`: any configuration with `n_experts`, `top_k`, `d_model`,
+    `d_expert`, `routed_scaling` and `dtype` (`models/mla_moe.py`'s too);
+    `eps`: what the family adds to the chosen scores' sum, where that is
+    not `route`'s own."""
+    cfg: Any
+    # (None and not 1e-6: `route` is then called with three arguments, as
+    # the planted routes of `benchmarks/tools/lfm2_moe_faults.py` take it)
+    eps: float | None = None
 
     def setup(self):
         c = self.cfg
@@ -223,8 +231,9 @@ class RoutedExperts(nn.Module):
         c = self.cfg
         flat = u.reshape(-1, c.d_model)
         with jax.named_scope("route"):
-            idx, gates = route(router_logits(flat, self.router),
-                               self.expert_bias, c.top_k)
+            idx, gates = route(
+                router_logits(flat, self.router), self.expert_bias, c.top_k,
+                **({} if self.eps is None else {"eps": self.eps}))
         with jax.named_scope("experts"):
             out, counts = expert_ffn(
                 flat, idx, gates, self.w13, self.w2,
@@ -532,14 +541,24 @@ def init_params(cfg: Lfm2MoeConfig, key, *, embed_std: float = 0.02,
     at 0); the final norm's scale `final_norm`, the other norms 1; the
     conv's taps as their module draws them.  (Which values a benchmark
     takes, and why, is the benchmark's: `benchmarks/families/lfm2_moe.py`.)"""
-    model = Lfm2MoeModel(cfg)
-    drawn = model.init(key, jnp.zeros((1, 8), jnp.int32))
-    flat = jax.tree_util.tree_flatten_with_path(drawn)[0]
-    keys = jax.random.split(jax.random.fold_in(key, 1), len(flat))
+    drawn = Lfm2MoeModel(cfg).init(key, jnp.zeros((1, 8), jnp.int32))
     stds = {"embed": embed_std, "qkv_proj": qkv_std, "in_proj": in_std,
             "w13": in_std, "o_proj": out_std, "out_proj": out_std,
             "mlp/w2": ffn_out_std, "experts/w2": expert_out_std,
             "router": router_std, "expert_bias": bias_std}
+    return draw_named(
+        drawn, key, stds,
+        lambda names, leaf: leaf * final_norm
+        if names[1:] == ["norm", "scale"] else leaf)
+
+
+def draw_named(drawn, key, stds: dict, other=lambda names, leaf: leaf):
+    """The parameter tree `drawn` with every leaf that `stds` names (by
+    its module's name, or by the last two names of its path) drawn anew
+    from normal(0, its std), a key a leaf in the tree's order; the rest
+    through `other(names, leaf)`."""
+    flat = jax.tree_util.tree_flatten_with_path(drawn)[0]
+    keys = jax.random.split(jax.random.fold_in(key, 1), len(flat))
     out = []
     for (path, leaf), k in zip(flat, keys):
         names = [p.key for p in path]
@@ -549,8 +568,8 @@ def init_params(cfg: Lfm2MoeConfig, key, *, embed_std: float = 0.02,
         if name in stds:
             leaf = (jax.random.normal(k, leaf.shape, jnp.float32)
                     * stds[name]).astype(leaf.dtype)
-        elif names[1:] == ["norm", "scale"]:
-            leaf = leaf * final_norm
+        else:
+            leaf = other(names, leaf)
         out.append(leaf)
     return jax.tree_util.tree_unflatten(jax.tree_util.tree_structure(drawn),
                                         out)

@@ -26,8 +26,10 @@ def _tiles(rows: int, k: int, n: int) -> tuple:
     """Tiles of the product (rows, contraction, columns): the whole
     contraction and 512 columns, so that a group's matrix streams through
     in slabs of 1.5-2 MB, over one tile of 128 rows (a decode step) or
-    tiles of 256 (a prompt)."""
-    return (_TILE_ROWS if rows <= _TILE_ROWS else 256), k, min(n, 512)
+    tiles of 256 (a prompt).  Where 512 does not divide the columns, the
+    widest multiple of 128 under it that does (2,816 = 11 x 256)."""
+    cols = next((c for c in (512, 384, 256, 128) if n % c == 0), min(n, 512))
+    return (_TILE_ROWS if rows <= _TILE_ROWS else 256), k, cols
 
 
 def grouped_matmul(x, w, sizes):

@@ -347,6 +347,234 @@ def _paged_decode_batch_call(q, k_pool, v_pool, page_tables, lengths, new, *,
     return result.reshape(B, H, D)
 
 
+# ---------------------------------------------------------------------------
+# Latent pages: one pool a layer, read once, key and value both
+# ---------------------------------------------------------------------------
+#
+# A latent-attention layer caches ONE row a token for all its heads: the
+# normed latent (`d_value` wide) and behind it the rotated key they share,
+# padded with zeros to whole lanes (`W`: 512 + 64 -> 640).  With the
+# up-projections absorbed into the query and into the output, a head's
+# score is its query against the whole row and its output the softmax over
+# the row's first `d_value` columns: every head reads the same page, so a
+# page is copied ONCE and serves as key and as value.  The K/V kernel above
+# takes two pools of one width; handed the latent twice it would copy every
+# page twice.  What is shared with it: the order of the copies (a block of
+# live pages into one of two VMEM slots while the other is computed, the
+# last block of a sequence starting the first of the next), the online
+# softmax's recurrence, and the step's row written into the copy of the
+# sequence's last live page, which goes back in place.
+#
+# The query's rows and the softmax's weights enter their products as two
+# bfloat16 terms (rows side by side: 2 x heads rows against the page, one
+# product each), so that the latent is multiplied as it is stored and never
+# widened to float32: sixteen heads over a 640-wide key are 30 operations
+# a byte, which float32 products would make the kernel's bound.
+
+_LATENT_VMEM_BYTES = 3 * 1024 * 1024
+
+
+def _against_rows(a, rows, contract: int):
+    """a (H, n) float32 times the cached `rows` over their axis
+    `contract`, accumulated in float32.  Rows in bfloat16 are multiplied as
+    they are: `a` enters as two bfloat16 terms (its leading 8 bits and
+    what they left, one under the other: ONE product of 2 H rows)."""
+    dims = (((1,), (contract,)), ((), ()))
+    if rows.dtype == jnp.float32:
+        return jax.lax.dot_general(a, rows, dims)
+    # (the leading bits by a mask, as `models/sambay._two_terms` takes
+    # them: a round trip through bfloat16 is the compiler's to elide)
+    hi = jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(a, jnp.uint32) & jnp.uint32(0xFFFF0000),
+        jnp.float32)
+    terms = jnp.concatenate([hi, a - hi], axis=0).astype(jnp.bfloat16)
+    out = jax.lax.dot_general(terms, rows, dims,
+                              preferred_element_type=jnp.float32)
+    return out[: a.shape[0]] + out[a.shape[0]:]
+
+
+def _paged_latent_kernel(length_ref, page_table_ref,    # scalar prefetch
+                         q_ref, new_ref, _, o_ref, pool, buf, sems,
+                         slot_ref, m_scratch, l_scratch, acc_scratch,
+                         *, page_size: int, pages_per_block: int,
+                         table_pages: int, d_value: int, sm_scale: float):
+    # Grid: (B,), one step a sequence; `pool` is the output aliased to the
+    # input pool (read and written through the one ref).
+    b = pl.program_id(0)
+    num_seqs = pl.num_programs(0)
+    block_tokens = page_size * pages_per_block
+    table_tokens = table_pages * page_size
+
+    def length_of(seq):
+        return jnp.minimum(length_ref[seq], table_tokens)
+
+    length = length_of(b)
+    num_blocks = pl.cdiv(length, block_tokens)
+
+    def for_live_pages(seq, blk, slot, act):
+        first = blk * pages_per_block
+        live = jnp.clip(pl.cdiv(length_of(seq), page_size) - first,
+                        0, pages_per_block)
+
+        def page(j, carry):
+            src = page_table_ref[seq * table_pages + first + j]
+            act(pltpu.make_async_copy(pool.at[src], buf.at[slot, j],
+                                      sems.at[slot]))
+            return carry
+
+        jax.lax.fori_loop(0, live, page, None)
+
+    def start(seq, blk, slot):
+        for_live_pages(seq, blk, slot, lambda copy: copy.start())
+
+    def wait(seq, blk, slot):
+        for_live_pages(seq, blk, slot, lambda copy: copy.wait())
+
+    # The new token: position `length - 1`, in the last live page.  A
+    # position outside the table or a length of 0 writes nothing.
+    new_pos = length_ref[b] - 1
+    new_page = new_pos // page_size
+    puts = jnp.logical_and(new_pos >= 0, new_pos < table_tokens)
+
+    def new_page_back(slot, j):
+        return pltpu.make_async_copy(
+            buf.at[slot, j],
+            pool.at[page_table_ref[b * table_pages + new_page]], sems.at[2])
+
+    @pl.when(b == 0)
+    def _first():
+        # pages past a length are never copied and their weights are 0
+        # exactly; what those multiply must still be finite
+        buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = 0
+        start(0, 0, 0)
+
+    first_slot = slot_ref[0]
+    m_scratch[...] = jnp.full_like(m_scratch, _NEG_INF)
+    l_scratch[...] = jnp.zeros_like(l_scratch)
+    acc_scratch[...] = jnp.zeros_like(acc_scratch)
+    q = q_ref[0].astype(jnp.float32)                        # (H, W)
+
+    def block(blk, carry):
+        slot = (first_slot + blk) % 2
+        last = blk + 1 == num_blocks
+
+        @pl.when(jnp.logical_or(jnp.logical_not(last), b + 1 < num_seqs))
+        def _next():
+            start(jnp.where(last, b + 1, b), jnp.where(last, 0, blk + 1),
+                  1 - slot)
+
+        wait(b, blk, slot)
+        new_j = new_page - blk * pages_per_block
+
+        @pl.when(jnp.logical_and(last, puts))
+        def _put():
+            here = jax.lax.broadcasted_iota(
+                jnp.int32, buf.shape[-2:], 0) == new_pos % page_size
+            buf[slot, new_j] = jnp.where(here, new_ref[0], buf[slot, new_j])
+            new_page_back(slot, new_j).start()
+
+        rows = buf[slot].reshape(block_tokens, -1)          # (tokens, W)
+        s = _against_rows(q, rows, 1) * sm_scale            # (H, tokens)
+        token_idx = blk * block_tokens + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        s = jnp.where(token_idx < length, s, _NEG_INF)
+        m_prev = m_scratch[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        pv = _against_rows(p, rows[:, :d_value], 0)         # (H, d_value)
+        m_scratch[...] = m_new
+        l_scratch[...] = alpha * l_scratch[...] + jnp.sum(
+            p, axis=-1, keepdims=True)
+        acc_scratch[...] = acc_scratch[...] * alpha + pv
+
+        # before the slot is filled again, and before the call ends
+        @pl.when(jnp.logical_and(last, puts))
+        def _put_done():
+            new_page_back(slot, new_j).wait()
+        return carry
+
+    jax.lax.fori_loop(0, num_blocks, block, None)
+
+    @pl.when(jnp.logical_and(num_blocks == 0, b + 1 < num_seqs))
+    def _empty():
+        start(b + 1, 0, first_slot)
+
+    slot_ref[0] = (first_slot + num_blocks) % 2
+    o_ref[0] = _normalized(l_scratch[...],
+                           acc_scratch[...]).astype(o_ref.dtype)
+
+
+def paged_latent_attention_batch(q, pool, page_tables, lengths, new, *,
+                                 d_value: int, sm_scale: float):
+    """Batched single-token decode attention of a latent-attention layer
+    over its paged latent cache; the call writes the step's rows first.
+
+    q:           (B, H, W) float32: each head's absorbed query, laid out
+                 as a cached row is (zeros where the row is padding)
+    pool:        (P, page_size, W) the layer's ONE pool, shared by all
+                 heads: a page is one contiguous page_size x W block
+    page_tables: (B, NP) int32; lengths: (B,) int32, the current token
+                 counted (a row of length 0 returns zeros and writes
+                 nothing), as `paged_decode_attention_batch` reads them
+    new:         (B, W) the current tokens' rows, written to page
+                 `page_tables[b, (lengths[b] - 1) // page_size]` before the
+                 call attends (that page must be sequence b's own)
+    Returns (out (B, H, d_value) float32: the softmax over the rows' first
+    `d_value` columns, the pool updated in place: donate it)."""
+    _, page_size, W = pool.shape
+    return _paged_latent_call(
+        q, pool, page_tables, lengths, new.astype(pool.dtype)[:, None],
+        d_value=d_value, sm_scale=sm_scale,
+        pages_per_block=max(1, min(
+            page_tables.shape[1], _LATENT_VMEM_BYTES
+            // (2 * page_size * W * pool.dtype.itemsize))),
+        interpret=_interpret_mode())
+
+
+# (jit: the layers of a model share one lowering, as the kernel above)
+@functools.partial(jax.jit, static_argnames=(
+    "d_value", "sm_scale", "pages_per_block", "interpret"))
+def _paged_latent_call(q, pool, page_tables, lengths, new, *, d_value: int,
+                       sm_scale: float, pages_per_block: int,
+                       interpret: bool):
+    B, H, W = q.shape
+    _, page_size, _ = pool.shape
+    row = lambda *shape: pl.BlockSpec(  # noqa: E731
+        (1, *shape), lambda b, ln, pt: (b, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out, pool = pl.pallas_call(
+        functools.partial(_paged_latent_kernel, page_size=page_size,
+                          pages_per_block=pages_per_block,
+                          table_pages=page_tables.shape[1],
+                          d_value=d_value, sm_scale=sm_scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[row(H, W), row(1, W), in_hbm],
+            out_specs=[row(H, d_value), in_hbm],
+            scratch_shapes=[
+                pltpu.VMEM((2, pages_per_block, page_size, W), pool.dtype),
+                # (the two slots' reads and the new page's write)
+                pltpu.SemaphoreType.DMA((3,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, d_value), jnp.float32),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((B, H, d_value), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        # (operands count from the scalar-prefetched two)
+        input_output_aliases={4: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(lengths.astype(jnp.int32), page_tables.astype(jnp.int32).reshape(-1),
+      q, new, pool)
+    return out, pool
+
+
 class PageAllocator:
     """Host-side free-list allocator for KV pool pages (one per engine).
 

@@ -76,6 +76,7 @@ from ray_tpu.models.granite_hybrid import (GraniteHybridConfig,
 from ray_tpu.models.lfm2_moe import Lfm2MoeConfig, Lfm2MoeModel
 from ray_tpu.models.llama import (FRESH_KV, LlamaConfig, LlamaModel,
                                   PagedKVCache)
+from ray_tpu.models.mla_moe import MlaMoeConfig, MlaMoeModel
 from ray_tpu.models.sambay import SambaYConfig, SambaYModel
 
 # Rows of the batched prefill program (fewer where the slots are fewer).
@@ -377,9 +378,72 @@ class Lfm2MoeServing:
         return logits, state, counts[:3]
 
 
+class MlaMoeServing:
+    """`models/mla_moe.py`: ONE pool a layer of latent rows, (pages, page,
+    `latent_row`), shared by every head and under one table row a sequence;
+    nothing fixed per slot, so a step may be run again.  A token holds
+    `latent_dim` values a layer (576: 1,152 bytes in bfloat16, a fourteenth
+    of sixteen K/V heads of 128) in a row padded to whole lanes (640)."""
+
+    rewinds = True
+    portable_kv = False     # (a hand-over carries (k, v) pairs a layer)
+    pool_readers = 1
+    state_bytes_per_slot = 0
+    # `models/lfm2_moe.EXPERT_COUNTS` as `Lfm2MoeServing` names them, and
+    # what the step's latent kernels read: the tokens resident under its
+    # occupied rows (one layer's; summed over a chunk's steps)
+    step_counters = (("experts_touched", "sum"), ("expert_slots", "sum"),
+                     ("expert_rows_max", "max"), ("latent_tokens", "sum"))
+    prefill_counters = (("expert_rows_max", "max"), ("expert_rows", "sum"))
+
+    def __init__(self, cfg: MlaMoeConfig, max_len: int):
+        self.cfg, self.max_len = cfg, max_len
+        self.model = MlaMoeModel(cfg)
+
+    def ring_tokens(self, lens) -> int:
+        return 0
+
+    def prefill_width(self, bucket: int, max_batch: int) -> int:
+        # A token is six (row, expert) pairs of two terms each in the
+        # grouped products: 8,192 tokens a dispatch, as `Lfm2MoeServing`.
+        return _rows_under_the_token_cap(bucket, max_batch,
+                                         _PREFILL_TOKENS // 2)
+
+    def prompt_pages(self, bucket: int, page_size: int) -> int:
+        return bucket // page_size
+
+    def init_state(self, max_batch: int, num_pages: int, page_size: int):
+        c = self.cfg
+        # (every leaf a buffer of its own: the state is donated)
+        return [jnp.zeros((num_pages, page_size, c.latent_row), c.dtype)
+                for _ in range(c.n_layers)]
+
+    def prefill(self, params, tokens, last_idx):
+        logits, rows, counts = self.model.apply(
+            params, tokens, last_idx, method=MlaMoeModel.prefill)
+        return logits, rows, counts[2:]
+
+    def write_prompt(self, pools, fresh, slots, page_ids):
+        # rows (W, L, row) cut into pages, in the order of a flattened
+        # (W, L / page): padding names the dummy page
+        flat = page_ids.reshape(-1)
+        page = pools[0].shape[1:]
+        return [pool.at[flat].set(rows.reshape(-1, *page))
+                for pool, rows in zip(pools, fresh)]
+
+    def decode(self, params, token, pos, pools, tables, lens, live):
+        occupied = lens > 0 if live is None else live
+        logits, pools, counts = self.model.apply(
+            params, token, pos, pools, tables, lens, occupied,
+            method=MlaMoeModel.decode)
+        read = jnp.sum(jnp.where(occupied, lens + 1, 0))
+        return logits, pools, jnp.concatenate(
+            [counts[:3], read.astype(jnp.int32)[None]])
+
+
 _FAMILIES = {LlamaConfig: LlamaServing, SambaYConfig: SambaYServing,
              GraniteHybridConfig: GraniteHybridServing,
-             Lfm2MoeConfig: Lfm2MoeServing}
+             Lfm2MoeConfig: Lfm2MoeServing, MlaMoeConfig: MlaMoeServing}
 
 
 def family_of(cfg, max_len: int):

@@ -7,7 +7,7 @@ interpret-mode path on CPU).  Produces:
   2. a (block_q, block_k) timing sweep of flash fwd+bwd at the bench
      shape (B2 H16 S2048 D128, causal, bf16).
 
-Usage: python scripts/tpu_kernel_sweep.py [--sweep-only|--check-only]
+Usage: python scripts/tpu_kernel_sweep.py [--sweep-only|--check-only|--latent]
 """
 
 from __future__ import annotations
@@ -205,12 +205,103 @@ def sweep_flash():
                           "best": best, "all": results}))
 
 
+def _latent_case(lengths, page: int, npages_seq: int, H: int = 16,
+                 W: int = 640, seed: int = 0):
+    """A pool of latent rows (zeros past the 576 cached values, as the
+    model writes them), scattered tables, queries and the step's rows."""
+    B = len(lengths)
+    pool_pages = B * npages_seq + 1
+    rng = np.random.default_rng(seed)
+    pool = rng.standard_normal((pool_pages, page, W)).astype(np.float32)
+    pool[..., 576:] = 0.0
+    free = list(1 + rng.permutation(pool_pages - 1))
+    tables = np.zeros((B, npages_seq), np.int32)
+    for b in range(B):
+        for p in range((int(lengths[b]) + page - 1) // page):
+            tables[b, p] = free.pop()
+    q = rng.standard_normal((B, H, W)).astype(np.float32) * 0.3
+    new = rng.standard_normal((B, W)).astype(np.float32)
+    q[..., 576:] = 0.0
+    new[..., 576:] = 0.0
+    return (jnp.asarray(q), jnp.asarray(pool, jnp.bfloat16),
+            jnp.asarray(tables), jnp.asarray(np.asarray(lengths, np.int32)),
+            jnp.asarray(new, jnp.bfloat16))
+
+
+def check_latent(page: int = 64, npages_seq: int = 37,
+                 lengths=(0, 1, 64, 65, 0, 700, 37 * 64, 0)):
+    """The latent-page kernel on the chip against numpy over gathered
+    pages: the pool it returns is the pool with the step's rows scattered
+    in, bit for bit, and its output the softmax over those rows."""
+    from ray_tpu.ops.paged_attention import paged_latent_attention_batch
+    q, pool, tables, lens, new = _latent_case(lengths, page, npages_seq)
+    scale = 192 ** -0.5
+    out, got = paged_latent_attention_batch(q, pool, tables, lens, new,
+                                            d_value=512, sm_scale=scale)
+    lengths = np.asarray(lengths)
+    at = np.maximum(lengths - 1, 0)
+    rows = np.flatnonzero(lengths > 0)
+    pages = np.asarray(tables)[rows, at[rows] // page]
+    want = pool.at[pages, at[rows] % page].set(new[rows])
+    bits = lambda a: np.asarray(a).view(np.uint16)  # noqa: E731
+    pool_equal = bool((bits(got) == bits(want)).all())
+    err = 0.0
+    for b in rows:
+        L = int(lengths[b])
+        kb = np.concatenate([np.asarray(want[tables[b, p]], np.float32)
+                             for p in range(-(-L // page))], 0)[:L]
+        s = np.asarray(q[b]) @ kb.T * scale
+        p_ = np.exp(s - s.max(-1, keepdims=True))
+        p_ /= p_.sum(-1, keepdims=True)
+        err = max(err, float(np.abs(np.asarray(out[b])
+                                    - p_ @ kb[:, :512]).max()))
+    empty = float(np.abs(np.asarray(out)[lengths == 0]).max(initial=0.0))
+    ok = pool_equal and err < 2e-3 and empty == 0.0
+    print(json.dumps({"check": "paged_latent_onchip", "page": page,
+                      "lengths": lengths.tolist(),
+                      "max_abs_err": err, "empty_rows_max": empty,
+                      "written_pool_bit_equal": pool_equal, "ok": ok}))
+    return ok
+
+
+def time_latent(batch: int = 64, page: int = 64):
+    """The latent-page kernel alone at 64 rows of 2k, 4k and 8k tokens
+    against its least time (1,152 bytes a token read once; 16 heads x
+    (576 + 512) x 2 operations a token), the chip's published peaks."""
+    from ray_tpu.ops.paged_attention import paged_latent_attention_batch
+    for tokens in (2048, 4096, 8192):
+        q, pool, tables, lens, new = _latent_case(
+            [tokens] * batch, page, tokens // page + 1)
+        f = jax.jit(functools.partial(paged_latent_attention_batch,
+                                      d_value=512, sm_scale=192 ** -0.5),
+                    donate_argnums=(1,))
+        out, pool = f(q, pool, tables, lens, new)
+        _sync(out)
+        t0 = time.perf_counter()
+        n = 20
+        for _ in range(n):
+            out, pool = f(q, pool, tables, lens, new)
+        _sync(out)
+        ms = (time.perf_counter() - t0) / n * 1e3
+        resident = batch * tokens
+        least_ms = max(resident * 1152 / 819e9,
+                       resident * 16 * (576 + 512) * 2 / 197e12) * 1e3
+        print(json.dumps({"time": "paged_latent", "rows": batch,
+                          "tokens_a_row": tokens, "ms": round(ms, 4),
+                          "least_ms": round(least_ms, 4),
+                          "roofline_share": round(least_ms / ms, 4)}))
+
+
 def main():
     if jax.default_backend() != "tpu":
         sys.exit(f"tpu_kernel_sweep: an on-chip script, and the JAX backend "
                  f"here is {jax.default_backend()!r} (tests cover the CPU)")
     mode = sys.argv[1] if len(sys.argv) > 1 else ""
     ok = True
+    if mode == "--latent":      # the latent-page kernel alone, and its time
+        ok = check_latent() and check_latent(lengths=(5, 128, 1000, 64))
+        time_latent()
+        sys.exit(0 if ok else 1)
     if mode != "--sweep-only":
         ok = check_flash() and ok
         ok = check_paged(Hkv=8) and ok   # MHA
@@ -220,6 +311,7 @@ def main():
         # last block, a full table, and empty slots first, between, last.
         ok = check_paged(Hkv=2, page=64, npages_seq=37,
                          lengths=(0, 1, 64, 65, 0, 700, 37 * 64, 0)) and ok
+        ok = check_latent() and ok
     if mode != "--check-only":
         sweep_flash()
     sys.exit(0 if ok else 1)

@@ -87,7 +87,10 @@ def ray_start_cluster_head():
 # cell, `configs` up to its configuration, and a metric's `workloads` up to
 # that cell's name. (`test_lfm2_moe_cell.py` looks every entry up by name
 # and compares what a cell reports as a superset: the next cell needs no
-# such fixture.) The `benchmark` PR that makes the two old modules do the
+# such fixture. PR 45 appended a configuration, a cell, five entries and
+# that cell's name to eleven `workloads` lists, all AFTER what is cut here:
+# the three modules see the benchmark as before, and `test_mla_moe_cell.py`
+# looks its entries up by name too.) The `benchmark` PR that makes the two old modules do the
 # same deletes this with that conftest's fixture.
 # module -> (the newest per-layer entry it knows, the newest cell it knows:
 # None = the module's own CELL)

@@ -12,6 +12,7 @@ Everything that touches the topology happens inside fixtures and tests
 the TPU library.  Keep these cases in this one file.
 """
 
+import functools
 import os
 import re
 import signal
@@ -728,6 +729,107 @@ def test_lfm2_moe_engine_programs_fit_the_chip(one_chip, monkeypatch):
         resident = _peak_bytes(prefill) / 1e9 + gb(eng._pools)
         assert resident == pytest.approx(
             recorded["prefill_many_2x4096_gb"]["peak_with_state_resident"],
+            abs=0.05)
+        assert resident * 1e9 < 15.75e9 < HBM_BYTES
+    finally:
+        eng.shutdown()
+
+
+# ---- the latent-attention family at the sizes of `kimivl-serve-pages-closed`
+
+
+@pytest.mark.parametrize("control", [
+    None, "kernel_query_and_weights_in_one_bf16_term"])
+def test_latent_kernel_at_the_cells_shapes(one_chip, control):
+    """The latent-page kernel at the cell's shapes (64 rows, a table of
+    146 pages, a pool of 5,633 pages of 64 rows of 640): Mosaic takes it,
+    and the pool is aliased from input to output.  Also with the control
+    that `benchmarks/tools/mla_moe_faults.py` plants in it on the chip
+    (the query and the softmax's weights in one bfloat16 term)."""
+    from benchmarks.tools.mla_moe_faults import planted
+
+    S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    pool = S((5633, 64, 640), jnp.bfloat16)
+    with planted(control):
+        compiled = jax.jit(
+            functools.partial(paged_attention.paged_latent_attention_batch,
+                              d_value=512, sm_scale=192 ** -0.5),
+            donate_argnums=(1,)).lower(
+                S((64, 16, 640), jnp.float32), pool, S((64, 146), jnp.int32),
+                S((64,), jnp.int32), S((64, 640), jnp.bfloat16)).compile()
+    assert compiled.as_text().count(KERNEL) == 1
+    m = compiled.memory_analysis()
+    pool_bytes = 5633 * 64 * 640 * 2
+    assert m.alias_size_in_bytes >= pool_bytes
+    assert m.temp_size_in_bytes < pool_bytes // 10
+
+
+@pytest.mark.time_limit(600)   # two programs of 7 layers: 75 s alone here
+def test_mla_moe_engine_programs_fit_the_chip(one_chip, monkeypatch):
+    """The cell's engine at published widths, built from the configuration
+    file: the decode chunk (7 latent calls and 12 grouped products a step:
+    19 Pallas calls; no pool copied or moved) and the largest prefill (one
+    row of 8,192 tokens) hold the bytes the file's `memory` records,
+    beside 8.53 GB of weights and 3.23 GB of latent pages."""
+    import json
+
+    from benchmarks.families import mla_moe as family
+    from ray_tpu.models import mla_moe
+    from ray_tpu.ops import grouped_matmul
+    from ray_tpu.serve.llm import LLMEngine
+
+    monkeypatch.setattr(grouped_matmul, "_interpret_mode", lambda: False)
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "kimi-vl-a3b-l7.json")) as f:
+        conf = json.load(f)
+    cfg = family.program_config(family.sizes(conf))
+    params = jax.eval_shape(
+        lambda: mla_moe.MlaMoeModel(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    engine = conf["serve"]["engine"]
+    # (the engine's own pools are made on this machine's CPU: a page a
+    # slot here, the cell's 5,633 pages as shapes below)
+    eng = LLMEngine(cfg, params, **dict(engine, kv_pool_tokens=64 * 64))
+    try:
+        recorded = conf["memory"]
+        S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=one_chip)
+        gb = lambda tree: sum(  # noqa: E731
+            int(np.prod(x.shape)) * x.dtype.itemsize
+            for x in jax.tree_util.tree_leaves(tree)) / 1e9
+        pools = jax.eval_shape(lambda: eng.family.init_state(
+            engine["max_batch"],
+            engine["kv_pool_tokens"] // engine["page_size"] + 1,
+            engine["page_size"]))
+        assert gb(params) == pytest.approx(recorded["weights_gb"], abs=1e-3)
+        assert gb(pools) == pytest.approx(recorded["state_gb"]["all"],
+                                          abs=1e-3)
+        assert eng.family.state_bytes_per_slot == 0
+        B = eng.max_batch
+        decode = eng._decode_chunk_paged.lower(
+            _on(one_chip, params), S((B,), jnp.int32), S((B,), jnp.int32),
+            _on(one_chip, pools), S(eng._tables.shape, jnp.int32),
+            S((B,), jnp.int32), S((B,), jnp.float32), S((B,), jnp.int32),
+            S((B,), jnp.float32), S((2,), jnp.uint32),
+            S((), jnp.int32)).compile()
+        text = decode.as_text()
+        assert text.count(KERNEL) == \
+            recorded["decode_chunk_paged_gb"]["pallas_calls"] == 19
+        assert chip_smoke.state_moves(text, pools) == {
+            "loop": _NOTHING, "outside": _NOTHING}
+        assert _peak_bytes(decode) / 1e9 == pytest.approx(
+            recorded["decode_chunk_paged_gb"]["peak_with_weights_and_state"],
+            abs=0.05)
+        assert eng.family.prefill_width(8192, B) == 1
+        prefill = eng._prefill_one.lower(
+            _on(one_chip, params), S((1, 8192), jnp.int32),
+            S((1,), jnp.int32)).compile()
+        assert prefill.as_text().count(KERNEL) == 19
+        resident = _peak_bytes(prefill) / 1e9 + gb(pools)
+        assert resident == pytest.approx(
+            recorded["prefill_one_8192_gb"]["peak_with_state_resident"],
             abs=0.05)
         assert resident * 1e9 < 15.75e9 < HBM_BYTES
     finally:
