@@ -7,7 +7,13 @@ interpret-mode path on CPU).  Produces:
   2. a (block_q, block_k) timing sweep of flash fwd+bwd at the bench
      shape (B2 H16 S2048 D128, causal, bf16).
 
-Usage: python scripts/tpu_kernel_sweep.py [--sweep-only|--check-only|--latent]
+  3. `--gmm`: the grouped product (`ops/grouped_matmul.py`) at the two
+     routed cells' own shapes over row and column tiles, the custom
+     call's DEVICE time read from a profiler trace, against its least
+     time: the table `_tiles` rests on (PERF.md, PR 46).
+
+Usage: python scripts/tpu_kernel_sweep.py
+           [--sweep-only|--check-only|--latent|--gmm [family ...]]
 """
 
 from __future__ import annotations
@@ -24,7 +30,10 @@ import numpy as np
 
 # `python3 scripts/tpu_kernel_sweep.py` from the root of a checkout: the
 # package is not installed, and sys.path[0] is scripts/.
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO)
+
+PEAK_FLOPS, PEAK_BYTES = 197e12, 819e9      # Google Cloud, "TPU v5e"
 
 
 def _sync(x):
@@ -284,12 +293,226 @@ def time_latent(batch: int = 64, page: int = 64):
         _sync(out)
         ms = (time.perf_counter() - t0) / n * 1e3
         resident = batch * tokens
-        least_ms = max(resident * 1152 / 819e9,
-                       resident * 16 * (576 + 512) * 2 / 197e12) * 1e3
+        least_ms = max(resident * 1152 / PEAK_BYTES,
+                       resident * 16 * (576 + 512) * 2 / PEAK_FLOPS) * 1e3
         print(json.dumps({"time": "paged_latent", "rows": batch,
                           "tokens_a_row": tokens, "ms": round(ms, 4),
                           "least_ms": round(least_ms, 4),
                           "roofline_share": round(least_ms / ms, 4)}))
+
+
+# ---- the grouped product: tiles against device time ------------------------
+
+GMM_ROW_TILES = (32, 64, 128, 256, 512)
+GMM_REPS = 5
+# family -> (configuration file, its costs, its key for the experts held,
+# experts a decode step touches in the cell (ledger, PRs 42 and 45),
+# prompt tokens: short, median and long prompts of the cell's mix)
+GMM_FAMILIES = {
+    "mla_moe": ("kimi-vl-a3b-l7.json", "mla_moe_costs", "n_routed_experts",
+                63, (512, 2048, 8192)),
+    "lfm2_moe": ("lfm2-24b-a2b-l9.json", "lfm2_moe_costs", "num_experts",
+                 40, (128, 256, 512, 1024, 4096)),
+}
+
+
+def _even_sizes(pairs: int, groups: int, touched: int):
+    """Rows of each group, two a (row, expert) pair, of a decode step: its
+    pairs spread evenly over `touched` groups that lie evenly among all."""
+    at = np.linspace(0, groups - 1, touched).round().astype(int)
+    sizes = np.zeros(groups, np.int32)
+    sizes[at] = pairs // touched
+    sizes[at[: pairs % touched]] += 1
+    return 2 * sizes
+
+
+def _routed_sizes(tokens: int, top_k: int, groups: int, rng):
+    """The same of a prompt: each token draws distinct experts at random."""
+    sizes = np.zeros(groups, np.int32)
+    for _ in range(tokens):
+        sizes[rng.choice(groups, top_k, replace=False)] += 1
+    return 2 * sizes
+
+
+def _gmm_cases(family: str):
+    """(label, group sizes, tokens) of the family's decode step and
+    prompts, and its (k, n) of W1|W3 and of W2."""
+    name, costs, experts_key, touched, prompts = GMM_FAMILIES[family]
+    with open(os.path.join(_REPO, "benchmarks", "configs", name)) as f:
+        conf = json.load(f)
+    E, top_k = conf[experts_key], conf["num_experts_per_tok"]
+    d, f_ = conf["hidden_size"], conf["moe_intermediate_size"]
+    batch = conf["serve"]["engine"]["max_batch"]
+    rng = np.random.default_rng(0)
+    cases = [(f"decode {batch}", _even_sizes(batch * top_k, E, touched),
+              batch)]
+    cases += [(f"prompt {t}", _routed_sizes(t, top_k, E, rng), t)
+              for t in prompts]
+    return conf, costs, cases, (("w13", d, 2 * f_), ("w2", f_, d))
+
+
+def _gmm_cost(pairs: int, touched: int, k: int, n: int) -> tuple:
+    """One product's part of `grouped_product_cost` -> (operations,
+    bytes): two operations a pair and parameter; each touched group's
+    matrix once, each pair's row in and out in float32."""
+    return 2.0 * pairs * k * n, touched * k * n * 2 + pairs * 4.0 * (k + n)
+
+
+def _gmm_tilings(k: int, n: int):
+    """Row tiles x column tiles: 512 where it divides the columns and
+    1,024 beside it; for 2,816 columns 256 (PR 45's), 128 and 1,408 (11 x
+    128; the contraction whole and in two)."""
+    if n % 512:
+        cols = [(256, k), (128, k), (n // 2, k), (n // 2, k // 2)]
+    else:
+        cols = [(512, k), (1024, k)]
+    return [(tm, tk, tn) for tn, tk in cols for tm in GMM_ROW_TILES]
+
+
+def _device_ms(trace_dir: str) -> dict:
+    """{program: [ms of its Pallas calls, a run each]} from the xplane a
+    profiler session left: the line `XLA Modules` holds the runs of a
+    jitted function, `XLA Ops` its operations, a kernel by its target."""
+    import glob
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/device:TPU:"):
+            continue
+        lines = {ln.name: ln for ln in plane.lines}
+        runs = sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                      for e in lines["XLA Modules"].events)
+        calls = sorted((e.start_ns, e.duration_ns)
+                       for e in lines["XLA Ops"].events
+                       if 'custom_call_target="tpu_custom_call"' in e.name)
+        for t0, t1, name in runs:
+            ms = sum(d for s, d in calls if t0 <= s < t1) / 1e6
+            name = name.split("(")[0].removeprefix("jit_")
+            out.setdefault(name, []).append(ms)
+    return out
+
+
+def sweep_gmm(families):
+    """One line a (family, case, product, tiling): the median device time
+    of `megablox.gmm` alone against the product's least time, its share of
+    `grouped_product_cost` (the two products' shares add up to it), and
+    whether the rows of groups came out bit for bit as at the first
+    tiling (32 rows, 512 columns or 256, the contraction whole)."""
+    import importlib
+    import tempfile
+
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    device = jax.devices()[0].device_kind
+    lines = []
+    out_dir = os.path.join(_REPO, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    record = open(os.path.join(out_dir, "gmm_sweep.jsonl"), "w")
+
+    def emit(line):
+        lines.append(line)
+        record.write(json.dumps(line) + "\n")
+        record.flush()
+        print(json.dumps(line), flush=True)
+
+    for family in families:
+        conf, costs, cases, products = _gmm_cases(family)
+        costs = importlib.import_module("benchmarks.layer_metrics." + costs)
+        for which, k, n in products:
+            for label, sizes, _ in cases:
+                E, rows = len(sizes), int(sizes.sum())
+                touched = int((sizes > 0).sum())
+                flops, nbytes = _gmm_cost(rows // 2, touched, k, n)
+                assert np.allclose(
+                    np.sum([_gmm_cost(rows // 2, touched, a, b)
+                            for _, a, b in products], axis=0),
+                    costs.grouped_product_cost(conf, rows // 2, touched))
+                least = max(flops / PEAK_FLOPS, nbytes / PEAK_BYTES) * 1e3
+                args = (jax.random.normal(jax.random.PRNGKey(2), (rows, k),
+                                          jnp.bfloat16),
+                        jax.random.normal(jax.random.PRNGKey(1), (E, k, n),
+                                          jnp.bfloat16) * 0.02,
+                        jnp.asarray(sizes))
+                said = {"family": family, "case": label, "product": which}
+                fns, same, first = {}, {}, None
+                for tiles in _gmm_tilings(k, n):
+                    def run(x, w, sizes, tiles=tiles):
+                        return gmm(jnp.pad(x, ((0, -x.shape[0] % tiles[0]),
+                                               (0, 0))),
+                                   w, sizes, jnp.float32, tiles)[: x.shape[0]]
+                    run.__name__ = "gmm_%d_%d_%d" % tiles
+                    f = jax.jit(run)
+                    try:
+                        got = f(*args)
+                        first = got if first is None else first
+                        same[tiles] = bool(jnp.array_equal(got, first))
+                        fns[tiles] = f
+                    except Exception as e:  # noqa: BLE001 — record, go on
+                        emit({**said, "tiles": list(tiles),
+                              "error": str(e).splitlines()[0][:160]})
+                first = got = None
+                with tempfile.TemporaryDirectory() as trace_dir:
+                    with jax.profiler.trace(trace_dir):
+                        for f in fns.values():
+                            for _ in range(GMM_REPS):
+                                got = f(*args)      # (one result held)
+                            _sync(got)
+                    ms = _device_ms(trace_dir)
+                got = None
+                for tiles in fns:
+                    runs = sorted(ms.get("gmm_%d_%d_%d" % tiles, []))
+                    if not runs:
+                        emit({**said, "tiles": list(tiles),
+                              "error": f"no run in the trace: {sorted(ms)}"})
+                        continue
+                    median = runs[len(runs) // 2]
+                    emit({**said, "rows": rows, "groups": E, "k": k, "n": n,
+                          "touched": touched,
+                          "rows_a_group": round(rows / E, 1),
+                          "tiles": list(tiles),
+                          "same_bits_as_the_first": same[tiles],
+                          "ms": round(median, 4),
+                          "least_ms": round(least, 4),
+                          "bound": "bytes" if nbytes / PEAK_BYTES
+                          > flops / PEAK_FLOPS else "operations",
+                          "share": round(least / median, 4),
+                          "device": device})
+    record.close()
+    table = _gmm_table(lines)
+    with open(os.path.join(out_dir, "gmm_sweep.md"), "w") as f:
+        f.write(table + "\n")
+    print(table)
+
+
+def _gmm_table(lines) -> str:
+    """The sweep as a table: a row a (family, case, product, column tile),
+    a column a row tile, `ms (share of the least time)`; in bold what
+    `_tiles` picks, `!` where the bits differ from the first tiling's."""
+    from ray_tpu.ops.grouped_matmul import _tiles
+
+    def cell(ln):
+        text = f"{ln['ms']:.3f} ({100 * ln['share']:.0f}%)" \
+            + ("" if ln["same_bits_as_the_first"] else " !")
+        picked = _tiles(ln["rows"], ln["groups"], ln["k"], ln["n"])
+        return f"**{text}**" if list(picked) == ln["tiles"] else text
+
+    rows = {}
+    for ln in (ln for ln in lines if "ms" in ln):
+        tm, tk, tn = ln["tiles"]
+        key = (ln["family"], ln["case"], ln["product"], tn, tk)
+        rows.setdefault(key, {"rows_a_group": ln["rows_a_group"],
+                              "least_ms": ln["least_ms"]})[tm] = ln
+    head = ["family", "case", "product", "rows a group", "cols x k tile",
+            "least ms"] + [f"rows {tm}" for tm in GMM_ROW_TILES]
+    table = ["| " + " | ".join(head) + " |",
+             "|" + " --- |" * len(head)]
+    for (family, case, which, tn, tk), r in rows.items():
+        cells = [cell(r[tm]) if tm in r else "-" for tm in GMM_ROW_TILES]
+        table.append("| " + " | ".join(
+            [family, case, which, str(r["rows_a_group"]), f"{tn} x {tk}",
+             f"{r['least_ms']:.3f}"] + cells) + " |")
+    return "\n".join(table)
 
 
 def main():
@@ -302,6 +525,9 @@ def main():
         ok = check_latent() and check_latent(lengths=(5, 128, 1000, 64))
         time_latent()
         sys.exit(0 if ok else 1)
+    if mode == "--gmm":         # the grouped product's tiles, from a trace
+        sweep_gmm(sys.argv[2:] or list(GMM_FAMILIES))
+        sys.exit(0)
     if mode != "--sweep-only":
         ok = check_flash() and ok
         ok = check_paged(Hkv=8) and ok   # MHA

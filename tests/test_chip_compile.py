@@ -679,6 +679,21 @@ def test_granite_engine_programs_fit_the_chip_and_leave_the_state(one_chip):
 # ---- the LFM2-MoE family at the sizes of `lfm2moe-serve-agents-closed` -----
 
 
+def _tiles_seen(monkeypatch) -> list:
+    """(rows, groups, tiling) of every `megablox.gmm` call made while a
+    program is traced, in order: what `ops/grouped_matmul.py` chose."""
+    from jax.experimental.pallas.ops.tpu import megablox
+
+    seen, real = [], megablox.gmm
+
+    def gmm(lhs, rhs, sizes, out_type, tiling, **kw):
+        seen.append((lhs.shape[0], rhs.shape[0], tiling))
+        return real(lhs, rhs, sizes, out_type, tiling, **kw)
+
+    monkeypatch.setattr(megablox, "gmm", gmm)
+    return seen
+
+
 @pytest.mark.time_limit(600)   # two programs of 9 layers: 60 s alone here
 def test_lfm2_moe_engine_programs_fit_the_chip(one_chip, monkeypatch):
     """The cell's engine at published widths, built from the configuration
@@ -695,6 +710,7 @@ def test_lfm2_moe_engine_programs_fit_the_chip(one_chip, monkeypatch):
     from ray_tpu.serve.llm import LLMEngine
 
     monkeypatch.setattr(grouped_matmul, "_interpret_mode", lambda: False)
+    seen = _tiles_seen(monkeypatch)
     with open(os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "benchmarks", "configs",
             "lfm2-24b-a2b-l9.json")) as f:
@@ -716,16 +732,23 @@ def test_lfm2_moe_engine_programs_fit_the_chip(one_chip, monkeypatch):
                                                abs=1e-3)
         assert eng.family.state_bytes_per_slot == \
             recorded["conv_window_bytes_per_sequence"]
+        del seen[:]         # (the engine traced its programs' shapes)
         decode = _compiled_decode_chunk(eng, params, one_chip)
         assert decode.as_text().count(KERNEL) == 18
-        assert _peak_bytes(decode) / 1e9 == pytest.approx(
-            recorded["decode_chunk_paged_gb"]["peak_with_weights_and_state"],
-            abs=0.05)
+        # 16 slots x 4 experts x 2 terms: 2 rows a group, one 128-row tile
+        assert seen == 8 * [(128, 64, (128, 2048, 512)),
+                            (128, 64, (128, 1536, 512))]
+        peak = recorded["decode_chunk_paged_gb"]["peak_with_weights_and_state"]
+        assert peak - 0.05 < _peak_bytes(decode) / 1e9 < peak + 0.005
+        del seen[:]
         assert eng.family.prefill_width(4096, eng.max_batch) == 2
         prefill = eng._prefill_many.lower(
             _on(one_chip, params), S((2, 4096), jnp.int32),
             S((2,), jnp.int32)).compile()
         assert prefill.as_text().count(KERNEL) >= 18
+        # 8,192 tokens, 1,024 rows a group: the prompt's tile
+        assert set(seen) == {(65536, 64, (256, 2048, 512)),
+                             (65536, 64, (256, 1536, 512))}
         resident = _peak_bytes(prefill) / 1e9 + gb(eng._pools)
         assert resident == pytest.approx(
             recorded["prefill_many_2x4096_gb"]["peak_with_state_resident"],
@@ -780,6 +803,7 @@ def test_mla_moe_engine_programs_fit_the_chip(one_chip, monkeypatch):
     from ray_tpu.serve.llm import LLMEngine
 
     monkeypatch.setattr(grouped_matmul, "_interpret_mode", lambda: False)
+    seen = _tiles_seen(monkeypatch)
     with open(os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "benchmarks", "configs",
             "kimi-vl-a3b-l7.json")) as f:
@@ -808,6 +832,7 @@ def test_mla_moe_engine_programs_fit_the_chip(one_chip, monkeypatch):
                                           abs=1e-3)
         assert eng.family.state_bytes_per_slot == 0
         B = eng.max_batch
+        del seen[:]         # (the engine traced its programs' shapes)
         decode = eng._decode_chunk_paged.lower(
             _on(one_chip, params), S((B,), jnp.int32), S((B,), jnp.int32),
             _on(one_chip, pools), S(eng._tables.shape, jnp.int32),
@@ -819,14 +844,22 @@ def test_mla_moe_engine_programs_fit_the_chip(one_chip, monkeypatch):
             recorded["decode_chunk_paged_gb"]["pallas_calls"] == 19
         assert chip_smoke.state_moves(text, pools) == {
             "loop": _NOTHING, "outside": _NOTHING}
-        assert _peak_bytes(decode) / 1e9 == pytest.approx(
-            recorded["decode_chunk_paged_gb"]["peak_with_weights_and_state"],
-            abs=0.05)
+        # 64 slots x 6 experts x 2 terms: 12 rows a group, 128-row tiles
+        # (tiled as a prompt until PR 46: 256 rows, W1|W3 in 256 columns)
+        assert seen == 6 * [(768, 64, (128, 2048, 1408)),
+                            (768, 64, (128, 1408, 512))]
+        peak = recorded["decode_chunk_paged_gb"]["peak_with_weights_and_state"]
+        assert peak - 0.05 < _peak_bytes(decode) / 1e9 < peak + 0.005
+        del seen[:]
         assert eng.family.prefill_width(8192, B) == 1
         prefill = eng._prefill_one.lower(
             _on(one_chip, params), S((1, 8192), jnp.int32),
             S((1,), jnp.int32)).compile()
         assert prefill.as_text().count(KERNEL) == 19
+        # 8,192 tokens, 1,536 rows a group: the prompt's tiles (in 128-row
+        # tiles this program holds 1.1 GB more: 15.34 GB with the state)
+        assert set(seen) == {(98304, 64, (256, 2048, 256)),
+                             (98304, 64, (256, 1408, 512))}
         resident = _peak_bytes(prefill) / 1e9 + gb(pools)
         assert resident == pytest.approx(
             recorded["prefill_one_8192_gb"]["peak_with_state_resident"],

@@ -339,13 +339,102 @@ def test_route_takes_each_familys_constant():
     assert float(fine.sum()) == pytest.approx(1.0, abs=5e-3)
 
 
-@pytest.mark.parametrize("n, cols", [(2816, 256), (3072, 512), (2048, 512),
-                                     (64, 64)])
-def test_grouped_product_tiles_divide_the_columns(n, cols):
+# (rows, groups, k, n) of a call as the two routed cells make it (two rows
+# a (token, expert) pair: 6 pairs a token in `kimivl-serve-pages-closed`,
+# 4 in `lfm2moe-serve-agents-closed`; 64 groups) -> (row tile, column tile).
+_KIMI_W13, _KIMI_W2 = (2048, 2816), (1408, 2048)
+_LFM2_W13, _LFM2_W2 = (2048, 3072), (1536, 2048)
+_CALLS = {
+    "kimi decode w13": ((64 * 12, 64) + _KIMI_W13, (128, 1408)),
+    "kimi decode w2": ((64 * 12, 64) + _KIMI_W2, (128, 512)),
+    "kimi prompt 512 w13": ((512 * 12, 64) + _KIMI_W13, (256, 256)),
+    "kimi prompt 2048 w13": ((2048 * 12, 64) + _KIMI_W13, (256, 256)),
+    "kimi prompt 8192 w2": ((8192 * 12, 64) + _KIMI_W2, (256, 512)),
+    "lfm2 decode w13": ((16 * 8, 64) + _LFM2_W13, (128, 512)),
+    "lfm2 decode w2": ((16 * 8, 64) + _LFM2_W2, (128, 512)),
+    "lfm2 prompt 128 w13": ((128 * 8, 64) + _LFM2_W13, (128, 512)),
+    "lfm2 prompt 512 w2": ((512 * 8, 64) + _LFM2_W2, (128, 512)),
+    "lfm2 prompt 1024 w13": ((1024 * 8, 64) + _LFM2_W13, (256, 512)),
+    "lfm2 prompt 4096 w2": ((4096 * 8, 64) + _LFM2_W2, (256, 512)),
+    "a tiny model": ((16, 8, 64, 64), (128, 64)),
+    "half a matrix too large for VMEM": ((768, 64, 4096, 2816), (128, 256)),
+}
+
+
+@pytest.mark.parametrize("call", _CALLS)
+def test_grouped_product_tiles_follow_the_rows_a_group_holds(call):
+    """The row tile is chosen by the rows a GROUP can hold (the call's rows
+    over its groups), not by the rows of the call: a decode step of 64
+    slots (768 rows, 12 a group) is tiled as one of 16 (128 rows, 2 a
+    group), a prompt from 96 rows a group up as before.  The contraction
+    stays whole and the column tile divides the columns: 512, or where 512
+    does not divide them a multiple of 128 that does, half of them beside
+    the small row tile."""
     from ray_tpu.ops.grouped_matmul import _tiles
 
-    assert _tiles(768, 2048, n) == (256, 2048, cols)
-    assert _tiles(16, 2048, n)[0] == 128
+    (rows, groups, k, n), (row_tile, col_tile) = _CALLS[call]
+    assert _tiles(rows, groups, k, n) == (row_tile, k, col_tile)
+    assert n % col_tile == 0 and (col_tile % 128 == 0 or col_tile == n)
+
+
+def test_a_rows_product_does_not_depend_on_its_tile(monkeypatch):
+    """With the contraction whole, a row's result is the same bits at
+    every tile: groups of 0, 1, 12 and 300 rows and rows past the last
+    group, at the row tile a decode step of 64 slots took before PR 46, at
+    smaller ones and at half the column tile."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import grouped_matmul as gm
+
+    sizes = np.asarray([0, 1, 12, 300], np.int32)
+    held, rows, k, n = int(sizes.sum()), 320, 64, 256
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.standard_normal((rows, k)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((4, k, n)) * 0.1, jnp.bfloat16)
+
+    def at(row_tile, cols=n):
+        monkeypatch.setattr(gm, "_tiles", lambda *a: (row_tile, k, cols))
+        return np.asarray(gm.grouped_matmul(x, w, jnp.asarray(sizes)))[:held]
+
+    old = at(256)
+    group = np.repeat(np.arange(4), sizes)
+    plain = np.einsum("rk,rkn->rn", np.asarray(x, np.float32)[:held],
+                      np.asarray(w, np.float32)[group])
+    assert np.abs(old - plain).max() < 1e-4
+    for tiles in ((128,), (64,), (32,), (128, n // 2)):
+        assert (at(*tiles).view(np.uint32) == old.view(np.uint32)).all()
+
+
+@pytest.mark.parametrize("family, decode_call, touched", [
+    ("mla_moe", "kimi decode w13", 63), ("lfm2_moe", "lfm2 decode w13", 40)])
+def test_the_sweep_runs_the_cells_own_shapes(family, decode_call, touched):
+    """`scripts/tpu_kernel_sweep.py --gmm` takes its shapes from the cells'
+    configuration files: the decode call is the one `_CALLS` names, a
+    prompt's rows are two a (token, expert) pair, and the two products'
+    least times add up to the benchmark's `grouped_product_cost`."""
+    import importlib
+
+    sys.path.insert(0, os.path.join(_REPO, "scripts"))
+    try:
+        sweep = importlib.import_module("tpu_kernel_sweep")
+    finally:
+        sys.path.remove(os.path.join(_REPO, "scripts"))
+    conf, costs, cases, products = sweep._gmm_cases(family)
+    costs = importlib.import_module("benchmarks.layer_metrics." + costs)
+    (rows, groups, k, n), _ = _CALLS[decode_call]
+    label, sizes, _ = cases[0]
+    assert label.startswith("decode") and products[0] == ("w13", k, n)
+    assert (int(sizes.sum()), len(sizes), int((sizes > 0).sum())) == \
+        (rows, groups, touched)
+    for label, sizes, tokens in cases[1:]:
+        assert sizes.sum() == tokens * conf["num_experts_per_tok"] * 2
+        assert (sizes % 2 == 0).all()
+    pairs = rows // 2
+    parts = [sweep._gmm_cost(pairs, touched, a, b) for _, a, b in products]
+    assert np.allclose(np.sum(parts, axis=0),
+                       costs.grouped_product_cost(conf, pairs, touched))
+    assert all(tk in (k, k // 2) and n % tn == 0
+               for _, tk, tn in sweep._gmm_tilings(k, n))
 
 
 def _fault_names():
