@@ -35,11 +35,10 @@ Routed feed-forward (`route`, `expert_ffn`):
 Every token gets all of its experts: there is no capacity.  The step's
 (row, expert) pairs are sorted by expert, and each of an expert's two
 matrices (`w13` = [W1 | W3] side by side, and `w2`) is ONE grouped matrix
-product over the sorted rows (`ops/grouped_matmul.py`: the Pallas
-`megablox.gmm`, which visits only the experts that hold a row and only the
-row tiles that hold a pair, so a decode step of 16 rows streams the 40
-experts its rows chose and not 64, and a padded prompt pays for its real
-tokens).  A row
+product over the sorted rows (`ops/grouped_matmul.py`: a Pallas kernel
+that visits only the experts that hold a row and only the row tiles that
+hold a pair, so a decode step of 16 rows streams the 40 experts its rows
+chose and not 64, and a padded prompt pays for its real tokens).  A row
 that is not `valid` (a slot held still, a position past a prompt's end)
 is given to no expert: it is sorted past the last group, costs nothing
 and gets zeros.
@@ -48,8 +47,9 @@ Precision (`models/sambay.py`'s `matmul` is this file's): parameters and
 the K and V stored between steps are the configuration's `dtype`; the conv
 windows, the router (its matrix, logits, sigmoid, top-k and gates), the
 norms, the residual stream and softmax are float32.  An activation enters
-every product with a weight as two bfloat16 terms, in the grouped products
-as two adjacent rows of the same group, over a prompt as in a decode step.
+every product with a weight as two bfloat16 terms, over a prompt as in a
+decode step; the grouped products take the float32 rows and make the two
+terms inside the kernel, a row tile at a time (`_two_terms`, handed to it).
 
 What a step counted rides back with its logits (`expert_counts`: experts
 touched, the slots they are counted against, the most rows one expert
@@ -72,8 +72,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.llama import RMSNorm, apply_rope, rope_frequencies
-from ray_tpu.models.sambay import (Linear, _halves, _two_terms,
-                                   causal_attention, matmul)
+from ray_tpu.models.sambay import (Linear, _two_terms, causal_attention,
+                                   matmul)
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 
 _PERIOD = ("full_attention", "conv", "conv", "conv")
@@ -149,41 +149,34 @@ def route(logits, bias, top_k: int, eps: float = 1e-6):
     return idx, chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + eps)
 
 
-def _grouped(x, w, sizes):
-    """`grouped_matmul` as `sambay.matmul` makes a product: where w is
-    bfloat16 a row enters as two terms, two adjacent rows of the same
-    group, the group sizes doubled, the two results added."""
-    if w.dtype == jnp.float32:
-        return grouped_matmul(x, w, sizes)
-    M, k = x.shape
-    out = grouped_matmul(_two_terms(x[:, None], 1).reshape(2 * M, k), w,
-                         2 * sizes)
-    return _halves(out.reshape(M, 2, -1), 1)[:, 0]
-
-
 def expert_ffn(u, idx, gates, w13, w2, valid=None):
     """u (T, d) float32; idx, gates (T, k); w13 (E, d, 2 f) = [W1 | W3];
     w2 (E, f, d); valid (T,) bool or None -> (sum over a row's experts of
     g W2 (silu(W1 u) * W3 u), (T, d) float32, zeros where not valid;
     `expert_counts` of the call).  The (row, expert) pairs are sorted by
-    expert and each matrix is one grouped product over them; every pair
-    of a valid row is computed, whatever the routing."""
+    expert and each matrix is one grouped product over their float32 rows
+    (T k rows in, T k out: where the matrices are bfloat16 the kernel
+    makes a row's two terms itself); every pair of a valid row is
+    computed, whatever the routing."""
     T, k = idx.shape
     E = w13.shape[0]
-    flat = idx.reshape(-1)
+    # pair p = j T + t is row t's j-th expert: (k, T, d) is then (k T, d)
+    # as it lies, where (T, k, d) pads k to the float32 tile's 8 rows and
+    # is a copy of every pair's result (a `reshape` line of a profile)
+    flat = idx.T.reshape(-1)
     if valid is not None:
         # no expert: sorted past the last group
-        flat = jnp.where(jnp.repeat(valid, k), flat, E)
+        flat = jnp.where(jnp.tile(valid, k), flat, E)
     order = jnp.argsort(flat)                   # stable: pairs by expert
     sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
-    x = jnp.take(u, order // k, axis=0)         # each sorted pair's row
-    a, b = jnp.split(_grouped(x, w13, sizes), 2, axis=-1)
-    y = _grouped(nn.silu(a) * b, w2, sizes)
-    # back to (row, k) order; a pair of no group holds anything: dropped
-    y = jnp.take(y, jnp.argsort(order), axis=0).reshape(T, k, -1)
+    x = u[order % T]                            # each sorted pair's row
+    a, b = jnp.split(grouped_matmul(x, w13, sizes, _two_terms), 2, axis=-1)
+    y = grouped_matmul(nn.silu(a) * b, w2, sizes, _two_terms)
+    # back to pair order; a pair of no group holds anything: dropped
+    y = y[jnp.argsort(order)].reshape(k, T, -1)
     kept = gates if valid is None else jnp.where(valid[:, None], gates, 0.0)
-    out = jnp.sum(jnp.where(kept[..., None] > 0, y, 0.0) * kept[..., None],
-                  axis=1)
+    kept = kept.T[..., None]
+    out = jnp.sum(jnp.where(kept > 0, y, 0.0) * kept, axis=0)
     return out, expert_counts(sizes)
 
 

@@ -2,90 +2,196 @@
 own matrix, in ONE call (a routed feed-forward's experts over the (row,
 expert) pairs of a step: `models/lfm2_moe.py`).
 
-It is the Pallas `megablox.gmm` of jax, which visits only the groups that
-hold a row and only the row tiles that hold one: a decode step of 16 rows
+A Pallas kernel of this repo's own, on the scheme of jax's `megablox.gmm`
+(whose group metadata it imports): the (group, row tile) visits come from
+the group sizes by scalar prefetch, so only the groups that hold a row are
+visited and only the row tiles that hold one: a decode step of 16 rows
 streams the 40 experts its rows chose and not all 64, and a padded prompt
-pays for its real tokens.  Read on the chip against `jax.lax.ragged_dot`
-at a decode step's and a prompt's shapes and at eight tilings (PERF.md,
-PR 42): `gmm` was 25-35% faster at every shape, and nothing chooses
-between them.
+pays for its real tokens.  Rows of another group are masked at the store;
+rows past the last group are never written.
 
-The tiles (`_tiles`) follow the rows a GROUP can hold, rows / groups of the
-call's static shapes.  Device time of the kernel alone from a profiler
+Where the matrices are bfloat16 the kernel takes the float32 rows as they
+are and makes an activation's two bfloat16 terms ITSELF (the caller's
+`sambay._two_terms`: the value with the low 16 bits of its float32 form
+cleared, and what that left), once a row tile, into VMEM: both terms go
+through the matrix unit against the same slab of the group's matrix, each
+accumulated in float32 over the whole contraction, and their sum is
+stored.  M float32 rows in, M out, bit for bit what `megablox.gmm` gives
+over the two terms as two adjacent rows of a group with the two results
+added (as `models/lfm2_moe.py` called it until PR 47, laying the rows out
+around it: a `reshape` line of 0.55 s in a traced slot of
+`kimivl-serve-pages-closed`).  float32 matrices (the tests' tiny models)
+take the rows in one term.
+
+The visits are the OUTER grid axis and a visit's column tiles the inner
+one (`megablox.gmm` has the columns outermost, and reads a row tile again
+for each of them): x is read once a row tile, its terms are made once, and
+the output block is a whole row of column tiles, (row tile, n) float32,
+which stays in VMEM across a visit's column tiles and across the next
+group's visit of the same row tile.  With the columns whole the slab's
+block is the group's whole matrix, and the pipeline brings it in once a
+GROUP, however many row tiles the group spans.
+
+The tiles (`_tiles`) rest on the kernel's device time from a profiler
 trace, `scripts/tpu_kernel_sweep.py --gmm` on a TPU v5 lite (PERF.md, PR
-46; ms at row tiles of 128 / 256, the contraction whole, 64 groups):
+47; ms, 64 groups, the contraction whole; beside `megablox.gmm` over the
+doubled rows at the tiles PR 46 gave it; every tiling gave the same bits
+as that call):
 
-    rows a group (the call)                columns   128      256
-      2  (LFM2, decode, 16 slots)    W1|W3   512     0.683    0.756
-     12  (Kimi-VL, decode, 64 slots) W1|W3   256     1.081    1.285
-     12                              W1|W3  1408     1.019    (no room)
-     12                              W2      512     0.565    0.617
-     16  (LFM2, prompt of 128)       W1|W3   512     1.236    1.297
-     32  (LFM2, prompt of 256)       W1|W3   512     1.334    1.370
-     64  (LFM2, prompt of 512)       W1|W3   512     1.548    1.575
-     96  (Kimi-VL, prompt of 512)    W2      512     0.921    0.890
-    128  (LFM2, prompt of 1,024)     W1|W3   512     2.014    1.934
-    512  (LFM2, prompt of 4,096)     W1|W3   512     3.893    3.864
-   1536  (Kimi-VL, prompt of 8,192)  W2      512     4.698    4.293
+    call (float32 rows a group)   doubled  64 rows, the columns  whole, rows
+                                     rows 512|256 halves  whole    128     32
+    Kimi-VL decode 64 (6)     W1|W3 1.019  1.064  1.038  1.009  1.030  1.008
+                              W2    0.532  0.531  0.528  0.509  0.528  0.509
+    Kimi-VL prompt 512 (48)   W1|W3 1.781  1.840  1.789  1.422  1.418  1.413
+                              W2    0.868  0.915  0.914  0.742  0.737  0.746
+    Kimi-VL prompt 2048 (192) W1|W3 3.476  4.381  4.248  2.586  2.638  2.568
+                              W2    1.662  2.210  2.225  1.348  1.387  1.377
+    Kimi-VL prompt 8192 (768) W1|W310.264 14.614 14.160  7.186  7.190  7.187
+                              W2    4.243  7.549  7.471  3.705  3.712  3.901
+    LFM2 decode 16 (1)        W1|W3 0.674  0.669  0.672  0.676  0.690  0.672
+                              W2    0.344  0.342  0.340  0.339  0.352  0.337
+    LFM2 prompt 128 (8)       W1|W3 1.178  1.199  1.202  1.146  1.157  1.142
+                              W2    0.600  0.605  0.612  0.578  0.592  0.578
+    LFM2 prompt 256 (16)      W1|W3 1.284  1.315  1.318  1.211  1.219  1.228
+                              W2    0.668  0.677  0.673  0.614  0.626  0.627
+    LFM2 prompt 512 (32)      W1|W3 1.536  1.646  1.650  1.388  1.389  1.403
+                              W2    0.812  0.839  0.848  0.712  0.715  0.739
+    LFM2 prompt 1024 (64)     W1|W3 1.918  2.226  2.230  1.700  1.682  1.688
+                              W2    1.021  1.156  1.154  0.884  0.870  0.885
+    LFM2 prompt 4096 (256)    W1|W3 3.853  5.824  5.830  3.381  3.426  3.411
+                              W2    2.083  3.190  3.217  1.746  1.781  1.810
 
-Up to 64 rows a group: tiles of 128 rows, and W1|W3's 2,816 columns in two
-halves; over that: 256 rows, and the columns in 256s.  (In halves at 128
-rows a PROMPT's W1|W3 is faster alone too, 7.25 against 10.16 ms at 8,192
-tokens; but with 128-row tiles, whatever the columns, the whole 8,192-token
-prefill compiles to 1.1 GB more of temporaries, 15.34 GB with the cell's
-state resident: not taken.)  Tiles of 32 and 64 rows are no faster than
-128 anywhere: the slab's DMA bounds a visit from 128 down, and every tile
-edge that cuts a group brings its slab in again.
+One tiling, 64 float32 rows and the columns whole, is the fastest or within
+1% of it at every call; at 256 rows every call is 10-100% slower, and with
+the columns in tiles a prompt pays for its slabs a visit (14.6 against 7.2
+ms).  The whole routed layer (`lfm2_moe.expert_ffn`, the same trace),
+parent -> this kernel: 1.699 -> 1.548 ms at Kimi-VL's decode call, 47.94 ->
+15.91 at 8,192 tokens; 1.099 -> 1.026 at LFM2's, 16.90 -> 6.86 at 4,096.
 
 On CPU (tests) the kernel runs in interpret mode.
 """
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.ops.tpu.megablox.gmm import make_group_metadata
 
 from ray_tpu.ops.attention import _interpret_mode
 
-_TILE_ROWS = 128
-# Rows a group can hold up to which the small row tile is the faster one.
-_FEW_ROWS_A_GROUP = 64
-# Elements of a group's matrix of which HALF the columns, in bfloat16 and
-# twice (the kernel's two buffers), fit the chip's VMEM beside a tile of
-# 128 rows, its result and the accumulator (2,048 x 2,816: 2 x 5.8 MB).
-_HALF_FITS = 6 * 2 ** 20
+# float32 rows of a row tile: two tiles of the matrix unit's 128 rows, one a
+# term.  By the sweep above nothing is gained over it and much lost under
+# it or at 256 (a visit multiplies the whole tile, whatever the group owns).
+_TILE_ROWS = 64
+# What the kernel asks to hold in VMEM (of a v5e's 128 MiB; the compiler's
+# own limit is 16 MiB, which a whole matrix of either cell, twice, passes).
+_VMEM_LIMIT = 48 * 2 ** 20
 
 
-def _tiles(rows: int, groups: int, k: int, n: int) -> tuple:
-    """Tiles of the product (rows, contraction, columns), from the call's
-    static shapes alone.
+def _vmem_bytes(tm: int, k: int, n: int, tn: int, w_bytes: int) -> int:
+    """What a call holds in VMEM: the row tile and the slab twice (the
+    pipeline's two buffers), the output block twice, the terms once, the
+    product of both terms and the sum of its halves."""
+    return (2 * tm * k * 4 + 2 * k * tn * w_bytes + 2 * tm * n * 4
+            + 2 * tm * k * 2 + 3 * tm * tn * 4)
 
-    `gmm` visits every (group, row tile) pair that shares a row and there
-    multiplies the WHOLE row tile by a (k, columns) slab of the group's
-    matrix, so a tile far taller than a group is operations on rows that
-    are masked away: where a group can hold few rows (a decode step's
-    2-12, whatever the rows of the call) the tile is 128 rows, where it can
-    hold more a prompt's 256.  The contraction is whole, so a row's result
-    is the same bits at every tile.  The columns go in tiles of 512, slabs
-    of 1.5-2 MB, or where 512 does not divide them in the widest multiple
-    of 128 under it that does (2,816 = 11 x 256).  x is read again for
-    every column tile, so such columns go in two halves (1,408 = 11 x 128)
-    where VMEM has the room: beside 128 rows, no more."""
-    cols = next((c for c in (512, 384, 256, 128) if n % c == 0), min(n, 512))
-    if rows > _FEW_ROWS_A_GROUP * groups:
-        return 256, k, cols
-    if cols < min(n, 512) and n % 256 == 0 and k * n <= _HALF_FITS:
-        cols = n // 2
+
+def _tiles(k: int, n: int, w_bytes: int = 2) -> tuple:
+    """Tiles of the product (float32 rows, contraction, columns), from the
+    matrices' static shape and type alone.
+
+    The kernel visits every (group, row tile) pair that shares a row and
+    there multiplies the WHOLE row tile, both terms, by a (k, columns) slab
+    of the group's matrix: a tile far taller than the rows a group holds is
+    operations on rows that are masked away, so the tile is 64 rows for a
+    decode step (1-6 rows a group) and for a prompt (8-768) alike.  The
+    contraction is whole, so a row's result is the same bits at every
+    tile.  The columns are whole too wherever the matrix fits VMEM twice
+    (every matrix of both cells: 11.5 and 12.6 MB): the slab's block is
+    then the GROUP's, and the pipeline brings it in once a group, not once
+    a visit, however many row tiles the group spans.  A wider matrix goes
+    in the widest column tile, a multiple of 128, that divides it and
+    fits (its slab comes in again at every visit: no cell has one)."""
+    cols = next((c for c in range(n, 127, -128) if n % c == 0 and _vmem_bytes(
+        _TILE_ROWS, k, n, c, w_bytes) <= _VMEM_LIMIT), n)
     return _TILE_ROWS, k, cols
 
 
-def grouped_matmul(x, w, sizes):
+def _kernel(offsets, group_ids, tile_ids, x, w, out, *terms, two_terms,
+            tm: int, tn: int):
+    """One (visit, column tile) of the grid.  x (tm, k) float32: the
+    visit's row tile; w (k, tn): a slab of the visit's group; out (tm, n)
+    float32: the row tile's whole output; terms: (2 tm, k) bfloat16 where
+    the rows enter as two terms, else nothing."""
+    visit, col = pl.program_id(0), pl.program_id(1)
+    if terms:
+        (terms,) = terms
+        before = jnp.maximum(visit - 1, 0)
+
+        # (the next group's visit of the same row tile finds them made)
+        @pl.when((col == 0) & ((visit == 0)
+                               | (tile_ids[visit] != tile_ids[before])))
+        def _split():
+            terms[...] = two_terms(x[...], 0)
+
+        both = jnp.dot(terms[...], w[...],
+                       preferred_element_type=jnp.float32)
+        got = both[:tm] + both[tm:]
+    else:
+        got = jnp.dot(x[...], w[...], preferred_element_type=jnp.float32)
+    group = group_ids[visit]
+    row = tile_ids[visit] * tm + jax.lax.broadcasted_iota(
+        jnp.int32, (tm, tn), 0)
+    mine = (row >= offsets[group]) & (row < offsets[group + 1])
+    at = pl.ds(pl.multiple_of(col * tn, tn), tn)
+    out[:, at] = jnp.where(mine, got, out[:, at])
+
+
+# (jit: the layers of a model share one lowering; a profile names the call)
+@functools.partial(jax.jit,
+                   static_argnames=("two_terms", "tiles", "interpret"))
+def _grouped_call(x, w, sizes, *, two_terms, tiles: tuple, interpret: bool):
+    tm, k, tn = tiles
+    M, (G, _, n) = x.shape[0], w.shape
+    (offsets, group_ids, tile_ids), visits = make_group_metadata(
+        group_sizes=sizes, m=M, tm=tm, start_group=jnp.int32(0),
+        num_nonzero_groups=G, visit_empty_groups=False)
+    two = w.dtype != jnp.float32
+    return pl.pallas_call(
+        functools.partial(_kernel, two_terms=two_terms, tm=tm, tn=tn),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(visits, n // tn),
+            in_specs=[
+                pl.BlockSpec((tm, k), lambda v, c, o, g, t: (t[v], 0)),
+                pl.BlockSpec((None, k, tn),
+                             lambda v, c, o, g, t: (g[v], 0, c)),
+            ],
+            out_specs=pl.BlockSpec((tm, n), lambda v, c, o, g, t: (t[v], 0)),
+            scratch_shapes=[pltpu.VMEM((2 * tm, k), jnp.bfloat16)] * two),
+        out_shape=jax.ShapeDtypeStruct((M, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * (1 + two) * M * k * n, transcendentals=0,
+            bytes_accessed=4 * M * (k + n) + G * k * n * w.dtype.itemsize),
+        interpret=interpret,
+    )(offsets, group_ids, tile_ids, x, w)
+
+
+def grouped_matmul(x, w, sizes, two_terms):
     """x (M, k), rows sorted by group; w (G, k, n); sizes (G,) int32, the
     rows of each group (rows past their sum belong to no group and come
-    back as anything) -> (M, n) float32.  x is taken in w's type."""
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
-
-    tiles = _tiles(x.shape[0], *w.shape)
-    return gmm(
-        jnp.pad(x.astype(w.dtype), ((0, -x.shape[0] % tiles[0]), (0, 0))),
-        w, sizes, jnp.float32, tiles,
-        interpret=_interpret_mode())[: x.shape[0]]
+    back as anything) -> (M, n) float32.  x is taken in float32; where w
+    is bfloat16 a row tile enters the product as `two_terms(tile, 0)`,
+    the caller's `sambay._two_terms`: its two bfloat16 terms, stacked."""
+    M = x.shape[0]
+    tiles = _tiles(*w.shape[1:], w.dtype.itemsize)
+    x = jnp.pad(x.astype(jnp.float32), ((0, -M % tiles[0]), (0, 0)))
+    return _grouped_call(x, w, sizes.astype(jnp.int32), two_terms=two_terms,
+                         tiles=tiles, interpret=_interpret_mode())[:M]

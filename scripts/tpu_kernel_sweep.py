@@ -10,10 +10,12 @@ interpret-mode path on CPU).  Produces:
   3. `--gmm`: the grouped product (`ops/grouped_matmul.py`) at the two
      routed cells' own shapes over row and column tiles, the custom
      call's DEVICE time read from a profiler trace, against its least
-     time: the table `_tiles` rests on (PERF.md, PR 46).
+     time and beside `megablox.gmm` over the doubled rows: the table
+     `_tiles` rests on (PERF.md, PRs 46 and 47); then, as `--ffn` alone
+     does on any tree, the whole `expert_ffn` at the same shapes.
 
 Usage: python scripts/tpu_kernel_sweep.py
-           [--sweep-only|--check-only|--latent|--gmm [family ...]]
+           [--sweep-only|--check-only|--latent|--gmm|--ffn [family ...]]
 """
 
 from __future__ import annotations
@@ -303,7 +305,7 @@ def time_latent(batch: int = 64, page: int = 64):
 
 # ---- the grouped product: tiles against device time ------------------------
 
-GMM_ROW_TILES = (32, 64, 128, 256, 512)
+GMM_ROW_TILES = (32, 64, 128, 256)
 GMM_REPS = 5
 # family -> (configuration file, its costs, its key for the experts held,
 # experts a decode step touches in the cell (ledger, PRs 42 and 45),
@@ -317,13 +319,13 @@ GMM_FAMILIES = {
 
 
 def _even_sizes(pairs: int, groups: int, touched: int):
-    """Rows of each group, two a (row, expert) pair, of a decode step: its
+    """Rows of each group, one a (row, expert) pair, of a decode step: its
     pairs spread evenly over `touched` groups that lie evenly among all."""
     at = np.linspace(0, groups - 1, touched).round().astype(int)
     sizes = np.zeros(groups, np.int32)
     sizes[at] = pairs // touched
     sizes[at[: pairs % touched]] += 1
-    return 2 * sizes
+    return sizes
 
 
 def _routed_sizes(tokens: int, top_k: int, groups: int, rng):
@@ -331,7 +333,7 @@ def _routed_sizes(tokens: int, top_k: int, groups: int, rng):
     sizes = np.zeros(groups, np.int32)
     for _ in range(tokens):
         sizes[rng.choice(groups, top_k, replace=False)] += 1
-    return 2 * sizes
+    return sizes
 
 
 def _gmm_cases(family: str):
@@ -359,20 +361,29 @@ def _gmm_cost(pairs: int, touched: int, k: int, n: int) -> tuple:
 
 
 def _gmm_tilings(k: int, n: int):
-    """Row tiles x column tiles: 512 where it divides the columns and
-    1,024 beside it; for 2,816 columns 256 (PR 45's), 128 and 1,408 (11 x
-    128; the contraction whole and in two)."""
-    if n % 512:
-        cols = [(256, k), (128, k), (n // 2, k), (n // 2, k // 2)]
-    else:
-        cols = [(512, k), (1024, k)]
-    return [(tm, tk, tn) for tn, tk in cols for tm in GMM_ROW_TILES]
+    """Row tiles (float32 rows) x column tiles, the contraction whole: the
+    columns in 512s (256s for 2,816: PR 46's tile for a prompt), in two
+    halves, and whole."""
+    cols = (512 if n % 512 == 0 else 256, n // 2, n)
+    return [(tm, k, tn) for tn in cols for tm in GMM_ROW_TILES]
+
+
+def _doubled_rows_tiles(rows: int, groups: int, k: int, n: int) -> tuple:
+    """What PR 46 gave `megablox.gmm` over DOUBLED rows (two bfloat16 rows
+    a pair): the sweep's yardstick."""
+    cols = next((c for c in (512, 384, 256, 128) if n % c == 0), min(n, 512))
+    if rows > 64 * groups:
+        return 256, k, cols
+    if cols < min(n, 512) and n % 256 == 0 and k * n <= 6 * 2 ** 20:
+        cols = n // 2
+    return 128, k, cols
 
 
 def _device_ms(trace_dir: str) -> dict:
-    """{program: [ms of its Pallas calls, a run each]} from the xplane a
-    profiler session left: the line `XLA Modules` holds the runs of a
-    jitted function, `XLA Ops` its operations, a kernel by its target."""
+    """{program: [(ms of its Pallas calls, ms of the whole run), a run
+    each]} from the xplane a profiler session left: the line `XLA Modules`
+    holds the runs of a jitted function, `XLA Ops` its operations, a
+    kernel by its target."""
     import glob
     path = sorted(glob.glob(os.path.join(
         trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
@@ -389,26 +400,33 @@ def _device_ms(trace_dir: str) -> dict:
         for t0, t1, name in runs:
             ms = sum(d for s, d in calls if t0 <= s < t1) / 1e6
             name = name.split("(")[0].removeprefix("jit_")
-            out.setdefault(name, []).append(ms)
+            out.setdefault(name, []).append((ms, (t1 - t0) / 1e6))
     return out
 
 
-def sweep_gmm(families):
-    """One line a (family, case, product, tiling): the median device time
-    of `megablox.gmm` alone against the product's least time, its share of
-    `grouped_product_cost` (the two products' shares add up to it), and
-    whether the rows of groups came out bit for bit as at the first
-    tiling (32 rows, 512 columns or 256, the contraction whole)."""
-    import importlib
+def _traced_ms(fns: dict, args) -> dict:
+    """{name: (median ms of the Pallas calls, of the whole run)} of jitted
+    functions (compiled already) over `args`, GMM_REPS runs each."""
     import tempfile
 
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    got = None
+    with tempfile.TemporaryDirectory() as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            for f in fns.values():
+                for _ in range(GMM_REPS):
+                    got = f(*args)      # (one result held)
+                _sync(got)
+        ms = _device_ms(trace_dir)
+    return {name: tuple(sorted(r[i] for r in ms[name])[len(ms[name]) // 2]
+                        for i in (0, 1))
+            for name in fns if ms.get(name)}
 
-    device = jax.devices()[0].device_kind
-    lines = []
+
+def _emitter(file_name: str):
     out_dir = os.path.join(_REPO, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
-    record = open(os.path.join(out_dir, "gmm_sweep.jsonl"), "w")
+    record = open(os.path.join(out_dir, file_name), "w")
+    lines = []
 
     def emit(line):
         lines.append(line)
@@ -416,6 +434,26 @@ def sweep_gmm(families):
         record.flush()
         print(json.dumps(line), flush=True)
 
+    return emit, lines
+
+
+def sweep_gmm(families):
+    """One line a (family, case, product, tiling): the median device time
+    of the repo's kernel alone (float32 rows in, the two terms made inside)
+    against the product's least time, its share of `grouped_product_cost`
+    (the two products' shares add up to it), and whether the rows of
+    groups came out bit for bit as `megablox.gmm` gives them over the
+    DOUBLED rows with the two halves added; that call, at the tiles PR 46
+    gave it, is timed beside (tiles [0, 0, 0])."""
+    import importlib
+
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from ray_tpu.models.sambay import _halves, _two_terms
+    from ray_tpu.ops import grouped_matmul as gm
+
+    device = jax.devices()[0].device_kind
+    emit, lines = _emitter("gmm_sweep.jsonl")
     for family in families:
         conf, costs, cases, products = _gmm_cases(family)
         costs = importlib.import_module("benchmarks.layer_metrics." + costs)
@@ -423,64 +461,76 @@ def sweep_gmm(families):
             for label, sizes, _ in cases:
                 E, rows = len(sizes), int(sizes.sum())
                 touched = int((sizes > 0).sum())
-                flops, nbytes = _gmm_cost(rows // 2, touched, k, n)
+                flops, nbytes = _gmm_cost(rows, touched, k, n)
                 assert np.allclose(
-                    np.sum([_gmm_cost(rows // 2, touched, a, b)
+                    np.sum([_gmm_cost(rows, touched, a, b)
                             for _, a, b in products], axis=0),
-                    costs.grouped_product_cost(conf, rows // 2, touched))
+                    costs.grouped_product_cost(conf, rows, touched))
                 least = max(flops / PEAK_FLOPS, nbytes / PEAK_BYTES) * 1e3
-                args = (jax.random.normal(jax.random.PRNGKey(2), (rows, k),
-                                          jnp.bfloat16),
-                        jax.random.normal(jax.random.PRNGKey(1), (E, k, n),
-                                          jnp.bfloat16) * 0.02,
-                        jnp.asarray(sizes))
+                x = jax.random.normal(jax.random.PRNGKey(2), (rows, k),
+                                      jnp.float32)
+                args = (x, jax.random.normal(
+                    jax.random.PRNGKey(1), (E, k, n), jnp.bfloat16) * 0.02,
+                    jnp.asarray(sizes))
                 said = {"family": family, "case": label, "product": which}
-                fns, same, first = {}, {}, None
+
+                old_tiles = _doubled_rows_tiles(2 * rows, E, k, n)
+
+                def doubled(xx, w, sizes):
+                    return gmm(jnp.pad(xx, ((0, -xx.shape[0] % old_tiles[0]),
+                                            (0, 0))),
+                               w, 2 * sizes, jnp.float32,
+                               old_tiles, interpret=gm._interpret_mode())[
+                                   : xx.shape[0]]
+                doubled.__name__ = "doubled_rows"
+                doubled = jax.jit(doubled)
+                xx = jax.jit(lambda x: _two_terms(x[:, None], 1).reshape(
+                    2 * rows, k))(x)
+                first = jax.jit(lambda o: _halves(
+                    o.reshape(rows, 2, -1), 1)[:, 0])(
+                        doubled(xx, *args[1:]))
+                fns, same = {}, {}
                 for tiles in _gmm_tilings(k, n):
+                    if gm._vmem_bytes(tiles[0], k, n, tiles[2], 2) \
+                            > gm._VMEM_LIMIT:
+                        continue
+
                     def run(x, w, sizes, tiles=tiles):
-                        return gmm(jnp.pad(x, ((0, -x.shape[0] % tiles[0]),
-                                               (0, 0))),
-                                   w, sizes, jnp.float32, tiles)[: x.shape[0]]
-                    run.__name__ = "gmm_%d_%d_%d" % tiles
+                        return gm._grouped_call(
+                            jnp.pad(x, ((0, -x.shape[0] % tiles[0]), (0, 0))),
+                            w, sizes, two_terms=_two_terms, tiles=tiles,
+                            interpret=gm._interpret_mode())[: x.shape[0]]
+                    run.__name__ = "grouped_%d_%d_%d" % tiles
                     f = jax.jit(run)
                     try:
-                        got = f(*args)
-                        first = got if first is None else first
-                        same[tiles] = bool(jnp.array_equal(got, first))
-                        fns[tiles] = f
+                        same[tiles] = bool(jnp.array_equal(f(*args), first))
+                        fns[run.__name__] = f
                     except Exception as e:  # noqa: BLE001 — record, go on
                         emit({**said, "tiles": list(tiles),
                               "error": str(e).splitlines()[0][:160]})
-                first = got = None
-                with tempfile.TemporaryDirectory() as trace_dir:
-                    with jax.profiler.trace(trace_dir):
-                        for f in fns.values():
-                            for _ in range(GMM_REPS):
-                                got = f(*args)      # (one result held)
-                            _sync(got)
-                    ms = _device_ms(trace_dir)
-                got = None
-                for tiles in fns:
-                    runs = sorted(ms.get("gmm_%d_%d_%d" % tiles, []))
-                    if not runs:
-                        emit({**said, "tiles": list(tiles),
-                              "error": f"no run in the trace: {sorted(ms)}"})
+                first = None
+                ms = _traced_ms(fns, args)
+                ms.update(_traced_ms({"doubled_rows": doubled},
+                                     (xx,) + args[1:]))
+                for tiles in [(0, 0, 0)] + _gmm_tilings(k, n):
+                    name = "grouped_%d_%d_%d" % tiles if tiles[0] \
+                        else "doubled_rows"
+                    if name not in ms:
                         continue
-                    median = runs[len(runs) // 2]
                     emit({**said, "rows": rows, "groups": E, "k": k, "n": n,
                           "touched": touched,
                           "rows_a_group": round(rows / E, 1),
                           "tiles": list(tiles),
-                          "same_bits_as_the_first": same[tiles],
-                          "ms": round(median, 4),
+                          "doubled_rows_tiles": list(old_tiles),
+                          "same_bits_as_doubled_rows": same.get(tiles, True),
+                          "ms": round(ms[name][0], 4),
                           "least_ms": round(least, 4),
                           "bound": "bytes" if nbytes / PEAK_BYTES
                           > flops / PEAK_FLOPS else "operations",
-                          "share": round(least / median, 4),
+                          "share": round(least / ms[name][0], 4),
                           "device": device})
-    record.close()
     table = _gmm_table(lines)
-    with open(os.path.join(out_dir, "gmm_sweep.md"), "w") as f:
+    with open(os.path.join(_REPO, "chiprun_out", "gmm_sweep.md"), "w") as f:
         f.write(table + "\n")
     print(table)
 
@@ -488,31 +538,81 @@ def sweep_gmm(families):
 def _gmm_table(lines) -> str:
     """The sweep as a table: a row a (family, case, product, column tile),
     a column a row tile, `ms (share of the least time)`; in bold what
-    `_tiles` picks, `!` where the bits differ from the first tiling's."""
+    `_tiles` picks, `!` where the bits differ from the doubled rows'; the
+    doubled rows through `megablox.gmm` in a column of their own."""
     from ray_tpu.ops.grouped_matmul import _tiles
 
     def cell(ln):
         text = f"{ln['ms']:.3f} ({100 * ln['share']:.0f}%)" \
-            + ("" if ln["same_bits_as_the_first"] else " !")
-        picked = _tiles(ln["rows"], ln["groups"], ln["k"], ln["n"])
+            + ("" if ln["same_bits_as_doubled_rows"] else " !")
+        picked = _tiles(ln["k"], ln["n"])
         return f"**{text}**" if list(picked) == ln["tiles"] else text
 
-    rows = {}
+    rows, doubled = {}, {}
     for ln in (ln for ln in lines if "ms" in ln):
         tm, tk, tn = ln["tiles"]
-        key = (ln["family"], ln["case"], ln["product"], tn, tk)
+        if not tm:
+            doubled[ln["family"], ln["case"], ln["product"]] = ln
+            continue
+        key = (ln["family"], ln["case"], ln["product"], tn)
         rows.setdefault(key, {"rows_a_group": ln["rows_a_group"],
                               "least_ms": ln["least_ms"]})[tm] = ln
-    head = ["family", "case", "product", "rows a group", "cols x k tile",
-            "least ms"] + [f"rows {tm}" for tm in GMM_ROW_TILES]
+    head = ["family", "case", "product", "rows a group", "least ms",
+            "doubled rows, gmm", "cols"] \
+        + [f"rows {tm}" for tm in GMM_ROW_TILES]
     table = ["| " + " | ".join(head) + " |",
              "|" + " --- |" * len(head)]
-    for (family, case, which, tn, tk), r in rows.items():
+    for (family, case, which, tn), r in rows.items():
         cells = [cell(r[tm]) if tm in r else "-" for tm in GMM_ROW_TILES]
+        old = doubled.get((family, case, which))
         table.append("| " + " | ".join(
-            [family, case, which, str(r["rows_a_group"]), f"{tn} x {tk}",
-             f"{r['least_ms']:.3f}"] + cells) + " |")
+            [family, case, which, str(r["rows_a_group"]),
+             f"{r['least_ms']:.3f}",
+             f"{old['ms']:.3f} at {old['doubled_rows_tiles'][0]} x "
+             f"{old['doubled_rows_tiles'][2]}" if old else "-", str(tn)]
+            + cells) + " |")
     return "\n".join(table)
+
+
+def time_expert_ffn(families):
+    """The whole routed feed-forward (`lfm2_moe.expert_ffn`: the sort, both
+    grouped products and what stands around them) at the sweep's cases:
+    the median device time of the jitted call and of its Pallas calls.
+    Reads nothing of the kernel's own, so the same script times a parent
+    commit's tree (`--ffn`)."""
+    from ray_tpu.models.lfm2_moe import expert_ffn
+
+    emit, _ = _emitter("expert_ffn.jsonl")
+    for family in families:
+        conf, _, cases, products = _gmm_cases(family)
+        (_, d, f2), _ = products
+        top_k = conf["num_experts_per_tok"]
+        for label, sizes, tokens in cases:
+            E = len(sizes)
+            rng = np.random.default_rng(1)
+            idx = rng.permutation(np.repeat(np.arange(E), sizes)).reshape(
+                tokens, top_k).astype(np.int32)
+            keys = jax.random.split(jax.random.PRNGKey(3), 4)
+            args = (jax.random.normal(keys[0], (tokens, d), jnp.float32),
+                    jnp.asarray(idx),
+                    jax.nn.softmax(jax.random.normal(
+                        keys[1], (tokens, top_k), jnp.float32)),
+                    jax.random.normal(keys[2], (E, d, f2),
+                                      jnp.bfloat16) * 0.02,
+                    jax.random.normal(keys[3], (E, f2 // 2, d),
+                                      jnp.bfloat16) * 0.02)
+
+            def ffn(*a):
+                return expert_ffn(*a)[0]
+            f = jax.jit(ffn)
+            out = f(*args)
+            kernel_ms, whole_ms = _traced_ms({"ffn": f}, args)["ffn"]
+            emit({"family": family, "case": label, "tokens": tokens,
+                  "pairs": int(sizes.sum()), "kernels_ms": round(kernel_ms, 4),
+                  "expert_ffn_ms": round(whole_ms, 4),
+                  "around_ms": round(whole_ms - kernel_ms, 4),
+                  "checksum": float(jnp.sum(jnp.abs(out))),
+                  "device": jax.devices()[0].device_kind})
 
 
 def main():
@@ -527,6 +627,8 @@ def main():
         sys.exit(0 if ok else 1)
     if mode == "--gmm":         # the grouped product's tiles, from a trace
         sweep_gmm(sys.argv[2:] or list(GMM_FAMILIES))
+    if mode in ("--gmm", "--ffn"):  # and the routed layer around it
+        time_expert_ffn(sys.argv[2:] or list(GMM_FAMILIES))
         sys.exit(0)
     if mode != "--sweep-only":
         ok = check_flash() and ok

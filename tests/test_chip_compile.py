@@ -680,18 +680,49 @@ def test_granite_engine_programs_fit_the_chip_and_leave_the_state(one_chip):
 
 
 def _tiles_seen(monkeypatch) -> list:
-    """(rows, groups, tiling) of every `megablox.gmm` call made while a
-    program is traced, in order: what `ops/grouped_matmul.py` chose."""
-    from jax.experimental.pallas.ops.tpu import megablox
+    """(rows, groups, tiles) of every call of the grouped product's kernel
+    (`ops/grouped_matmul._grouped_call`) made while a program is traced,
+    in order: what `_tiles` chose, and that the rows are float32 rows."""
+    from ray_tpu.ops import grouped_matmul
 
-    seen, real = [], megablox.gmm
+    seen, real = [], grouped_matmul._grouped_call
 
-    def gmm(lhs, rhs, sizes, out_type, tiling, **kw):
-        seen.append((lhs.shape[0], rhs.shape[0], tiling))
-        return real(lhs, rhs, sizes, out_type, tiling, **kw)
+    def call(x, w, sizes, *, tiles, **kw):
+        assert x.dtype == jnp.float32 and w.dtype == jnp.bfloat16
+        seen.append((x.shape[0], w.shape[0], tiles))
+        return real(x, w, sizes, tiles=tiles, **kw)
 
-    monkeypatch.setattr(megablox, "gmm", gmm)
+    monkeypatch.setattr(grouped_matmul, "_grouped_call", call)
     return seen
+
+
+@pytest.mark.parametrize("tokens, top_k, d, f", [
+    (64, 6, 2048, 1408), (8192, 6, 2048, 1408), (1024, 4, 2048, 1536)],
+    ids=["kimi_decode_64", "kimi_prompt_8192", "lfm2_prompt_1024"])
+def test_routed_layer_holds_no_doubled_rows(one_chip, monkeypatch, tokens,
+                                            top_k, d, f):
+    """`expert_ffn` at a decode step's and at a prompt's shapes, lowered
+    and compiled for the chip: two Pallas calls, and no array of 2 x pairs
+    rows anywhere around them (until PR 47 each product's rows were laid
+    out as (pairs, 2, k) and copied to (2 pairs, k), its result back): the
+    kernel makes the two terms itself."""
+    from ray_tpu.models import lfm2_moe
+    from ray_tpu.ops import grouped_matmul
+
+    monkeypatch.setattr(grouped_matmul, "_interpret_mode", lambda: False)
+    S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    lowered = jax.jit(lfm2_moe.expert_ffn).lower(
+        S((tokens, d), jnp.float32), S((tokens, top_k), jnp.int32),
+        S((tokens, top_k), jnp.float32), S((64, d, 2 * f), jnp.bfloat16),
+        S((64, f, d), jnp.bfloat16))
+    compiled = lowered.compile().as_text()
+    assert compiled.count(KERNEL) == 2
+    pairs = tokens * top_k
+    assert re.search(rf"tensor<{pairs}x{d}xf32>", lowered.as_text())
+    assert not re.search(rf"tensor<{2 * pairs}x", lowered.as_text())
+    assert re.search(rf"f32\[{pairs},{d}\]", compiled)
+    assert not re.search(rf"\[{2 * pairs},", compiled)
 
 
 @pytest.mark.time_limit(600)   # two programs of 9 layers: 60 s alone here
@@ -735,9 +766,10 @@ def test_lfm2_moe_engine_programs_fit_the_chip(one_chip, monkeypatch):
         del seen[:]         # (the engine traced its programs' shapes)
         decode = _compiled_decode_chunk(eng, params, one_chip)
         assert decode.as_text().count(KERNEL) == 18
-        # 16 slots x 4 experts x 2 terms: 2 rows a group, one 128-row tile
-        assert seen == 8 * [(128, 64, (128, 2048, 512)),
-                            (128, 64, (128, 1536, 512))]
+        # 16 slots x 4 experts: 1 float32 row a group, one 64-row tile,
+        # and an expert's whole matrix a slab
+        assert seen == 8 * [(64, 64, (64, 2048, 3072)),
+                            (64, 64, (64, 1536, 2048))]
         peak = recorded["decode_chunk_paged_gb"]["peak_with_weights_and_state"]
         assert peak - 0.05 < _peak_bytes(decode) / 1e9 < peak + 0.005
         del seen[:]
@@ -746,13 +778,13 @@ def test_lfm2_moe_engine_programs_fit_the_chip(one_chip, monkeypatch):
             _on(one_chip, params), S((2, 4096), jnp.int32),
             S((2,), jnp.int32)).compile()
         assert prefill.as_text().count(KERNEL) >= 18
-        # 8,192 tokens, 1,024 rows a group: the prompt's tile
-        assert set(seen) == {(65536, 64, (256, 2048, 512)),
-                             (65536, 64, (256, 1536, 512))}
+        # 8,192 tokens, 512 float32 rows a group: the same tiles
+        assert set(seen) == {(32768, 64, (64, 2048, 3072)),
+                             (32768, 64, (64, 1536, 2048))}
         resident = _peak_bytes(prefill) / 1e9 + gb(eng._pools)
-        assert resident == pytest.approx(
-            recorded["prefill_many_2x4096_gb"]["peak_with_state_resident"],
-            abs=0.05)
+        # (the file records PR 42's 13.12 GB, over doubled rows)
+        assert resident == pytest.approx(13.12, abs=0.05) and resident <= \
+            recorded["prefill_many_2x4096_gb"]["peak_with_state_resident"]
         assert resident * 1e9 < 15.75e9 < HBM_BYTES
     finally:
         eng.shutdown()
@@ -844,10 +876,10 @@ def test_mla_moe_engine_programs_fit_the_chip(one_chip, monkeypatch):
             recorded["decode_chunk_paged_gb"]["pallas_calls"] == 19
         assert chip_smoke.state_moves(text, pools) == {
             "loop": _NOTHING, "outside": _NOTHING}
-        # 64 slots x 6 experts x 2 terms: 12 rows a group, 128-row tiles
-        # (tiled as a prompt until PR 46: 256 rows, W1|W3 in 256 columns)
-        assert seen == 6 * [(768, 64, (128, 2048, 1408)),
-                            (768, 64, (128, 1408, 512))]
+        # 64 slots x 6 experts: 6 float32 rows a group, 64-row tiles, and
+        # an expert's whole matrix a slab
+        assert seen == 6 * [(384, 64, (64, 2048, 2816)),
+                            (384, 64, (64, 1408, 2048))]
         peak = recorded["decode_chunk_paged_gb"]["peak_with_weights_and_state"]
         assert peak - 0.05 < _peak_bytes(decode) / 1e9 < peak + 0.005
         del seen[:]
@@ -856,14 +888,13 @@ def test_mla_moe_engine_programs_fit_the_chip(one_chip, monkeypatch):
             _on(one_chip, params), S((1, 8192), jnp.int32),
             S((1,), jnp.int32)).compile()
         assert prefill.as_text().count(KERNEL) == 19
-        # 8,192 tokens, 1,536 rows a group: the prompt's tiles (in 128-row
-        # tiles this program holds 1.1 GB more: 15.34 GB with the state)
-        assert set(seen) == {(98304, 64, (256, 2048, 256)),
-                             (98304, 64, (256, 1408, 512))}
+        # 8,192 tokens, 768 float32 rows a group: the same tiles
+        assert set(seen) == {(49152, 64, (64, 2048, 2816)),
+                             (49152, 64, (64, 1408, 2048))}
         resident = _peak_bytes(prefill) / 1e9 + gb(pools)
-        assert resident == pytest.approx(
-            recorded["prefill_one_8192_gb"]["peak_with_state_resident"],
-            abs=0.05)
+        # (the file records PR 45's 14.23 GB, over doubled rows)
+        assert resident == pytest.approx(14.15, abs=0.05) and resident < \
+            recorded["prefill_one_8192_gb"]["peak_with_state_resident"]
         assert resident * 1e9 < 15.75e9 < HBM_BYTES
     finally:
         eng.shutdown()

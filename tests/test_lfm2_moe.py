@@ -287,7 +287,8 @@ def test_a_planted_fault_fails_the_comparison(tiny, fault):
 
 def test_bf16_in_two_terms_holds_and_in_one_term_does_not(tiny):
     """The served type: bfloat16 weights and K, V, float32 conv windows,
-    activations in two terms (the grouped products' rows doubled), against
+    activations in two terms (the grouped products make them in the kernel,
+    from `lfm2_moe._two_terms`), against
     the reference on the same weights: the widest difference over 168
     positions reads 5.5e-4 (median 2.1e-4: K and V rounded to 2^-9).  With
     every product's activation rounded to ONE bfloat16 term, as a plain

@@ -339,69 +339,161 @@ def test_route_takes_each_familys_constant():
     assert float(fine.sum()) == pytest.approx(1.0, abs=5e-3)
 
 
-# (rows, groups, k, n) of a call as the two routed cells make it (two rows
-# a (token, expert) pair: 6 pairs a token in `kimivl-serve-pages-closed`,
-# 4 in `lfm2moe-serve-agents-closed`; 64 groups) -> (row tile, column tile).
+# (k, n) of a call's matrices as the two routed cells make it -> its column
+# tile; the float32 rows of the call (one a (token, expert) pair: 6 pairs a
+# token in `kimivl-serve-pages-closed`, 4 in `lfm2moe-serve-agents-closed`)
+# and its 64 groups, for `test_the_sweep_runs_the_cells_own_shapes`.
 _KIMI_W13, _KIMI_W2 = (2048, 2816), (1408, 2048)
 _LFM2_W13, _LFM2_W2 = (2048, 3072), (1536, 2048)
 _CALLS = {
-    "kimi decode w13": ((64 * 12, 64) + _KIMI_W13, (128, 1408)),
-    "kimi decode w2": ((64 * 12, 64) + _KIMI_W2, (128, 512)),
-    "kimi prompt 512 w13": ((512 * 12, 64) + _KIMI_W13, (256, 256)),
-    "kimi prompt 2048 w13": ((2048 * 12, 64) + _KIMI_W13, (256, 256)),
-    "kimi prompt 8192 w2": ((8192 * 12, 64) + _KIMI_W2, (256, 512)),
-    "lfm2 decode w13": ((16 * 8, 64) + _LFM2_W13, (128, 512)),
-    "lfm2 decode w2": ((16 * 8, 64) + _LFM2_W2, (128, 512)),
-    "lfm2 prompt 128 w13": ((128 * 8, 64) + _LFM2_W13, (128, 512)),
-    "lfm2 prompt 512 w2": ((512 * 8, 64) + _LFM2_W2, (128, 512)),
-    "lfm2 prompt 1024 w13": ((1024 * 8, 64) + _LFM2_W13, (256, 512)),
-    "lfm2 prompt 4096 w2": ((4096 * 8, 64) + _LFM2_W2, (256, 512)),
-    "a tiny model": ((16, 8, 64, 64), (128, 64)),
-    "half a matrix too large for VMEM": ((768, 64, 4096, 2816), (128, 256)),
+    "kimi decode w13": ((64 * 6, 64) + _KIMI_W13, 2816),
+    "kimi decode w2": ((64 * 6, 64) + _KIMI_W2, 2048),
+    "kimi prompt 512 w13": ((512 * 6, 64) + _KIMI_W13, 2816),
+    "kimi prompt 2048 w13": ((2048 * 6, 64) + _KIMI_W13, 2816),
+    "kimi prompt 8192 w2": ((8192 * 6, 64) + _KIMI_W2, 2048),
+    "lfm2 decode w13": ((16 * 4, 64) + _LFM2_W13, 3072),
+    "lfm2 decode w2": ((16 * 4, 64) + _LFM2_W2, 2048),
+    "lfm2 prompt 128 w13": ((128 * 4, 64) + _LFM2_W13, 3072),
+    "lfm2 prompt 512 w2": ((512 * 4, 64) + _LFM2_W2, 2048),
+    "lfm2 prompt 1024 w13": ((1024 * 4, 64) + _LFM2_W13, 3072),
+    "lfm2 prompt 4096 w2": ((4096 * 4, 64) + _LFM2_W2, 2048),
+    "a tiny model": ((8, 8, 64, 64), 64),
+    "a matrix too large for VMEM twice": ((384, 64, 4096, 2816), 1408),
 }
 
 
 @pytest.mark.parametrize("call", _CALLS)
-def test_grouped_product_tiles_follow_the_rows_a_group_holds(call):
-    """The row tile is chosen by the rows a GROUP can hold (the call's rows
-    over its groups), not by the rows of the call: a decode step of 64
-    slots (768 rows, 12 a group) is tiled as one of 16 (128 rows, 2 a
-    group), a prompt from 96 rows a group up as before.  The contraction
-    stays whole and the column tile divides the columns: 512, or where 512
-    does not divide them a multiple of 128 that does, half of them beside
-    the small row tile."""
-    from ray_tpu.ops.grouped_matmul import _tiles
+def test_grouped_product_tiles_hold_a_groups_whole_matrix(call):
+    """One tiling for a decode step and a prompt (until PR 47 the row tile
+    followed the rows a group can hold, 128 or 256 DOUBLED rows, and the
+    columns went in 512s, 256s or two halves): 64 float32 rows, the
+    contraction whole, and the columns whole, so that a group's matrix is
+    brought in once a group; a matrix that does not fit VMEM twice goes in
+    the widest multiple of 128 that divides its columns and does.  The
+    call fits the VMEM it asks for."""
+    from ray_tpu.ops import grouped_matmul as gm
 
-    (rows, groups, k, n), (row_tile, col_tile) = _CALLS[call]
-    assert _tiles(rows, groups, k, n) == (row_tile, k, col_tile)
+    (_, _, k, n), col_tile = _CALLS[call]
+    assert gm._tiles(k, n) == (64, k, col_tile)
     assert n % col_tile == 0 and (col_tile % 128 == 0 or col_tile == n)
+    assert gm._vmem_bytes(64, k, n, col_tile, 2) <= gm._VMEM_LIMIT
 
 
-def test_a_rows_product_does_not_depend_on_its_tile(monkeypatch):
-    """With the contraction whole, a row's result is the same bits at
-    every tile: groups of 0, 1, 12 and 300 rows and rows past the last
-    group, at the row tile a decode step of 64 slots took before PR 46, at
-    smaller ones and at half the column tile."""
+def _doubled_rows(x, w, sizes, tiles):
+    """The grouped product as it was until PR 47: `megablox.gmm` over the
+    two terms of each row as two adjacent rows of the same group, the
+    group sizes doubled, the two halves of the result added."""
     import jax.numpy as jnp
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from ray_tpu.models.sambay import _halves, _two_terms
+
+    M, k = x.shape
+    rows = _two_terms(x[:, None], 1).reshape(2 * M, k)
+    out = gmm(jnp.pad(rows, ((0, -2 * M % tiles[0]), (0, 0))), w,
+              2 * sizes, jnp.float32, tiles, interpret=True)[: 2 * M]
+    return _halves(out.reshape(M, 2, -1), 1)[:, 0]
+
+
+# groups of 0, 1, 12 and 300 rows, and 7 rows past the last group
+_SIZES = np.asarray([0, 1, 12, 300], np.int32)
+_ROWS, _K, _N = 320, 64, 1536
+# the row tile `_tiles` chooses and the one a prompt took over doubled rows;
+# the columns whole (what `_tiles` chooses where the matrix fits), in halves
+# and in narrower multiples of 128 that divide them
+_TILINGS = [(tm, tn) for tm in (64, 128) for tn in (1536, 768, 512, 384, 128)]
+
+
+def _rows_and_matrices(dtype):
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(0)
+    return (jnp.asarray(rng.standard_normal((_ROWS, _K)), jnp.float32),
+            jnp.asarray(rng.standard_normal((4, _K, _N)) * 0.1, dtype),
+            jnp.asarray(_SIZES))
+
+
+@pytest.fixture(scope="module")
+def doubled_rows():
+    import jax.numpy as jnp
+
+    x, w, sizes = _rows_and_matrices(jnp.bfloat16)
+    return np.asarray(_doubled_rows(x, w, sizes, (256, _K, 512)))
+
+
+@pytest.mark.parametrize("row_tile, col_tile", _TILINGS)
+def test_the_kernel_makes_the_two_terms_of_the_doubled_rows(
+        monkeypatch, doubled_rows, row_tile, col_tile):
+    """Float32 rows in, the two bfloat16 terms made inside the kernel:
+    bit for bit what `megablox.gmm` gives over the doubled rows with the
+    two halves added, for groups of 0, 1, 12 and 300 rows, at every tile
+    `_tiles` can choose; the rows past the last group come back as
+    anything."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.sambay import _two_terms
+    from ray_tpu.ops import grouped_matmul as gm
+
+    assert gm._tiles(_K, _N) == _TILINGS[0][:1] + (_K, _TILINGS[0][1])
+    held = int(_SIZES.sum())
+    x, w, sizes = _rows_and_matrices(jnp.bfloat16)
+    monkeypatch.setattr(gm, "_tiles", lambda *a: (row_tile, _K, col_tile))
+    got = np.asarray(gm.grouped_matmul(x, w, sizes, _two_terms))
+    assert got.shape == (_ROWS, _N) and got.dtype == np.float32
+    assert (got[:held].view(np.uint32)
+            == doubled_rows[:held].view(np.uint32)).all()
+    # and both terms are in it: the product of the float32 rows to 2^-16
+    group = np.repeat(np.arange(4), _SIZES)
+    plain = np.einsum("rk,rkn->rn", np.asarray(x)[:held],
+                      np.asarray(w, np.float32)[group])
+    assert np.abs(got[:held] - plain).max() < 1e-4
+
+
+def test_float32_matrices_take_the_rows_in_one_term():
+    """The tests' tiny models: float32 matrices, the rows as they are, no
+    split (`two_terms` is not called): `megablox.gmm` over the same rows."""
+    import jax.numpy as jnp
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     from ray_tpu.ops import grouped_matmul as gm
 
-    sizes = np.asarray([0, 1, 12, 300], np.int32)
-    held, rows, k, n = int(sizes.sum()), 320, 64, 256
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.standard_normal((rows, k)), jnp.bfloat16)
-    w = jnp.asarray(rng.standard_normal((4, k, n)) * 0.1, jnp.bfloat16)
+    def never(a, axis):
+        raise AssertionError("float32 matrices: one term")
+
+    held = int(_SIZES.sum())
+    x, w, sizes = _rows_and_matrices(jnp.float32)
+    got = np.asarray(gm.grouped_matmul(x, w, sizes, never))[:held]
+    want = np.asarray(gmm(jnp.pad(x, ((0, 64), (0, 0))), w, sizes,
+                          jnp.float32, (128, _K, 512), interpret=True))[:held]
+    assert (got.view(np.uint32) == want.view(np.uint32)).all()
+
+
+@pytest.mark.parametrize("rows_as", ["float32", "bfloat16"])
+def test_a_rows_product_does_not_depend_on_its_tile(monkeypatch, rows_as):
+    """With the contraction whole, a row's result is the same bits at
+    every tile: groups of 0, 1, 12 and 300 rows and rows past the last
+    group, at a prompt's row tile, at smaller ones and at half the column
+    tile.  Float32 rows (two terms made inside) and, as until PR 47,
+    bfloat16 ones (their second term is zero)."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.sambay import _two_terms
+    from ray_tpu.ops import grouped_matmul as gm
+
+    held, n = int(_SIZES.sum()), 256
+    x, w, sizes = _rows_and_matrices(jnp.bfloat16)
+    x, w = x.astype(rows_as), w[:, :, :n]
 
     def at(row_tile, cols=n):
-        monkeypatch.setattr(gm, "_tiles", lambda *a: (row_tile, k, cols))
-        return np.asarray(gm.grouped_matmul(x, w, jnp.asarray(sizes)))[:held]
+        monkeypatch.setattr(gm, "_tiles", lambda *a: (row_tile, _K, cols))
+        return np.asarray(gm.grouped_matmul(x, w, sizes, _two_terms))[:held]
 
-    old = at(256)
-    group = np.repeat(np.arange(4), sizes)
+    old = at(128)
+    group = np.repeat(np.arange(4), _SIZES)
     plain = np.einsum("rk,rkn->rn", np.asarray(x, np.float32)[:held],
                       np.asarray(w, np.float32)[group])
     assert np.abs(old - plain).max() < 1e-4
-    for tiles in ((128,), (64,), (32,), (128, n // 2)):
+    for tiles in ((64,), (32,), (16,), (64, n // 2)):
         assert (at(*tiles).view(np.uint32) == old.view(np.uint32)).all()
 
 
@@ -410,7 +502,7 @@ def test_a_rows_product_does_not_depend_on_its_tile(monkeypatch):
 def test_the_sweep_runs_the_cells_own_shapes(family, decode_call, touched):
     """`scripts/tpu_kernel_sweep.py --gmm` takes its shapes from the cells'
     configuration files: the decode call is the one `_CALLS` names, a
-    prompt's rows are two a (token, expert) pair, and the two products'
+    prompt's rows are one a (token, expert) pair, and the two products'
     least times add up to the benchmark's `grouped_product_cost`."""
     import importlib
 
@@ -427,13 +519,15 @@ def test_the_sweep_runs_the_cells_own_shapes(family, decode_call, touched):
     assert (int(sizes.sum()), len(sizes), int((sizes > 0).sum())) == \
         (rows, groups, touched)
     for label, sizes, tokens in cases[1:]:
-        assert sizes.sum() == tokens * conf["num_experts_per_tok"] * 2
-        assert (sizes % 2 == 0).all()
-    pairs = rows // 2
+        assert sizes.sum() == tokens * conf["num_experts_per_tok"]
+    # beside it, `megablox.gmm` over the doubled rows at PR 46's tiles
+    assert sweep._doubled_rows_tiles(2 * rows, groups, k, n) == \
+        (128, k, 1408 if family == "mla_moe" else 512)
+    pairs = rows
     parts = [sweep._gmm_cost(pairs, touched, a, b) for _, a, b in products]
     assert np.allclose(np.sum(parts, axis=0),
                        costs.grouped_product_cost(conf, pairs, touched))
-    assert all(tk in (k, k // 2) and n % tn == 0
+    assert all(tk == k and n % tn == 0
                for _, tk, tn in sweep._gmm_tilings(k, n))
 
 
