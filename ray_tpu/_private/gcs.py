@@ -258,6 +258,10 @@ class GcsServer:
         self.placement_groups: dict[str, dict] = {}
         self.task_events: deque = deque(maxlen=self.config.task_events_max_buffer)
         self.pending_demand: dict[str, list] = {}
+        # actor -> (node, placement demand) of the creations this GCS has
+        # sent to a raylet and that have not answered yet: what a heartbeat's
+        # `available_resources` may not know of (`handle_heartbeat`).
+        self._placing: dict[str, tuple[str, dict]] = {}
         # Forwarding directory for objects evacuated off drained nodes:
         # oid_hex -> node_id of the copy's new home. Owners consult it
         # (GetObjectRelocations) before falling back to lineage
@@ -1364,7 +1368,10 @@ class GcsServer:
         loop = asyncio.get_running_loop()
         grace = (self.config.health_check_period_s
                  * self.config.num_heartbeats_timeout)
-        rebind_deadline = loop.time() + grace
+        # The window for a rebind opens when the connection is MISSED, not
+        # with the call: a call may be older than the grace when the socket
+        # drops under its reply (a CreateActor waits for a worker's start).
+        rebind_deadline = None
         call_deadline = None if timeout is None else loop.time() + timeout
         sent_once = False
         try:
@@ -1375,11 +1382,14 @@ class GcsServer:
                         f"node {node_id[:8]} is dead")
                 conn = self.node_conns.get(node_id)
                 if conn is None or conn.closed:
+                    if rebind_deadline is None:
+                        rebind_deadline = loop.time() + grace
                     if not wait_rebind or loop.time() > rebind_deadline:
                         raise rpc.ConnectionLost(
                             f"no raylet connection to node {node_id[:8]}")
                     await asyncio.sleep(0.05)
                     continue
+                rebind_deadline = None
                 if stamped is not None:
                     outstanding = sess["outstanding"]
                     stamped[rpc._ACK_KEY] = (min(outstanding) - 1
@@ -1428,6 +1438,18 @@ class GcsServer:
                               "re-register to reattach"}
         node.last_heartbeat = time.monotonic()
         node.available_resources = payload.get("available_resources", node.available_resources)
+        # The raylet's word is the truth about what it HOLDS; a creation
+        # sent there that it has not got round to (a loaded host: seconds
+        # between CreateActor's arrival and the acquire) is not held yet,
+        # and a view without it herds the next creation of a burst onto
+        # the same node, where it waits out the lease timeout and dies.
+        # Charged twice for the moment between the raylet's acquire and
+        # its answer: that errs to the safe side, and ends with the answer.
+        for node_id, demand in self._placing.values():
+            if node_id == node.node_id:
+                for name, amount in demand.items():
+                    node.available_resources[name] = max(
+                        0.0, node.available_resources.get(name, 0.0) - amount)
         if self.native_sched is not None:
             # A draining node keeps heartbeating but must stay dead in
             # the placement mirror (update_node defaults alive=True).
@@ -1948,6 +1970,7 @@ class GcsServer:
             self._creation_task_id(actor_id, a["spec"]), a["class_name"],
             "CREATE_SCHEDULED", job_id=a.get("job_id", ""),
             actor_id=actor_id, target_node=node_id)
+        self._placing[actor_id] = (node_id, placement_demand)
         try:
             # _call_node, not a raw conn.call: a socket flap mid-create
             # replays the request after the raylet re-registers, and the
@@ -1978,6 +2001,8 @@ class GcsServer:
             logger.warning("actor %s creation rpc to node %s failed: %s",
                            actor_id[:8], node_id[:8], e)
             await self._on_actor_worker_death(actor_id, f"creation rpc failed: {e}")
+        finally:
+            self._placing.pop(actor_id, None)
 
     async def handle_actor_ready(self, conn, payload):
         require_fields(payload, "actor_id", "address",

@@ -672,13 +672,16 @@ class ResilientConnection:
         # clock running) across cycles instead of resetting per cycle.
         if self._established_at and \
                 loop.time() - self._established_at < _MIN_STABLE_S:
-            if not self._flap_attempts:
-                self._flap_started = loop.time()
             self._flap_attempts += 1
         else:
+            # A cycle that replaces a connection which HELD begins a
+            # streak and anchors its grace. (The cycle's failed dials
+            # count into _flap_attempts too: a count above zero does not
+            # say that a quick death took an anchor.)
             self._flap_attempts = 0
+            self._flap_started = loop.time()
         attempt = self._flap_attempts
-        deadline = (self._flap_started if attempt else loop.time()) + budget
+        deadline = self._flap_started + budget
         # One quick death is a normal restart race; a STREAK of them is
         # the accept-then-close pattern — only then pre-delay the dial.
         if attempt >= 2:

@@ -475,15 +475,27 @@ class NetChaos:
                     except Exception:
                         pass
                 link.writers.clear()
+            # Every proxied connection has a handler and two pumps on this
+            # loop, and closing their sockets only ASKS them to end: a loop
+            # stopped before they did leaves them pending, and they are
+            # then destroyed pending ("Task was destroyed but it is
+            # pending!") whenever the collector finds the loop.
+            rest = [t for t in asyncio.all_tasks()
+                    if t is not asyncio.current_task()]
+            for t in rest:
+                t.cancel()
+            await asyncio.gather(*rest, return_exceptions=True)
 
+        loop = self._loop
         try:
-            asyncio.run_coroutine_threadsafe(
-                _shutdown(), self._loop).result(10.0)
+            asyncio.run_coroutine_threadsafe(_shutdown(), loop).result(10.0)
         except Exception:
             pass
-        self._loop.call_soon_threadsafe(self._loop.stop)
+        loop.call_soon_threadsafe(loop.stop)
         if self._thread is not None:
             self._thread.join(timeout=5)
+            if not self._thread.is_alive():
+                loop.close()
         self._loop = None
 
     def __enter__(self):

@@ -73,6 +73,14 @@ def _check_boundary(s: _Session) -> None:
         raise ElasticPauseInterrupt()
 
 
+def check_boundary() -> None:
+    """For a loop that WAITS inside a step (for its peers, for data): the
+    check `report` makes at a step's boundary, to be made while waiting,
+    so that a stop or an elastic pause ends the wait as it ends a step
+    (it raises what they raise)."""
+    _check_boundary(_get_session())
+
+
 def _set_session(s: _Session | None) -> None:
     _local.session = s
 

@@ -21,6 +21,7 @@ re-shard → checkpoint restart (counted) → fail.
 
 from __future__ import annotations
 
+import logging
 import queue as _queue
 import time
 import uuid
@@ -34,6 +35,8 @@ from ray_tpu.train.checkpoint import Checkpoint
 from ray_tpu.train.config import (ElasticConfig, FailureConfig, RunConfig,
                                   ScalingConfig)
 from ray_tpu.train.worker_group import WorkerGroup
+
+logger = logging.getLogger(__name__)
 
 
 @dataclass
@@ -103,6 +106,15 @@ class JaxTrainer:
                 restore_from = getattr(e, "_last_checkpoint", None) \
                     or restore_from
                 self.telemetry["full_restarts"] += 1
+                # Never silent: WHY the attempt ended is what the next
+                # reader of a counted fallback asks first.
+                self.telemetry.setdefault("restart_reasons", []).append(
+                    f"{type(e).__name__}: {e}")
+                logger.warning("training attempt %d ended (%s: %s); "
+                               "restarting from %s", attempt,
+                               type(e).__name__, e,
+                               restore_from.path if restore_from
+                               else "the start")
                 if elastic is not None:
                     self.telemetry["elastic_fallbacks"] += 1
                     _note_elastic("fallback")
@@ -308,7 +320,8 @@ class JaxTrainer:
                     raise err
                 epoch += 1
                 self._resize(group, survivors, 0, elastic, run_id, blob,
-                             epoch, fold, last_ckpt, direction="shrink")
+                             epoch, fold, last_ckpt, node_of,
+                             direction="shrink")
                 node_of = dict(zip(group.workers,
                                    group.run_on_all("node_id")))
                 continue
@@ -327,23 +340,30 @@ class JaxTrainer:
                     epoch += 1
                     self._resize(group, list(group.workers), n_new,
                                  elastic, run_id, blob, epoch, fold,
-                                 last_ckpt, direction="grow")
+                                 last_ckpt, node_of, direction="grow")
                     node_of = dict(zip(group.workers,
                                        group.run_on_all("node_id")))
             time.sleep(0.05)
 
     def _resize(self, group: WorkerGroup, survivors: list, n_new: int,
                 elastic: ElasticConfig, run_id: str, blob: bytes,
-                epoch: int, fold, last_ckpt, *, direction: str) -> None:
+                epoch: int, fold, last_ckpt, node_of: dict, *,
+                direction: str) -> None:
         """One membership change: pause at the step boundary, re-home
         state through the device plane, rebuild the rendezvous, resume.
         Any failure raises RayTpuError carrying the newest checkpoint —
-        fit()'s retry loop is the (counted) fallback rung."""
+        fit()'s retry loop is the (counted) fallback rung.
+
+        A second reclaim may land while this one is being answered (spot
+        capacity goes in batches): a member whose node started to drain,
+        or died drained, AFTER the resize began leaves with this resize
+        too. Only a death with no pre-death signal gives the path up."""
         from ray_tpu._private import device_objects
         from ray_tpu._private.api_internal import get_core_worker
 
         cw = get_core_worker()
         deadline = time.monotonic() + elastic.reshard_timeout_s
+        survivors = list(survivors)
         departing = [w for w in group.workers if w not in survivors]
 
         def fallback(why: str):
@@ -351,16 +371,24 @@ class JaxTrainer:
             err._last_checkpoint = last_ckpt
             return err
 
+        def leaves_too(w) -> None:
+            survivors.remove(w)
+            departing.append(w)
+            if len(survivors) < elastic.min_workers:
+                raise fallback(
+                    f"would leave {len(survivors)} < min_workers="
+                    f"{elastic.min_workers} workers")
+
         # a. Pause everyone at the next step boundary.
         for w in group.workers:
             w.request_pause.remote()
         lost_alive: set = set()
         max_step = -1
-        survivor_steps: list[int] = []
+        parked_at: dict = {}        # a parked member -> its kept step
         park_detail: list = []
         while True:
             parked = True
-            survivor_steps = []
+            parked_at = {}
             park_detail = []
             for i, w in enumerate(group.workers):
                 if w in lost_alive:
@@ -374,6 +402,12 @@ class JaxTrainer:
                     if w in departing:
                         lost_alive.add(w)
                         continue
+                    if _node_is_leaving(node_of.get(w)):
+                        # (its node was draining or is recorded dead: a
+                        # reclaim that came during this resize)
+                        leaves_too(w)
+                        lost_alive.add(w)
+                        continue
                     raise fallback("survivor died during pause")
                 fold([p])
                 max_step = max(max_step, p.get("state_step", -1))
@@ -383,18 +417,22 @@ class JaxTrainer:
                                     "state_step": p.get("state_step")})
                 if not (p.get("paused") or p.get("done")):
                     parked = False
-                elif w in survivors:
-                    s_step = p.get("state_step", -1)
-                    # state_step < 0 = still warming up (never reached
-                    # keep_state): zero steps computed, zero lost.
-                    if s_step >= 0:
-                        survivor_steps.append(s_step)
+                elif p.get("state_step", -1) >= 0:
+                    # (state_step < 0 = still warming up, never reached
+                    # keep_state: zero steps computed, zero lost)
+                    parked_at[w] = p["state_step"]
             if parked:
                 break
             if time.monotonic() > deadline:
                 raise fallback("gang did not reach a step boundary "
                                f"within {elastic.reshard_timeout_s:g}s")
             time.sleep(0.02)
+        # The gang is parked. A survivor whose node has begun to drain
+        # since leaves now, while it can still hand its state over, and
+        # not when the drain's deadline kills it in the middle of e.-g.
+        for w in list(survivors):
+            if _node_is_leaving(node_of.get(w)):
+                leaves_too(w)
 
         # b. Re-home departing state: resolve each departing rank's kept
         # tree through the device plane — pulled from the worker while
@@ -466,6 +504,8 @@ class JaxTrainer:
         # g. Rebuild the rendezvous + resume at step N+1.
         self._start_epoch(group, run_id, epoch, blob, None)
 
+        # (of those who STAY: a member that left after parking is not one)
+        survivor_steps = [parked_at[w] for w in survivors if w in parked_at]
         resumed_from = min(survivor_steps) if survivor_steps else -1
         lost = max(0, max_step - resumed_from) \
             if (max_step >= 0 and survivor_steps) else 0
@@ -480,15 +520,30 @@ class JaxTrainer:
         _note_elastic(direction, steps_lost=lost)
 
 
-def _node_is_alive(node_id: str) -> bool:
+def _node_row(node_id: "str | None") -> "dict | None":
+    """The node table's row, or None: no such node, or no table."""
     try:
-        for node in ray_tpu.nodes():
-            if node.get("node_id") == node_id:
-                return bool(node.get("alive")) \
-                    and node.get("state") in (None, "ALIVE")
+        return next((node for node in ray_tpu.nodes()
+                     if node.get("node_id") == node_id), None)
     except Exception:
-        pass
-    return False
+        return None
+
+
+def _row_is_up(row: dict) -> bool:
+    return bool(row.get("alive")) and row.get("state") in (None, "ALIVE")
+
+
+def _node_is_alive(node_id: str) -> bool:
+    row = _node_row(node_id)
+    return row is not None and _row_is_up(row)
+
+
+def _node_is_leaving(node_id: "str | None") -> bool:
+    """True where the node table SAYS so: draining, or dead. (Not the
+    negation of `_node_is_alive`: a table that could not be read is no
+    pre-death signal.)"""
+    row = _node_row(node_id)
+    return row is not None and not _row_is_up(row)
 
 
 def _free_worker_slots(scaling: ScalingConfig, exclude: set) -> int:

@@ -160,8 +160,72 @@ TIME_LIMIT_S = 180.0
 _real_stderr_fd = None
 
 
+# ---- one compile cache a run, one session directory a worker ----
+# About a hundred test engines share five tiny configurations and each
+# compiles its programs anew (they are closures of `LLMEngine.__init__`);
+# six workers compile the same tiny models side by side. The run keeps ONE
+# persistent cache under its own base temp: empty when the run starts (a
+# run's time never depends on the run before it), shared by the workers and
+# by every process a test starts (the variables are inherited; a lease-holder
+# sets no directory of its own where JAX_COMPILATION_CACHE_DIR is set).
+# `tests/chip_compile` switches it off around its described-device compiles,
+# which cannot be read back.
+
+
+def _run_base_temp(config) -> str:
+    if hasattr(config, "workerinput"):      # an xdist worker: <base>/popen-gwN
+        return os.path.dirname(config.option.basetemp)
+    return str(config._tmp_path_factory.getbasetemp())
+
+
+def pytest_sessionstart(session):
+    # A worker's clusters keep their sessions under the worker's own base
+    # temp, not in the machine's /tmp/ray_tpu_sessions: the benchmark's span
+    # readers take "the newest session with a span in the window", and a
+    # rehearsal on another worker has one (`experts_touched_share` came out
+    # None so, in one whole run); and thousands of old sessions are listed
+    # and dated at every such reading.
+    os.environ["RAY_TPU_TEMP_DIR"] = os.path.join(
+        str(session.config._tmp_path_factory.getbasetemp()), "sessions")
+    cache = os.path.join(_run_base_temp(session.config), "jax_cache")
+    os.makedirs(cache, exist_ok=True)
+    settings = {"jax_compilation_cache_dir": cache,
+                "jax_persistent_cache_min_compile_time_secs": 0,
+                "jax_persistent_cache_min_entry_size_bytes": -1}
+    for name, value in settings.items():
+        os.environ[name.upper()] = str(value)
+        if "jax" in sys.modules:
+            jax.config.update(name, value)
+
+
+# ---- the order the files are handed out in ----
+# Under `--dist loadfile` a file is one worker's chain, and the run is over
+# when the last chain is. xdist hands the files out by their NUMBER of
+# tests, most first, which puts the files of one to three tests at the end
+# of the queue: the real-width compiles, two minutes a test, ran as the
+# run's tail beside five idle workers (100 s of a 956-s run). One rule:
+# those files go first; the others follow as xdist would have put them, by
+# their number of tests (the controller is told to keep the order it gets).
+_FIRST = "tests/test_chip_compile_"
+
+
+def pytest_collection_modifyitems(items):
+    tests_of = {}
+    for item in items:
+        name = item.nodeid.split("::")[0]
+        tests_of[name] = tests_of.get(name, 0) + 1
+
+    def place(item):
+        name = item.nodeid.split("::")[0]
+        return not name.startswith(_FIRST), -tests_of[name], name
+
+    items.sort(key=place)       # (stable: a file's tests keep their order)
+
+
 def pytest_configure(config):
     global _real_stderr_fd
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
     config.addinivalue_line(
         "markers", "time_limit(seconds): this test's own limit in place of "
         f"the default {TIME_LIMIT_S:g} s; say why beside it")
@@ -193,6 +257,48 @@ def pytest_runtest_protocol(item, nextitem):
         signal.setitimer(signal.ITIMER_REAL, 0)
         signal.signal(signal.SIGALRM, previous)
         faulthandler.cancel_dump_traceback_later()
+
+
+# ---- where the run's time went ----
+# The suite lives under a clock (the driver cuts it at 1,470 s) and is
+# planned by its costliest files: under `--dist loadfile` a file is one
+# worker's chain. The run says both itself, at its end, in the log the
+# driver keeps (`/tmp/_t1.log`).
+
+
+def where_the_time_went(durations, files: int = 10, tests: int = 20) -> list:
+    """(nodeid, seconds) of every phase of every test -> the summary's
+    lines: the sum, the costliest files (their seconds summed over the
+    workers, and their tests), the costliest tests."""
+    by_test, by_file = {}, {}
+    for nodeid, seconds in durations:
+        by_test[nodeid] = by_test.get(nodeid, 0.0) + seconds
+    for nodeid, seconds in by_test.items():
+        cost, n = by_file.get(nodeid.split("::")[0], (0.0, 0))
+        by_file[nodeid.split("::")[0]] = (cost + seconds, n + 1)
+    top = lambda d, n, key: sorted(d.items(), key=key)[:n]  # noqa: E731
+    lines = [f"{sum(by_test.values()):.0f} s summed over {len(by_test)} "
+             f"tests in {len(by_file)} files; the {files} costliest files "
+             f"(seconds, tests):"]
+    lines += [f"{cost:8.1f} {n:4d}  {name}" for name, (cost, n) in
+              top(by_file, files, lambda kv: (-kv[1][0], kv[0]))]
+    lines.append(f"the {tests} costliest tests (seconds):")
+    lines += [f"{cost:8.1f}  {name}" for name, cost in
+              top(by_test, tests, lambda kv: (-kv[1], kv[0]))]
+    return lines
+
+
+def pytest_terminal_summary(terminalreporter, config):
+    if hasattr(config, "workerinput"):      # the controller prints, once
+        return
+    durations = [(r.nodeid, r.duration)
+                 for reports in terminalreporter.stats.values()
+                 for r in reports
+                 if hasattr(r, "duration") and hasattr(r, "nodeid")]
+    if durations:
+        terminalreporter.section("where the time went")
+        for line in where_the_time_went(durations):
+            terminalreporter.write_line(line)
 
 
 # ---- teardown-hygiene enforcement (VERDICT r3 weak #5) ----

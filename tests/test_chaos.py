@@ -116,22 +116,27 @@ def test_preempted_node_is_a_non_event(ray_start_cluster_head):
 
 
 @pytest.mark.smoke
-def test_preemption_deadline_fail_fast(ray_start_cluster_head):
+def test_preemption_deadline_fail_fast(ray_start_cluster_head, tmp_path):
     """Work that exceeds the drain deadline is failed fast and
     RETRYABLE: the drain completes on time and the task finishes on a
     surviving node instead of being failed infeasible."""
     cluster = ray_start_cluster_head
     target = cluster.add_node(num_cpus=2, resources={"side": 1})
-    cluster.add_node(num_cpus=2, resources={"side": 1})
     cluster.wait_for_nodes()
+    started = tmp_path / "started"
 
     @ray_tpu.remote(resources={"side": 0.1}, max_retries=3)
     def outlives_deadline(x):
-        time.sleep(20.0)
+        # (the first attempt, which the drain fails, and not its retry)
+        if not started.exists():
+            started.touch()
+            time.sleep(20.0)
         return x * 3
 
     ref = outlives_deadline.remote(5)
-    time.sleep(1.5)
+    wait_for_condition(started.exists)      # running, and on the target
+    cluster.add_node(num_cpus=2, resources={"side": 1})
+    cluster.wait_for_nodes()
     preempter = NodePreempter(cluster, deadline_s=2)
     t0 = time.monotonic()
     result = preempter.preempt(target)

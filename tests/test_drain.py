@@ -8,6 +8,7 @@ Smoke-marked tier-1 gates; each test keeps its cluster small and its
 deadlines short so the suite stays inside the tier-1 budget.
 """
 
+import os
 import time
 
 import numpy as np
@@ -149,11 +150,14 @@ def test_drain_deadline_fails_running_lease_retryable(drain_cluster):
     cw = get_core_worker()
 
     @ray_tpu.remote(resources={"pin": 0.1}, max_retries=3)
-    def stuck(x):
-        time.sleep(20.0)
+    def stuck(x, on):
+        # (stuck where the drain kills it, and nowhere else: the retry
+        # answers at once)
+        if ray_tpu.get_runtime_context().get_node_id() == on:
+            time.sleep(20.0)
         return x + 1
 
-    ref = stuck.remote(1)
+    ref = stuck.remote(1, target.node_id)
     # Running on the target: a fresh node takes seconds to attach its
     # first worker, and until then the drain has no lease to kill.
     wait_for_condition(lambda: _raylet_state(target)["leases_granted"] == 1)
@@ -200,7 +204,7 @@ def test_drain_rejects_grant_still_attaching_its_worker(drain_cluster):
     assert ray_tpu.get(ref, timeout=60) == other.node_id
 
 
-def test_drain_rejection_is_retry_elsewhere(drain_cluster):
+def test_drain_rejection_is_retry_elsewhere(drain_cluster, tmp_path):
     """Regression (satellite): a lease that races the drain flag used to
     be failed INFEASIBLE by the owner ({"error": "node draining"} with
     no retry classification → _fail_queued_infeasible). It must stay
@@ -209,9 +213,12 @@ def test_drain_rejection_is_retry_elsewhere(drain_cluster):
     target = cluster.add_node(num_cpus=1, resources={"pin": 1})
     cluster.wait_for_nodes()
 
+    released = str(tmp_path / "released")
+
     @ray_tpu.remote(resources={"pin": 0.1})
     def hold(x):
-        time.sleep(3.0)
+        while not os.path.exists(released):
+            time.sleep(0.02)
         return x
 
     @ray_tpu.remote(resources={"pin": 0.1})
@@ -221,14 +228,15 @@ def test_drain_rejection_is_retry_elsewhere(drain_cluster):
     # One running lease occupies the node; the next requests queue at
     # the target raylet (no other node offers "pin").
     running = hold.remote(0)
-    time.sleep(1.0)
+    wait_for_condition(lambda: _raylet_state(target)["active_leases"] == 1)
     queued = [quick.remote(i) for i in range(3)]
-    time.sleep(0.5)
+    wait_for_condition(lambda: _raylet_state(target)["pending_leases"] >= 1)
     # Drain with nowhere to respill: the queued leases get the
     # {"error": "node draining", "draining": True} rejection.
     resp = cluster.drain_node(target, deadline_s=4, reason="manual",
                               wait=False)
     assert resp.get("ok"), resp
+    open(released, "w").close()  # the running lease ends within the deadline
     # New capacity arrives while the owner is in its drain-retry loop.
     cluster.add_node(num_cpus=2, resources={"pin": 1})
     cluster.wait_for_nodes()

@@ -14,7 +14,6 @@ pins (evacuating to the same dying node is pointless).
 """
 
 import threading
-import time
 
 import pytest
 
@@ -63,30 +62,65 @@ def _scaling(n, *, min_workers, max_workers=None, reshard_timeout_s=20.0,
                               grow_poll_s=grow_poll_s))
 
 
-def _elastic_loop(cfg):
-    """Counts steps in a jax array preserved via session.keep_state.
+FINISH, LAST_STEP = "finish", "last_step"
 
-    Steps are paced on wall-clock boundaries shared via cfg["t0"] — the
-    no-collective stand-in for a lockstep SPMD gang: every worker's
-    step k starts at t0 + k*period, so the gang stays within a step of
-    each other and self-realigns after a pause (steps behind schedule
-    run back-to-back). That keeps max_step − min(survivor_step) — the
-    steps-lost metric — an honest ≈1 per resize, like a real gang.
+
+def _elastic_loop(cfg):
+    """Counts steps in a jax array preserved via session.keep_state, and
+    trains UNTIL IT IS TOLD to finish (`_finish`): how long the gang
+    trains is the test's to say, after it has seen what it came to see,
+    and not the clock's. (The loop used to stop at a step count, and a
+    step WAS (time - t0) / period: on a loaded host 200 steps of 0.05 s
+    were over before a replacement node had registered, and nothing was
+    left to grow; and members that fell behind the clock ran their steps
+    back to back, each at its own pace, fifty steps apart.)
+
+    The gang is in lockstep as an SPMD gang is, by meeting and not by the
+    clock: a member starts step k when every member of its epoch has
+    finished step k - 1 (each leaves its last finished step in a file of
+    `cfg["signal_dir"]`), so at any moment the members are within a step
+    of each other whatever the host is busy with, and
+    max_step − min(survivor_step) — the steps-lost metric — is an honest
+    ≈1 per resize. A pause or a stop ends the wait for the others as it
+    ends a step (a collective the trainer aborts). `period` only paces.
+
+    Told to finish, rank 0 names the last step, a few past its own: nobody
+    is further ahead of it than one.
 
     The invariant w[0] == kept_step + 1 proves the re-sharded array
     really round-tripped through the device plane with its contents
     intact (state_ok). Rank 0 also reports dict checkpoints so the
     fallback rung WOULD be available — the happy-path assertions check
     it is never taken (restored stays False)."""
+    import os
     import time as _t
 
     import jax.numpy as jnp
 
     from ray_tpu.train import session
 
-    total = cfg["total_steps"]
-    period = cfg.get("period", 0.05)
-    t0 = cfg["t0"]
+    rank, world = session.get_world_rank(), session.get_world_size()
+    epoch = session.get_elastic_epoch()
+
+    def at(member):     # where a member of this epoch leaves its last step
+        return os.path.join(cfg["signal_dir"], f"at-{epoch}-{member}")
+
+    def read(path):
+        try:
+            with open(path) as f:
+                return int(f.read())
+        except (OSError, ValueError):
+            return None
+
+    def write(path, value):
+        with open(f"{path}.{rank}.new", "w") as f:
+            f.write(str(value))
+        os.replace(f"{path}.{rank}.new", path)
+
+    def gang_finished(step):
+        done = [read(at(r)) for r in range(world)]
+        return all(d is not None and d >= step for d in done)
+
     restored = session.get_checkpoint() is not None
     state = session.get_elastic_state()
     peers = session.get_peer_states()
@@ -97,29 +131,45 @@ def _elastic_loop(cfg):
         seeded = True
     state_ok = True
     if state is None:
-        # Fresh start: join at the CURRENT wall-clock step, not step 0.
-        # A real gang rendezvous-barriers at startup (nobody computes
-        # until all arrive); without that, a worker whose process spawn
-        # lost seconds to CPU contention would crawl through a hundred
-        # catch-up steps and its lag would read as "steps lost".
-        start = min(total - 1, max(0, int((_t.time() - t0) / period)))
-        w = jnp.full((8,), float(start), jnp.float32)
+        step = 0
+        w = jnp.zeros((8,), jnp.float32)
     else:
-        start = int(state["step"]) + 1
+        step = int(state["step"]) + 1
         w = state["w"]
         state_ok = abs(float(w[0]) - (int(state["step"]) + 1)) < 1e-6
-    for step in range(start, total):
+    write(at(rank), step - 1)
+    last_path = os.path.join(cfg["signal_dir"], LAST_STEP)
+    finish = os.path.join(cfg["signal_dir"], FINISH)
+    while (last := read(last_path)) is None or step <= last:
+        while not gang_finished(step - 1):
+            session.check_boundary()
+            _t.sleep(0.005)
+        if rank == 0 and last is None and os.path.exists(finish):
+            write(last_path, step + 5)
         w = w + 1.0
-        ckpt = ({"step": step} if session.get_world_rank() == 0
-                and step % 10 == 0 else None)
-        session.report({"step": step, "restored": restored,
-                        "world": session.get_world_size(),
-                        "epoch": session.get_elastic_epoch(),
-                        "peers": len(peers), "seeded": seeded,
-                        "state_ok": bool(state_ok)}, checkpoint=ckpt)
+        ckpt = ({"step": step} if rank == 0 and step % 10 == 0 else None)
+        session.report({"step": step, "restored": restored, "world": world,
+                        "epoch": epoch, "peers": len(peers),
+                        "seeded": seeded, "state_ok": bool(state_ok)},
+                       checkpoint=ckpt)
         session.keep_state({"step": step, "w": w}, step=step)
-        _t.sleep(max(0.0, t0 + (step + 1) * period - _t.time()))
+        write(at(rank), step)
+        _t.sleep(cfg["period"])
+        step += 1
     return float(w[0])
+
+
+def _finish(th, holder, cfg, timeout) -> int:
+    """Tells the gang to finish and waits for `fit()` -> the last step
+    the gang agreed on."""
+    import os
+
+    open(os.path.join(cfg["signal_dir"], FINISH), "w").close()
+    th.join(timeout=timeout)
+    assert not th.is_alive(), "fit() did not finish"
+    assert "error" not in holder, f"fit raised: {holder.get('error')}"
+    with open(os.path.join(cfg["signal_dir"], LAST_STEP)) as f:
+        return int(f.read())
 
 
 def _fit_in_thread(trainer):
@@ -146,10 +196,9 @@ def test_elastic_shrink_then_grow_back(elastic_cluster, tmp_path):
     cluster.wait_for_nodes()
     gauges_before = util_metrics.train_elastic_snapshot()
 
+    loop = {"period": 0.05, "signal_dir": str(tmp_path)}
     trainer = JaxTrainer(
-        _elastic_loop,
-        train_loop_config={"total_steps": 200, "period": 0.05,
-                           "t0": time.time()},
+        _elastic_loop, train_loop_config=loop,
         scaling_config=_scaling(4, min_workers=2, max_workers=4),
         run_config=RunConfig(storage_path=str(tmp_path),
                              failure_config=FailureConfig(max_failures=1)),
@@ -167,17 +216,16 @@ def test_elastic_shrink_then_grow_back(elastic_cluster, tmp_path):
     wait_for_condition(lambda: trainer.telemetry["shrinks"] >= 1, timeout=30)
     cluster.remove_node(nodes[1])
 
-    # Capacity returns: the trainer must grow back on its own.
+    # Capacity returns: the trainer must grow back on its own, while the
+    # gang still trains.
     _gang_node(cluster)
     wait_for_condition(lambda: trainer.telemetry["grows"] >= 1, timeout=60)
 
-    th.join(timeout=120)
-    assert not th.is_alive(), "fit() did not finish"
-    assert "error" not in holder, f"fit raised: {holder.get('error')}"
+    last = _finish(th, holder, loop, timeout=120)
     result = holder["result"]
 
     hist = result.metrics_history
-    assert result.metrics["step"] == 199
+    assert result.metrics["step"] == last
     # Membership went 4 → 3 → 4, and the run ended on the regrown gang.
     worlds = [h["world"] for h in hist]
     assert 3 in worlds and 4 in worlds
@@ -192,14 +240,15 @@ def test_elastic_shrink_then_grow_back(elastic_cluster, tmp_path):
     assert not any(h["restored"] for h in hist)
     t = trainer.telemetry
     assert t["shrinks"] >= 1 and t["grows"] >= 1
-    assert t["elastic_fallbacks"] == 0 and t["full_restarts"] == 0
+    assert t["elastic_fallbacks"] == 0 and t["full_restarts"] == 0, \
+        t.get("restart_reasons")
     # Steps-lost-per-resize ≤ 2 (target ≈ 1): pause lands at the NEXT
     # step boundary, so survivors resume within a step of the leader.
     assert t["steps_lost"] <= 2 * t["resizes"], str(t["resize_log"])
     # History is continuous across the resizes (no step goes backward by
     # more than the replayed boundary step).
     steps = [h["step"] for h in hist]
-    assert steps[-1] == 199
+    assert steps[-1] == last
     assert all(b - a >= -2 for a, b in zip(steps, steps[1:]))
     # The resize/steps-lost counters reached the util.metrics gauges
     # (and through them /metrics + `ray_tpu status`).
@@ -293,10 +342,9 @@ def test_chaos_spot_preemption_rate(elastic_cluster, tmp_path):
         _gang_node(cluster)
     cluster.wait_for_nodes()
 
+    loop = {"period": 0.06, "signal_dir": str(tmp_path)}
     trainer = JaxTrainer(
-        _elastic_loop,
-        train_loop_config={"total_steps": 120, "period": 0.06,
-                           "t0": time.time()},
+        _elastic_loop, train_loop_config=loop,
         scaling_config=_scaling(4, min_workers=2, max_workers=4,
                                 grow_poll_s=0.5),
         run_config=RunConfig(storage_path=str(tmp_path),
@@ -311,26 +359,97 @@ def test_chaos_spot_preemption_rate(elastic_cluster, tmp_path):
         node_args={"num_cpus": 2, "resources": {"trainer": 1}},
         step_source=lambda: int(trainer.latest_metrics.get("step", -1)))
     with preempter:
-        th.join(timeout=240)
-    assert not th.is_alive(), "fit() did not finish"
-    assert "error" not in holder, f"fit raised: {holder.get('error')}"
+        # The gang trains until the schedule is spent (both preemptions,
+        # each with its respawn: leaving `with` waits that out), a shrink
+        # is behind it and the gang has grown back onto a respawned node;
+        # or until the elastic path gave up, which the assertions name.
+        gave_up = ("elastic_fallbacks", "full_restarts")
+        wait_for_condition(
+            lambda: "error" in holder
+            or any(trainer.telemetry[k] for k in gave_up)
+            or (preempter.preemptions >= 2
+                and all(trainer.telemetry[k] for k in ("shrinks", "grows"))),
+            timeout=180)
+    last = _finish(th, holder, loop, timeout=60)
     result = holder["result"]
 
-    assert preempter.preemptions >= 1
+    assert preempter.preemptions == 2
     # The schedule is reproducible: fired near the seeded gaps.
     assert preempter.step_schedule
     assert preempter.step_schedule[0] >= 14  # first gap ∈ [14, 26]
 
-    hist = result.metrics_history
-    assert result.metrics["step"] == 119
-    assert all(h["state_ok"] for h in hist)
-    # Zero checkpoint restores, zero full-job restarts.
-    assert not any(h["restored"] for h in hist)
+    # Zero full-job restarts, zero checkpoint restores.
     t = trainer.telemetry
-    assert t["full_restarts"] == 0 and t["elastic_fallbacks"] == 0
-    assert t["shrinks"] >= 1
+    assert t["full_restarts"] == 0 and t["elastic_fallbacks"] == 0, \
+        t.get("restart_reasons")
+    hist = result.metrics_history
+    assert result.metrics["step"] == last
+    assert all(h["state_ok"] for h in hist)
+    assert not any(h["restored"] for h in hist)
+    assert t["shrinks"] >= 1 and t["grows"] >= 1
     # steps-lost-per-preemption ≤ 2 (target ≈ 1).
-    assert t["steps_lost"] <= 2 * t["resizes"]
+    assert t["steps_lost"] <= 2 * t["resizes"], str(t["resize_log"])
+
+
+def test_reclaims_during_a_resize_leave_with_it(elastic_cluster, tmp_path):
+    """Spot capacity goes in batches: while the trainer answers one
+    reclaim, a second node is reclaimed on a deadline too short for its
+    member to leave by (dead when the pause polls it) and a third begins
+    to drain (draining still when the gang has parked). Both leave WITH
+    this resize, the dead one's shard lost, the draining one's handed
+    over; nothing falls back. ("survivor died during pause" was a counted
+    fallback: the node table had said why the member died.)"""
+    cluster = elastic_cluster
+    nodes = [_gang_node(cluster) for _ in range(4)]
+    cluster.wait_for_nodes()
+
+    loop = {"period": 0.05, "signal_dir": str(tmp_path)}
+    trainer = JaxTrainer(
+        _elastic_loop, train_loop_config=loop,
+        scaling_config=_scaling(4, min_workers=1, max_workers=4),
+        run_config=RunConfig(storage_path=str(tmp_path),
+                             failure_config=FailureConfig(max_failures=1)),
+        collective_backend=None)
+    resize = trainer._resize
+
+    def resize_after_two_more_reclaims(*args, **kwargs):
+        trainer._resize = resize
+        NodePreempter(cluster, deadline_s=1).preempt(nodes[2])
+        cluster.drain_node(nodes[3], deadline_s=60, reason="preemption",
+                           wait=False)
+        return resize(*args, **kwargs)
+
+    trainer._resize = resize_after_two_more_reclaims
+    th, holder = _fit_in_thread(trainer)
+    wait_for_condition(
+        lambda: trainer.latest_metrics.get("step", -1) >= 5, timeout=60)
+
+    cluster.drain_node(nodes[1], deadline_s=60, reason="preemption",
+                       wait=False)
+    gave_up = ("elastic_fallbacks", "full_restarts")
+    wait_for_condition(
+        lambda: "error" in holder
+        or any(trainer.telemetry[k] for k in ("shrinks",) + gave_up),
+        timeout=90)
+    last = _finish(th, holder, loop, timeout=60)
+    result = holder["result"]
+
+    t = trainer.telemetry
+    assert t["elastic_fallbacks"] == 0 and t["full_restarts"] == 0, \
+        t.get("restart_reasons")
+    # ONE resize took all three: 4 -> 1.
+    assert (t["shrinks"], t["resizes"]) == (1, 1), str(t["resize_log"])
+    hist = result.metrics_history
+    assert result.metrics["step"] == last and hist[-1]["world"] == 1
+    assert all(h["state_ok"] for h in hist)
+    assert not any(h["restored"] for h in hist)
+    # The survivor holds the trees of the two members that could still
+    # hand theirs over (the first reclaim's and the draining one's).
+    assert any(h["peers"] == 2 for h in hist if h["world"] == 1)
+    # What the gang resumed from is the step of the one who stayed.
+    log, = t["resize_log"]
+    assert len(log["survivor_steps"]) == 1
+    assert t["steps_lost"] <= 2, str(log)
 
 
 def test_preempter_step_schedule_deterministic():

@@ -56,26 +56,39 @@ def _engine(tiny):
                      decode_chunk=4)
 
 
-@pytest.fixture
-def served(tiny, tmp_path, monkeypatch):
-    """Three requests over two slots, inside a session directory of the
-    test's own: (handles, counters before, counters after, this run's
-    spans, the session directory)."""
+WRITER_WROTE: list = []      # the span files the writer thread made itself
+
+
+@pytest.fixture(scope="module")
+def served(tiny, tmp_path_factory):
+    """Three requests over two slots of a new engine, inside a session
+    directory of the module's own, served ONCE for the tests that read the
+    spans: (handles, counters before, counters after, this run's spans,
+    the session directory, where the run's spans were written out)."""
     from ray_tpu.models.generate import SamplingParams
 
-    session = tmp_path / "session-test"
-    monkeypatch.setenv("RAY_TPU_SESSION_DIR", str(session))
-    eng = _engine(tiny)
-    try:
-        seen = {s["id"] for s in tracing.recent_spans()}
-        before = eng.report_metrics()
-        handles = [eng.submit(list(range(1, n)),
-                              SamplingParams(max_new_tokens=10))
-                   for n in (4, 7, 21)]
-        outs = [h.tokens() for h in handles]
-        after = eng.report_metrics()
-    finally:
-        eng.shutdown()
+    session = tmp_path_factory.mktemp("spans") / "session-test"
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv("RAY_TPU_SESSION_DIR", str(session))
+        eng = _engine(tiny)
+        try:
+            seen = {s["id"] for s in tracing.recent_spans()}
+            before = eng.report_metrics()
+            handles = [eng.submit(list(range(1, n)),
+                                  SamplingParams(max_new_tokens=10))
+                       for n in (4, 7, 21)]
+            outs = [h.tokens() for h in handles]
+            after = eng.report_metrics()
+        finally:
+            eng.shutdown()
+        # The writer thread's own doing, about once a second; then what it
+        # has not reached yet (outside a session spans are dropped).
+        deadline = time.monotonic() + 2.0
+        pattern = os.path.join(session, "logs", "spans-*.jsonl")
+        while time.monotonic() < deadline and not glob.glob(pattern):
+            time.sleep(0.05)
+        WRITER_WROTE.extend(glob.glob(pattern))
+        tracing.flush_spans()
     assert [len(o) for o in outs] == [10, 10, 10]
     spans = [s for s in tracing.recent_spans() if s["id"] not in seen]
     return handles, before, after, spans, session
@@ -186,14 +199,30 @@ def test_a_build_says_what_it_uploaded_and_holds_the_transfers(served):
     assert builds[0]["attrs"]["uploaded"] == 8
 
 
+def test_timeline_draws_the_span_files_as_rows(served):
+    _, _, _, spans, session = served
+    tracing.flush_spans()
+    rows = timeline.span_trace_events(str(session))
+    assert {r["cat"] for r in rows} == {"span"} and \
+        {r["ph"] for r in rows} == {"X"}
+    mine = [r for r in rows if r["args"].get("id") in
+            {s["id"] for s in spans}]
+    assert len(mine) == len(spans)
+    # One row a process and thread; wall-clock microseconds.
+    assert {(r["pid"], r["tid"]) for r in mine} == \
+        {(f"spans:pid{os.getpid()}", LOOP_THREAD)}
+    wait = next(r for r in mine if r["name"] == "engine.decode.wait")
+    assert abs(wait["ts"] / 1e6 - time.time()) < 600
+    assert wait["args"]["pages_table"] > 0
+
+
 def test_the_span_file_appears_in_the_session_and_stays_under_its_cap(
         served, monkeypatch):
+    """(The last reader of `served`: it turns the session's files over.)"""
     _, _, _, spans, session = served
-    deadline = time.monotonic() + 2.0
+    monkeypatch.setenv("RAY_TPU_SESSION_DIR", str(session))
     pattern = os.path.join(session, "logs", "spans-*.jsonl")
-    while time.monotonic() < deadline and not glob.glob(pattern):
-        time.sleep(0.05)
-    assert len(glob.glob(pattern)) == 1
+    assert len(WRITER_WROTE) == 1 and glob.glob(pattern) == WRITER_WROTE
     tracing.flush_spans()
     (header, written), = tracing.read_span_files(str(session))
     assert header["pid"] == os.getpid()
@@ -218,23 +247,6 @@ def test_the_span_file_appears_in_the_session_and_stays_under_its_cap(
     assert len(files) == 2 and files[1].endswith(".1")
     newest = tracing.read_span_files(str(session))[-1][1]
     assert newest[-1]["attrs"] == {"round": 5, "i": 19}
-
-
-def test_timeline_draws_the_span_files_as_rows(served):
-    _, _, _, spans, session = served
-    tracing.flush_spans()
-    rows = timeline.span_trace_events(str(session))
-    assert {r["cat"] for r in rows} == {"span"} and \
-        {r["ph"] for r in rows} == {"X"}
-    mine = [r for r in rows if r["args"].get("id") in
-            {s["id"] for s in spans}]
-    assert len(mine) == len(spans)
-    # One row a process and thread; wall-clock microseconds.
-    assert {(r["pid"], r["tid"]) for r in mine} == \
-        {(f"spans:pid{os.getpid()}", LOOP_THREAD)}
-    wait = next(r for r in mine if r["name"] == "engine.decode.wait")
-    assert abs(wait["ts"] / 1e6 - time.time()) < 600
-    assert wait["args"]["pages_table"] > 0
 
 
 def test_spans_lie_on_the_profilers_host_plane(tiny, tmp_path):

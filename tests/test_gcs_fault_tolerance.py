@@ -244,19 +244,30 @@ def test_partition_flap_is_a_non_event(ft_cluster):
         def inc(x):
             return x + 1
 
+        def back_from(flaps):
+            row = _node_row(target.node_id)
+            return row.get("state") in ("ALIVE", "DEAD") \
+                and (row.get("suspect_recoveries", 0) >= flaps
+                     or not row.get("alive"))
+
         refs = []
         for i in range(500):
+            if i == 300:
+                # TWO outages, each well under the grace: the second begins
+                # when the node is back from the first. (Run together they
+                # are one of over a second, and with the redial's backoff
+                # on top of it, up to 1 s a step, that is not under 2 s.)
+                wait_for_condition(lambda: back_from(1), timeout=15)
             if i in (100, 300):
                 chaos.flap("flap-gcs", down_s=0.5)
             refs.append(inc.remote(i))
         assert ray_tpu.get(refs, timeout=180) == [i + 1 for i in range(500)]
 
-        wait_for_condition(
-            lambda: _node_row(target.node_id).get("state") == "ALIVE",
-            timeout=15)
+        wait_for_condition(lambda: back_from(2), timeout=15)
         row = _node_row(target.node_id)
-        assert row.get("suspect_recoveries", 0) >= 1, \
-            f"flap never entered the SUSPECT rung: {row}"
+        assert row.get("state") == "ALIVE" \
+            and row.get("suspect_recoveries", 0) == 2, \
+            f"a flap was not a SUSPECT rung climbed and left: {row}"
         # Same actor process, same counter: no duplicate creation, no
         # restart — the flap was invisible to it.
         pid1, n1 = ray_tpu.get(actor.incr.remote(), timeout=30)

@@ -22,31 +22,21 @@ second batch (0.27 in the first); no head norms 0.056; the others 0.26 to
 in two terms) the system lies within TOL_BF16 of the reference on the same
 weights at EVERY position, and a program whose products take ONE bfloat16
 term does not at nine positions in ten.
-"""
 
-import os
-import sys
+The model, its sizes and `make` are `tests/tiny_families.py`'s; through the
+engine the family is a case of `tests/test_families_served.py`, and its
+tiny configuration one of `tests/test_families_models.py`.
+"""
 
 import numpy as np
 import pytest
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if _REPO not in sys.path:
-    sys.path.insert(0, _REPO)
+from tests.tiny_families import lfm2_moe as family
 
 TOL = 3e-5
 FAULT = 1e-3
 TOL_BF16 = 0.002
-SIZES = dict(
-    conv_L_cache=3, conv_bias=False, hidden_size=64, intermediate_size=128,
-    layer_types=["conv"] + ["full_attention", "conv", "conv"] * 2,
-    max_position_embeddings=256, moe_intermediate_size=32, norm_eps=1e-5,
-    norm_topk_prob=True, num_attention_heads=4, num_dense_layers=1,
-    num_experts=8, num_experts_per_tok=2, num_hidden_layers=7,
-    num_key_value_heads=2,
-    rope_parameters={"rope_theta": 1000000, "rope_type": "default"},
-    routed_scaling_factor=1, use_expert_bias=True, vocab_size=256,
-    torch_dtype="float32")
+SIZES = family.SIZES
 PAGE, TABLE, BUCKET = 4, 16, 32
 # Two batches through the same four slots: rows of very different lengths
 # in one padded bucket, and every slot used twice.
@@ -54,34 +44,9 @@ LENGTHS = ((5, 19, 12, 30), (27, 3, 22, 9))
 STEPS = 10
 
 
-def make(cfg, seed=0):
-    """The benchmark's initialiser with the matrices' deviations scaled
-    from the published width to this one (sqrt(2048 / 64)), so that
-    activations and router logits have the scale they have at the
-    published widths.  The embedding keeps its deviation and the final
-    norm's scale takes the factor instead: the logits' deviation is the
-    published widths' (0.9)."""
-    import jax
-
-    from benchmarks.families.lfm2_moe import WEIGHTS
-    from ray_tpu.models.lfm2_moe import init_params
-
-    wider = (2048 / cfg.d_model) ** 0.5
-    scaled = {k: WEIGHTS[k] * wider for k in (
-        "in_std", "qkv_std", "out_std", "ffn_out_std", "expert_out_std",
-        "router_std", "final_norm")}
-    return init_params(cfg, jax.random.PRNGKey(seed),
-                       **dict(WEIGHTS, **scaled))
-
-
 @pytest.fixture(scope="module")
 def tiny():
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    from ray_tpu.models.lfm2_moe import TINY_LFM2_MOE
-
-    return TINY_LFM2_MOE, make(TINY_LFM2_MOE)
+    return family.cfg, family.params
 
 
 def _sequences(seed, lengths, extra=STEPS):
@@ -90,9 +55,7 @@ def _sequences(seed, lengths, extra=STEPS):
 
 
 def _reference(params, seq, rows=None, sizes=SIZES):
-    from benchmarks.reference import lfm2_moe as ref
-
-    return np.asarray(ref.logits(params, sizes, seq, rows))
+    return family.reference(params, seq, rows, sizes)
 
 
 class Served:
@@ -160,18 +123,6 @@ def _differences(params, cfg, sizes=SIZES):
 
 def _widest(params, cfg):
     return [d.max() for d in _differences(params, cfg)]
-
-
-def test_the_tiny_configuration_is_the_familys(tiny):
-    from benchmarks.families import lfm2_moe as family
-    from ray_tpu.models.lfm2_moe import count_params
-
-    cfg, params = tiny
-    assert family.program_config(SIZES, attention="reference") == cfg
-    import jax
-
-    assert count_params(cfg)["total"] == sum(
-        x.size for x in jax.tree_util.tree_leaves(params))
 
 
 def test_full_forward_is_the_references(tiny):
@@ -320,70 +271,6 @@ def test_bf16_in_two_terms_holds_and_in_one_term_does_not(tiny):
             mock.patch("ray_tpu.models.sambay._two_terms", one_term):
         one = np.concatenate(_differences(served, cfg, sizes))
     assert np.quantile(one, 0.1) > TOL_BF16
-
-
-# ---------------------------------------------------------------------------
-# Through the engine
-# ---------------------------------------------------------------------------
-
-ENGINE = dict(max_batch=4, max_len=128, page_size=16, decode_chunk=4)
-PROMPTS = (5, 19, 33, 40, 17, 64, 28, 3, 50)
-
-
-def _serve(eng, prompts, new=16):
-    from ray_tpu.models.generate import SamplingParams
-
-    eng.quiesce_for_drain()
-    handles = [eng.submit(p, SamplingParams(max_new_tokens=new))
-               for p in prompts]
-    eng.resume()
-    return [h.tokens() for h in handles]
-
-
-def test_engine_streams_are_the_references_greedy(tiny):
-    """Nine requests over four slots through `LLMEngine`: batched prefills,
-    singles, admission mid-flight, every slot used at least twice.  In
-    float32 the engine's greedy tokens are the reference's argmax at every
-    position, and what the programs counted is on the spans and in
-    `report_metrics()`."""
-    from ray_tpu.serve.llm import LLMEngine
-    from ray_tpu.util import tracing
-
-    cfg, params = tiny
-    eng = LLMEngine(cfg, params, **ENGINE)
-    try:
-        rng = np.random.default_rng(0)
-        prompts = [rng.integers(1, 256, size=n).tolist() for n in PROMPTS]
-        outs = _serve(eng, prompts)
-        repeats = []
-        for p, o in zip(prompts, outs):
-            seq = p + o[:-1]
-            rows = list(range(len(p) - 1, len(seq)))
-            lg = _reference(params, seq, rows)
-            assert (lg.max(-1) - lg[np.arange(len(o)), o]).max() == 0.0
-            repeats.append((lg.argmax(-1) == np.asarray(seq)[rows]).mean())
-        assert np.mean(repeats) < 0.2       # not echoes of the input
-        got = eng.report_metrics()
-        assert got["state_slots_reset"] == len(prompts)
-        # seven conv layers... of (2, 64) float32 windows: five here
-        assert got["state_bytes_per_slot"] == 5 * 2 * 64 * 4
-        # every real prompt token and every padding row's one, twice in
-        # each of six routed layers
-        assert got["expert_rows"] >= sum(PROMPTS) * 2 * 6
-        assert 0 < got["experts_touched"] <= got["expert_slots"]
-        assert got["expert_slots"] == got["decode_passes"] * 4 * 6 * 8
-        waits = [s["attrs"] for s in tracing.recent_spans()
-                 if s["name"] == "engine.decode.wait"
-                 and "expert_slots" in s.get("attrs", {})]
-        assert waits and all(a["expert_slots"] == 4 * 6 * 8 and
-                             a["experts_touched"] <= a["expert_slots"] and
-                             1 <= a["expert_rows_max"] <= 4 for a in waits)
-        fills = [s["attrs"] for s in tracing.recent_spans()
-                 if s["name"] == "engine.prefill.wait"
-                 and "expert_rows" in s.get("attrs", {})]
-        assert sum(a["expert_rows"] for a in fills) == got["expert_rows"]
-    finally:
-        eng.shutdown()
 
 
 def test_the_family_sizes_state_and_prefill_from_shapes():

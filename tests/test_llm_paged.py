@@ -9,34 +9,24 @@ import contextlib
 import numpy as np
 import pytest
 
-from ray_tpu.models.generate import Generator, SamplingParams
-from ray_tpu.models.llama import LlamaConfig, LlamaModel
+from ray_tpu.models.generate import SamplingParams
 from ray_tpu.serve.llm import LLMEngine
+from tests.tiny_families import dense
 
 
 @pytest.fixture(scope="module")
 def tiny_model():
-    import jax
-    import jax.numpy as jnp
-
-    cfg = LlamaConfig(vocab_size=128, d_model=64, n_layers=2, n_heads=4,
-                      n_kv_heads=2, d_ff=128, max_seq_len=128,
-                      dtype=jnp.float32, attention="reference", remat=False)
-    model = LlamaModel(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
-    return cfg, params
+    return dense.cfg, dense.params
 
 
 def _reference_greedy(cfg, params, prompt, n_new):
-    gen = Generator(cfg, params, batch=1, max_len=len(prompt) + n_new)
-    return gen.generate(np.asarray([prompt], np.int32),
-                        SamplingParams(max_new_tokens=n_new))[0].tolist()
+    return dense.greedy(prompt, n_new)
 
 
 @contextlib.contextmanager
 def _page_writes(eng):
     """What `eng` hands its page writer, a call: the shapes of the fresh
-    K/V leaves and the page columns. Shuts the engine down on the way out."""
+    K/V leaves and the page columns."""
     seen = []
     real = eng._write_prompt_pages
 
@@ -50,7 +40,25 @@ def _page_writes(eng):
         yield seen
     finally:
         eng._write_prompt_pages = real
-        eng.shutdown()
+
+
+def _engine(tiny_model, slots):
+    cfg, params = tiny_model
+    eng = LLMEngine(cfg, params, max_batch=slots, max_len=96, page_size=16)
+    yield eng
+    eng.shutdown()
+
+
+# One engine a module for the tests that submit to it, read what comes
+# back and leave it idle (a double one of them plants it takes away again).
+@pytest.fixture(scope="module")
+def two_slots(tiny_model):
+    yield from _engine(tiny_model, 2)
+
+
+@pytest.fixture(scope="module")
+def four_slots(tiny_model):
+    yield from _engine(tiny_model, 4)
 
 
 def test_paged_admission_bounded_by_pool_not_slots(tiny_model):
@@ -87,25 +95,25 @@ def test_paged_pool_capacity_rejects_oversized_request(tiny_model):
         eng.shutdown()
 
 
-def test_paged_pages_freed_on_completion(tiny_model):
-    cfg, params = tiny_model
-    eng = LLMEngine(cfg, params, max_batch=2, max_len=96, page_size=16)
-    try:
-        baseline = eng._alloc.free_pages
-        out = eng.generate([3, 1, 4], SamplingParams(max_new_tokens=6))
-        assert len(out) == 6
-        assert eng._alloc.free_pages == baseline
-    finally:
-        eng.shutdown()
+def test_paged_pages_freed_on_completion(two_slots):
+    eng = two_slots
+    assert eng.quiesce_for_drain()
+    baseline = eng._alloc.free_pages
+    eng.resume()
+    out = eng.generate([3, 1, 4], SamplingParams(max_new_tokens=6))
+    assert len(out) == 6
+    assert eng.quiesce_for_drain()
+    assert eng._alloc.free_pages == baseline
+    eng.resume()
 
 
-def test_batched_prefill_used_and_bit_equal(tiny_model):
+def test_batched_prefill_used_and_bit_equal(tiny_model, four_slots):
     """A burst of same-bucket requests must go through the fixed-width
     prefill_many program (one dispatch for the group) AND stay greedy
     bit-equal to the one-shot Generator — batched rows may not perturb
     single-sequence numerics."""
     cfg, params = tiny_model
-    eng = LLMEngine(cfg, params, max_batch=4, max_len=96, page_size=16)
+    eng = four_slots
     calls = {"many": 0, "one": 0}
     real_many, real_one = eng._prefill_many, eng._prefill_one
 
@@ -130,15 +138,14 @@ def test_batched_prefill_used_and_bit_equal(tiny_model):
             f"prefill program (calls={calls})")
     finally:
         eng._prefill_many, eng._prefill_one = real_many, real_one
-        eng.shutdown()
 
 
 @pytest.mark.parametrize("prompt_len, bucket",
                          [(5, 16), (16, 16), (17, 32), (40, 64), (70, 96)],
                          ids=["in-a-page", "a-page-full", "two-pages",
                               "mid", "max_len"])
-def test_prefill_hands_over_kv_as_long_as_the_bucket(tiny_model, prompt_len,
-                                                     bucket):
+def test_prefill_hands_over_kv_as_long_as_the_bucket(tiny_model, two_slots,
+                                                     prompt_len, bucket):
     """A prompt attends over itself, so what its prefill hands the page
     writer is K/V of the bucket's length with as many page columns, and
     not a cache of `max_len` (6 columns here, whatever the bucket): a
@@ -146,7 +153,7 @@ def test_prefill_hands_over_kv_as_long_as_the_bucket(tiny_model, prompt_len,
     bucket that is `max_len` itself and no power of two. The greedy
     stream is the Generator's, as it was over the dense cache."""
     cfg, params = tiny_model
-    eng = LLMEngine(cfg, params, max_batch=2, max_len=96, page_size=16)
+    eng = two_slots
     with _page_writes(eng) as seen:
         prompt = [(i * 11 + 5) % 120 + 1 for i in range(prompt_len)]
         n_new = min(12, 96 - prompt_len)
@@ -158,12 +165,12 @@ def test_prefill_hands_over_kv_as_long_as_the_bucket(tiny_model, prompt_len,
 
 
 def test_batched_prefill_of_unequal_rows_fills_only_their_own_pages(
-        tiny_model):
+        tiny_model, four_slots):
     """Rows of one bucket and unequal length through `prefill_many`: a
     row's pages past its prompt are the dummy page's columns, a padding
     row's all of them, and every stream is the Generator's."""
     cfg, params = tiny_model
-    eng = LLMEngine(cfg, params, max_batch=4, max_len=96, page_size=16)
+    eng = four_slots
     dummy = eng._dummy_page
     with _page_writes(eng) as seen:
         prompts = [[(i * 7 + r) % 120 + 1 for i in range(n)]

@@ -9,84 +9,33 @@ again: `rewinds`) and a hybrid one (they cannot: each slot's `steps` are
 sized on the host, and sent when they differ from the chunk's before).
 """
 
-import os
-import sys
 import time
 
 import numpy as np
 import pytest
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if _REPO not in sys.path:
-    sys.path.insert(0, _REPO)
+from ray_tpu.models.generate import SamplingParams
+from ray_tpu.serve.llm import _MIRRORS, LLMEngine
+from ray_tpu.util import tracing
+from tests import tiny_families
+from tests.tiny_families import LOOP_ENGINE as ENGINE, LOOP_K as K
 
-from ray_tpu.models.generate import Generator, SamplingParams  # noqa: E402
-from ray_tpu.serve.llm import _MIRRORS, LLMEngine  # noqa: E402
-from ray_tpu.util import tracing  # noqa: E402
-
-K = 4
-ENGINE = dict(max_batch=3, max_len=128, page_size=16, decode_chunk=K)
 # Where `decode_chunk_paged` takes each resident argument.
 ARG_AT = {"token": 1, "pos": 2, "tables": 4, "lens": 5, "temps": 6,
           "top_ks": 7, "top_ps": 8, "chunk_no": 10, "steps": 11}
+MODELS = {"dense": tiny_families.dense,
+          "hybrid": tiny_families.granite_hybrid}
 
 
-class Model:
-    """A tiny model, and whether a stream is what its plain reference
-    decodes greedily from the prompt."""
-
-    def __init__(self, cfg, params, rewinds, is_greedy):
-        self.cfg, self.params = cfg, params
-        self.rewinds, self.is_greedy = rewinds, is_greedy
-
-
-@pytest.fixture(scope="module")
-def dense():
-    import jax
-    import jax.numpy as jnp
-
-    jax.config.update("jax_platforms", "cpu")
-    from ray_tpu.models.llama import LlamaConfig, LlamaModel
-
-    cfg = LlamaConfig(vocab_size=128, d_model=64, n_layers=2, n_heads=4,
-                      n_kv_heads=2, d_ff=128, max_seq_len=128,
-                      dtype=jnp.float32, attention="reference", remat=False)
-    params = LlamaModel(cfg).init(jax.random.PRNGKey(0),
-                                  jnp.zeros((1, 8), jnp.int32))
-
-    def is_greedy(prompt, out):
-        gen = Generator(cfg, params, batch=1, max_len=len(prompt) + len(out))
-        return out == gen.generate(
-            np.asarray([prompt], np.int32),
-            SamplingParams(max_new_tokens=len(out)))[0].tolist()
-
-    return Model(cfg, params, True, is_greedy)
-
-
-@pytest.fixture(scope="module")
-def hybrid():
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    from ray_tpu.models.granite_hybrid import TINY_GRANITE
-    from tests.test_models_granite_hybrid import make
-    from tests.test_serve_granite_hybrid import _reference_gap
-
-    params = make(TINY_GRANITE)
-
-    def is_greedy(prompt, out):
-        return _reference_gap(params, prompt, out)[0].max() == 0.0
-
-    return Model(TINY_GRANITE, params, False, is_greedy)
-
-
-@pytest.fixture(params=["dense", "hybrid"])
+@pytest.fixture(params=list(MODELS))
 def model(request):
-    return request.getfixturevalue(request.param)
+    """A tiny model (`cfg`, `params`, `rewinds`), and whether a stream is
+    what its plain reference decodes greedily from the prompt."""
+    return MODELS[request.param]
 
 
-def _prompt(seed, n, vocab=120):
-    return np.random.default_rng(seed).integers(1, vocab, size=n).tolist()
+def _prompt(seed, n):
+    return tiny_families.prompts(seed, (n,), vocab=120)[0]
 
 
 class Watch:

@@ -20,6 +20,10 @@ subtlest, the selection bias used as a gate, 0.011).  In bfloat16 (the
 served type: weights and latents rounded, activations in two terms) the
 system lies within TOL_BF16 of the reference's pass that rounds what the
 cache holds, on the same weights at every position.
+
+The model, its sizes and `make` are `tests/tiny_families.py`'s; through the
+engine the family is a case of `tests/test_families_served.py`, and its
+tiny configuration one of `tests/test_families_models.py`.
 """
 
 import os
@@ -28,65 +32,26 @@ import sys
 import numpy as np
 import pytest
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if _REPO not in sys.path:
-    sys.path.insert(0, _REPO)
+from tests.tiny_families import mla_moe as family
 
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOL = 3e-5
 FAULT = 1e-3
 TOL_BF16 = 0.01
-SIZES = dict(
-    vocab_size=256, max_position_embeddings=256, hidden_size=64,
-    intermediate_size=128, moe_intermediate_size=32, num_hidden_layers=3,
-    num_attention_heads=4, n_shared_experts=2, n_routed_experts=8,
-    routed_scaling_factor=2.446, kv_lora_rank=32, q_lora_rank=None,
-    qk_rope_head_dim=8, v_head_dim=16, qk_nope_head_dim=16,
-    topk_method="noaux_tc", n_group=1, topk_group=1, num_experts_per_tok=3,
-    moe_layer_freq=1, first_k_dense_replace=1, norm_topk_prob=True,
-    scoring_func="sigmoid", num_key_value_heads=4, hidden_act="silu",
-    rms_norm_eps=1e-5, rope_theta=800000, rope_scaling=None,
-    attention_bias=False, tie_word_embeddings=False, torch_dtype="float32")
+SIZES = family.SIZES
 PAGE, TABLE, BUCKET = 4, 16, 32
 # Two batches through the same four slots: rows of very different lengths
 # in one padded bucket (one ends ON a page boundary, one a token past
 # one), and every slot used twice.
 LENGTHS = ((5, 19, 12, 30), (27, 3, 22, 9))
 STEPS = 10
-
-
-def make(cfg, seed=0):
-    """The benchmark's initialiser with the matrices' deviations scaled
-    from the published widths to these (by the root of the width each
-    matrix sums over), so that activations, scores and router logits have
-    the scale they have there."""
-    import jax
-
-    from benchmarks.families.mla_moe import WEIGHTS
-    from ray_tpu.models.mla_moe import init_params
-
-    over_d = (2048 / cfg.d_model) ** 0.5
-    scaled = dict(
-        WEIGHTS,
-        **{k: WEIGHTS[k] * over_d for k in (
-            "in_std", "q_std", "kv_a_std", "router_std", "head_std")},
-        kv_b_std=WEIGHTS["kv_b_std"] * (512 / cfg.kv_rank) ** 0.5,
-        out_std=WEIGHTS["out_std"] * (2048 / (cfg.n_heads * cfg.d_v)) ** 0.5,
-        ffn_out_std=WEIGHTS["ffn_out_std"] * (11264 / cfg.d_ff) ** 0.5,
-        expert_out_std=WEIGHTS["expert_out_std"]
-        * (1408 / cfg.d_expert) ** 0.5,
-        shared_out_std=WEIGHTS["shared_out_std"]
-        * (1408 / cfg.d_expert) ** 0.5)
-    return init_params(cfg, jax.random.PRNGKey(seed), **scaled)
+# (`tests/benchmarks/test_mla_moe_cell.py` takes the weights from here)
+make = family.make
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    from ray_tpu.models.mla_moe import TINY_MLA_MOE
-
-    return TINY_MLA_MOE, make(TINY_MLA_MOE)
+    return family.cfg, family.params
 
 
 def _sequences(seed, lengths, extra=STEPS):
@@ -95,11 +60,7 @@ def _sequences(seed, lengths, extra=STEPS):
 
 
 def _reference(params, seq, rows=None, sizes=SIZES, **how):
-    from benchmarks.reference import mla_moe as ref
-
-    # (a level's own logits: `ref.logits` hands the harness a level's best
-    # token standing over the float32 logits)
-    return np.asarray(ref.rounded_logits(params, sizes, seq, rows, **how))
+    return family.reference(params, seq, rows, sizes, **how)
 
 
 class Served:
@@ -167,16 +128,6 @@ def _differences(params, cfg, sizes=SIZES, **how):
 
 def _widest(params, cfg):
     return [d.max() for d in _differences(params, cfg)]
-
-
-def test_the_tiny_configuration_is_the_familys(tiny):
-    from benchmarks.families import mla_moe as family
-
-    cfg, _ = tiny
-    assert family.program_config(family.sizes(SIZES),
-                                 attention="reference") == cfg
-    assert cfg.latent_dim == 40 and cfg.latent_row == 128
-    assert cfg.d_qk == 24
 
 
 def test_full_forward_is_the_references(tiny):
@@ -658,63 +609,6 @@ def test_the_reference_is_the_installed_deepseek_v3(tiny):
     ours = _reference(params, seq)
     assert np.abs(theirs).max() > 1.0
     assert np.abs(theirs - ours).max() < TOL
-
-
-# ---------------------------------------------------------------------------
-# Through the engine
-# ---------------------------------------------------------------------------
-
-ENGINE = dict(max_batch=4, max_len=128, page_size=16, decode_chunk=4)
-PROMPTS = (5, 19, 33, 40, 17, 64, 28, 3, 50)
-
-
-def test_engine_streams_are_the_references_greedy(tiny):
-    """Nine requests over four slots through `LLMEngine`: batched prefills,
-    singles, admission mid-flight, every slot used at least twice.  In
-    float32 the engine's greedy tokens are the reference's argmax at every
-    position, and what the programs counted is on the spans and in
-    `report_metrics()`."""
-    from ray_tpu.models.generate import SamplingParams
-    from ray_tpu.serve.llm import LLMEngine
-    from ray_tpu.util import tracing
-
-    cfg, params = tiny
-    eng = LLMEngine(cfg, params, **ENGINE)
-    try:
-        rng = np.random.default_rng(0)
-        prompts = [rng.integers(1, 256, size=n).tolist() for n in PROMPTS]
-        eng.quiesce_for_drain()
-        handles = [eng.submit(p, SamplingParams(max_new_tokens=16))
-                   for p in prompts]
-        eng.resume()
-        outs = [h.tokens() for h in handles]
-        for p, o in zip(prompts, outs):
-            seq = p + o[:-1]
-            lg = _reference(params, seq, list(range(len(p) - 1, len(seq))))
-            assert (lg.max(-1) - lg[np.arange(len(o)), o]).max() == 0.0
-        got = eng.report_metrics()
-        assert got["state_bytes_per_slot"] == 0
-        # every real prompt token, three pairs in each of two routed layers
-        assert got["expert_rows"] >= sum(PROMPTS) * 3 * 2
-        assert 0 < got["experts_touched"] <= got["expert_slots"]
-        assert got["expert_slots"] == got["decode_passes"] * 4 * 2 * 8
-        # (a token's bytes are the configuration's constant: the reader's
-        # costs module has them, no counter carries them)
-        assert "latent_bytes_per_token" not in got
-        waits = [s["attrs"] for s in tracing.recent_spans()
-                 if s["name"] == "engine.decode.wait"
-                 and "latent_tokens" in s.get("attrs", {})]
-        assert waits and all(
-            a["expert_slots"] == 4 * 2 * 8 and
-            a["experts_touched"] <= a["expert_slots"] and
-            0 < a["latent_tokens"] <= 4 * 4 * 128 for a in waits)
-        assert sum(a["latent_tokens"] for a in waits) == got["latent_tokens"]
-        fills = [s["attrs"] for s in tracing.recent_spans()
-                 if s["name"] == "engine.prefill.wait"
-                 and "expert_rows" in s.get("attrs", {})]
-        assert sum(a["expert_rows"] for a in fills) == got["expert_rows"]
-    finally:
-        eng.shutdown()
 
 
 def test_the_family_sizes_state_and_prefill_from_shapes():

@@ -17,129 +17,30 @@ subtlest: a state of 32 x 16 a head here, 64 x 128 published; 2.1e-4 under
 Mamba-2's published initialiser, whose heads forget in tens of steps and
 whose state is a thirtieth of a layer's output), bfloat16 K, V and conv
 windows 0.032, a reused slot's state 1.0, the others from 2.6 up.
+
+The model and `make` are `tests/tiny_families.py`'s; what this family owes
+its reference as every recurrent family does (the whole forward, rows of
+one padded bucket, prefill then paged decode, the served type) is held, a
+case a family, by `tests/test_families_models.py`.
 """
 
 import dataclasses
-import os
-import sys
 
 import numpy as np
 import pytest
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if _REPO not in sys.path:
-    sys.path.insert(0, _REPO)
+from tests.tiny_families import granite_hybrid as family
 
-TOL = 3e-5
+TOL = family.TOL       # (3e-5)
 FAULT = 1e-3
-SIZES = dict(
-    hidden_size=64, intermediate_size=128, shared_intermediate_size=128,
-    num_hidden_layers=8,
-    layer_types=["mamba", "mamba", "attention", "mamba"] * 2,
-    num_attention_heads=4, num_key_value_heads=2, vocab_size=256,
-    mamba_n_heads=4, mamba_d_head=32, mamba_d_state=16, mamba_d_conv=4,
-    mamba_n_groups=1, mamba_expand=2, mamba_chunk_size=8,
-    attention_multiplier=0.015625, embedding_multiplier=12,
-    residual_multiplier=0.22, logits_scaling=8, rms_norm_eps=1e-5,
-    position_embedding_type="nope", rope_theta=10000,
-    max_position_embeddings=256, tie_word_embeddings=True,
-    torch_dtype="float32")
-PAGE, TABLE, BUCKET = 4, 16, 32
-
-
-def make(cfg, seed=0):
-    """The benchmark's initialiser with the matrices' deviations scaled
-    from the published width to this one (sqrt(2048 / 64)), so that
-    activations, step sizes and attention scores have the scale they have
-    at the published widths: the state then carries as much of a layer's
-    output as the skip term does, and a fault in it shows.  The embedding
-    keeps its deviation and the final norm's scale takes the factor
-    instead: the logits' deviation is the published widths' (0.91),
-    and the token just read, whose embedding enters the stream times 12
-    and is also its row of the head, is not what the layers are drowned
-    by (with the embedding scaled too, greedy decoding here repeats its
-    input at 19 positions in 20, whatever the state holds)."""
-    import jax
-
-    from benchmarks.families.granite_hybrid import WEIGHTS
-    from ray_tpu.models.granite_hybrid import init_params
-
-    wider = (2048 / cfg.d_model) ** 0.5
-    scaled = {k: WEIGHTS[k] * wider
-              for k in ("in_std", "qkv_std", "out_std", "final_norm")}
-    return init_params(cfg, jax.random.PRNGKey(seed),
-                       **dict(WEIGHTS, **scaled))
+BUCKET = family.BUCKET
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    from ray_tpu.models.granite_hybrid import (TINY_GRANITE,
-                                               GraniteHybridModel)
-
-    cfg = TINY_GRANITE
-    assert list(cfg.layer_types) == SIZES["layer_types"]
-    return cfg, GraniteHybridModel(cfg), make(cfg)
-
-
-def _reference(params, tokens, rounded=0, **sizes):
-    from benchmarks.reference import granite_hybrid as ref
-
-    return np.asarray(ref.logits(params, dict(SIZES, **sizes), list(tokens),
-                                 rounded=rounded))
-
-
-def _tokens(seed, shape):
-    return np.random.default_rng(seed).integers(1, 256, size=shape)
-
-
-def _prefill(model, params, rows, bucket, last=None):
-    """Right-padded rows through `prefill` -> logits, state."""
-    import jax.numpy as jnp
-
-    from ray_tpu.models.granite_hybrid import GraniteHybridModel
-
-    padded = np.zeros((len(rows), bucket), np.int32)
-    for r, row in enumerate(rows):
-        padded[r, : len(row)] = row
-    if last is None:
-        last = [len(row) - 1 for row in rows]
-    return model.apply(params, jnp.asarray(padded),
-                       jnp.asarray(last, jnp.int32),
-                       method=GraniteHybridModel.prefill)
-
-
-def _paged_state(fresh, batch):
-    """The prefill's state with its K and V cut into the pages of pools:
-    row b owns pages 1 + b * TABLE ..., page 0 is nobody's."""
-    import jax.numpy as jnp
-
-    table = jnp.asarray(
-        1 + np.arange(batch * TABLE).reshape(batch, TABLE), jnp.int32)
-
-    def pool(a):
-        B, H, S, D = a.shape
-        pages = a.reshape(B, H, S // PAGE, PAGE, D).transpose(0, 2, 1, 3, 4)
-        out = jnp.zeros((1 + batch * TABLE, H, PAGE, D), a.dtype)
-        return out.at[table[:, : S // PAGE].reshape(-1)].set(
-            pages.reshape(-1, H, PAGE, D))
-
-    return {"ssm": fresh["ssm"],
-            "pools": [(pool(k), pool(v)) for k, v in fresh["kv"]]}, table
-
-
-def test_whole_forward_matches_the_reference(tiny):
-    import jax.numpy as jnp
-
-    cfg, model, params = tiny
-    tokens = _tokens(1, (2, 37))       # 37: no multiple of the chunk of 8
-    got = np.asarray(model.apply(params, jnp.asarray(tokens)))
-    for b in range(2):
-        want = _reference(params, tokens[b])
-        assert 0.3 < want.std() < 1.0
-        np.testing.assert_allclose(got[b], want, atol=TOL, rtol=0)
+    cfg = family.cfg
+    assert list(cfg.layer_types) == family.SIZES["layer_types"]
+    return cfg, family.model(), family.params
 
 
 def test_the_benchmarks_weights_give_the_heads_a_long_memory(tiny):
@@ -215,65 +116,8 @@ def test_the_chunked_matrix_form_equals_the_recurrence():
                                atol=2e-5, rtol=2e-5)
 
 
-def test_rows_of_one_padded_bucket_each_get_their_own_last_state(tiny):
-    """Right-padding is harmless to causal attention and wrong for a
-    recurrence: each row's state and conv window must be those at ITS
-    last token, as if it had been prefilled alone."""
-    cfg, model, params = tiny
-    rows = [_tokens(3, 27), _tokens(4, 11), _tokens(5, 2)]
-    logits, both = _prefill(model, params, rows, BUCKET)
-    for r, row in enumerate(rows):
-        alone_logits, alone = _prefill(model, params, [row], len(row))
-        np.testing.assert_allclose(logits[r], alone_logits[0], atol=TOL)
-        for (conv2, s2), (conv1, s1) in zip(both["ssm"], alone["ssm"]):
-            np.testing.assert_allclose(conv2[r], conv1[0], atol=1e-5)
-            np.testing.assert_allclose(s2[r], s1[0], atol=1e-5)
-    assert len(both["ssm"]) == 6 and len(both["kv"]) == 2
-    # K and V of two KV heads of 16 lie side by side in one head of 32
-    assert both["kv"][0][0].shape == (3, 1, BUCKET, 32)
-
-
-def _decode_against_reference(model, params, seqs, prompt_lens, steps,
-                              rounded=0, fault=None, **sizes):
-    """Prefill the prompts in one bucket, then `steps` teacher-forced
-    paged decode steps; the widest gap to the reference's full pass.
-    `fault(what, state)` may spoil the state on its way."""
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.models.granite_hybrid import GraniteHybridModel
-
-    fault = fault or (lambda what, state: state)
-    B = len(seqs)
-    logits, fresh = _prefill(
-        model, params, [s[:n] for s, n in zip(seqs, prompt_lens)], BUCKET)
-    state, table = _paged_state(fault("prefilled", fresh), B)
-    want = [_reference(params, s, rounded, **sizes) for s in seqs]
-    worst = max(np.abs(np.asarray(logits[b]) - want[b][n - 1]).max()
-                for b, n in enumerate(prompt_lens))
-    decode = jax.jit(lambda p, t, s, ln: model.apply(
-        p, t, ln, s, table, ln, method=GraniteHybridModel.decode))
-    length = jnp.asarray(prompt_lens, jnp.int32)
-    for k in range(steps):
-        token = jnp.asarray([s[n + k] for s, n in zip(seqs, prompt_lens)])
-        logits, state = decode(params, token, state, length)
-        state = fault("stepped", state)
-        for b, n in enumerate(prompt_lens):
-            worst = max(worst, np.abs(np.asarray(logits[b])
-                                      - want[b][n + k]).max())
-        length = length + 1
-    return worst
-
-
+# (as `tests/test_families_models.py` decodes them)
 SEQS, PROMPTS, STEPS = (7, (2, 60)), [21, 13], 24
-
-
-def test_prefill_then_paged_decode_matches_the_reference(tiny):
-    """24 decode steps through pages of 4 and the recurrent state, against
-    the reference's whole pass over prompt + generated."""
-    cfg, model, params = tiny
-    assert _decode_against_reference(model, params, _tokens(*SEQS), PROMPTS,
-                                     STEPS) < TOL
 
 
 # ---- planted faults: each must read far over TOL ---------------------------
@@ -311,8 +155,9 @@ def _last_streams_state_kept(what, state):
                          ids=lambda f: f.__name__.strip("_"))
 def test_a_fault_in_the_state_is_seen(tiny, fault):
     cfg, model, params = tiny
-    assert _decode_against_reference(model, params, _tokens(*SEQS), PROMPTS,
-                                     STEPS, fault=fault) > FAULT
+    assert family.decode_against_reference(
+        model, params, family.tokens(*SEQS), PROMPTS, STEPS,
+        fault=fault) > FAULT
 
 
 def test_padding_that_leaks_into_a_rows_state_is_seen(tiny):
@@ -320,18 +165,18 @@ def test_padding_that_leaks_into_a_rows_state_is_seen(tiny):
     `prefill` would hand on if it did not hold the recurrence still past
     `last_idx`."""
     cfg, model, params = tiny
-    seqs = _tokens(*SEQS)
+    seqs = family.tokens(*SEQS)
 
     def leak(what, state):
         if what != "prefilled":
             return state
-        _, at_the_end = _prefill(
+        _, at_the_end = family.prefill(
             model, params, [s[:n] for s, n in zip(seqs, PROMPTS)], BUCKET,
             last=[BUCKET - 1] * len(PROMPTS))
         return dict(state, ssm=at_the_end["ssm"])
 
-    assert _decode_against_reference(model, params, seqs, PROMPTS, STEPS,
-                                     fault=leak) > FAULT
+    assert family.decode_against_reference(
+        model, params, seqs, PROMPTS, STEPS, fault=leak) > FAULT
 
 
 @pytest.mark.parametrize("wrong", [dict(residual_multiplier=1.0),
@@ -343,8 +188,8 @@ def test_a_model_wired_otherwise_is_seen(tiny, wrong):
 
     cfg, _, params = tiny
     model = GraniteHybridModel(dataclasses.replace(cfg, **wrong))
-    assert _decode_against_reference(model, params, _tokens(*SEQS), PROMPTS,
-                                     STEPS) > FAULT
+    assert family.decode_against_reference(
+        model, params, family.tokens(*SEQS), PROMPTS, STEPS) > FAULT
 
 
 def test_the_tolerance_would_refuse_bf16_kv_and_conv_windows(tiny):
@@ -352,8 +197,9 @@ def test_the_tolerance_would_refuse_bf16_kv_and_conv_windows(tiny):
     rounded to bfloat16 (its first level of rounding) misses TOL: the
     tolerance sees a cache held in a lower precision."""
     cfg, model, params = tiny
-    assert _decode_against_reference(model, params, _tokens(*SEQS), PROMPTS,
-                                     STEPS, rounded=1) > FAULT
+    assert family.decode_against_reference(
+        model, params, family.tokens(*SEQS), PROMPTS, STEPS,
+        rounded=1) > FAULT
 
 
 def test_the_parameter_count_is_the_published_one():
@@ -373,22 +219,3 @@ def test_the_parameter_count_is_the_published_one():
     assert (counts["mamba"], counts["attention"]) == (76_182_976,
                                                       60_821_504)
     assert GRANITE_4_H_MICRO.layers_of("attention") == [5, 15, 25, 35]
-
-
-def test_the_served_type_decodes_near_the_reference(tiny):
-    """bfloat16 weights, the engine's own prefill and decode: with
-    two-term products the logits stay within 0.06 of the float32
-    reference's (logits of deviation 0.91; measured 0.019) over 24 steps.
-    Not a strict bound at these tiny widths: it catches a path that rounds
-    where it should not, or a type that does not fit the state."""
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.models.granite_hybrid import GraniteHybridModel
-
-    cfg = dataclasses.replace(tiny[0], dtype=jnp.bfloat16)
-    params = make(cfg)
-    assert all(x.dtype in (jnp.bfloat16, jnp.float32)
-               for x in jax.tree_util.tree_leaves(params))
-    assert _decode_against_reference(GraniteHybridModel(cfg), params,
-                                     _tokens(*SEQS), PROMPTS, STEPS) < 0.06

@@ -11,96 +11,29 @@ logits here lie within +-1.2; float32 reordering moves them by 1e-7 to
 5e-7.  TOL = 2e-5 leaves two orders of room above that and is two orders
 under what K and V held in bfloat16 cost (5e-3 here, shown by
 `test_the_tolerance_would_refuse_bf16_kv`).
-"""
 
-import os
-import sys
+The model is `tests/tiny_families.py`'s; what this family owes its
+reference as every recurrent family does (the whole forward, rows of one
+padded bucket, prefill then paged decode, the served type) is held, a case
+a family, by `tests/test_families_models.py`.
+"""
 
 import numpy as np
 import pytest
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if _REPO not in sys.path:
-    sys.path.insert(0, _REPO)
+from tests.tiny_families import sambay as family
 
-TOL = 2e-5
-SIZES = dict(hidden_size=64, intermediate_size=128, num_hidden_layers=8,
-             num_attention_heads=4, num_key_value_heads=2, sliding_window=8,
-             vocab_size=256, layer_norm_eps=1e-5, tie_word_embeddings=True,
-             mamba_d_state=4, mamba_d_conv=4, mamba_expand=2,
-             mamba_dt_rank=4)
-PAGE, TABLE = 4, 16
+TOL = family.TOL       # (2e-5)
+SEQS = (5, (2, 60))     # as `tests/test_families_models.py` decodes them
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    from ray_tpu.models.sambay import (TINY_SAMBAY, SambaYModel,
-                                       init_params)
-
-    cfg = TINY_SAMBAY
+    cfg = family.cfg
     assert [cfg.kind(i) for i in range(8)] == [
         "mamba", "window", "mamba", "window", "mamba", "full", "gmu",
         "cross"]
-    return cfg, SambaYModel(cfg), init_params(cfg, jax.random.PRNGKey(0))
-
-
-def _reference(params, tokens, rounded=0):
-    from benchmarks.reference import sambay as ref
-
-    return np.asarray(ref.logits(params, SIZES, list(tokens),
-                                 rounded=rounded))
-
-
-def _tokens(seed, shape):
-    return np.random.default_rng(seed).integers(1, 256, size=shape)
-
-
-def _prefill(model, params, rows, bucket):
-    """Right-padded rows through `prefill` -> logits, state."""
-    import jax.numpy as jnp
-
-    from ray_tpu.models.sambay import SambaYModel
-
-    padded = np.zeros((len(rows), bucket), np.int32)
-    for r, row in enumerate(rows):
-        padded[r, : len(row)] = row
-    last = jnp.asarray([len(row) - 1 for row in rows], jnp.int32)
-    return model.apply(params, jnp.asarray(padded), last,
-                       method=SambaYModel.prefill)
-
-
-def _paged_state(state, batch):
-    """The prefill's state with its cache cut into the pages of a pool:
-    row b owns pages 1 + b * TABLE ..., page 0 is nobody's."""
-    import jax.numpy as jnp
-
-    k, v = state["cache"]
-    B, H, S, D = k.shape
-    table = jnp.asarray(
-        1 + np.arange(batch * TABLE).reshape(batch, TABLE), jnp.int32)
-
-    def pool(a):
-        pages = a.reshape(B, H, S // PAGE, PAGE, D).transpose(0, 2, 1, 3, 4)
-        out = jnp.zeros((1 + batch * TABLE, H, PAGE, D), a.dtype)
-        return out.at[table[:, : S // PAGE].reshape(-1)].set(
-            pages.reshape(-1, H, PAGE, D))
-
-    return {"mamba": state["mamba"], "rings": state["rings"],
-            "pool": (pool(k), pool(v))}, table
-
-
-def test_whole_forward_matches_the_reference(tiny):
-    import jax.numpy as jnp
-
-    cfg, model, params = tiny
-    tokens = _tokens(1, (2, 37))
-    got = np.asarray(model.apply(params, jnp.asarray(tokens)))
-    for b in range(2):
-        np.testing.assert_allclose(got[b], _reference(params, tokens[b]),
-                                   atol=TOL, rtol=0)
+    return cfg, family.model(), family.params
 
 
 def test_prefill_that_skips_the_cross_decoder_equals_the_full_forward(tiny):
@@ -109,9 +42,9 @@ def test_prefill_that_skips_the_cross_decoder_equals_the_full_forward(tiny):
     import jax.numpy as jnp
 
     cfg, model, params = tiny
-    tokens = _tokens(2, (1, 29))
+    tokens = family.tokens(2, (1, 29))
     full = np.asarray(model.apply(params, jnp.asarray(tokens)))
-    logits, state = _prefill(model, params, [tokens[0]], 32)
+    logits, state = family.prefill(model, params, [tokens[0]], 32)
     np.testing.assert_allclose(np.asarray(logits[0]), full[0, -1],
                                atol=TOL, rtol=0)
     # and the cache it hands on covers the row, for one layer only
@@ -120,71 +53,14 @@ def test_prefill_that_skips_the_cross_decoder_equals_the_full_forward(tiny):
     assert len(state["rings"]) == 2 and len(state["mamba"]) == 3
 
 
-def test_rows_of_one_padded_bucket_each_get_their_own_last_state(tiny):
-    """Right-padding is harmless to causal attention and wrong for a
-    recurrence: each row's scan state, conv window and rings must be
-    those at ITS last token, as if it had been prefilled alone."""
-    cfg, model, params = tiny
-    rows = [_tokens(3, 27), _tokens(4, 11)]
-    _, both = _prefill(model, params, rows, 32)
-    for r, row in enumerate(rows):
-        _, alone = _prefill(model, params, [row], len(row))
-        for (conv2, scan2), (conv1, scan1) in zip(both["mamba"],
-                                                  alone["mamba"]):
-            np.testing.assert_allclose(conv2[r], conv1[0], atol=1e-6)
-            np.testing.assert_allclose(scan2[r], scan1[0], atol=1e-6)
-        for (k2, v2), (k1, v1) in zip(both["rings"], alone["rings"]):
-            np.testing.assert_allclose(k2[r], k1[0], atol=1e-6)
-            np.testing.assert_allclose(v2[r], v1[0], atol=1e-6)
-
-
-def _decode_against_reference(model, params, seqs, prompt_lens, steps,
-                              rounded=0):
-    """Prefill the prompts in one bucket, then `steps` teacher-forced paged
-    decode steps; the widest gap to the reference's full pass."""
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.models.sambay import SambaYModel
-
-    B = len(seqs)
-    logits, fresh = _prefill(
-        model, params, [s[:n] for s, n in zip(seqs, prompt_lens)], 32)
-    state, table = _paged_state(fresh, B)
-    want = [_reference(params, s, rounded) for s in seqs]
-    worst = max(np.abs(np.asarray(logits[b]) - want[b][n - 1]).max()
-                for b, n in enumerate(prompt_lens))
-    decode = jax.jit(lambda p, t, s, ln: model.apply(
-        p, t, s, table, ln, method=SambaYModel.decode))
-    length = jnp.asarray(prompt_lens, jnp.int32)
-    for k in range(steps):
-        token = jnp.asarray([s[n + k] for s, n in zip(seqs, prompt_lens)])
-        logits, state = decode(params, token, state, length)
-        for b, n in enumerate(prompt_lens):
-            worst = max(worst, np.abs(np.asarray(logits[b])
-                                      - want[b][n + k]).max())
-        length = length + 1
-    return worst
-
-
-def test_prefill_then_paged_decode_matches_the_reference(tiny):
-    """24 decode steps through rings (three times round a window of 8),
-    pages of 4 and the recurrent state, against the reference's whole
-    pass over prompt + generated."""
-    cfg, model, params = tiny
-    seqs = _tokens(5, (2, 60))
-    assert _decode_against_reference(model, params, seqs, [21, 13],
-                                     24) < TOL
-
-
 def test_the_tolerance_would_refuse_bf16_kv(tiny):
     """The same run against the reference with K and V rounded to
     bfloat16 (its first level of rounding) misses TOL a hundredfold: the
     tolerance is tight enough to see a cache held in a lower precision."""
     cfg, model, params = tiny
-    seqs = _tokens(5, (2, 60))
-    assert _decode_against_reference(model, params, seqs, [21, 13], 24,
-                                     rounded=1) > 50 * TOL
+    assert family.decode_against_reference(
+        model, params, family.tokens(*SEQS), [21, 13], 24,
+        rounded=1) > 50 * TOL
 
 
 def test_the_chunked_scan_equals_the_sequential_one(tiny):
@@ -296,27 +172,3 @@ def test_two_term_products_do_not_round_the_activation():
     off = [np.abs(np.asarray(masked_attention(q, k, v, seen, 0.2, p))
                   - want).max() for p in (False, True)]
     assert off[1] < 0.02 * off[0]
-
-
-def test_the_served_type_decodes_near_the_reference(tiny):
-    """bfloat16 weights, the engine's own prefill and decode: with two-term
-    products in decode the logits stay within 0.02 of the float32
-    reference's over 24 steps (the plain bfloat16 whole forward is
-    within 0.05 on the same tokens: the bound is not a strict one at
-    these tiny widths, it catches a path that rounds where it should
-    not, or a type that does not fit the state)."""
-    import dataclasses
-
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.models.sambay import SambaYModel, init_params
-
-    cfg = dataclasses.replace(tiny[0], dtype=jnp.bfloat16)
-    model = SambaYModel(cfg)
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    assert all(x.dtype in (jnp.bfloat16, jnp.float32)
-               for x in jax.tree_util.tree_leaves(params))
-    seqs = _tokens(5, (2, 60))
-    assert _decode_against_reference(model, params, seqs, [21, 13],
-                                     24) < 0.02

@@ -483,6 +483,123 @@ def test_draining_node_excluded_from_native_picks(tmp_path, monkeypatch):
     run(main())
 
 
+def test_a_creation_in_flight_stays_charged_across_a_heartbeat(
+        tmp_path, monkeypatch):
+    """A heartbeat tells what a raylet HOLDS.  A creation the GCS has sent
+    there and the raylet has not got round to (seconds, on a loaded host)
+    is not held yet: the view must keep it charged until the raylet
+    answers, or the next creation of a burst is sent to the same node,
+    waits out the lease timeout there and dies ("timeout acquiring actor
+    resources": what failed `test_elastic_shrink_then_grow_back` beside
+    busy workers, two of a gang's four members on one node)."""
+    monkeypatch.setenv("RAY_TPU_NATIVE_CONTROL", "1")
+    node_a, node_b = "a1" * 16, "b2" * 16
+
+    async def main():
+        gcs = GcsServer(persistence_path=str(tmp_path / "gcs_state"))
+        host, port = await gcs.start()
+        try:
+            creates = {node_a: [], node_b: []}
+            answer = asyncio.Event()
+
+            def mk_create(node_id):
+                async def h(conn, payload):
+                    creates[node_id].append(payload["actor_id"])
+                    await answer.wait()
+                    return {"ok": True}
+                return h
+
+            raylets = {}
+            for node_id in (node_a, node_b):
+                raylets[node_id], _, _ = await _fake_raylet_ex(
+                    host, port, node_id, on_create=mk_create(node_id))
+            driver = await rpc.connect_session(host, port, name="driver")
+
+            async def create(actor_id, cpus):
+                r = await driver.call("RegisterActor", {
+                    "actor_id": actor_id, "spec": b"\x08s",
+                    "max_restarts": 0, "class_name": "G",
+                    "resources": {"CPU": cpus}, "strategy": ["spread"]})
+                assert r["ok"]
+
+            await create("gang-0", 4.0)         # a whole node's CPUs
+            await _wait_for(lambda: creates[node_a] or creates[node_b],
+                            what="the first creation to reach a raylet")
+            first = node_a if creates[node_a] else node_b
+            other = node_b if first == node_a else node_a
+            # The raylet has not acquired anything yet, and says so; the
+            # other node is half taken by something else.
+            for node_id, free in ((first, 4.0), (other, 2.0)):
+                r = await raylets[node_id].call("Heartbeat", {
+                    "node_id": node_id,
+                    "available_resources": {"CPU": free}})
+                assert r["ok"]
+            assert gcs.nodes[first].available_resources["CPU"] == 0.0
+            # Spread over what looks free: the other node, not the one a
+            # creation is on its way to.
+            await create("gang-1", 2.0)
+            await _wait_for(lambda: creates[other], what="the second creation")
+            assert creates == {first: ["gang-0"], other: ["gang-1"]}
+            # Answered, the raylet's word is the whole truth again.
+            answer.set()
+            await _wait_for(lambda: not gcs._placing, what="the answers")
+            r = await raylets[first].call("Heartbeat", {
+                "node_id": first, "available_resources": {"CPU": 0.0}})
+            assert gcs.nodes[first].available_resources["CPU"] == 0.0
+
+            await driver.close()
+            for raylet in raylets.values():
+                await raylet.close()
+        finally:
+            await gcs.stop()
+
+    run(main())
+
+
+def test_a_call_older_than_the_grace_still_waits_for_the_rebind(tmp_path):
+    """A GCS->raylet call waits out a socket flap for the node's grace and
+    is replayed when the raylet has re-registered. The grace is counted
+    from the moment the connection is MISSED, whatever the call's age: a
+    CreateActor that had been in flight for longer than the grace (a
+    worker's start on a loaded host) was failed the instant the socket
+    dropped, and a live actor restarted over a 0.5-s flap
+    (`test_partition_flap_is_a_non_event` beside busy workers)."""
+    async def main():
+        gcs = GcsServer(persistence_path=str(tmp_path / "gcs_state"))
+        host, port = await gcs.start()
+        try:
+            held, answer = asyncio.Event(), asyncio.Event()
+
+            async def slow(conn, payload):
+                held.set()
+                await answer.wait()
+                return {"ok": True}
+
+            raylet, _, _ = await _fake_raylet_ex(
+                host, port, NODE_ID, handlers={"Slow": slow},
+                reconnect_register=True)
+            # The grace `_call_node` reads, 0.3 s (the health check, long
+            # started, keeps its 5 s: this raylet sends no heartbeats).
+            gcs.config.health_check_period_s = 0.1
+            gcs.config.num_heartbeats_timeout = 3
+            call = asyncio.ensure_future(
+                gcs._call_node(NODE_ID, "Slow", {}, timeout=20))
+            await held.wait()
+            await asyncio.sleep(0.4)        # older than the grace now
+            await gcs.node_conns[NODE_ID].close()
+            await _wait_for(
+                lambda: gcs.nodes[NODE_ID].suspect_recoveries >= 1,
+                what="the raylet to re-register")
+            assert not call.done(), call
+            answer.set()
+            assert (await asyncio.wait_for(call, 10))["ok"]
+            await raylet.close()
+        finally:
+            await gcs.stop()
+
+    run(main())
+
+
 def test_gcs_restart_rehydrates_native_plane(tmp_path, monkeypatch):
     """Crash rehydration: a restarted GCS replays the persisted node
     and actor tables into a fresh native plane — the ALIVE actor is

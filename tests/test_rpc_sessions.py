@@ -366,6 +366,47 @@ def test_accept_then_close_peer_does_not_spin_redials():
     run(main())
 
 
+def test_a_cut_soon_after_a_retried_reconnect_does_not_fail_the_session():
+    """Two outages close together (a link that flaps twice): the first
+    costs the session at least one failed dial (the handshake meets a
+    refusing link), the second cuts the new connection younger than
+    _MIN_STABLE_S. The second is a continuation of the first's streak,
+    with the streak's OWN start as its grace anchor: the session redials
+    and answers. (The anchor was only taken when a streak began with a
+    quick death, so this one read its deadline off 0.0 and failed at
+    once, "flapping (accept-then-close) for 30s", with 29 s of grace
+    left: a raylet exited on the second of two 0.5-s flaps.)"""
+    async def main():
+        server = echo_server()
+        host, port = await server.start()
+        chaos = NetChaos(seed=3).start()
+        try:
+            ph, pp = chaos.link("twice", host, port)
+
+            async def handshake(conn):
+                await conn.call("Echo", {"v": 0}, timeout=5)
+
+            sess = await rpc.connect_session(ph, pp, name="twice",
+                                             grace_s=30.0,
+                                             on_reconnect=handshake)
+            assert (await sess.call("Echo", {"v": 1}))["v"] == 1
+            # The first connection HELD (a raylet's has, for hours): the
+            # first outage begins a streak, it does not continue one.
+            await asyncio.sleep(rpc._MIN_STABLE_S + 0.1)
+            await asyncio.to_thread(chaos.flap, "twice", 0.3)
+            assert (await sess.call("Echo", {"v": 2}, timeout=10))["v"] == 2
+            assert chaos.stats("twice")["conns_refused"] >= 1
+            chaos.cut("twice")      # (well inside _MIN_STABLE_S of it)
+            assert (await sess.call("Echo", {"v": 3}, timeout=10))["v"] == 3
+            assert not sess.closed and sess.reconnects >= 2
+            await sess.close()
+        finally:
+            await server.stop()
+            chaos.stop()
+
+    run(main())
+
+
 def test_netchaos_deterministic_per_seed():
     """Same seed, same per-direction rng draw sequence — the fault
     schedule replays exactly."""

@@ -12,24 +12,16 @@ import numpy as np
 import pytest
 
 import ray_tpu
-from ray_tpu.models.generate import Generator, SamplingParams
-from ray_tpu.models.llama import LlamaConfig, LlamaModel
+from ray_tpu.models.generate import SamplingParams
 from ray_tpu.serve.llm import LLMEngine, _Prefilled
 from ray_tpu.serve.llm_disagg import PrefillEngine, PrefixCache
 from ray_tpu.test_utils import wait_for_condition
+from tests.tiny_families import dense
 
 
 @pytest.fixture(scope="module")
 def tiny_model():
-    import jax
-    import jax.numpy as jnp
-
-    cfg = LlamaConfig(vocab_size=128, d_model=64, n_layers=2, n_heads=4,
-                      n_kv_heads=2, d_ff=128, max_seq_len=128,
-                      dtype=jnp.float32, attention="reference", remat=False)
-    model = LlamaModel(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
-    return cfg, params
+    return dense.cfg, dense.params
 
 
 @pytest.fixture
@@ -42,9 +34,7 @@ def collective_env():
 
 
 def _reference_greedy(cfg, params, prompt, n_new):
-    gen = Generator(cfg, params, batch=1, max_len=len(prompt) + n_new)
-    return gen.generate(np.asarray([prompt], np.int32),
-                        SamplingParams(max_new_tokens=n_new))[0].tolist()
+    return dense.greedy(prompt, n_new)
 
 
 # ---------------------------------------------------------------------------

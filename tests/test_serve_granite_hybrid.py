@@ -4,100 +4,34 @@ behind the same loop, slots and allocator that serve a Llama.  Tiny
 widths, float32, the benchmark's plain reference as the judge: in float32
 on the CPU the engine's greedy tokens are the reference's argmax at every
 position (the top-2 margins of these logits, 1e-3 and up, are far above
-float32 reordering, 5e-6).
+float32 reordering, 5e-6).  Its streams against the reference, as every
+family's: `tests/test_families_served.py`.
 """
-
-import os
-import sys
 
 import numpy as np
 import pytest
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if _REPO not in sys.path:
-    sys.path.insert(0, _REPO)
-
-from tests.test_models_granite_hybrid import SIZES, make  # noqa: E402
-
-ENGINE = dict(max_batch=4, max_len=128, page_size=16, decode_chunk=4)
-
-
-@pytest.fixture(scope="module")
-def tiny():
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    from ray_tpu.models.granite_hybrid import TINY_GRANITE
-
-    return TINY_GRANITE, make(TINY_GRANITE)
-
-
-def _prompts(seed, lengths):
-    rng = np.random.default_rng(seed)
-    return [rng.integers(1, 256, size=n).tolist() for n in lengths]
-
-
-def _reference_gap(params, prompt, output):
-    """How far the reference's logit of each engine token lies under the
-    reference's best, teacher-forced over prompt + output (the rule of the
-    benchmark's `correct`), and the share of positions at which the
-    reference's best is the token just read."""
-    from benchmarks.reference import granite_hybrid as ref
-
-    seq = prompt + output[:-1]
-    rows = list(range(len(prompt) - 1, len(seq)))
-    lg = np.asarray(ref.logits(params, SIZES, seq, rows))
-    repeats = (lg.argmax(-1) == np.asarray(seq)[rows]).mean()
-    return lg.max(-1) - lg[np.arange(len(output)), output], repeats
-
-
-def _serve(eng, prompts, new=24):
-    from ray_tpu.models.generate import SamplingParams
-
-    eng.quiesce_for_drain()
-    handles = [eng.submit(p, SamplingParams(max_new_tokens=new))
-               for p in prompts]
-    eng.resume()
-    return [h.tokens() for h in handles]
-
+from tests.tiny_families import ENGINE, prompts as _prompts, serve
+from tests.tiny_families import granite_hybrid as family
 
 LENGTHS = (5, 19, 33, 40, 17, 64, 28, 3, 50)
 
 
-def test_engine_streams_are_the_references_greedy(tiny):
-    """Nine requests over four slots: batched prefills (rows of very
-    different lengths in one padded bucket), singles, admission
-    mid-flight, and every slot used at least twice.  A reused slot starts
-    from what its own prefill computed from zero, or the second stream in
-    it would leave the reference."""
+@pytest.fixture(scope="module")
+def tiny():
+    return family.cfg, family.params
+
+
+@pytest.fixture(scope="module")
+def engine(tiny):
+    """One engine for the tests that read it and plant nothing in it: the
+    first finds it new and leaves it held, the last asks it only what it
+    refuses."""
     from ray_tpu.serve.llm import LLMEngine
 
-    cfg, params = tiny
-    eng = LLMEngine(cfg, params, **ENGINE)
-    try:
-        prompts = _prompts(0, LENGTHS)
-        outs = _serve(eng, prompts)
-        repeats = []
-        for p, o in zip(prompts, outs):
-            assert len(o) == 24
-            gap, repeat = _reference_gap(params, p, o)
-            assert gap.max() == 0.0
-            repeats.append(repeat)
-        # the streams are not echoes of their input: the layers decide
-        assert np.mean(repeats) < 0.2
-        got = eng.report_metrics()
-        assert got["state_slots_reset"] == len(prompts)
-        assert got["ring_tokens"] == 0 and eng.num_active() == 0
-        assert got["paged_pages_live"] > 0
-        # what the fixed state costs, for a reader that knows no model:
-        # six Mamba-2 layers of (3, 160) conv inputs and (4, 32, 16) state
-        assert got["state_bytes_per_slot"] == 6 * (3 * 160 + 4 * 32 * 16) * 4
-        # every token but a stream's first came from a decode step of a
-        # live slot (a chunk may run past a stream's end by less than 4)
-        decoded = len(prompts) * 23
-        assert decoded <= got["state_slot_steps"] < decoded + 4 * len(prompts)
-    finally:
-        eng.shutdown()
+    eng = LLMEngine(*tiny, **ENGINE)
+    yield eng
+    eng.shutdown()
 
 
 def test_a_reused_slot_that_keeps_its_last_streams_state_is_seen(tiny,
@@ -126,8 +60,8 @@ def test_a_reused_slot_that_keeps_its_last_streams_state_is_seen(tiny,
     eng = LLMEngine(cfg, params, **ENGINE)
     try:
         prompts = _prompts(0, LENGTHS)
-        outs = _serve(eng, prompts)
-        gaps = [_reference_gap(params, p, o)[0].max()
+        outs = serve(eng, prompts, new=24)
+        gaps = [family.reference_gap(p, o)[0].max()
                 for p, o in zip(prompts, outs)]
         # (at these widths a state's trace in the logits is small: two of
         # the five reused streams leave the reference's greedy path, by
@@ -138,7 +72,7 @@ def test_a_reused_slot_that_keeps_its_last_streams_state_is_seen(tiny,
         eng.shutdown()
 
 
-def test_a_parked_and_an_empty_slot_keep_their_state(tiny):
+def test_a_parked_and_an_empty_slot_keep_their_state(engine):
     """The decode program with a count of steps a slot: a slot given none
     (parked, or empty) keeps conv window and state bit for bit while its
     neighbour decodes; its token, position and length stay."""
@@ -146,72 +80,62 @@ def test_a_parked_and_an_empty_slot_keep_their_state(tiny):
     import jax.numpy as jnp
 
     from ray_tpu.models.generate import SamplingParams
-    from ray_tpu.serve.llm import LLMEngine
 
-    cfg, params = tiny
-    eng = LLMEngine(cfg, params, **ENGINE)
-    try:
-        eng.quiesce_for_drain()
-        prompts = _prompts(1, (21, 30))
-        handles = [eng.submit(p, SamplingParams(max_new_tokens=40))
-                   for p in prompts]
-        eng.resume()
-        firsts = [next(iter(h)) for h in handles]   # both admitted
-        assert len(firsts) == 2
-        assert eng.quiesce_for_drain()
-        B, K = eng.max_batch, eng.decode_chunk
-        before = jax.tree_util.tree_map(np.array, eng._pools)   # copies
-        steps = np.zeros(B, np.int32)
-        steps[0] = K                    # slot 0 decodes, 1 is parked,
-        toks, after, token, pos, lens, chunk_no = eng._decode_chunk_paged(
-            eng.params, jnp.asarray(eng._token), jnp.asarray(eng._pos),
-            eng._pools, jnp.asarray(eng._tables), jnp.asarray(eng._lens),
-            jnp.asarray(eng._temps), jnp.asarray(eng._topks),
-            jnp.asarray(eng._topps), jax.random.PRNGKey(0), jnp.int32(5),
-            jnp.asarray(steps))          # 2 and 3 are empty
-        eng._pools = after              # (the old buffers were donated)
-        # The carry the program hands back: slot 0's cursor after K steps
-        # and its last token, the others' as they were; the chunk's number.
-        np.testing.assert_array_equal(lens, eng._lens + steps)
-        np.testing.assert_array_equal(pos, eng._pos + steps)
-        np.testing.assert_array_equal(token[1:], eng._token[1:])
-        assert int(token[0]) == int(toks[K - 1, 0]) and int(chunk_no) == 6
-        after = jax.tree_util.tree_map(np.asarray, after)
-        fixed = lambda s: jax.tree_util.tree_leaves(s["ssm"])  # noqa: E731
-        # (layer 0's conv window holds its last three INPUTS, a function
-        # of the tokens alone: a stream that repeats a token leaves it as
-        # it was, so it is not asked to move)
-        moved = [not np.array_equal(a[0], b[0])
-                 for a, b in zip(fixed(after), fixed(before))]
-        assert all(moved[1:]), "the decoding slot's state did not advance"
-        for a, b in zip(fixed(after), fixed(before)):
-            np.testing.assert_array_equal(a[1:], b[1:])
-    finally:
-        eng.shutdown()
+    eng = engine            # new: the two streams get slots 0 and 1
+    eng.quiesce_for_drain()
+    prompts = _prompts(1, (21, 30))
+    handles = [eng.submit(p, SamplingParams(max_new_tokens=40))
+               for p in prompts]
+    eng.resume()
+    firsts = [next(iter(h)) for h in handles]   # both admitted
+    assert len(firsts) == 2
+    assert eng.quiesce_for_drain()
+    B, K = eng.max_batch, eng.decode_chunk
+    before = jax.tree_util.tree_map(np.array, eng._pools)   # copies
+    steps = np.zeros(B, np.int32)
+    steps[0] = K                    # slot 0 decodes, 1 is parked,
+    toks, after, token, pos, lens, chunk_no = eng._decode_chunk_paged(
+        eng.params, jnp.asarray(eng._token), jnp.asarray(eng._pos),
+        eng._pools, jnp.asarray(eng._tables), jnp.asarray(eng._lens),
+        jnp.asarray(eng._temps), jnp.asarray(eng._topks),
+        jnp.asarray(eng._topps), jax.random.PRNGKey(0), jnp.int32(5),
+        jnp.asarray(steps))          # 2 and 3 are empty
+    eng._pools = after              # (the old buffers were donated)
+    # The carry the program hands back: slot 0's cursor after K steps
+    # and its last token, the others' as they were; the chunk's number.
+    np.testing.assert_array_equal(lens, eng._lens + steps)
+    np.testing.assert_array_equal(pos, eng._pos + steps)
+    np.testing.assert_array_equal(token[1:], eng._token[1:])
+    assert int(token[0]) == int(toks[K - 1, 0]) and int(chunk_no) == 6
+    after = jax.tree_util.tree_map(np.asarray, after)
+    fixed = lambda s: jax.tree_util.tree_leaves(s["ssm"])  # noqa: E731
+    # (layer 0's conv window holds its last three INPUTS, a function
+    # of the tokens alone: a stream that repeats a token leaves it as
+    # it was, so it is not asked to move)
+    moved = [not np.array_equal(a[0], b[0])
+             for a, b in zip(fixed(after), fixed(before))]
+    assert all(moved[1:]), "the decoding slot's state did not advance"
+    for a, b in zip(fixed(after), fixed(before)):
+        np.testing.assert_array_equal(a[1:], b[1:])
 
 
-def test_what_the_family_cannot_do_is_refused_in_words(tiny):
+def test_what_the_family_cannot_do_is_refused_in_words(engine):
     """A stream's state is pages AND fixed per-slot state: it cannot be
     handed to another engine (disaggregated prefill), nor carried by a
     drain snapshot."""
-    from ray_tpu.serve.llm import LLMEngine, _Prefilled
+    from ray_tpu.serve.llm import _Prefilled
     from ray_tpu.serve.llm_families import family_of
 
-    cfg, params = tiny
     with pytest.raises(TypeError, match="GraniteHybridConfig.*a new family "
                                         "is a class"):
         family_of(object(), 64)
-    eng = LLMEngine(cfg, params, **ENGINE)
-    try:
-        for refused in (
-                lambda: eng.submit_prefilled(
-                    _Prefilled([], 1, 4, 4, 0, [], True)),
-                eng.snapshot_active_streams):
-            with pytest.raises(NotImplementedError,
-                               match="fixed per-slot state.*prefilled where"):
-                refused()
-    finally:
-        eng.shutdown()
+    for refused in (
+            lambda: engine.submit_prefilled(
+                _Prefilled([], 1, 4, 4, 0, [], True)),
+            engine.snapshot_active_streams):
+        with pytest.raises(NotImplementedError,
+                           match="fixed per-slot state.*prefilled where"):
+            refused()
 
 
 def test_the_family_sizes_state_and_prefill_from_shapes():

@@ -4,29 +4,22 @@ against the one-shot Generator, which is the spec for greedy decoding)."""
 
 import time
 
-import numpy as np
 import pytest
 
-from ray_tpu.models.generate import Generator, SamplingParams
-from ray_tpu.models.llama import LlamaConfig, LlamaModel
+from ray_tpu.models.generate import SamplingParams
 from ray_tpu.serve.llm import LLMEngine, _Prefilled
+from tests.tiny_families import dense
 
 
 @pytest.fixture(scope="module")
 def tiny_model():
-    import jax
-    import jax.numpy as jnp
-
-    cfg = LlamaConfig(vocab_size=128, d_model=64, n_layers=2, n_heads=4,
-                      n_kv_heads=2, d_ff=128, max_seq_len=128,
-                      dtype=jnp.float32, attention="reference", remat=False)
-    model = LlamaModel(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
-    return cfg, params
+    return dense.cfg, dense.params
 
 
-@pytest.fixture()
+@pytest.fixture(scope="module")
 def engine(tiny_model):
+    """One engine for the tests that only submit to it and read what comes
+    back: each leaves it idle."""
     cfg, params = tiny_model
     eng = LLMEngine(cfg, params, max_batch=3, max_len=96, page_size=16)
     yield eng
@@ -34,12 +27,11 @@ def engine(tiny_model):
 
 
 def _reference_greedy(cfg, params, prompt, n_new):
-    gen = Generator(cfg, params, batch=1, max_len=len(prompt) + n_new)
-    return gen.generate(np.asarray([prompt], np.int32),
-                        SamplingParams(max_new_tokens=n_new))[0].tolist()
+    return dense.greedy(prompt, n_new)
 
 
-@pytest.fixture(params=[0, 64], ids=["default-pool", "small-pool"])
+@pytest.fixture(scope="module", params=[0, 64],
+                ids=["default-pool", "small-pool"])
 def pooled_engine(request, tiny_model):
     """The engine with room for every slot's longest stream (the default
     pool), and with fewer tokens than three requests need at once, so

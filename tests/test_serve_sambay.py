@@ -3,81 +3,38 @@ one layer, rings and recurrent state behind the same loop, slots and
 allocator that serve a Llama.  Tiny widths, float32, the benchmark's plain
 reference as the judge: in float32 on the CPU the engine's greedy tokens
 are the reference's argmax at every position (the top-2 margins of these
-logits, 1e-3 and up, are far above float32 reordering, 5e-7).
+logits, 1e-3 and up, are far above float32 reordering, 5e-7).  Its
+streams against the reference, as every family's:
+`tests/test_families_served.py`.
 """
 
-import os
-import sys
 import time
 
 import numpy as np
 import pytest
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if _REPO not in sys.path:
-    sys.path.insert(0, _REPO)
-
-from tests.test_models_sambay import SIZES  # noqa: E402
-
-ENGINE = dict(max_batch=4, max_len=128, page_size=16, decode_chunk=4)
+from tests.tiny_families import ENGINE, prompts as _prompts
+from tests.tiny_families import sambay as family
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    from ray_tpu.models.sambay import TINY_SAMBAY, init_params
-
-    return TINY_SAMBAY, init_params(TINY_SAMBAY, jax.random.PRNGKey(0))
+    return family.cfg, family.params
 
 
-def _prompts(seed, lengths):
-    rng = np.random.default_rng(seed)
-    return [rng.integers(1, 256, size=n).tolist() for n in lengths]
-
-
-def _reference_gap(params, prompt, output):
-    """How far the reference's logit of each engine token lies under the
-    reference's best, teacher-forced over prompt + output."""
-    from benchmarks.reference import sambay as ref
-
-    rows = list(range(len(prompt) - 1, len(prompt) + len(output) - 1))
-    lg = np.asarray(ref.logits(params, SIZES, prompt + output[:-1], rows))
-    return lg.max(-1) - lg[np.arange(len(output)), output]
-
-
-def test_engine_streams_are_the_references_greedy(tiny):
-    """Six requests over four slots: a batched prefill (two rows of one
-    bucket), singles, admission mid-flight and two slots used twice.  A
-    reused slot starts from what its own prefill computed from zero, or
-    the second stream in it would leave the reference."""
-    from ray_tpu.models.generate import SamplingParams
+@pytest.fixture(scope="module")
+def engine(tiny):
+    """One engine for the tests that read it and plant nothing in it: the
+    first finds it new and leaves it held, the last asks it only what it
+    refuses."""
     from ray_tpu.serve.llm import LLMEngine
 
-    cfg, params = tiny
-    eng = LLMEngine(cfg, params, **ENGINE)
-    try:
-        prompts = _prompts(0, (5, 19, 33, 40, 17, 64))
-        eng.quiesce_for_drain()
-        handles = [eng.submit(p, SamplingParams(max_new_tokens=24))
-                   for p in prompts]
-        eng.resume()
-        outs = [h.tokens() for h in handles]
-        for p, o in zip(prompts, outs):
-            assert len(o) == 24
-            assert _reference_gap(params, p, o).max() == 0.0
-        got = eng.report_metrics()
-        assert got["state_slots_reset"] == 6
-        assert got["ring_tokens"] == 0 and eng.num_active() == 0
-        # the one paged layer is read by the full layer and the cross layer
-        assert got["shared_pool_pages_live"] == 2 * got["paged_pages_live"]
-        assert got["paged_pages_live"] > 0
-    finally:
-        eng.shutdown()
+    eng = LLMEngine(*tiny, **ENGINE)
+    yield eng
+    eng.shutdown()
 
 
-def test_a_parked_and_an_empty_slot_keep_their_state(tiny):
+def test_a_parked_and_an_empty_slot_keep_their_state(engine):
     """The decode program with a count of steps a slot: a slot given none
     (parked, or empty) keeps rings, conv window and scan state bit for
     bit while its neighbours decode; its token, position and length stay."""
@@ -85,49 +42,44 @@ def test_a_parked_and_an_empty_slot_keep_their_state(tiny):
     import jax.numpy as jnp
 
     from ray_tpu.models.generate import SamplingParams
-    from ray_tpu.serve.llm import LLMEngine
 
-    cfg, params = tiny
-    eng = LLMEngine(cfg, params, **ENGINE)
-    try:
-        eng.quiesce_for_drain()
-        prompts = _prompts(1, (21, 30))
-        handles = [eng.submit(p, SamplingParams(max_new_tokens=40))
-                   for p in prompts]
-        eng.resume()
-        firsts = [next(iter(h)) for h in handles]   # both admitted
-        assert len(firsts) == 2
-        assert eng.quiesce_for_drain()
-        B, K = eng.max_batch, eng.decode_chunk
-        before = jax.tree_util.tree_map(np.array, eng._pools)   # copies
-        steps = np.zeros(B, np.int32)
-        steps[0] = K                    # slot 0 decodes, 1 is parked,
-        toks, after, token, pos, lens, chunk_no = eng._decode_chunk_paged(
-            eng.params, jnp.asarray(eng._token), jnp.asarray(eng._pos),
-            eng._pools, jnp.asarray(eng._tables), jnp.asarray(eng._lens),
-            jnp.asarray(eng._temps), jnp.asarray(eng._topks),
-            jnp.asarray(eng._topps), jax.random.PRNGKey(0), jnp.int32(5),
-            jnp.asarray(steps))          # 2 and 3 are empty
-        eng._pools = after              # (the old buffers were donated)
-        # The carry the program hands back: slot 0's cursor after K steps
-        # and its last token, the others' as they were; the chunk's number.
-        np.testing.assert_array_equal(lens, eng._lens + steps)
-        np.testing.assert_array_equal(pos, eng._pos + steps)
-        np.testing.assert_array_equal(token[1:], eng._token[1:])
-        assert int(token[0]) == int(toks[K - 1, 0]) and int(chunk_no) == 6
-        after = jax.tree_util.tree_map(np.asarray, after)
-        fixed = lambda s: jax.tree_util.tree_leaves(  # noqa: E731
-            {"rings": s["rings"], "mamba": s["mamba"]})
-        # (layer 0's conv window holds its last three INPUTS, a function
-        # of the tokens alone: a stream that repeats a token leaves it as
-        # it was, so it is not asked to move)
-        moved = [not np.array_equal(a[0], b[0])
-                 for a, b in zip(fixed(after), fixed(before))]
-        assert all(moved[1:]), "the decoding slot's state did not advance"
-        for a, b in zip(fixed(after), fixed(before)):
-            np.testing.assert_array_equal(a[1:], b[1:])
-    finally:
-        eng.shutdown()
+    eng = engine            # new: the two streams get slots 0 and 1
+    eng.quiesce_for_drain()
+    prompts = _prompts(1, (21, 30))
+    handles = [eng.submit(p, SamplingParams(max_new_tokens=40))
+               for p in prompts]
+    eng.resume()
+    firsts = [next(iter(h)) for h in handles]   # both admitted
+    assert len(firsts) == 2
+    assert eng.quiesce_for_drain()
+    B, K = eng.max_batch, eng.decode_chunk
+    before = jax.tree_util.tree_map(np.array, eng._pools)   # copies
+    steps = np.zeros(B, np.int32)
+    steps[0] = K                    # slot 0 decodes, 1 is parked,
+    toks, after, token, pos, lens, chunk_no = eng._decode_chunk_paged(
+        eng.params, jnp.asarray(eng._token), jnp.asarray(eng._pos),
+        eng._pools, jnp.asarray(eng._tables), jnp.asarray(eng._lens),
+        jnp.asarray(eng._temps), jnp.asarray(eng._topks),
+        jnp.asarray(eng._topps), jax.random.PRNGKey(0), jnp.int32(5),
+        jnp.asarray(steps))          # 2 and 3 are empty
+    eng._pools = after              # (the old buffers were donated)
+    # The carry the program hands back: slot 0's cursor after K steps
+    # and its last token, the others' as they were; the chunk's number.
+    np.testing.assert_array_equal(lens, eng._lens + steps)
+    np.testing.assert_array_equal(pos, eng._pos + steps)
+    np.testing.assert_array_equal(token[1:], eng._token[1:])
+    assert int(token[0]) == int(toks[K - 1, 0]) and int(chunk_no) == 6
+    after = jax.tree_util.tree_map(np.asarray, after)
+    fixed = lambda s: jax.tree_util.tree_leaves(  # noqa: E731
+        {"rings": s["rings"], "mamba": s["mamba"]})
+    # (layer 0's conv window holds its last three INPUTS, a function
+    # of the tokens alone: a stream that repeats a token leaves it as
+    # it was, so it is not asked to move)
+    moved = [not np.array_equal(a[0], b[0])
+             for a, b in zip(fixed(after), fixed(before))]
+    assert all(moved[1:]), "the decoding slot's state did not advance"
+    for a, b in zip(fixed(after), fixed(before)):
+        np.testing.assert_array_equal(a[1:], b[1:])
 
 
 def test_a_slow_consumer_parks_its_slot_and_loses_nothing(tiny):
@@ -148,26 +100,22 @@ def test_a_slow_consumer_parks_its_slot_and_loses_nothing(tiny):
         time.sleep(0.2)
         slow_out = slow.tokens()
         assert len(slow_out) == len(fast_out) == 30
-        assert _reference_gap(params, slow_p, slow_out).max() == 0.0
-        assert _reference_gap(params, fast_p, fast_out).max() == 0.0
+        assert family.is_greedy(slow_p, slow_out)
+        assert family.is_greedy(fast_p, fast_out)
     finally:
         eng.shutdown()
 
 
-def test_what_the_family_cannot_do_is_refused_in_words(tiny):
+def test_what_the_family_cannot_do_is_refused_in_words(tiny, engine):
     from ray_tpu.serve.llm import LLMEngine, _Prefilled
 
     cfg, params = tiny
     with pytest.raises(TypeError, match="a new family is a class"):
         LLMEngine(object(), params, max_batch=2, max_len=64, page_size=16)
-    eng = LLMEngine(cfg, params, **ENGINE)
-    try:
-        with pytest.raises(NotImplementedError, match="prefilled where"):
-            eng.submit_prefilled(_Prefilled([], 1, 4, 4, 0, [], True))
-        with pytest.raises(NotImplementedError, match="prefilled where"):
-            eng.snapshot_active_streams()
-    finally:
-        eng.shutdown()
+    with pytest.raises(NotImplementedError, match="prefilled where"):
+        engine.submit_prefilled(_Prefilled([], 1, 4, 4, 0, [], True))
+    with pytest.raises(NotImplementedError, match="prefilled where"):
+        engine.snapshot_active_streams()
 
 
 def test_the_family_sizes_the_batched_prefill_by_bucket(tiny):

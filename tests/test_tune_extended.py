@@ -67,15 +67,15 @@ def test_tpe_in_tuner_finds_minimum(ray_start_regular):
         session.report({"loss": (config["lr"] - 0.01) ** 2})
 
     searcher = TPESearcher({"lr": tune.loguniform(1e-4, 1.0)},
-                           metric="loss", mode="min", num_samples=12,
-                           n_initial=6, seed=1)
+                           metric="loss", mode="min", num_samples=6,
+                           n_initial=3, seed=1)
     tuner = tune.Tuner(
         objective,
         tune_config=tune.TuneConfig(metric="loss", mode="min",
                                     search_alg=searcher,
                                     max_concurrent_trials=3))
     results = tuner.fit()
-    assert len(results) == 12
+    assert len(results) == 6
     best = results.get_best_result()
     assert best.metrics["loss"] < 0.05
 
@@ -216,7 +216,7 @@ def test_bohb_factory_in_tuner(ray_start_regular):
                 {"loss": (config["lr"] - 0.01) ** 2 + 0.1 / (i + 1)})
 
     searcher, scheduler = bohb({"lr": tune.loguniform(1e-4, 1.0)},
-                               metric="loss", mode="min", num_samples=8,
+                               metric="loss", mode="min", num_samples=4,
                                max_t=8, seed=2)
     results = tune.Tuner(
         objective,
@@ -224,7 +224,7 @@ def test_bohb_factory_in_tuner(ray_start_regular):
                                     search_alg=searcher,
                                     scheduler=scheduler,
                                     max_concurrent_trials=2)).fit()
-    assert len(results) == 8
+    assert len(results) == 4
     assert results.get_best_result().metrics["loss"] < 0.3
 
 
