@@ -3,12 +3,15 @@
 The reference ships no model implementations (its release gates pull
 GPT-J/vicuna through external torch engines); here the flagship decoder,
 an expert-parallel MoE, and the generation path are part of the framework.
-`serve.LLMEngine` serves five of them (`serve/llm_families.py`):
+`serve.LLMEngine` serves six of them (`serve/llm_families.py`):
 `LlamaConfig`, `SambaYConfig`, `GraniteHybridConfig`, `Lfm2MoeConfig`
 (routed experts with no token dropped and a cached decode path; `moe.py`'s
-capacity-bounded layer trains at toy sizes and is not served) and
+capacity-bounded layer trains at toy sizes and is not served),
 `MlaMoeConfig` (latent attention over a latent paged cache, the same
-routed layer with shared experts beside it).
+routed layer with shared experts beside it) and `MiniCpmSalaConfig`
+(block-sparse attention that chooses its pages through a pool of
+compressed keys, among lightning linear-attention layers whose state
+`granite_hybrid`'s scan advances).
 """
 
 from ray_tpu.models.lfm2_moe import (
@@ -26,6 +29,12 @@ from ray_tpu.models.llama import (
     LlamaModel,
     cross_entropy_loss,
     init_kv_caches,
+)
+from ray_tpu.models.minicpm_sala import (
+    MINICPM_SALA_L8,
+    TINY_SALA,
+    MiniCpmSalaConfig,
+    MiniCpmSalaModel,
 )
 from ray_tpu.models.mla_moe import (
     KIMI_VL_A3B,
@@ -114,4 +123,5 @@ __all__ = [
     "TINY_GRANITE",
     "Lfm2MoeModel", "Lfm2MoeConfig", "LFM2_24B_A2B", "TINY_LFM2_MOE",
     "MlaMoeModel", "MlaMoeConfig", "KIMI_VL_A3B", "TINY_MLA_MOE",
+    "MiniCpmSalaModel", "MiniCpmSalaConfig", "MINICPM_SALA_L8", "TINY_SALA",
 ]

@@ -215,11 +215,13 @@ def ssd_scan(x, dt, a, b_sel, c_sel, s0=None, *, chunk: int):
     """The Mamba-2 recurrence over whole rows in its chunked matrix form.
 
     x (B, S, H, P); dt (B, S, H) float32, >= 0; a (H,) negative; b_sel,
-    c_sel (B, S, N) (one group: every head reads the same B and C); s0
-    (B, H, P, N) float32 or None (zeros).  Returns y (B, S, H, P) float32
-    WITHOUT the skip term, and the state after the last position.  A
-    position whose dt is 0 leaves the state as it was (decay 1, drive 0):
-    that is how a right-padded row keeps the state of its own last token.
+    c_sel (B, S, N) (one group: every head reads the same B and C) or
+    (B, S, H, N) (a head its own: linear attention, B its keys and C its
+    queries, `models/minicpm_sala.py`); s0 (B, H, P, N) float32 or None
+    (zeros).  Returns y (B, S, H, P) float32 WITHOUT the skip term, and the
+    state after the last position.  A position whose dt is 0 leaves the
+    state as it was (decay 1, drive 0): that is how a right-padded row
+    keeps the state of its own last token.
 
     With l_t = cumsum(dt_t a) inside a chunk of Q positions:
       inside    y_t += sum_{s<=t} exp(l_t - l_s) (C_t . B_s) dt_s x_s
@@ -230,7 +232,6 @@ def ssd_scan(x, dt, a, b_sel, c_sel, s0=None, *, chunk: int):
     token and layer beside 152 of projections, and what they round the
     state keeps."""
     B, S, H, P = x.shape
-    N = b_sel.shape[-1]
     Q = min(chunk, S)
     pad = -S % Q
     if pad:
@@ -242,32 +243,47 @@ def ssd_scan(x, dt, a, b_sel, c_sel, s0=None, *, chunk: int):
     exact = dict(precision=jax.lax.Precision.HIGHEST)
     x, dt, b_sel, c_sel = (v.reshape(B, nc, Q, *v.shape[2:])
                            for v in (x, dt, b_sel, c_sel))
+    # B and C of a position: "n" where the heads share them, "hn" a head
+    n = "n" if b_sel.ndim == 4 else "hn"
     l = jnp.cumsum(dt * a, axis=2)                          # (B, nc, Q, H)
     drive = dt[..., None] * x                               # dt_s x_s
     # inside a chunk
-    scores = jnp.einsum("bctn,bcsn->bcts", c_sel, b_sel, **exact)
+    scores = jnp.einsum(f"bct{n},bcs{n}->bc{n[:-1]}ts", c_sel, b_sel, **exact)
+    if n == "n":
+        scores = scores[:, :, None]
     lh = l.transpose(0, 1, 3, 2)                            # (B, nc, H, Q)
     seen = jnp.tril(jnp.ones((Q, Q), bool))
     decay = jnp.exp(jnp.where(seen, lh[..., :, None] - lh[..., None, :],
                               -jnp.inf))                    # (B, nc, H, t, s)
-    y = jnp.einsum("bchts,bcshp->bcthp", decay * scores[:, :, None], drive,
-                   **exact)
+    y = jnp.einsum("bchts,bcshp->bcthp", decay * scores, drive, **exact)
     # each chunk's own contribution to the state at its end
     to_end = jnp.exp(l[:, :, -1:, :] - l)                   # (B, nc, Q, H)
-    own = jnp.einsum("bcshp,bcsn->bchpn", to_end[..., None] * drive, b_sel,
-                     **exact)
+    own = jnp.einsum(f"bcshp,bcs{n}->bchpn", to_end[..., None] * drive,
+                     b_sel, **exact)
     # between chunks: the state each chunk starts from
     whole = jnp.exp(l[:, :, -1, :])                         # (B, nc, H)
-    s = jnp.zeros((B, H, P, N), jnp.float32) if s0 is None \
+    s = jnp.zeros((B, H, P, b_sel.shape[-1]), jnp.float32) if s0 is None \
         else s0.astype(jnp.float32)
     starts = []
     for c in range(nc):
         starts.append(s)
         s = whole[:, c, :, None, None] * s + own[:, c]
     s_in = jnp.stack(starts, axis=1)                        # (B, nc, H, P, N)
-    y = y + jnp.einsum("bctn,bchpn->bcthp", c_sel, s_in, **exact) \
+    y = y + jnp.einsum(f"bct{n},bchpn->bcthp", c_sel, s_in, **exact) \
         * jnp.exp(l)[..., None]
     return y.reshape(B, nc * Q, H, P)[:, :S], s
+
+
+def state_step(s_prev, decay, drive, b_sel, c_sel):
+    """The recurrence itself, one token: s_prev (B, H, P, N) float32, decay
+    (B, H) or (H,), drive (B, H, P) (dt x), b_sel and c_sel (B, N) or (B, H,
+    N) as `ssd_scan` takes them -> y (B, H, P) without the skip term, and
+    the new state.  Each sequence's state is read and written once."""
+    if b_sel.ndim == 2:
+        b_sel, c_sel = b_sel[:, None], c_sel[:, None]
+    s = decay[..., None, None] * s_prev \
+        + drive[..., None] * b_sel[:, :, None, :]
+    return jnp.sum(s * c_sel[:, :, None, :], axis=-1), s
 
 
 class Mamba2(nn.Module):
@@ -359,10 +375,8 @@ class Mamba2(nn.Module):
             x, b_sel, c_sel = self._split_conv(xbc)         # x (B, H, P)
             dt = self._step_size(dt)                        # (B, H)
             decay = jnp.exp(dt * -jnp.exp(self.a_log))
-            s = decay[:, :, None, None] * s_prev \
-                + (dt[:, :, None] * x)[..., None] * b_sel[:, None, None, :]
-            y = jnp.sum(s * c_sel[:, None, None, :], axis=-1) \
-                + self.d_skip[:, None] * x
+            y, s = state_step(s_prev, decay, dt[:, :, None] * x, b_sel, c_sel)
+            y = y + self.d_skip[:, None] * x
             new = (window[:, 1:].astype(conv.dtype), s)
             if live is not None:
                 new = (jnp.where(live[:, None, None], new[0], conv),

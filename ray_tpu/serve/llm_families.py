@@ -76,6 +76,8 @@ from ray_tpu.models.granite_hybrid import (GraniteHybridConfig,
 from ray_tpu.models.lfm2_moe import Lfm2MoeConfig, Lfm2MoeModel
 from ray_tpu.models.llama import (FRESH_KV, LlamaConfig, LlamaModel,
                                   PagedKVCache)
+from ray_tpu.models.minicpm_sala import (LIGHTNING, SPARSE, STEP_COUNTS,
+                                         MiniCpmSalaConfig, MiniCpmSalaModel)
 from ray_tpu.models.mla_moe import MlaMoeConfig, MlaMoeModel
 from ray_tpu.models.sambay import SambaYConfig, SambaYModel
 
@@ -83,10 +85,10 @@ from ray_tpu.models.sambay import SambaYConfig, SambaYModel
 BATCH_PREFILL_WIDTH = 8
 
 
-def _fixed_bytes_per_slot(family, fixed) -> int:
+def _fixed_bytes_per_slot(family, fixed, page_size: int = 64) -> int:
     """Bytes one slot holds of the per-slot part of a family's state
     (`fixed(state)` picks it out), from shapes alone."""
-    state = jax.eval_shape(lambda: family.init_state(1, 1, 64))
+    state = jax.eval_shape(lambda: family.init_state(1, 1, page_size))
     return sum(math.prod(x.shape) * x.dtype.itemsize
                for x in jax.tree_util.tree_leaves(fixed(state)))
 
@@ -441,9 +443,102 @@ class MlaMoeServing:
             [counts[:3], read.astype(jnp.int32)[None]])
 
 
+class MiniCpmSalaServing:
+    """`models/minicpm_sala.py`: a sparse layer holds a (k, v) pool with one
+    row a (page, K/V head), `page * Hkv + head`, so that a decode step's
+    kernel reads for each K/V head the pages its own selection gathered; a
+    float32 pool of compressed keys, one row a page, under the same table
+    row; and,
+    fixed per slot, the sums of the two key segments still open.  A
+    lightning layer holds its state S (H, 128, 128) float32, fixed per slot
+    (2 MiB a layer at the published sizes, as `GraniteHybridServing`'s).
+    A page IS a block of the selection: `page_size` must be the
+    configuration's `block_size`."""
+
+    rewinds = False
+    portable_kv = False
+    pool_readers = 1
+    # `models/minicpm_sala.STEP_COUNTS`: over a step's live rows and its
+    # (sparse layer, K/V head) tables; a prefill counts its prompts past
+    # `dense_len`
+    step_counters = tuple((name, "sum") for name in STEP_COUNTS)
+    prefill_counters = (("sparse_prompts", "sum"),)
+
+    def __init__(self, cfg: MiniCpmSalaConfig, max_len: int):
+        self.cfg, self.max_len = cfg, max_len
+        self.model = MiniCpmSalaModel(cfg)
+        self.state_bytes_per_slot = _fixed_bytes_per_slot(
+            self, lambda s: (s["open"], s["lightning"]), cfg.block_size)
+
+    def ring_tokens(self, lens) -> int:
+        return 0
+
+    def prefill_width(self, bucket: int, max_batch: int) -> int:
+        # One program a bucket: the prompts are long (a 32,768 bucket alone
+        # is the tokens eight rows of another family's are), and the
+        # clients of a closed loop arrive one at a time.
+        return 1
+
+    def prompt_pages(self, bucket: int, page_size: int) -> int:
+        return -(-bucket // page_size)
+
+    def init_state(self, max_batch: int, num_pages: int, page_size: int):
+        c = self.cfg
+        if page_size != c.block_size:
+            raise ValueError(
+                f"page_size={page_size}: a page is a block of the selection "
+                f"(block_size {c.block_size})")
+        # (every leaf a buffer of its own: the state is donated)
+        pool = lambda: jnp.zeros(  # noqa: E731
+            (num_pages * c.n_kv_heads, 1, page_size, c.head_dim), c.dtype)
+        sparse, lightning = c.layers_of(SPARSE), c.layers_of(LIGHTNING)
+        return {
+            "pools": [(pool(), pool()) for _ in sparse],
+            "cpools": [jnp.zeros((num_pages, c.ckey_row), jnp.float32)
+                       for _ in sparse],
+            "open": [jnp.zeros((max_batch, c.n_kv_heads, 2, c.head_dim),
+                               jnp.float32) for _ in sparse],
+            "lightning": [jnp.zeros((max_batch, c.n_heads, c.head_dim,
+                                     c.head_dim), jnp.float32)
+                          for _ in lightning]}
+
+    def prefill(self, params, tokens, last_idx):
+        return self.model.apply(params, tokens, last_idx,
+                                method=MiniCpmSalaModel.prefill)
+
+    def write_prompt(self, state, fresh, slots, page_ids):
+        c = self.cfg
+        flat = page_ids.reshape(-1)
+        ps = state["pools"][0][0].shape[2]
+        rows = (flat[:, None] * c.n_kv_heads
+                + jnp.arange(c.n_kv_heads)[None]).reshape(-1)
+        # (a bucket that is no whole pages: its last page is padded)
+        paged = lambda a: _pages(jnp.pad(  # noqa: E731
+            a, ((0, 0), (0, 0), (0, -a.shape[2] % ps), (0, 0))), ps) \
+            .reshape(-1, 1, ps, c.head_dim)
+        put = lambda old, new: old.at[slots].set(  # noqa: E731
+            new, mode="drop")
+        return {
+            "pools": [(kp.at[rows].set(paged(k)), vp.at[rows].set(paged(v)))
+                      for (kp, vp), (k, v) in zip(state["pools"],
+                                                  fresh["kv"])],
+            "cpools": [pool.at[flat].set(ck.reshape(-1, c.ckey_row))
+                       for pool, ck in zip(state["cpools"], fresh["ckeys"])],
+            "open": list(map(put, state["open"], fresh["open"])),
+            "lightning": list(map(put, state["lightning"],
+                                  fresh["lightning"]))}
+
+    def decode(self, params, token, pos, state, tables, lens, live):
+        return self.model.apply(
+            params, token, pos, state, tables, lens,
+            lens > 0 if live is None else live,
+            method=MiniCpmSalaModel.decode)
+
+
 _FAMILIES = {LlamaConfig: LlamaServing, SambaYConfig: SambaYServing,
              GraniteHybridConfig: GraniteHybridServing,
-             Lfm2MoeConfig: Lfm2MoeServing, MlaMoeConfig: MlaMoeServing}
+             Lfm2MoeConfig: Lfm2MoeServing, MlaMoeConfig: MlaMoeServing,
+             MiniCpmSalaConfig: MiniCpmSalaServing}
 
 
 def family_of(cfg, max_len: int):

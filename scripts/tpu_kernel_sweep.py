@@ -15,7 +15,8 @@ interpret-mode path on CPU).  Produces:
      does on any tree, the whole `expert_ffn` at the same shapes.
 
 Usage: python scripts/tpu_kernel_sweep.py
-           [--sweep-only|--check-only|--latent|--gmm|--ffn [family ...]]
+           [--sweep-only|--check-only|--latent|--gathered|--gmm|--ffn
+            [family ...]]
 """
 
 from __future__ import annotations
@@ -90,7 +91,7 @@ def check_flash():
 
 
 def check_paged(Hkv: int = 8, page: int = 16, npages_seq: int = 8,
-                lengths=(37, 128, 1, 100)):
+                lengths=(37, 128, 1, 100), H: int = 8):
     """Hkv == H exercises MHA; Hkv < H exercises the GQA grouped-query
     q-block path (groups > 1), which must be validated on-chip too.
     `lengths` may be ragged and may hold 0 (an empty slot: its row is
@@ -99,7 +100,7 @@ def check_paged(Hkv: int = 8, page: int = 16, npages_seq: int = 8,
     with those rows scattered in, bit for bit, and its output is the
     read-only call's on them."""
     from ray_tpu.ops.paged_attention import paged_decode_attention_batch
-    H, D = 8, 128
+    D = 128
     B = len(lengths)
     pool_pages = B * npages_seq + 1
     groups = H // Hkv
@@ -299,6 +300,46 @@ def time_latent(batch: int = 64, page: int = 64):
                        resident * 16 * (576 + 512) * 2 / PEAK_FLOPS) * 1e3
         print(json.dumps({"time": "paged_latent", "rows": batch,
                           "tokens_a_row": tokens, "ms": round(ms, 4),
+                          "least_ms": round(least_ms, 4),
+                          "roofline_share": round(least_ms / ms, 4)}))
+
+
+def time_gathered(rows: int = 24, page: int = 64, table: int = 128):
+    """The paged kernel alone as a sparse layer of `models/minicpm_sala.py`
+    calls it (`--gathered`): 12 sequences x 2 K/V heads as 24 rows of 16
+    float32 query heads over ONE K/V head, a gathered table of 128 columns
+    of which 97 are live (the sparse regime) or all (a sequence at
+    `dense_len`), pool rows of one (page, head): against its least time
+    (K and V of each live page once, 32 KB; 16 heads x 64 x 128 x 4
+    operations a page), the chip's published peaks."""
+    from ray_tpu.ops.paged_attention import paged_decode_attention_batch
+    rng = np.random.default_rng(0)
+    pool_pages = rows * table + 1
+    pool = lambda: jnp.asarray(rng.standard_normal(  # noqa: E731
+        (pool_pages, 1, page, 128)), jnp.bfloat16)
+    k_pool, v_pool = pool(), pool()
+    tables = jnp.asarray(1 + rng.permutation(pool_pages - 1).reshape(
+        rows, table), jnp.int32)
+    q = jnp.asarray(rng.standard_normal((rows, 16, 128)), jnp.float32)
+    new = jnp.asarray(rng.standard_normal((rows, 1, 128)), jnp.float32)
+    f = jax.jit(lambda q, k, v, t, n, kn, vn: paged_decode_attention_batch(
+        q, k, v, t, n, k_new=kn, v_new=vn), donate_argnums=(1, 2))
+    for pages in (97, table):
+        lens = jnp.full((rows,), (pages - 1) * page + 17, jnp.int32)
+        out, k_pool, v_pool = f(q, k_pool, v_pool, tables, lens, new, new)
+        _sync(out)
+        t0 = time.perf_counter()
+        n = 50
+        for _ in range(n):
+            out, k_pool, v_pool = f(q, k_pool, v_pool, tables, lens, new,
+                                    new)
+        _sync(out)
+        ms = (time.perf_counter() - t0) / n * 1e3
+        live = rows * pages
+        least_ms = max(live * 2 * page * 128 * 2 / PEAK_BYTES,
+                       live * 4 * 16 * page * 128 / PEAK_FLOPS) * 1e3
+        print(json.dumps({"time": "paged_gathered", "rows": rows,
+                          "live_pages_a_row": pages, "ms": round(ms, 4),
                           "least_ms": round(least_ms, 4),
                           "roofline_share": round(least_ms / ms, 4)}))
 
@@ -625,6 +666,11 @@ def main():
         ok = check_latent() and check_latent(lengths=(5, 128, 1000, 64))
         time_latent()
         sys.exit(0 if ok else 1)
+    if mode == "--gathered":    # the paged kernel over a gathered table
+        ok = check_paged(Hkv=1, H=16, page=64, npages_seq=128,
+                         lengths=(96 * 64 + 17, 8192, 1, 0, 6000, 97 * 64))
+        time_gathered()
+        sys.exit(0 if ok else 1)
     if mode == "--gmm":         # the grouped product's tiles, from a trace
         sweep_gmm(sys.argv[2:] or list(GMM_FAMILIES))
     if mode in ("--gmm", "--ffn"):  # and the routed layer around it
@@ -640,6 +686,10 @@ def main():
         ok = check_paged(Hkv=2, page=64, npages_seq=37,
                          lengths=(0, 1, 64, 65, 0, 700, 37 * 64, 0)) and ok
         ok = check_latent() and ok
+        # A sparse layer's call: a row a (sequence, K/V head), 16 query
+        # heads over ONE K/V head, a gathered table of 128 columns.
+        ok = check_paged(Hkv=1, H=16, page=64, npages_seq=128,
+                         lengths=(96 * 64 + 17, 8192, 1, 0, 6000)) and ok
     if mode != "--check-only":
         sweep_flash()
     sys.exit(0 if ok else 1)

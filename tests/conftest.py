@@ -90,7 +90,10 @@ def ray_start_cluster_head():
 # such fixture. PR 45 appended a configuration, a cell, five entries and
 # that cell's name to eleven `workloads` lists, all AFTER what is cut here:
 # the three modules see the benchmark as before, and `test_mla_moe_cell.py`
-# looks its entries up by name too.) The `benchmark` PR that makes the two old modules do the
+# looks its entries up by name too. PR 49 did the same: a configuration, a
+# cell, four entries and that cell's name on nine `workloads` lists, all
+# after the cut; `test_minicpm_sala_cell.py` looks its entries up by name.)
+# The `benchmark` PR that makes the two old modules do the
 # same deletes this with that conftest's fixture.
 # module -> (the newest per-layer entry it knows, the newest cell it knows:
 # None = the module's own CELL)

@@ -1,4 +1,4 @@
-"""The five served families at tiny widths, in ONE place: for each its tiny
+"""The six served families at tiny widths, in ONE place: for each its tiny
 configuration (the model file's own, `benchmarks/families` checks it), the
 sizes its plain reference reads, its seeded weights (made once a process, on
 first use), and "is this stream what the plain reference decodes greedily".
@@ -509,7 +509,68 @@ class MlaMoe(Family):
                                              list(seq), rows, **how))
 
 
+class MiniCpmSala(Family):
+    """MiniCPM-SALA's decoder: d 64, 4 layers (sparse, lightning, lightning,
+    sparse), 4 query heads over 2 K/V heads of 16, blocks (pages) of 8
+    tokens, compressed keys of 4 tokens every 2, one initial block, a
+    window of 2 blocks and the 2 best others kept past `dense_len` 64;
+    float32.  Its engine has pages of 8 (`SALA_ENGINE`), so it stands
+    beside `FAMILIES`, whose cases share pages of 16."""
+
+    name = "minicpm_sala"
+    SIZES = dict(
+        attention_bias=False, attn_use_rope=False, head_dim=16,
+        hidden_act="silu", hidden_size=64, intermediate_size=128,
+        lightning_head_dim=16, lightning_nh=4, lightning_nkv=4,
+        lightning_scale="1/sqrt(d)", lightning_use_rope=True,
+        max_position_embeddings=512,
+        mixer_types=["minicpm4", "lightning-attn", "lightning-attn",
+                     "minicpm4"],
+        num_attention_heads=4, num_hidden_layers=4, num_key_value_heads=2,
+        qk_norm=True, rms_norm_eps=1e-6, vocab_size=256, rope_theta=10000,
+        scale_emb=12, scale_depth=1.4, dim_model_base=16,
+        tie_word_embeddings=False, use_output_gate=True,
+        use_output_norm=True, attn_use_output_gate=True,
+        torch_dtype="float32", published_layers=16,
+        sparse_config=dict(kernel_size=4, kernel_stride=2, block_size=8,
+                           topk=2, window_size=16, init_blocks=1,
+                           dense_len=64))
+
+    @functools.cached_property
+    def cfg(self):
+        from ray_tpu.models.minicpm_sala import TINY_SALA
+
+        return TINY_SALA
+
+    def make(self, cfg, seed=0):
+        """The benchmark's initialiser with the matrices' deviations scaled
+        from the published widths to these (by the root of the width each
+        matrix sums over): the mixers and the feed-forwards each write a
+        visible share of the stream, and the sparse layers' softmax is as
+        sharp as there."""
+        from benchmarks.families.minicpm_sala import WEIGHTS
+        from ray_tpu.models.minicpm_sala import init_params
+
+        over_d = (4096 / cfg.d_model) ** 0.5
+        # (both sparse layers' W_o at 0.025: the benchmark's (0.0025, 0.04)
+        # are for what bfloat16 pages and seven layers make of a block
+        # exchanged at a near-tie there; float32 against float32 exchanges
+        # none here, and these tests' streams were chosen at 0.025)
+        weights = dict(WEIGHTS, sparse_out_std=0.025)
+        return init_params(cfg, _cpu().random.PRNGKey(seed), **_scaled(
+            weights, dict(
+                dict.fromkeys(("in_std", "sparse_out_std",
+                               "lightning_out_std"), over_d),
+                ffn_out_std=(16384 / cfg.d_ff) ** 0.5,
+                # (the head reads the stream over d / dim_model_base: 16
+                # there, 4 here)
+                head_std=over_d * (cfg.d_model / cfg.dim_model_base) / 16)))
+
+
+SALA_ENGINE = dict(max_batch=3, max_len=160, page_size=8, decode_chunk=4)
+
 dense, sambay, granite_hybrid, lfm2_moe, mla_moe = \
     Dense(), SambaY(), GraniteHybrid(), Lfm2Moe(), MlaMoe()
 FAMILIES = {f.name: f for f in (dense, sambay, granite_hybrid, lfm2_moe,
                                 mla_moe)}
+minicpm_sala = MiniCpmSala()
