@@ -28,8 +28,11 @@ pages of one layer's K/V, read by every cross layer; a ring of the last
 `window` tokens' K/V for each window layer; and (conv window, scan
 state) for each Mamba layer.  Prefill does less than a forward pass,
 exactly: a cross-decoder layer at a prompt position feeds nothing but
-that position's own logits, so `prefill` runs the self-decoder over the
-prompt and the cross-decoder at each row's last token only.
+that position's own logits, and of the full-attention layer nothing but
+its K and V is read at another position.  So `prefill` runs layers
+0 .. L/2 and the full layer's K/V projection over the prompt, and the
+rest of the full layer and the cross-decoder at each row's last token
+only: one query a row.
 
 Differential attention (Ye et al. 2024) on ordinary attention kernels.
 Heads of 64 pair up, (2p, 2p+1) -> pair p; a pair's output is
@@ -195,9 +198,13 @@ class Linear(nn.Module):
     bias_init: Any = nn.initializers.zeros
 
     @nn.compact
-    def __call__(self, x, precise: bool = False):
+    def __call__(self, x, precise: bool = False, columns=None):
+        """`columns` (first, end): that slice of the kernel's columns alone
+        (a projection that is several side by side; no bias then)."""
         kernel = self.param("kernel", nn.initializers.lecun_normal(),
                             (x.shape[-1], self.features), self.dtype)
+        if columns is not None:
+            return matmul(x, kernel[:, columns[0]:columns[1]], precise)
         out = matmul(x, kernel, precise)
         if self.use_bias:
             out = out + self.param("bias", self.bias_init,
@@ -334,20 +341,45 @@ class DiffAttention(nn.Module):
         half, and for a self-attention layer K, V (B, Hkv/2, S, 2 Dh) in
         the type a cache holds them in."""
         c = self.cfg
-        B, S, _ = h.shape
-        Dh, Hq, Hkv = c.head_dim, c.n_heads, c.n_kv_heads
         if self.cross:
-            q, k, v = self.q_proj(h, precise), None, None
-        else:
-            q, k, v = jnp.split(self.qkv_proj(h, precise),
-                                [Hq * Dh, (Hq + Hkv) * Dh], axis=-1)
-            k, v = (a.astype(c.dtype).reshape(B, S, Hkv // 2, 2 * Dh)
-                    .transpose(0, 2, 1, 3) for a in (k, v))
+            return self._padded(self.q_proj(h, precise)), None, None
+        q, k, v = jnp.split(
+            self.qkv_proj(h, precise),
+            [c.n_heads * c.head_dim, (c.n_heads + c.n_kv_heads) * c.head_dim],
+            axis=-1)
+        return self._padded(q), self._pairs(k), self._pairs(v)
+
+    def queries(self, h, precise: bool = False):
+        """A self-attention layer's q' alone: the projection's first
+        columns."""
+        c = self.cfg
+        return self._padded(self.qkv_proj(
+            h, precise, columns=(0, c.n_heads * c.head_dim)))
+
+    def keys_values(self, h, precise: bool = False):
+        """... and its K, V alone: the rest."""
+        c = self.cfg
+        first = c.n_heads * c.head_dim
+        k, v = jnp.split(self.qkv_proj(
+            h, precise, columns=(first, first + 2 * c.n_kv_heads
+                                 * c.head_dim)), 2, axis=-1)
+        return self._pairs(k), self._pairs(v)
+
+    def _pairs(self, a):
+        c = self.cfg
+        return a.astype(c.dtype).reshape(
+            *a.shape[:2], c.n_kv_heads // 2, 2 * c.head_dim).transpose(
+                0, 2, 1, 3)
+
+    def _padded(self, q):
+        c = self.cfg
+        B, S, _ = q.shape
+        Dh, Hq = c.head_dim, c.n_heads
         q = q.reshape(B, S, Hq // 2, 2, Dh)
         zeros = jnp.zeros_like(q[:, :, :, 0])
         q = jnp.stack([jnp.concatenate([q[:, :, :, 0], zeros], -1),
                        jnp.concatenate([zeros, q[:, :, :, 1]], -1)], axis=3)
-        return q.reshape(B, S, Hq, 2 * Dh).transpose(0, 2, 1, 3), k, v
+        return q.reshape(B, S, Hq, 2 * Dh).transpose(0, 2, 1, 3)
 
     def combine(self, attn, precise: bool = False):
         """attn (B, Hq, S, 2 Dh), the two softmaxes of each pair applied
@@ -527,6 +559,13 @@ def _ring_slots(last_idx, window: int):
     return last_idx[:, None] - (last_idx[:, None] - r) % window
 
 
+def _up_to(last_idx, S: int):
+    """The mask of one query a row over S keys: those up to its row's
+    `last_idx`."""
+    return (jnp.arange(S)[None, :] <= last_idx[:, None])[
+        :, None, None, None, :]
+
+
 class SambaYModel(nn.Module):
     cfg: SambaYConfig
 
@@ -542,10 +581,15 @@ class SambaYModel(nn.Module):
     def _self_decoder(self, tokens, last_idx=None, precise: bool = False):
         """Layers 0 .. L/2+1 over (B, S) tokens -> x, the memory (B, S, E),
         the cache's K and V (B, Hkv/2, S, 2 Dh), and the per-sequence
-        state at `last_idx`: Mamba states and window rings, by layer."""
+        state at `last_idx`: Mamba states and window rings, by layer.
+        Given `last_idx` (a prompt), x and the memory are (B, 1, .), at
+        that position alone: of the full layer nothing but K and V is
+        read at another, so it attends, projects out and feeds forward
+        for ONE query a row, every product with two terms."""
         c = self.cfg
         B, S = tokens.shape
-        if last_idx is None:
+        whole = last_idx is None
+        if whole:
             last_idx = jnp.full((B,), S - 1, jnp.int32)
         x = self.embed(tokens).astype(jnp.float32)
         mamba, rings = [], []
@@ -559,6 +603,14 @@ class SambaYModel(nn.Module):
                 mamba.append(state)
                 continue
             attn = layer.attn
+            if kind == "full" and not whole:
+                cache = k, v = attn.keys_values(layer.input_norm(x), precise)
+                x, memory = (jnp.take_along_axis(
+                    a, last_idx[:, None, None], axis=1) for a in (x, memory))
+                x, = layer.mix(x, lambda h: (attn.combine(masked_attention(
+                    attn.queries(h, True), k, v, _up_to(last_idx, S),
+                    attn.sm_scale, True), True),), True)
+                continue
 
             def mixer(h):
                 q, k, v = attn.project(h, precise)
@@ -619,16 +671,12 @@ class SambaYModel(nn.Module):
         `last_idx` -> float32 logits (B, V) at that token, and the state
         a decode continues from: {"mamba": [(conv, scan)], "rings":
         [(k, v)] (B, Hkv/2, window, 2 Dh), "cache": (k, v) over the whole
-        row}.  The cross-decoder runs at the last token only."""
+        row}.  The full layer but for its K and V, and the cross-decoder,
+        run at the last token only: no `flash_attention` call here."""
         x, memory, (k, v), state = self._self_decoder(tokens, last_idx)
-        take = lambda a: jnp.take_along_axis(  # noqa: E731
-            a, last_idx[:, None, None], axis=1)
-        S = tokens.shape[1]
-        mask = (jnp.arange(S)[None, :] <= last_idx[:, None])[
-            :, None, None, None, :]
-        # one token a row from here on: every product with two terms
-        logits = self._cross_decoder(take(x), take(memory), k, v, mask,
-                                     True)
+        # one token a row: every product with two terms
+        logits = self._cross_decoder(
+            x, memory, k, v, _up_to(last_idx, tokens.shape[1]), True)
         return logits[:, 0], dict(state, cache=(k, v))
 
     # ---- one token a sequence ---------------------------------------------

@@ -665,16 +665,19 @@ def time_expert_ffn(families):
 
 
 # family -> (configuration file, rows x bucket of the programs timed).
-# The next family that takes `prompt_blocks` (sambay, granite_hybrid) is
+# The next family that takes `prompt_blocks` (granite_hybrid, lfm2_moe) is
 # sized here: add its line.
 PREFILL_FAMILIES = {
     "dense_decoder": ("mistral-7b-v0.3-l16-b4.json",
                       ((1, 1024), (1, 2048), (4, 2048))),
     "mla_moe": ("kimi-vl-a3b-l7.json", ((1, 2048), (1, 4096), (1, 8192))),
+    "sambay": ("phi-4-mini-flash-reasoning.json",
+               ((1, 2048), (1, 4096), (1, 8192), (1, 16384), (2, 8192))),
 }
 PREFILL_BLOCKS = (128, 256, 512, 1024)      # positions of a block
 # the flash kernel's (block_q, block_k), tried at PREFILL_AT's block
 PREFILL_KERNEL_BLOCKS = ((512, 1024), (512, 512))
+# (a family with no entry runs every bucket whole: `sambay`, timed as it is)
 PREFILL_AT = {"dense_decoder": 512, "mla_moe": 256}
 PREFILL_FILLS = (0.6, 0.75, 1.0)
 PREFILL_REPS = 3
@@ -709,7 +712,7 @@ def sweep_prefill(families):
     kernel = attention.causal_over_itself
 
     def variants(family):
-        if prompt_blocks is None:
+        if prompt_blocks is None or family not in PREFILL_AT:
             return [(None, None)]
         return [(None, None)] + [(b, None) for b in PREFILL_BLOCKS] + \
             [(PREFILL_AT[family], kb) for kb in PREFILL_KERNEL_BLOCKS]

@@ -22,8 +22,12 @@ def test_sambay_kernels_at_the_cells_shapes(one_chip):
     """Heads of 64 run as pairs: 40 zero-padded query heads and 10 KV
     heads of 128, scale 1/8.  The paged kernel over the one shared pool
     (4,737 pages of 64, a table of ceil((17472 + 8) / 64) = 274 columns,
-    float32 queries and rows) and the flash kernel over a 16,384-token
-    prompt."""
+    float32 queries and rows), and the flash kernel over 16,384 tokens as
+    the WHOLE forward (`SambaYModel.__call__`, every layer at every
+    position) calls it for the full-attention layer.  No program of the
+    cell's engine calls it since PR 51: a prefill attends ONE query a row
+    over the full layer's K and V
+    (`test_sambay_prefills_call_no_kernel_and_fit_beside_the_state`)."""
     for writes in (True, False):    # the full layer's call, a cross layer's
         compiled = paged_call(one_chip, 32, 40, 10, 4737, 274, jnp.float32,
                               writes, sm_scale=0.125)
@@ -74,14 +78,49 @@ def test_sambay_decode_chunk_leaves_the_pool_where_it_lies(one_chip):
         eng.shutdown()
 
 
+# What PR 50's programs (the flash kernel over the full layer, compiled here
+# the same way) held with the engine's state resident: 13.99 GB at
+# 1 x 16,384, 13.49 at 2 x 8,192.  With the full layer at one query a row
+# (PR 51): 13.994 and 13.488, unchanged.  The limits leave the readings
+# 0.06 GB.
+SAMBAY_PREFILL_BYTES = {(1, 16384): 14.05e9, (2, 8192): 13.55e9}
+
+
+@pytest.mark.time_limit(400)
+def test_sambay_prefills_call_no_kernel_and_fit_beside_the_state(one_chip):
+    """The cell's two largest prefill programs at published widths: layers
+    0-16 over the whole bucket, the full layer but for its K and V and the
+    cross-decoder at one token a row.  No Pallas call is left in them (the
+    flash kernel's was the only one), and beside the engine's resident
+    state they hold what PR 50's did (the scan and the window layers'
+    temporaries set the peak, not the full layer)."""
+    from ray_tpu.models.sambay import PHI4_MINI_FLASH
+
+    eng, params = _sambay_engine(PHI4_MINI_FLASH)
+    try:
+        held = {}
+        for W, bucket in SAMBAY_PREFILL_BYTES:
+            assert eng.family.prefill_width(bucket, eng.max_batch) == W
+            lowered, prefill = compiled_prefill(eng, params, one_chip, W,
+                                                bucket)
+            assert KERNEL not in prefill.as_text()
+            assert "flash" not in lowered.as_text()
+            # (the state is not an argument of the prefill: it is resident)
+            held[W, bucket] = peak_bytes(prefill) + gb(eng._pools) * 1e9
+        assert all(held[k] < limit < HBM_BYTES
+                   for k, limit in SAMBAY_PREFILL_BYTES.items()), held
+    finally:
+        eng.shutdown()
+
+
 @pytest.mark.slow     # 45 s of a many-threaded compile: by hand, not in tier-1
 @pytest.mark.time_limit(600)
 def test_sambay_engine_programs_fit_the_chip(one_chip):
     """The cell's decode chunk (eight paged calls a step, rings and
     recurrent state carried through the scan, a count of steps a slot)
-    and its largest prefill (one row of 16,384 tokens: the scan, eight
-    windowed layers in blocks, flash over the full layer) at published
-    widths, each beside the weights and the engine's whole state."""
+    at published widths beside the weights and the engine's whole state
+    (its prefills:
+    `test_sambay_prefills_call_no_kernel_and_fit_beside_the_state`)."""
     from ray_tpu.models.sambay import PHI4_MINI_FLASH
 
     eng, params = _sambay_engine(PHI4_MINI_FLASH)
@@ -90,11 +129,6 @@ def test_sambay_engine_programs_fit_the_chip(one_chip):
         assert decode.as_text().count(KERNEL) == 8
         _assert_the_pool_stays(decode.as_text(), eng)
         assert peak_bytes(decode) < HBM_BYTES
-        assert eng.family.prefill_width(16384, eng.max_batch) == 1
-        _, prefill = compiled_prefill(eng, params, one_chip, 1, 16384)
-        assert KERNEL in prefill.as_text()
-        # (the state is not an argument of the prefill: it is resident)
-        assert peak_bytes(prefill) + gb(eng._pools) * 1e9 < HBM_BYTES
     finally:
         eng.shutdown()
 

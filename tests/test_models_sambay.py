@@ -172,3 +172,112 @@ def test_two_term_products_do_not_round_the_activation():
     off = [np.abs(np.asarray(masked_attention(q, k, v, seen, 0.2, p))
                   - want).max() for p in (False, True)]
     assert off[1] < 0.02 * off[0]
+
+
+# ---- a prompt's full layer at one query a row (`SambaYModel.prefill`) -----
+
+# lengths of the rows of one bucket of 64 (the window is 8)
+ONE_QUERY = {
+    "shorter-than-a-window": [5], "one-token": [1],
+    "a-windows-last-position": [16], "a-windows-first-position": [17],
+    "mid-window": [29], "fills-its-bucket": [64],
+    "rows-that-end-apart": [40, 9, 1], "one-fills-one-ends-on-an-edge":
+    [64, 32], "neighbours-across-an-edge": [17, 16],
+}
+
+
+@pytest.fixture(scope="module")
+def passes(tiny):
+    """The tiny model's self-decoder given `last_idx` and over whole rows,
+    and its whole forward, each jitted once a shape."""
+    import jax
+
+    _, model, params = tiny
+    self_decoder = type(model)._self_decoder
+    return (jax.jit(lambda t, last: model.apply(params, t, last,
+                                                method=self_decoder)),
+            jax.jit(lambda t: model.apply(params, t, method=self_decoder)),
+            jax.jit(lambda t: model.apply(params, t)))
+
+
+@pytest.mark.parametrize("case", ONE_QUERY)
+def test_a_prompts_full_layer_runs_for_one_query_a_row(tiny, passes, case):
+    """Given `last_idx`, the self-decoder hands back the stream and the
+    memory at that position alone, and they are what the whole rows' pass
+    (the full layer attending, projecting out and feeding forward at every
+    position) holds there; K and V of every position are that pass's; and
+    a prefill's logits are the whole forward's at each row's last token
+    (what lies right of it in the bucket is read by nothing)."""
+    import jax.numpy as jnp
+
+    cfg, model, params = tiny
+    at_last, over_rows, forward = passes
+    lengths = ONE_QUERY[case]
+    B, S = len(lengths), 64
+    rows = family.tokens(sum(lengths), (B, S))
+    tokens = jnp.asarray(np.where(np.arange(S)[None] < np.asarray(
+        lengths)[:, None], rows, 0), jnp.int32)
+    last = np.asarray(lengths) - 1
+    x, memory, cache, _ = at_last(tokens, jnp.asarray(last, jnp.int32))
+    wx, wmemory, wcache, _ = over_rows(tokens)
+    assert x.shape == (B, 1, cfg.d_model) and wx.shape == (B, S, cfg.d_model)
+    assert memory.shape == (B, 1, cfg.d_inner)
+    np.testing.assert_allclose(x[:, 0], np.asarray(wx)[np.arange(B), last],
+                               atol=TOL, rtol=0)
+    np.testing.assert_allclose(memory[:, 0],
+                               np.asarray(wmemory)[np.arange(B), last],
+                               atol=TOL, rtol=0)
+    for a, b in zip(cache, wcache):
+        assert a.shape == (B, cfg.kv_pairs, S, 2 * cfg.head_dim)
+        np.testing.assert_allclose(a, b, atol=1e-6, rtol=0)
+    logits, state = family.prefill(
+        model, params, [row[:n] for row, n in zip(rows, lengths)], S)
+    np.testing.assert_allclose(
+        logits, np.asarray(forward(tokens))[np.arange(B), last], atol=TOL,
+        rtol=0)
+    for a, b in zip(state["cache"], wcache):
+        np.testing.assert_allclose(a, b, atol=1e-6, rtol=0)
+
+
+def test_a_prefill_calls_no_attention_kernel_and_feeds_forward_once_a_row():
+    """With the flash kernel configured, the whole forward calls it (the
+    full layer over every position) and a prefill does not; and of the
+    prefill's feed-forwards over (rows, bucket) one is gone for each layer
+    that runs at the last token: the full layer's and the cross-decoder's."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    cfg = dataclasses.replace(family.cfg, attention="flash", d_ff=136)
+    model = family.model(cfg)
+    tokens = jnp.zeros((2, 64), jnp.int32)
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0),
+                                               tokens[:, :8]))
+
+    def shapes(f, *args):
+        found = []
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                found.append((eqn.primitive.name,
+                              tuple(v.aval.shape for v in eqn.outvars)))
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+        walk(jax.make_jaxpr(f)(params, *args).jaxpr)
+        return found
+
+    forward = shapes(lambda p, t: model.apply(p, t), tokens)
+    prefill = shapes(lambda p, t, last: model.apply(
+        p, t, last, method=type(model).prefill), tokens,
+        jnp.asarray([40, 9], jnp.int32))
+    assert any(name == "pallas_call" for name, _ in forward)
+    assert not any(name == "pallas_call" for name, _ in prefill)
+    # (an MLP's gate and up products are the two a layer that come out d_ff
+    # wide, which no other width of this model is)
+    wide = lambda found, rows: sum(  # noqa: E731
+        name == "dot_general" and out[0] == (2, rows, cfg.d_ff)
+        for name, out in found)
+    assert wide(forward, 64) == 2 * cfg.n_layers
+    assert wide(prefill, 64) == 2 * (cfg.n_self - 1)
+    assert wide(prefill, 1) == 2 * (cfg.n_layers - cfg.n_self + 1)

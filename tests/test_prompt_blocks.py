@@ -300,7 +300,9 @@ def test_the_prefill_sweep_times_programs_the_cells_engines_run():
     """`scripts/tpu_kernel_sweep.py --prefill` takes its widths from the
     cells' configuration files, and each (rows, bucket) it times is a
     program that cell's engine runs: one row, or the width the family gives
-    the bucket; the blocks it tries bracket the rule's."""
+    the bucket; the blocks it tries bracket the rule's (`sambay`, which
+    takes no block, is timed whole at the buckets its cell's long prompts
+    fall in)."""
     import importlib
     import json
     import os
@@ -314,7 +316,7 @@ def test_the_prefill_sweep_times_programs_the_cells_engines_run():
         sweep = importlib.import_module("tpu_kernel_sweep")
     finally:
         sys.path.remove(os.path.join(repo, "scripts"))
-    assert set(sweep.PREFILL_AT) == set(sweep.PREFILL_FAMILIES)
+    assert set(sweep.PREFILL_FAMILIES) - set(sweep.PREFILL_AT) == {"sambay"}
     for family, (name, cases) in sweep.PREFILL_FAMILIES.items():
         with open(os.path.join(repo, "benchmarks", "configs", name)) as f:
             conf = json.load(f)
@@ -327,10 +329,14 @@ def test_the_prefill_sweep_times_programs_the_cells_engines_run():
             assert bucket <= engine["max_len"] and bucket & (bucket - 1) == 0
             assert rows in (1, serving.prefill_width(bucket,
                                                      engine["max_batch"]))
+        if family not in sweep.PREFILL_AT:
+            assert not hasattr(serving, "prefill_computed")
+            continue
         block = serving.prefill_computed(1 << 20, [1])  # (a row's first)
         assert min(sweep.PREFILL_BLOCKS) <= block <= max(sweep.PREFILL_BLOCKS)
         assert sweep.PREFILL_AT[family] in sweep.PREFILL_BLOCKS
     assert 1.0 in sweep.PREFILL_FILLS
+    assert (1, 16384) in sweep.PREFILL_FAMILIES["sambay"][1]
 
 
 def _planted_in_a_prefill():

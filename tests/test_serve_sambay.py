@@ -137,3 +137,30 @@ def test_the_family_sizes_the_batched_prefill_by_bucket(tiny):
     # (36, the pages of max_len, while the prefill held a dense cache)
     assert [llama.prompt_pages(b, 64) for b in (64, 2048, 2304)] == \
         [1, 32, 36]
+
+
+def test_an_engines_prefill_computes_every_buckets_positions(tiny):
+    """The family's prefill runs layers 0 .. L/2 over every position of a
+    bucket and says nothing of what it computes, so `computed` on
+    `engine.prefill` is every row's whole bucket; and the streams decoded
+    from what those prefills wrote (the full layer at ONE query a row) are
+    the reference's greedy tokens."""
+    from ray_tpu.models.generate import SamplingParams
+    from ray_tpu.serve.llm import LLMEngine
+    from ray_tpu.util import tracing
+
+    cfg, params = tiny
+    eng = LLMEngine(cfg, params, **ENGINE)
+    assert not hasattr(eng.family, "prefill_computed")
+    try:
+        seen = {s["id"] for s in tracing.recent_spans()}
+        for prompt in _prompts(3, (40, 10)):
+            out = eng.submit(prompt, SamplingParams(
+                max_new_tokens=20)).tokens()
+            assert len(out) == 20 and family.is_greedy(prompt, out)
+    finally:
+        eng.shutdown()
+    got = [(s["attrs"]["bucket"], s["attrs"]["rows"], s["attrs"]["computed"])
+           for s in tracing.recent_spans()
+           if s["name"] == "engine.prefill" and s["id"] not in seen]
+    assert got == [(64, 1, 64), (16, 1, 16)]
