@@ -4,7 +4,10 @@ The reference ships no model implementations (its release gates pull
 GPT-J/vicuna through external torch engines); here the flagship decoder,
 an expert-parallel MoE, and the generation path are part of the framework.
 `serve.LLMEngine` serves six of them (`serve/llm_families.py`):
-`LlamaConfig`, `SambaYConfig`, `GraniteHybridConfig`, `Lfm2MoeConfig`
+`LlamaConfig`, `SambaYConfig`, `GraniteHybridConfig` (its dense members,
+and with `n_experts` its routed ones: a shared feed-forward beside
+`Lfm2MoeConfig`'s routed layer under Granite's own router, a chip holding
+all of a layer's experts or a stated share of them), `Lfm2MoeConfig`
 (routed experts with no token dropped and a cached decode path; `moe.py`'s
 capacity-bounded layer trains at toy sizes and is not served),
 `MlaMoeConfig` (latent attention over a latent paged cache, the same
@@ -74,6 +77,7 @@ from ray_tpu.models.generate import Generator, SamplingParams, generate
 from ray_tpu.models.granite_hybrid import (
     GRANITE_4_H_MICRO,
     TINY_GRANITE,
+    TINY_GRANITE_MOE,
     GraniteHybridConfig,
     GraniteHybridModel,
 )
@@ -121,6 +125,7 @@ __all__ = [
     "SambaYModel", "SambaYConfig", "PHI4_MINI_FLASH", "TINY_SAMBAY",
     "GraniteHybridModel", "GraniteHybridConfig", "GRANITE_4_H_MICRO",
     "TINY_GRANITE",
+    "TINY_GRANITE_MOE",
     "Lfm2MoeModel", "Lfm2MoeConfig", "LFM2_24B_A2B", "TINY_LFM2_MOE",
     "MlaMoeModel", "MlaMoeConfig", "KIMI_VL_A3B", "TINY_MLA_MOE",
     "MiniCpmSalaModel", "MiniCpmSalaConfig", "MINICPM_SALA_L8", "TINY_SALA",

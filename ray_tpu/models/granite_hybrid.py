@@ -1,18 +1,29 @@
-"""Granite-4.0-H decoder (`granitemoehybrid` with no routed experts),
-TPU-first: Mamba-2 layers with a few plain grouped-query attention layers
-among them, every layer followed by a gated feed-forward.
+"""Granite-4.0-H decoder (`granitemoehybrid`), TPU-first: Mamba-2 layers
+with a few plain grouped-query attention layers among them, every layer
+followed by a gated feed-forward: the shared one alone (the family's dense
+members, `n_experts` 0) or routed experts beside it.
 
     h = embedding_multiplier * E[token]
     for each layer, by `layer_types`:
         h += residual_multiplier * Mixer(RMSNorm(h))    Mamba-2 | attention
-        h += residual_multiplier * MLP(RMSNorm(h))
+        h += residual_multiplier * FF(RMSNorm(h))       shared [+ routed]
     logits = RMSNorm(h) E^T / logits_scaling            the embedding tied
 
+Routed feed-forward (`route`, then `models/lfm2_moe.RoutedExperts`): plain
+router logits `W_r u` (no bias, no sigmoid), the `top_k` largest, and the
+gates a softmax over THOSE logits alone; `FF = shared(u) + sum_{e chosen}
+g_e W2_e (silu(a_e) * b_e)`, no capacity and no scaling factor.  Where a
+layer's experts are divided over chips (`experts_held` = (first, count)),
+this chip routes over all `n_experts`, computes the pairs whose expert it
+holds and adds ITS PART of the routed sum; the other chips' parts are an
+exchange this file does not have (`PERF.md` section 7).
+
 Attention: no position term, causal, scores scaled by `attention_multiplier`
-(1/64 published, not 1/sqrt(head size)).  Heads of 64 run on the kernels'
-heads of 128 with no padding in the cache: KV heads (2j, 2j+1) lie as
-ONE head of 128, K' = [k_2j | k_2j+1] and V' likewise, and a query head is
-padded with zeros on the half it does not use, [q | 0] or [0 | q].  Then
+(1/64 or 1/128 published, not 1/sqrt(head size)).  Heads of 128 are the
+kernels' own and run as they are.  Narrower heads (64 published) run on the
+kernels' heads of 128 with no padding in the cache: KV heads (2j, 2j+1) lie
+as ONE head of 128, K' = [k_2j | k_2j+1] and V' likewise, and a query head
+is padded with zeros on the half it does not use, [q | 0] or [0 | q].  Then
 q' . K' = q . k exactly, every query head still has a softmax of its own,
 and its output is the half of p V' = [p v_2j | p v_2j+1] that belongs to
 its KV head.  (`models/sambay.py` pairs differential heads the same way;
@@ -48,7 +59,8 @@ every decode step after (PR 36; `PERF.md` section 6).  The flash kernel
 takes q, k and v in `dtype`.
 
 Every part runs under a `jax.named_scope` (`ssd_scan`, `state_step`,
-`attention`, `mlp`, `head`), so a profile's operation names carry them.
+`attention`, `mlp` or, beside routed experts, `shared_expert`, `route`,
+`experts`, `head`), so a profile's operation names carry them.
 """
 
 from __future__ import annotations
@@ -61,6 +73,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.lfm2_moe import RoutedExperts, add_counts
 from ray_tpu.models.llama import apply_rope, rope_frequencies
 from ray_tpu.models.sambay import Linear, causal_attention, matmul
 
@@ -74,7 +87,14 @@ class GraniteHybridConfig:
     layer_types: tuple = _PERIOD * 4
     n_heads: int = 32
     n_kv_heads: int = 8
-    d_ff: int = 8192
+    d_ff: int = 8192               # the shared feed-forward
+    # Routed experts beside it: the router's width (0: none), the experts a
+    # token chooses, one expert's width, and (first, count): the experts
+    # THIS chip holds where a layer's are divided over chips (None: all).
+    n_experts: int = 0
+    top_k: int = 0
+    d_expert: int = 0
+    experts_held: tuple | None = None
     mamba_heads: int = 64
     mamba_head_dim: int = 64
     d_state: int = 128
@@ -102,6 +122,25 @@ class GraniteHybridConfig:
         return self.d_model // self.n_heads
 
     @property
+    def paired(self) -> bool:
+        """Whether two KV heads lie in one head of the kernels' width (the
+        module's head): where the heads are narrower than 128."""
+        return self.head_dim < 128
+
+    @property
+    def kv_pool_heads(self) -> tuple:
+        """(heads, width) of the K and V a cache holds for one layer."""
+        return (self.n_kv_heads // 2, 2 * self.head_dim) if self.paired \
+            else (self.n_kv_heads, self.head_dim)
+
+    @property
+    def experts_here(self) -> int:
+        return self.experts_held[1] if self.experts_held else self.n_experts
+
+    # (`RoutedExperts` reads it: this family's routed sum is not scaled)
+    routed_scaling = 1.0
+
+    @property
     def d_inner(self) -> int:
         return self.mamba_heads * self.mamba_head_dim
 
@@ -121,6 +160,12 @@ TINY_GRANITE = GraniteHybridConfig(
     n_heads=4, n_kv_heads=2, d_ff=128, mamba_heads=4, mamba_head_dim=32,
     d_state=16, chunk=8, max_positions=256, dtype=jnp.float32,
     attention="reference")
+# The routed member at tiny widths: 8 experts of 32, three a token, beside
+# a shared one of 64; 4 query and 2 KV heads of 128 as they are (nothing
+# paired), so a stream of 512.
+TINY_GRANITE_MOE = dataclasses.replace(
+    TINY_GRANITE, d_model=512, d_ff=64, n_experts=8, top_k=3, d_expert=32,
+    logits_scaling=16.0)
 
 
 class RMSNorm(nn.Module):
@@ -135,8 +180,17 @@ class RMSNorm(nn.Module):
             jnp.mean(xf * xf, axis=-1, keepdims=True) + self.eps) * scale
 
 
+def route(logits, top_k: int):
+    """Router logits (T, E) float32 -> the chosen experts (T, k) and their
+    gates (T, k), float32: the k largest LOGITS, and a softmax over those
+    k alone (not over all E: the unchosen take no share of it)."""
+    top, idx = jax.lax.top_k(logits.astype(jnp.float32), top_k)
+    return idx, jax.nn.softmax(top, axis=-1)
+
+
 class MLP(nn.Module):
-    """`W_out (silu(a) * b)`, `(a, b) = split(W_in x)`."""
+    """`W_out (silu(a) * b)`, `(a, b) = split(W_in x)`: the layer's whole
+    feed-forward, or the shared expert beside the routed ones."""
     cfg: GraniteHybridConfig
 
     def setup(self):
@@ -145,13 +199,15 @@ class MLP(nn.Module):
         self.out_proj = Linear(c.d_model, c.dtype)
 
     def __call__(self, x):
-        with jax.named_scope("mlp"):
+        with jax.named_scope("shared_expert" if self.cfg.n_experts
+                             else "mlp"):
             a, b = jnp.split(self.in_proj(x, precise=True), 2, axis=-1)
             return self.out_proj(nn.silu(a) * b, precise=True)
 
 
 # ---------------------------------------------------------------------------
-# Attention: heads of 64 as halves of heads of 128 (see the module's head)
+# Attention: heads of 128 as they are, heads of 64 as halves of heads of 128
+# (see the module's head)
 # ---------------------------------------------------------------------------
 
 
@@ -160,7 +216,7 @@ class Attention(nn.Module):
 
     def setup(self):
         c = self.cfg
-        if c.n_kv_heads % 2 or c.n_heads % c.n_kv_heads:
+        if (c.paired and c.n_kv_heads % 2) or c.n_heads % c.n_kv_heads:
             raise ValueError("KV heads pair up, and whole groups of query "
                              "heads share a KV head")
         self.qkv_proj = Linear((c.n_heads + 2 * c.n_kv_heads) * c.head_dim,
@@ -170,7 +226,8 @@ class Attention(nn.Module):
     def project(self, h, positions):
         """h (B, S, d) -> q' (B, Hq, S, 2 Dh) zero-padded on the half it
         does not use, K' and V' (B, Hkv/2, S, 2 Dh) in the type a cache
-        holds them in.  `positions` (B, S) are read only where the
+        holds them in; where nothing is paired q (B, Hq, S, Dh), K and V
+        (B, Hkv, S, Dh).  `positions` (B, S) are read only where the
         configuration rotates."""
         c = self.cfg
         B, S, _ = h.shape
@@ -185,6 +242,10 @@ class Attention(nn.Module):
                 a.transpose(0, 2, 1, 3).astype(jnp.float32), cos, sin,
                 positions).transpose(0, 2, 1, 3)
             q, k = rot(q), rot(k)
+        if not c.paired:
+            k, v = (a.astype(c.dtype).reshape(B, S, Hkv, Dh)
+                    .transpose(0, 2, 1, 3) for a in (k, v))
+            return q.transpose(0, 2, 1, 3), k, v
         k, v = (a.astype(c.dtype).reshape(B, S, Hkv // 2, 2 * Dh)
                 .transpose(0, 2, 1, 3) for a in (k, v))
         # query heads of KV head 2j use the left half, of 2j + 1 the right
@@ -196,10 +257,14 @@ class Attention(nn.Module):
 
     def combine(self, attn):
         """attn (B, Hq, S, 2 Dh), each query head's softmax applied to
-        [v_2j | v_2j+1] -> its own half -> the layer's output (B, S, d)."""
+        [v_2j | v_2j+1] -> its own half -> the layer's output (B, S, d);
+        (B, Hq, S, Dh) where nothing is paired."""
         c = self.cfg
         B, Hq, S, D2 = attn.shape
         Dh, G = c.head_dim, c.n_heads // c.n_kv_heads
+        if not c.paired:
+            return self.o_proj(attn.transpose(0, 2, 1, 3).reshape(
+                B, S, c.d_model), precise=True)
         a = attn.reshape(B, c.n_kv_heads // 2, 2, G, S, 2, Dh)
         o = jnp.stack([a[:, :, 0, :, :, 0], a[:, :, 1, :, :, 1]], axis=2)
         o = o.reshape(B, Hq, S, Dh).transpose(0, 2, 1, 3)
@@ -403,6 +468,29 @@ def _a_log_init(key, shape, dtype=jnp.float32):
 # ---------------------------------------------------------------------------
 
 
+# What a routed layer counts of one call: `lfm2_moe.EXPERT_COUNTS` over the
+# experts HELD here (touched, held, the most rows one took, the (row,
+# expert) pairs that lay in a held group), then the pairs the router made.
+EXPERT_COUNTS = ("experts_touched", "expert_slots", "expert_rows_max",
+                 "expert_rows", "expert_pairs")
+
+
+def expert_counts_of(held, u, valid, top_k: int):
+    """`EXPERT_COUNTS` of one layer's call over rows u: what
+    `RoutedExperts` counted of the experts held, and `top_k` pairs for
+    every row that is `valid` (None: every row)."""
+    rows = math.prod(u.shape[:-1]) if valid is None else jnp.sum(valid)
+    return jnp.concatenate(
+        [held, (jnp.int32(top_k) * rows).astype(jnp.int32)[None]])
+
+
+def _sum_counts(a, b):
+    """Two layers' counts as one (None: no routed layer there)."""
+    if a is None or b is None:
+        return b if a is None else a
+    return jnp.concatenate([add_counts(a[:4], b[:4]), a[4:] + b[4:]])
+
+
 class Layer(nn.Module):
     """One decoder block; its mixer is what `layer_types` says."""
     cfg: GraniteHybridConfig
@@ -413,6 +501,9 @@ class Layer(nn.Module):
         self.input_norm = RMSNorm(c.norm_eps)
         self.post_norm = RMSNorm(c.norm_eps)
         self.mlp = MLP(c)
+        if c.n_experts:
+            self.experts = RoutedExperts(c, choose=route,
+                                         held=c.experts_held)
         if self.kind == "mamba":
             self.mamba = Mamba2(c)
         elif self.kind == "attention":
@@ -421,13 +512,20 @@ class Layer(nn.Module):
             raise ValueError(f"layer_types holds {self.kind!r}: a layer is "
                              "'mamba' or 'attention'")
 
-    def mix(self, x, mixer):
-        """x += r * mixer(norm(x)); x += r * MLP(norm(x)), the stream in
-        float32; whatever else the mixer returns is handed back beside x."""
+    def mix(self, x, mixer, valid=None):
+        """x += r * mixer(norm(x)); x += r * FF(norm(x)), the stream in
+        float32; whatever else the mixer returns is handed back beside x
+        and, last, a routed layer's counts (`expert_counts_of`; rows that
+        are not `valid` go to no expert; None where there is none)."""
         r = self.cfg.residual_multiplier
         out, *rest = mixer(self.input_norm(x))
         x = x + r * out
-        return (x + r * self.mlp(self.post_norm(x)), *rest)
+        if not self.cfg.n_experts:
+            return (x + r * self.mlp(self.post_norm(x)), *rest, None)
+        u = self.post_norm(x)
+        routed, counts = self.experts(u, valid)
+        return (x + r * (routed + self.mlp(u)), *rest,
+                expert_counts_of(counts, u, valid, self.cfg.top_k))
 
 
 class GraniteHybridModel(nn.Module):
@@ -450,17 +548,23 @@ class GraniteHybridModel(nn.Module):
                 / self.cfg.logits_scaling
 
     def _rows(self, tokens, last_idx=None):
-        """Every layer over (B, S) tokens -> the stream x (B, S, d) and
-        the per-sequence state at `last_idx`, by kind of layer."""
+        """Every layer over (B, S) tokens -> the stream x (B, S, d), the
+        per-sequence state at `last_idx`, by kind of layer, and the routed
+        layers' counts (None where there are none).  Positions past
+        `last_idx` are given to no expert."""
         c = self.cfg
         B, S = tokens.shape
         positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+        valid = None if last_idx is None or not c.n_experts \
+            else positions <= last_idx[:, None]
         x = self._embed(tokens)
-        ssm, kv = [], []
+        ssm, kv, counts = [], [], None
         for layer in self.layers:
             if layer.kind == "mamba":
-                x, state = layer.mix(x, lambda h: layer.mamba(h, last_idx))
+                x, state, mine = layer.mix(
+                    x, lambda h: layer.mamba(h, last_idx), valid)
                 ssm.append(state)
+                counts = _sum_counts(counts, mine)
                 continue
             attn = layer.attn
 
@@ -471,13 +575,14 @@ class GraniteHybridModel(nn.Module):
                                          c.attention)
                     return attn.combine(o), (k, v)
 
-            x, cache = layer.mix(x, mixer)
+            x, cache, mine = layer.mix(x, mixer, valid)
             kv.append(cache)
-        return x, {"ssm": ssm, "kv": kv}
+            counts = _sum_counts(counts, mine)
+        return x, {"ssm": ssm, "kv": kv}, counts
 
     def __call__(self, tokens):
         """Whole forward: (B, S) -> float32 logits (B, S, V)."""
-        x, _ = self._rows(tokens)
+        x, _, _ = self._rows(tokens)
         return self._head(x)
 
     def prefill(self, tokens, last_idx):
@@ -485,25 +590,30 @@ class GraniteHybridModel(nn.Module):
         `last_idx` -> float32 logits (B, V) at that token, and the state
         a decode continues from: {"ssm": [(conv window, S)] a Mamba
         layer, AT the row's last token; "kv": [(k, v)] an attention
-        layer, (B, Hkv/2, S, 2 Dh) over the whole row}."""
-        x, state = self._rows(tokens, last_idx)
+        layer, (B, Hkv/2, S, 2 Dh) over the whole row, or (B, Hkv, S, Dh)
+        where nothing is paired}; with routed experts also their counts
+        (`EXPERT_COUNTS`) over the rows' real tokens."""
+        x, state, counts = self._rows(tokens, last_idx)
         last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
-        return self._head(last), state
+        return (self._head(last), state) if counts is None \
+            else (self._head(last), state, counts)
 
     def decode(self, token, pos, state, table, length, live=None):
         """One token a sequence: token (B,), `length` (B,) tokens already
         cached, `pos` (B,) their positions (read only where the
         configuration rotates) -> float32 logits (B, V) and the state
-        with this token in it.  state: {"ssm"} as `prefill` gives it
-        (batch first) and "pools": [(k_pool, v_pool)] an attention layer,
-        (P, Hkv/2, page, 2 Dh) under `table` (B, NP).  A row where `live`
-        is False keeps its Mamba state (its pool writes land where its
-        next live step writes again)."""
+        with this token in it (and, with routed experts, the step's
+        counts).  state: {"ssm"} as `prefill` gives it (batch first) and
+        "pools": [(k_pool, v_pool)] an attention layer, (P, Hkv/2, page,
+        2 Dh) or (P, Hkv, page, Dh), under `table` (B, NP).  A row where
+        `live` is False keeps its Mamba state and is given to no expert
+        (its pool writes land where its next live step writes again)."""
         from ray_tpu.ops.paged_attention import paged_decode_attention_batch
 
         c = self.cfg
         x = self._embed(token)[:, None]                     # (B, 1, d)
-        ssm, pools = [], []
+        ssm, pools, counts = [], [], None
+        valid = None if live is None or not c.n_experts else live[:, None]
 
         def lift(f):        # a mixer over (B, d) as one over (B, 1, d)
             return lambda h: tuple(
@@ -513,9 +623,10 @@ class GraniteHybridModel(nn.Module):
         for layer in self.layers:
             if layer.kind == "mamba":
                 prev = state["ssm"][len(ssm)]
-                x, new = layer.mix(x, lift(
-                    lambda h: layer.mamba.step(h, prev, live)))
+                x, new, mine = layer.mix(x, lift(
+                    lambda h: layer.mamba.step(h, prev, live)), valid)
                 ssm.append(new)
+                counts = _sum_counts(counts, mine)
                 continue
             attn = layer.attn
             k_pool, v_pool = state["pools"][len(pools)]
@@ -532,9 +643,12 @@ class GraniteHybridModel(nn.Module):
                         v_new=v[:, :, 0], sm_scale=c.attention_multiplier)
                     return attn.combine(o[:, :, None]), (kp, vp)
 
-            x, pool = layer.mix(x, mixer)
+            x, pool, mine = layer.mix(x, mixer, valid)
             pools.append(pool)
-        return self._head(x[:, 0]), {"ssm": ssm, "pools": pools}
+            counts = _sum_counts(counts, mine)
+        new = {"ssm": ssm, "pools": pools}
+        return (self._head(x[:, 0]), new) if counts is None \
+            else (self._head(x[:, 0]), new, counts)
 
 
 # ---------------------------------------------------------------------------
@@ -544,11 +658,16 @@ class GraniteHybridModel(nn.Module):
 def init_params(cfg: GraniteHybridConfig, key, *, embed_std: float = 0.02,
                 in_std: float = 0.02, qkv_std: float = 0.02,
                 out_std: float = 0.02, final_norm: float = 1.0,
-                step_size: tuple | None = None, decay: tuple | None = None):
+                step_size: tuple | None = None, decay: tuple | None = None,
+                router_std: float = 0.02, expert_out_std: float = 0.02,
+                ffn_out_std: float | None = None):
     """Seeded random weights: the embedding normal(0, `embed_std`); the
     matrices that read the stream normal(0, `in_std`), the attention
     layers' among them normal(0, `qkv_std`); those that write into the
-    stream normal(0, `out_std`); the final norm's scale `final_norm`, the
+    stream normal(0, `out_std`), the feed-forward's apart where
+    `ffn_out_std` is given; of the routed experts W1 | W3 normal(0,
+    `in_std`), W2 normal(0, `expert_out_std`) and the router normal(0,
+    `router_std`); the final norm's scale `final_norm`, the
     other norms 1; Mamba-2's own parameters as their module draws them
     (the published initialiser: a head forgets in 1 / (dt |A|), some 1 to
     1,000 steps), unless `step_size` = (lo, hi) draws each head's dt
@@ -560,13 +679,17 @@ def init_params(cfg: GraniteHybridConfig, key, *, embed_std: float = 0.02,
     flat = jax.tree_util.tree_flatten_with_path(drawn)[0]
     keys = jax.random.split(jax.random.fold_in(key, 1), len(flat))
     stds = {"embed": embed_std, "qkv_proj": qkv_std, "in_proj": in_std,
-            "o_proj": out_std, "out_proj": out_std}
+            "o_proj": out_std, "out_proj": out_std, "w13": in_std,
+            "w2": expert_out_std, "router": router_std}
     out = []
     for (path, leaf), k in zip(flat, keys):
         names = [p.key for p in path]
-        if names[-1] in ("kernel", "embedding"):
-            leaf = (jax.random.normal(k, leaf.shape, jnp.float32)
-                    * stds[names[-2]]).astype(leaf.dtype)
+        if names[-1] in ("kernel", "embedding") or names[-2] == "experts":
+            std = stds[names[-1 if names[-2] == "experts" else -2]]
+            if names[-3:-1] == ["mlp", "out_proj"] and ffn_out_std is not None:
+                std = ffn_out_std
+            leaf = (jax.random.normal(k, leaf.shape, jnp.float32) * std
+                    ).astype(leaf.dtype)
         elif names[1:] == ["norm", "scale"]:
             leaf = leaf * final_norm
         elif names[-1] == "dt_bias" and step_size is not None:
@@ -580,9 +703,12 @@ def init_params(cfg: GraniteHybridConfig, key, *, embed_std: float = 0.02,
 
 
 def count_params(cfg: GraniteHybridConfig) -> dict:
-    """Parameters by kind of layer (one layer of each) and in all."""
+    """Parameters by kind of layer (one layer of each, with the experts
+    held HERE) and in all; one expert's and the router's beside them."""
     d, ff, E, H = cfg.d_model, cfg.d_ff, cfg.d_inner, cfg.mamba_heads
-    mlp = 3 * d * ff + 2 * d                  # + the layer's two norms
+    expert, router = 3 * d * cfg.d_expert, d * cfg.n_experts
+    # the shared feed-forward and the layer's two norms, then the routed
+    mlp = 3 * d * ff + 2 * d + cfg.experts_here * expert + router
     one = {
         "mamba": mlp + d * (E + cfg.conv_dim + H)
         + cfg.d_conv * cfg.conv_dim + cfg.conv_dim + 3 * H + E + E * d,
@@ -590,4 +716,5 @@ def count_params(cfg: GraniteHybridConfig) -> dict:
         * cfg.head_dim + cfg.n_heads * cfg.head_dim * d,
     }
     total = sum(one[k] for k in cfg.layer_types) + cfg.vocab_size * d + d
-    return dict(one, embedding=cfg.vocab_size * d, total=total)
+    routed = dict(expert=expert, router=router) if cfg.n_experts else {}
+    return dict(one, **routed, embedding=cfg.vocab_size * d, total=total)

@@ -149,7 +149,7 @@ def route(logits, bias, top_k: int, eps: float = 1e-6):
     return idx, chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + eps)
 
 
-def expert_ffn(u, idx, gates, w13, w2, valid=None):
+def expert_ffn(u, idx, gates, w13, w2, valid=None, first=None):
     """u (T, d) float32; idx, gates (T, k); w13 (E, d, 2 f) = [W1 | W3];
     w2 (E, f, d); valid (T,) bool or None -> (sum over a row's experts of
     g W2 (silu(W1 u) * W3 u), (T, d) float32, zeros where not valid;
@@ -157,16 +157,30 @@ def expert_ffn(u, idx, gates, w13, w2, valid=None):
     expert and each matrix is one grouped product over their float32 rows
     (T k rows in, T k out: where the matrices are bfloat16 the kernel
     makes a row's two terms itself); every pair of a valid row is
-    computed, whatever the routing."""
+    computed, whatever the routing.
+
+    `first`: the matrices are a chip's SHARE of a layer's experts, those
+    numbered `first` ... `first + E - 1` of the router's (None: all of
+    them, from 0).  `idx` and `gates` are then still the router's whole
+    choice; a pair whose expert is held elsewhere goes the way of a row
+    that is not valid (no group, no visit, zeros), and what comes back is
+    this share's PART of the routed sum, for whoever adds the parts."""
     T, k = idx.shape
     E = w13.shape[0]
     # pair p = j T + t is row t's j-th expert: (k, T, d) is then (k T, d)
     # as it lies, where (T, k, d) pads k to the float32 tile's 8 rows and
     # is a copy of every pair's result (a `reshape` line of a profile)
     flat = idx.T.reshape(-1)
+    kept = gates
+    if first is not None:
+        # held elsewhere: no expert here (E), and no gate
+        here = (idx >= first) & (idx < first + E)
+        flat = jnp.where(here.T.reshape(-1), flat - first, E)
+        kept = jnp.where(here, kept, 0.0)
     if valid is not None:
         # no expert: sorted past the last group
         flat = jnp.where(jnp.tile(valid, k), flat, E)
+        kept = jnp.where(valid[:, None], kept, 0.0)
     order = jnp.argsort(flat)                   # stable: pairs by expert
     sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
     x = u[order % T]                            # each sorted pair's row
@@ -174,7 +188,6 @@ def expert_ffn(u, idx, gates, w13, w2, valid=None):
     y = grouped_matmul(nn.silu(a) * b, w2, sizes, _two_terms)
     # back to pair order; a pair of no group holds anything: dropped
     y = y[jnp.argsort(order)].reshape(k, T, -1)
-    kept = gates if valid is None else jnp.where(valid[:, None], gates, 0.0)
     kept = kept.T[..., None]
     out = jnp.sum(jnp.where(kept > 0, y, 0.0) * kept, axis=0)
     return out, expert_counts(sizes)
@@ -200,37 +213,53 @@ def add_counts(a, b):
 
 class RoutedExperts(nn.Module):
     """`cfg`: any configuration with `n_experts`, `top_k`, `d_model`,
-    `d_expert`, `routed_scaling` and `dtype` (`models/mla_moe.py`'s too);
-    `eps`: what the family adds to the chosen scores' sum, where that is
-    not `route`'s own."""
+    `d_expert`, `routed_scaling` and `dtype` (`models/mla_moe.py`'s and
+    `models/granite_hybrid.py`'s too); `eps`: what the family adds to the
+    chosen scores' sum, where that is not `route`'s own; `choose`: a
+    family's own router, `choose(logits, top_k)` -> (idx, gates), in
+    `route`'s place (there is then no selection bias); `held` = (first,
+    count): the experts this chip holds of the router's `n_experts`, where
+    a layer's experts are divided over chips (None: all).  The router and
+    its choice are always the whole layer's; the output is the held
+    experts' part of the routed sum (`expert_ffn`)."""
     cfg: Any
     # (None and not 1e-6: `route` is then called with three arguments, as
     # the planted routes of `benchmarks/tools/lfm2_moe_faults.py` take it)
     eps: float | None = None
+    choose: Any = None
+    held: tuple | None = None
 
     def setup(self):
         c = self.cfg
         E, d, f = c.n_experts, c.d_model, c.d_expert
+        first, count = self.held or (0, E)
+        if not 0 <= first <= first + count <= E or not count:
+            raise ValueError(f"held={self.held}: a run of the router's "
+                             f"{E} experts")
         init = nn.initializers.normal(0.02)
         # the router in float32 (0.5 MB a layer at the published sizes)
         self.router = self.param("router", init, (d, E), jnp.float32)
-        self.expert_bias = self.param("expert_bias", nn.initializers.zeros,
-                                      (E,), jnp.float32)
-        self.w13 = self.param("w13", init, (E, d, 2 * f), c.dtype)
-        self.w2 = self.param("w2", init, (E, f, d), c.dtype)
+        if self.choose is None:
+            self.expert_bias = self.param(
+                "expert_bias", nn.initializers.zeros, (E,), jnp.float32)
+        self.w13 = self.param("w13", init, (count, d, 2 * f), c.dtype)
+        self.w2 = self.param("w2", init, (count, f, d), c.dtype)
 
     def __call__(self, u, valid=None):
         """u (..., d) float32 -> (the layer's output, its counts)."""
         c = self.cfg
         flat = u.reshape(-1, c.d_model)
         with jax.named_scope("route"):
-            idx, gates = route(
-                router_logits(flat, self.router), self.expert_bias, c.top_k,
-                **({} if self.eps is None else {"eps": self.eps}))
+            logits = router_logits(flat, self.router)
+            idx, gates = self.choose(logits, c.top_k) \
+                if self.choose is not None else route(
+                    logits, self.expert_bias, c.top_k,
+                    **({} if self.eps is None else {"eps": self.eps}))
         with jax.named_scope("experts"):
             out, counts = expert_ffn(
                 flat, idx, gates, self.w13, self.w2,
-                None if valid is None else valid.reshape(-1))
+                None if valid is None else valid.reshape(-1),
+                **({} if self.held is None else {"first": self.held[0]}))
         return (c.routed_scaling * out).reshape(u.shape), counts
 
 

@@ -259,9 +259,15 @@ class GraniteHybridServing:
     """`models/granite_hybrid.py`: a (k, v) pool for each attention layer,
     all under one table row a sequence (as `LlamaServing`), and (conv
     window, state) for each Mamba-2 layer, fixed per slot (as
-    `SambaYServing.mamba`; 2 MB of state a layer at the published sizes,
-    so the slots an engine can hold are bounded by `state_bytes_per_slot`,
-    not by `kv_pool_tokens`)."""
+    `SambaYServing.mamba`; the state of one layer is `mamba_heads` x 64 x
+    128 float32, 2 MB at Granite-4.0-H-Micro's 64 heads and 4 MB at
+    -Small's 128, so the slots an engine can hold are bounded by
+    `state_bytes_per_slot`, not by `kv_pool_tokens`).  Heads of 64 lie in
+    the pools two to a kernel's head of 128, heads of 128 as they are
+    (`GraniteHybridConfig.kv_pool_heads`).  With routed experts a decode
+    step and a prefill count what the routed layers did, as
+    `Lfm2MoeServing`'s do, and two counts more: the (row, expert) pairs
+    the router made and those whose expert this chip holds."""
 
     rewinds = False
     portable_kv = False
@@ -272,12 +278,28 @@ class GraniteHybridServing:
         self.model = GraniteHybridModel(cfg)
         self.state_bytes_per_slot = _fixed_bytes_per_slot(
             self, lambda s: s["ssm"])
+        if cfg.n_experts:
+            # `models/granite_hybrid.EXPERT_COUNTS`, a step's over its
+            # live rows, a prefill's over real tokens
+            self.step_counters = (
+                ("experts_touched", "sum"), ("expert_slots", "sum"),
+                ("expert_rows_max", "max"), ("expert_pairs_held", "sum"),
+                ("expert_pairs", "sum"))
+            self.prefill_counters = (("expert_rows_max", "max"),
+                                     ("expert_rows", "sum"))
 
     def ring_tokens(self, lens) -> int:
         return 0
 
     def prefill_width(self, bucket: int, max_batch: int) -> int:
-        return _rows_under_the_token_cap(bucket, max_batch)
+        # With routed experts a token is `top_k` (row, expert) pairs of
+        # float32 rows in and out of the grouped products, whichever chip
+        # holds the expert (`lfm2_moe.expert_ffn`): ten pairs of 4,096 are
+        # 0.5 MB a token and layer, so a dispatch holds a quarter of the
+        # dense member's tokens.
+        return _rows_under_the_token_cap(
+            bucket, max_batch,
+            _PREFILL_TOKENS // 4 if self.cfg.n_experts else _PREFILL_TOKENS)
 
     def prompt_pages(self, bucket: int, page_size: int) -> int:
         return bucket // page_size
@@ -285,10 +307,10 @@ class GraniteHybridServing:
     def init_state(self, max_batch: int, num_pages: int, page_size: int):
         c = self.cfg
         B = max_batch
+        heads, width = c.kv_pool_heads
         # (every leaf a buffer of its own: the state is donated)
         pool = lambda: jnp.zeros(  # noqa: E731
-            (num_pages, c.n_kv_heads // 2, page_size, 2 * c.head_dim),
-            c.dtype)
+            (num_pages, heads, page_size, width), c.dtype)
         return {
             "pools": [(pool(), pool()) for _ in c.layers_of("attention")],
             "ssm": [(jnp.zeros((B, c.d_conv - 1, c.conv_dim), c.dtype),
@@ -297,8 +319,9 @@ class GraniteHybridServing:
                     for _ in c.layers_of("mamba")]}
 
     def prefill(self, params, tokens, last_idx):
-        return self.model.apply(params, tokens, last_idx,
-                                method=GraniteHybridModel.prefill)
+        out = self.model.apply(params, tokens, last_idx,
+                               method=GraniteHybridModel.prefill)
+        return (*out[:2], out[2][2:4]) if self.cfg.n_experts else out
 
     def write_prompt(self, state, fresh, slots, page_ids):
         flat = page_ids.reshape(-1)
@@ -314,6 +337,8 @@ class GraniteHybridServing:
                     zip(state["ssm"], fresh["ssm"])]}
 
     def decode(self, params, token, pos, state, tables, lens, live):
+        # (with routed experts the model's counts follow, in
+        # `step_counters`' order: the held pairs are its `expert_rows`)
         return self.model.apply(params, token, pos, state, tables, lens,
                                 live, method=GraniteHybridModel.decode)
 

@@ -364,6 +364,11 @@ GMM_FAMILIES = {
                 63, (512, 2048, 8192)),
     "lfm2_moe": ("lfm2-24b-a2b-l9.json", "lfm2_moe_costs", "num_experts",
                  40, (128, 256, 512, 1024, 4096)),
+    # (one chip's share: 36 groups held of the 72 routed over, so that of a
+    # token's ten pairs about five lie in a group; PR 52)
+    "granite_moe_hybrid": ("granite-4.0-h-small-l10-e36.json",
+                           "granite_moe_hybrid_costs", "num_local_experts",
+                           36, (512, 2048)),
 }
 
 
@@ -377,12 +382,15 @@ def _even_sizes(pairs: int, groups: int, touched: int):
     return sizes
 
 
-def _routed_sizes(tokens: int, top_k: int, groups: int, rng):
-    """The same of a prompt: each token draws distinct experts at random."""
-    sizes = np.zeros(groups, np.int32)
+def _routed_sizes(tokens: int, top_k: int, groups: int, rng,
+                  routed: int | None = None):
+    """The same of a prompt: each token draws distinct experts at random,
+    of the `routed` experts there are (the groups held, where a layer's
+    are not divided over chips): the pairs of the others lie elsewhere."""
+    sizes = np.zeros(routed or groups, np.int32)
     for _ in range(tokens):
-        sizes[rng.choice(groups, top_k, replace=False)] += 1
-    return sizes
+        sizes[rng.choice(len(sizes), top_k, replace=False)] += 1
+    return sizes[:groups]
 
 
 def _gmm_cases(family: str):
@@ -392,12 +400,16 @@ def _gmm_cases(family: str):
     with open(os.path.join(_REPO, "benchmarks", "configs", name)) as f:
         conf = json.load(f)
     E, top_k = conf[experts_key], conf["num_experts_per_tok"]
-    d, f_ = conf["hidden_size"], conf["moe_intermediate_size"]
+    # (a chip's share of a layer: the pairs are spread over all of the
+    # experts routed over, and those of the groups held are here)
+    routed = conf.get("published", {}).get(experts_key, E)
+    d = conf["hidden_size"]
+    f_ = conf.get("moe_intermediate_size") or conf["intermediate_size"]
     batch = conf["serve"]["engine"]["max_batch"]
     rng = np.random.default_rng(0)
-    cases = [(f"decode {batch}", _even_sizes(batch * top_k, E, touched),
-              batch)]
-    cases += [(f"prompt {t}", _routed_sizes(t, top_k, E, rng), t)
+    cases = [(f"decode {batch}",
+              _even_sizes(batch * top_k * E // routed, E, touched), batch)]
+    cases += [(f"prompt {t}", _routed_sizes(t, top_k, E, rng, routed), t)
               for t in prompts]
     return conf, costs, cases, (("w13", d, 2 * f_), ("w2", f_, d))
 
@@ -639,8 +651,16 @@ def time_expert_ffn(families):
         for label, sizes, tokens in cases:
             E = len(sizes)
             rng = np.random.default_rng(1)
-            idx = rng.permutation(np.repeat(np.arange(E), sizes)).reshape(
-                tokens, top_k).astype(np.int32)
+            share = {}
+            if sizes.sum() == tokens * top_k:
+                idx = rng.permutation(np.repeat(np.arange(E), sizes)) \
+                    .reshape(tokens, top_k).astype(np.int32)
+            else:       # a chip's share: the rest of the pairs lie elsewhere
+                routed = conf["published"][GMM_FAMILIES[family][2]]
+                idx = np.stack([rng.choice(routed, top_k, replace=False)
+                                for _ in range(tokens)]).astype(np.int32)
+                sizes = np.bincount(idx[idx < E], minlength=E)
+                share = {"first": 0}
             keys = jax.random.split(jax.random.PRNGKey(3), 4)
             args = (jax.random.normal(keys[0], (tokens, d), jnp.float32),
                     jnp.asarray(idx),
@@ -651,8 +671,8 @@ def time_expert_ffn(families):
                     jax.random.normal(keys[3], (E, f2 // 2, d),
                                       jnp.bfloat16) * 0.02)
 
-            def ffn(*a):
-                return expert_ffn(*a)[0]
+            def ffn(*a, share=share):
+                return expert_ffn(*a, **share)[0]
             f = jax.jit(ffn)
             out = f(*args)
             kernel_ms, whole_ms = _traced_ms({"ffn": f}, args)["ffn"]

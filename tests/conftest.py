@@ -92,7 +92,10 @@ def ray_start_cluster_head():
 # the three modules see the benchmark as before, and `test_mla_moe_cell.py`
 # looks its entries up by name too. PR 49 did the same: a configuration, a
 # cell, four entries and that cell's name on nine `workloads` lists, all
-# after the cut; `test_minicpm_sala_cell.py` looks its entries up by name.)
+# after the cut; `test_minicpm_sala_cell.py` looks its entries up by name.
+# PR 52 likewise: a configuration, a cell, four entries and that cell's name
+# on ten `workloads` lists, all after the cut;
+# `test_granite_moe_hybrid_cell.py` looks its entries up by name.)
 # The `benchmark` PR that makes the two old modules do the
 # same deletes this with that conftest's fixture.
 # module -> (the newest per-layer entry it knows, the newest cell it knows:

@@ -21,7 +21,8 @@ from tests.tiny_families import ENGINE, FAMILIES, prompts, serve
 # each decodes.
 LENGTHS = (5, 19, 33, 40, 17, 64, 28, 3, 50)
 TRAFFIC = {"sambay": (LENGTHS[:6], 24), "granite_hybrid": (LENGTHS, 24),
-           "lfm2_moe": (LENGTHS, 16), "mla_moe": (LENGTHS, 16)}
+           "lfm2_moe": (LENGTHS, 16), "mla_moe": (LENGTHS, 16),
+           "granite_moe_hybrid": (LENGTHS, 16)}
 
 
 class Spans:
@@ -97,8 +98,31 @@ def _mla_moe(got, spans, lengths, new, repeats):
     assert sum(a["expert_rows"] for a in fills) == got["expert_rows"]
 
 
+def _granite_moe_hybrid(got, spans, lengths, new, repeats):
+    assert np.mean(repeats) < 0.2       # not echoes of the input
+    assert got["state_slots_reset"] == len(lengths)
+    # six Mamba-2 layers of (3, 160) conv inputs and (4, 32, 16) state
+    assert got["state_bytes_per_slot"] == 6 * (3 * 160 + 4 * 32 * 16) * 4
+    # every real prompt token, three pairs in each of eight layers, every
+    # expert held: a pair the router makes is a pair held
+    assert got["expert_rows"] >= sum(lengths) * 3 * 8
+    assert 0 < got["experts_touched"] <= got["expert_slots"]
+    assert got["expert_slots"] == got["decode_passes"] * 4 * 8 * 8
+    assert 0 < got["expert_pairs"] == got["expert_pairs_held"]
+    waits = spans("engine.decode.wait", "expert_pairs")
+    assert waits and all(
+        a["expert_slots"] == 4 * 8 * 8 and
+        a["experts_touched"] <= a["expert_slots"] and
+        a["expert_pairs_held"] == a["expert_pairs"] <= 4 * 4 * 3 * 8 and
+        1 <= a["expert_rows_max"] <= 4 for a in waits)
+    assert sum(a["expert_pairs"] for a in waits) == got["expert_pairs"]
+    fills = spans("engine.prefill.wait", "expert_rows")
+    assert sum(a["expert_rows"] for a in fills) == got["expert_rows"]
+
+
 COUNTED = {"sambay": _sambay, "granite_hybrid": _granite_hybrid,
-           "lfm2_moe": _lfm2_moe, "mla_moe": _mla_moe}
+           "lfm2_moe": _lfm2_moe, "mla_moe": _mla_moe,
+           "granite_moe_hybrid": _granite_moe_hybrid}
 
 
 @pytest.mark.parametrize("name", list(TRAFFIC))

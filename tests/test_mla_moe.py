@@ -482,6 +482,20 @@ def test_the_sweep_runs_the_cells_own_shapes(family, decode_call, touched):
                        costs.grouped_product_cost(conf, pairs, touched))
     assert all(tk == k and n % tn == 0
                for _, tk, tn in sweep._gmm_tilings(k, n))
+    # a chip's share of a layer: 36 groups held of 72 routed over, so half
+    # of a decode step's 480 pairs and about half of a prompt's lie here
+    share_conf, share_costs, cases, products = sweep._gmm_cases(
+        "granite_moe_hybrid")
+    share_costs = importlib.import_module(
+        "benchmarks.layer_metrics." + share_costs)
+    assert np.allclose(     # (the file as it lies is enough for the costs)
+        np.sum([sweep._gmm_cost(240, 36, a, b) for _, a, b in products],
+               axis=0), share_costs.grouped_product_cost(share_conf, 240, 36))
+    assert products == (("w13", 4096, 1536), ("w2", 768, 4096))
+    assert [(label, len(sizes), int(sizes.sum())) for label, sizes, _
+            in cases[:1]] == [("decode 48", 36, 240)]
+    for label, sizes, tokens in cases[1:]:
+        assert len(sizes) == 36 and 0.45 < sizes.sum() / (tokens * 10) < 0.55
 
 
 def _fault_names():

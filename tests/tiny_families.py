@@ -1,4 +1,4 @@
-"""The six served families at tiny widths, in ONE place: for each its tiny
+"""The seven served families at tiny widths, in ONE place: for each its tiny
 configuration (the model file's own, `benchmarks/families` checks it), the
 sizes its plain reference reads, its seeded weights (made once a process, on
 first use), and "is this stream what the plain reference decodes greedily".
@@ -252,8 +252,8 @@ class Recurrent(Family):
         state, table = self.paged_state(fault("prefilled", fresh), B)
         want = [self.reference(params, s, sizes=dict(self.SIZES, **sizes),
                                rounded=rounded) for s in seqs]
-        worst = max(np.abs(np.asarray(logits[b]) - want[b][n - 1]).max()
-                    for b, n in enumerate(prompt_lens))
+        gaps = [np.abs(np.asarray(logits[b]) - want[b][n - 1]).max()
+                for b, n in enumerate(prompt_lens)]
         decode = self._programs(model)[1]
         length = jnp.asarray(prompt_lens, jnp.int32)
         for k in range(steps):
@@ -261,11 +261,14 @@ class Recurrent(Family):
                                  zip(seqs, prompt_lens)])
             logits, state = decode(params, token, state, table, length)
             state = fault("stepped", state)
-            for b, n in enumerate(prompt_lens):
-                worst = max(worst, np.abs(np.asarray(logits[b])
-                                          - want[b][n + k]).max())
+            gaps += [np.abs(np.asarray(logits[b]) - want[b][n + k]).max()
+                     for b, n in enumerate(prompt_lens)]
             length = length + 1
-        return worst
+        return self.widest(gaps, model)
+
+    def widest(self, gaps, model):
+        """What `decode_against_reference` makes of its positions' gaps."""
+        return max(gaps)
 
 
 class SambaY(Recurrent):
@@ -396,6 +399,168 @@ class GraniteHybrid(Recurrent):
     def _decode(self, model, params, token, state, table, length):
         return model.apply(params, token, length, state, table, length,
                            method=type(model).decode)
+
+
+class GraniteMoeHybrid(GraniteHybrid):
+    """Granite-4.0-H's decoder with its routed experts: the tiny dense
+    member's mixers on a stream of 512, a shared feed-forward of 64 beside
+    8 routed experts of 32, three a token (the gates a softmax over the
+    three chosen logits), and 4 query and 2 KV heads of 128 as they are
+    (the kernels' width: nothing paired); float32.  Every expert is held
+    unless a test hands `share` its own."""
+
+    name = "granite_moe_hybrid"
+    SIZES = dict(
+        GraniteHybrid.SIZES, hidden_size=512, intermediate_size=32,
+        shared_intermediate_size=64, num_local_experts=8,
+        num_experts_per_tok=3, logits_scaling=16, router_experts=8,
+        experts_held=None)
+    # float32 reordering moves the logits (within +-3.6) by up to 4.4e-5
+    # and a row's state by 1.3e-5 (sums over a stream of 512, and the
+    # grouped products sum a row's experts in another order); served
+    # (`decode_against_reference`: at the WIDEST position, under the sets
+    # the program took): measured 0.060 (0.095 against the reference's own
+    # sets, 0.025 at the median position); the program took another set
+    # than the reference at 4 of 656 selections, its expert 0.010-0.013
+    # under the reference's third logit, where the median margin is 0.4
+    TOL, STATE_TOL, SERVED_TOL = 1e-4, 3e-5, 0.1
+    SERVED_TIE = 0.03
+
+    def check_prefill(self, both):
+        assert len(both["ssm"]) == 6 and len(both["kv"]) == 2
+        # K and V of the two KV heads of 128 lie as they are
+        assert both["kv"][0][0].shape == (len(self.ROWS), 2, self.BUCKET, 128)
+
+    def prefill(self, model, params, rows, bucket, last=None):
+        # (the routed member's prefill hands its counts back too)
+        return super().prefill(model, params, rows, bucket, last)[:2]
+
+    @functools.cached_property
+    def cfg(self):
+        from ray_tpu.models.granite_hybrid import TINY_GRANITE_MOE
+
+        return TINY_GRANITE_MOE
+
+    def share(self, first, count, cfg=None, params=None):
+        """(cfg, params) of the chip that holds experts `first` ...
+        `first + count - 1` of every layer: the same weights, the experts'
+        matrices cut to the share."""
+        import dataclasses
+
+        import jax
+
+        cfg = dataclasses.replace(cfg or self.cfg,
+                                  experts_held=(first, count))
+        cut = lambda path, leaf: leaf[first: first + count] if (  # noqa: E731
+            path[-1].key in ("w13", "w2")) else leaf
+        return cfg, jax.tree_util.tree_map_with_path(
+            cut, self.params if params is None else params)
+
+    def make(self, cfg, seed=0):
+        """The benchmark's initialiser with the matrices' deviations scaled
+        from the published width to this one (by the root of the width each
+        matrix sums over), as the dense member's `make` scales its own; the
+        router's too, so that its logits have the published widths'
+        deviation (1.3) and the three gates are not flat."""
+        from benchmarks.families.granite_moe_hybrid import WEIGHTS
+        from ray_tpu.models.granite_hybrid import init_params
+
+        wider = (4096 / cfg.d_model) ** 0.5
+        return init_params(cfg, _cpu().random.PRNGKey(seed), **_scaled(
+            WEIGHTS, dict(
+                dict.fromkeys(("in_std", "qkv_std", "router_std",
+                               "final_norm"), wider),
+                out_std=(8192 / cfg.d_inner) ** 0.5,
+                ffn_out_std=(1536 / cfg.d_ff) ** 0.5,
+                expert_out_std=(768 / cfg.d_expert) ** 0.5)))
+
+    def _decode(self, model, params, token, state, table, length):
+        return super()._decode(model, params, token, state, table,
+                               length)[:2]
+
+    def decode_against_reference(self, model, params, seqs, prompt_lens,
+                                 steps, rounded=0, fault=None, **sizes):
+        """The float32 model as every family's.  The served type routes on
+        logits that lie about 1e-3 off the reference's (conv windows and
+        K, V in bfloat16), so near a tie it takes another expert, at one
+        position in fifty here, and with three of eight experts at gates
+        of a third that is a jump of 0.2-2 on a logit which the state
+        carries on.  So the served type is held, at EVERY position, to the
+        reference UNDER THE SETS THE PROGRAM TOOK (`_telling` hands them
+        back; `rounded_logits(chosen=)`), and its sets to the reference's
+        margins: an expert the program chose lies no more than SERVED_TIE
+        under the reference's third logit of that token.  Returns the
+        widest gap."""
+        import jax.numpy as jnp
+        from benchmarks.reference import granite_moe_hybrid as ref
+
+        if model.cfg.dtype != jnp.bfloat16:
+            return super().decode_against_reference(
+                model, params, seqs, prompt_lens, steps, rounded, fault,
+                **sizes)
+        assert not (rounded or fault or sizes)
+        prefill, decode = self._telling(model)
+        B, L = len(seqs), model.cfg.n_layers
+        padded = np.zeros((B, self.BUCKET), np.int32)
+        for b, n in enumerate(prompt_lens):
+            padded[b, :n] = seqs[b][:n]
+        length = jnp.asarray(prompt_lens, jnp.int32)
+        (logits, fresh), sets = prefill(params, jnp.asarray(padded),
+                                        length - 1)
+        state, table = self.paged_state(fresh, B)
+        got = [[np.asarray(logits[b])] for b in range(B)]
+        # a layer's sets of row b: its prompt's, then a decode step's
+        chosen = [[[np.asarray(sets[i]).reshape(B, self.BUCKET, -1)[b, :n]]
+                   for i in range(L)] for b, n in enumerate(prompt_lens)]
+        for k in range(steps):
+            token = jnp.asarray([s[n + k] for s, n in
+                                 zip(seqs, prompt_lens)])
+            (logits, state), sets = decode(params, token, state, table,
+                                           length)
+            for b in range(B):
+                got[b].append(np.asarray(logits[b]))
+                for i in range(L):
+                    chosen[b][i].append(np.asarray(sets[i])[b: b + 1])
+            length = length + 1
+        gaps = []
+        for b, n in enumerate(prompt_lens):
+            scores: list = []
+            want = np.asarray(ref.rounded_logits(
+                params, self.SIZES, list(seqs[b][: n + steps]),
+                list(range(n - 1, n + steps)), scores=scores,
+                chosen=[np.concatenate(c) for c in chosen[b]]))
+            gaps.append(np.abs(np.stack(got[b]) - want).max())
+            for i, s in enumerate(scores):
+                s, took = np.asarray(s), np.concatenate(chosen[b][i])
+                third = np.sort(s, -1)[:, -took.shape[1]]
+                under = third[:, None] - np.take_along_axis(s, took, -1)
+                assert under.max() < self.SERVED_TIE, (b, i, under.max())
+        return max(gaps)
+
+    def _telling(self, model):
+        """The model's prefill and decode step, jitted, each handing back
+        beside its results the sets `route` chose in it, a layer in turn."""
+        from unittest import mock
+
+        from ray_tpu.models import granite_hybrid
+
+        def telling(f):
+            def run(*args):
+                sets, sound = [], granite_hybrid.route
+
+                def route(logits, top_k):
+                    idx, gates = sound(logits, top_k)
+                    sets.append(idx)
+                    return idx, gates
+
+                with mock.patch.object(granite_hybrid, "route", route):
+                    return f(*args), sets
+            return _cpu().jit(run)
+
+        return telling(lambda p, tokens, last: model.apply(
+            p, tokens, last, method=type(model).prefill)[:2]), \
+            telling(lambda p, t, s, table, ln: self._decode(
+                model, p, t, s, table, ln))
 
 
 class Lfm2Moe(Family):
@@ -569,8 +734,9 @@ class MiniCpmSala(Family):
 
 SALA_ENGINE = dict(max_batch=3, max_len=160, page_size=8, decode_chunk=4)
 
-dense, sambay, granite_hybrid, lfm2_moe, mla_moe = \
-    Dense(), SambaY(), GraniteHybrid(), Lfm2Moe(), MlaMoe()
+dense, sambay, granite_hybrid, lfm2_moe, mla_moe, granite_moe_hybrid = \
+    Dense(), SambaY(), GraniteHybrid(), Lfm2Moe(), MlaMoe(), \
+    GraniteMoeHybrid()
 FAMILIES = {f.name: f for f in (dense, sambay, granite_hybrid, lfm2_moe,
-                                mla_moe)}
+                                mla_moe, granite_moe_hybrid)}
 minicpm_sala = MiniCpmSala()
