@@ -155,9 +155,12 @@ def expert_ffn(u, idx, gates, w13, w2, valid=None, first=None):
     g W2 (silu(W1 u) * W3 u), (T, d) float32, zeros where not valid;
     `expert_counts` of the call).  The (row, expert) pairs are sorted by
     expert and each matrix is one grouped product over their float32 rows
-    (T k rows in, T k out: where the matrices are bfloat16 the kernel
-    makes a row's two terms itself); every pair of a valid row is
-    computed, whatever the routing.
+    (where the matrices are bfloat16 the kernel makes a row's two terms
+    itself); every pair of a valid row is computed, whatever the routing.
+    The kernel moves the rows: the first product reads each sorted pair's
+    row of u by its id and stores `silu(a) * b`, the second writes each
+    pair's result to its place in pair order, and nothing else touches a
+    (T k, d) array but the gated sum (`ops/grouped_matmul.py`, PR 54).
 
     `first`: the matrices are a chip's SHARE of a layer's experts, those
     numbered `first` ... `first + E - 1` of the router's (None: all of
@@ -183,13 +186,15 @@ def expert_ffn(u, idx, gates, w13, w2, valid=None, first=None):
         kept = jnp.where(valid[:, None], kept, 0.0)
     order = jnp.argsort(flat)                   # stable: pairs by expert
     sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
-    x = u[order % T]                            # each sorted pair's row
-    a, b = jnp.split(grouped_matmul(x, w13, sizes, _two_terms), 2, axis=-1)
-    y = grouped_matmul(nn.silu(a) * b, w2, sizes, _two_terms)
-    # back to pair order; a pair of no group holds anything: dropped
-    y = y[jnp.argsort(order)].reshape(k, T, -1)
-    kept = kept.T[..., None]
-    out = jnp.sum(jnp.where(kept > 0, y, 0.0) * kept, axis=0)
+    # the kernel moves the rows: sorted pair i's row of u comes in by its
+    # id, and its result goes out to row `order[i]`: pair order, each row
+    # in the parts it was copied in, which the gated sum reads as they lie
+    h = grouped_matmul(u, w13, sizes, _two_terms, rows=order % T, gated=True)
+    y = grouped_matmul(h, w2, sizes, _two_terms, to=order)
+    y = y.reshape(k, T, *y.shape[1:])
+    # a pair of no group was never written and holds anything: dropped
+    kept = kept.T[..., None, None]
+    out = jnp.sum(jnp.where(kept > 0, y, 0.0) * kept, axis=0).reshape(T, -1)
     return out, expert_counts(sizes)
 
 

@@ -296,7 +296,10 @@ class GraniteHybridServing:
         # float32 rows in and out of the grouped products, whichever chip
         # holds the expert (`lfm2_moe.expert_ffn`): ten pairs of 4,096 are
         # 0.5 MB a token and layer, so a dispatch holds a quarter of the
-        # dense member's tokens.
+        # dense member's tokens.  (Since PR 54 the grouped kernel brings
+        # the rows in itself and a pair held elsewhere moves nothing: the
+        # cap rests on temporaries that are gone and is left where it was,
+        # ROADMAP Speed 2.)
         return _rows_under_the_token_cap(
             bucket, max_batch,
             _PREFILL_TOKENS // 4 if self.cfg.n_experts else _PREFILL_TOKENS)
@@ -372,7 +375,8 @@ class Lfm2MoeServing:
         # Half the other hybrids' tokens a dispatch: a token is four
         # (row, expert) pairs of two terms each in the grouped products,
         # 2.4 GB of temporaries at 8,192 tokens and 4.8 at 16,384, beside
-        # 10.4 GB of weights (compiled for a described v5e, PR 42).
+        # 10.4 GB of weights (compiled for a described v5e, PR 42; the pair
+        # rows are no longer laid out around the kernel, PR 54: as above).
         return _rows_under_the_token_cap(bucket, max_batch,
                                          _PREFILL_TOKENS // 2)
 

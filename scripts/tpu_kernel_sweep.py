@@ -441,10 +441,10 @@ def _doubled_rows_tiles(rows: int, groups: int, k: int, n: int) -> tuple:
 
 
 def _device_ms(trace_dir: str) -> dict:
-    """{program: [(ms of its Pallas calls, ms of the whole run), a run
-    each]} from the xplane a profiler session left: the line `XLA Modules`
-    holds the runs of a jitted function, `XLA Ops` its operations, a
-    kernel by its target."""
+    """{program: [(ms of its Pallas calls, ms of the whole run, ms of each
+    call in the run's order), a run each]} from the xplane a profiler
+    session left: the line `XLA Modules` holds the runs of a jitted
+    function, `XLA Ops` its operations, a kernel by its target."""
     import glob
     path = sorted(glob.glob(os.path.join(
         trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
@@ -459,15 +459,17 @@ def _device_ms(trace_dir: str) -> dict:
                        for e in lines["XLA Ops"].events
                        if 'custom_call_target="tpu_custom_call"' in e.name)
         for t0, t1, name in runs:
-            ms = sum(d for s, d in calls if t0 <= s < t1) / 1e6
+            each = tuple(d / 1e6 for s, d in calls if t0 <= s < t1)
             name = name.split("(")[0].removeprefix("jit_")
-            out.setdefault(name, []).append((ms, (t1 - t0) / 1e6))
+            out.setdefault(name, []).append(
+                (sum(each), (t1 - t0) / 1e6, each))
     return out
 
 
 def _traced_ms(fns: dict, args) -> dict:
-    """{name: (median ms of the Pallas calls, of the whole run)} of jitted
-    functions (compiled already) over `args`, GMM_REPS runs each."""
+    """{name: (median ms of the Pallas calls, of the whole run, of each
+    call)} of jitted functions (compiled already) over `args`, GMM_REPS
+    runs each."""
     import tempfile
 
     got = None
@@ -478,8 +480,12 @@ def _traced_ms(fns: dict, args) -> dict:
                     got = f(*args)      # (one result held)
                 _sync(got)
         ms = _device_ms(trace_dir)
-    return {name: tuple(sorted(r[i] for r in ms[name])[len(ms[name]) // 2]
-                        for i in (0, 1))
+    def median(values):
+        return sorted(values)[len(values) // 2]
+
+    return {name: (median([r[0] for r in ms[name]]),
+                   median([r[1] for r in ms[name]]),
+                   tuple(map(median, zip(*(r[2] for r in ms[name])))))
             for name in fns if ms.get(name)}
 
 
@@ -557,10 +563,13 @@ def sweep_gmm(families):
                         continue
 
                     def run(x, w, sizes, tiles=tiles):
+                        M = x.shape[0]      # (the rows as they lie)
                         return gm._grouped_call(
-                            jnp.pad(x, ((0, -x.shape[0] % tiles[0]), (0, 0))),
-                            w, sizes, two_terms=_two_terms, tiles=tiles,
-                            interpret=gm._interpret_mode())[: x.shape[0]]
+                            x, w, sizes, jnp.pad(
+                                jnp.arange(M, dtype=jnp.int32),
+                                (0, -M % tiles[0])),
+                            None, two_terms=_two_terms, tiles=tiles,
+                            gated=False, interpret=gm._interpret_mode())[:M]
                     run.__name__ = "grouped_%d_%d_%d" % tiles
                     f = jax.jit(run)
                     try:
@@ -635,15 +644,60 @@ def _gmm_table(lines) -> str:
     return "\n".join(table)
 
 
+def _pairs_by_expert(idx, gates, E, valid=None, first=None):
+    """The (row, expert) pairs of a call as `lfm2_moe.expert_ffn` sorts
+    them -> (the sorted order, the rows of each of the E groups held here,
+    the gates kept): a pair of a row that is not `valid`, or whose expert
+    is none of `first` ... `first + E - 1`, is sorted past the last group
+    and its gate is 0."""
+    T, k = idx.shape
+    flat = idx.T.reshape(-1)
+    kept = gates
+    if first is not None:
+        here = (idx >= first) & (idx < first + E)
+        flat = jnp.where(here.T.reshape(-1), flat - first, E)
+        kept = jnp.where(here, kept, 0.0)
+    if valid is not None:
+        flat = jnp.where(jnp.tile(valid, k), flat, E)
+        kept = jnp.where(valid[:, None], kept, 0.0)
+    sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
+    return jnp.argsort(flat), sizes, kept
+
+
+def _expert_ffn_around_the_kernel(u, idx, gates, w13, w2, valid=None,
+                                  first=None):
+    """`lfm2_moe.expert_ffn` as it was until PR 54, XLA moving the rows
+    AROUND the kernel: a gather lays the sorted pairs' rows out, the kernel
+    takes them as they lie, XLA gates its result, and a second sort and
+    gather bring the rows back to pair order.  (On this tree the kernel
+    under it is this tree's, over `arange`; a parent's own numbers come
+    from this script run on the parent's tree.)"""
+    from ray_tpu.models.sambay import _two_terms
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+
+    T, k = idx.shape
+    order, sizes, kept = _pairs_by_expert(idx, gates, w13.shape[0], valid,
+                                          first)
+    x = u[order % T]
+    a, b = jnp.split(grouped_matmul(x, w13, sizes, _two_terms), 2, axis=-1)
+    y = grouped_matmul(jax.nn.silu(a) * b, w2, sizes, _two_terms)
+    y = y[jnp.argsort(order)].reshape(k, T, -1)
+    kept = kept.T[..., None]
+    return jnp.sum(jnp.where(kept > 0, y, 0.0) * kept, axis=0)
+
+
 def time_expert_ffn(families):
     """The whole routed feed-forward (`lfm2_moe.expert_ffn`: the sort, both
     grouped products and what stands around them) at the sweep's cases:
-    the median device time of the jitted call and of its Pallas calls.
-    Reads nothing of the kernel's own, so the same script times a parent
-    commit's tree (`--ffn`)."""
+    the median device time of the jitted call, of each of its two Pallas
+    calls and of what stands around them, and the same of the layer as it
+    was until PR 54 (`_expert_ffn_around_the_kernel`) beside it, with how
+    far the two results lie apart.  `expert_ffn` itself reads nothing of
+    the kernel's own, so the same script times a parent commit's tree
+    (`--ffn`)."""
     from ray_tpu.models.lfm2_moe import expert_ffn
 
-    emit, _ = _emitter("expert_ffn.jsonl")
+    emit, lines = _emitter("expert_ffn.jsonl")
     for family in families:
         conf, _, cases, products = _gmm_cases(family)
         (_, d, f2), _ = products
@@ -673,15 +727,53 @@ def time_expert_ffn(families):
 
             def ffn(*a, share=share):
                 return expert_ffn(*a, **share)[0]
-            f = jax.jit(ffn)
-            out = f(*args)
-            kernel_ms, whole_ms = _traced_ms({"ffn": f}, args)["ffn"]
-            emit({"family": family, "case": label, "tokens": tokens,
-                  "pairs": int(sizes.sum()), "kernels_ms": round(kernel_ms, 4),
-                  "expert_ffn_ms": round(whole_ms, 4),
-                  "around_ms": round(whole_ms - kernel_ms, 4),
-                  "checksum": float(jnp.sum(jnp.abs(out))),
-                  "device": jax.devices()[0].device_kind})
+
+            def around(*a, share=share):
+                return _expert_ffn_around_the_kernel(*a, **share)
+            fns = {"ffn": jax.jit(ffn), "around": jax.jit(around)}
+            out = fns["ffn"](*args)
+            apart = float(jnp.max(jnp.abs(fns["around"](*args) - out)))
+            for name, (kernel_ms, whole_ms, each) in _traced_ms(
+                    fns, args).items():
+                emit({"family": family, "case": label, "tokens": tokens,
+                      "pairs": int(sizes.sum()),
+                      "rows_moved": "by_xla_around_the_kernel"
+                      if name == "around" else "as_the_tree_has_it",
+                      "kernels_ms": round(kernel_ms, 4),
+                      "each_kernel_ms": [round(ms, 4) for ms in each],
+                      "expert_ffn_ms": round(whole_ms, 4),
+                      "around_ms": round(whole_ms - kernel_ms, 4),
+                      "checksum": float(jnp.sum(jnp.abs(out))),
+                      **({"apart": apart} if name == "around" else {}),
+                      "device": jax.devices()[0].device_kind})
+    table = _ffn_table(lines)
+    with open(os.path.join(_REPO, "chiprun_out", "expert_ffn.md"), "w") as f:
+        f.write(table + "\n")
+    print(table)
+
+
+def _ffn_table(lines) -> str:
+    """A call a row: the whole layer, W1|W3's kernel, W2's and what stands
+    around them (ms), XLA moving the rows around the kernel | as the tree
+    has it."""
+    head = ["family", "call", "pairs held", "whole layer", "W1|W3", "W2",
+            "around the kernels"]
+    table = ["| " + " | ".join(head) + " |", "|" + " --- |" * len(head)]
+    calls = {}
+    for ln in lines:
+        calls.setdefault((ln["family"], ln["case"], ln["pairs"]), {})[
+            ln["rows_moved"]] = ln
+    for (family, case, pairs), by in calls.items():
+        pair = [by[key] for key in ("by_xla_around_the_kernel",
+                                    "as_the_tree_has_it") if key in by]
+        cells = [" \\| ".join(f"{value(ln):.3f}" for ln in pair)
+                 for value in (lambda ln: ln["expert_ffn_ms"],
+                               lambda ln: ln["each_kernel_ms"][0],
+                               lambda ln: ln["each_kernel_ms"][1],
+                               lambda ln: ln["around_ms"])]
+        table.append("| " + " | ".join([family, case, str(pairs)] + cells)
+                     + " |")
+    return "\n".join(table)
 
 
 # family -> (configuration file, rows x bucket of the programs timed).

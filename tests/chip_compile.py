@@ -171,16 +171,20 @@ def paged_call(one_chip, B, H, Hkv, pool_pages, table_pages, q_dtype,
 def tiles_seen(monkeypatch) -> list:
     """(rows, groups, tiles) of every call of the grouped product's kernel
     (`ops/grouped_matmul._grouped_call`) made while a program is traced,
-    in order: what `_tiles` chose, and that the rows are float32 rows."""
+    in order: what `_tiles` chose, that the rows are float32 rows, and
+    that a routed layer is a pair of calls that move their own rows: the
+    first takes each sorted pair's row by its id and stores `silu(a) * b`,
+    the second takes those as they lie and writes each where it belongs."""
     from ray_tpu.ops import grouped_matmul
 
     seen, real = [], grouped_matmul._grouped_call
 
-    def call(x, w, sizes, *, tiles, **kw):
+    def call(x, w, sizes, src, dst, *, tiles, gated, **kw):
         assert x.dtype == jnp.float32 and w.dtype == jnp.bfloat16
-        seen.append((x.shape[0], w.shape[0], tiles))
-        return real(x, w, sizes, tiles=tiles, **kw)
+        first = len(seen) % 2 == 0
+        assert (src is not None, dst is None, gated) == (first,) * 3
+        seen.append(((src if first else x).shape[0], w.shape[0], tiles))
+        return real(x, w, sizes, src, dst, tiles=tiles, gated=gated, **kw)
 
     monkeypatch.setattr(grouped_matmul, "_grouped_call", call)
     return seen
-

@@ -3,6 +3,7 @@
 (`tests/chip_compile.py` says how)."""
 
 import contextlib
+import re
 
 import pytest
 
@@ -107,7 +108,14 @@ def test_granite_moe_largest_prefill_fits_beside_the_resident_state(
                              (40960, 36, (64, 768, 4096))}
         # (the state is not an argument of the prefill: it is resident)
         resident = peak_bytes(prefill) / 1e9 + gb(eng._pools)
-        assert resident == pytest.approx(
-            recorded["prefill_many_2x2048_gb"]["peak_with_state_resident"],
-            abs=0.05)
+        # (the file records PR 52's 14.559 GB, XLA laying the pair rows
+        # out around the kernel; 14.15 since the kernel moves them, PR 54:
+        # a ceiling from here on)
+        assert resident < 14.20 < recorded[
+            "prefill_many_2x2048_gb"]["peak_with_state_resident"]
         assert resident * 1e9 < 15.75e9 < HBM_BYTES
+        # no pair row is gathered or gated outside a kernel
+        text = prefill.as_text()
+        assert not re.search(r"f32\[40960,(4096|1536)\]", text)
+        assert re.search(r"f32\[40960,768\]\S* custom-call", text)
+        assert re.search(r"f32\[1310720,128\]\S* custom-call", text)

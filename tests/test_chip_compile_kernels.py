@@ -131,7 +131,12 @@ def test_routed_layer_holds_no_doubled_rows(one_chip, monkeypatch, tokens,
     and compiled for the chip: two Pallas calls, and no array of 2 x pairs
     rows anywhere around them (until PR 47 each product's rows were laid
     out as (pairs, 2, k) and copied to (2 pairs, k), its result back): the
-    kernel makes the two terms itself."""
+    kernel makes the two terms itself.  Nor is there an array of (pairs,
+    d) that XLA gathered or gated (until PR 54 `u[order % T]`, `silu(a) *
+    b` over (pairs, 2 f) and `y[argsort(order)]` stood around the calls):
+    the kernel's row copies lower for the chip, the first call stores
+    (pairs, f), and the second's rows lie where they belong in parts of
+    128 lanes, which the gated sum reads as they lie."""
     from ray_tpu.models import lfm2_moe
     from ray_tpu.ops import grouped_matmul
 
@@ -145,8 +150,13 @@ def test_routed_layer_holds_no_doubled_rows(one_chip, monkeypatch, tokens,
     compiled = lowered.compile().as_text()
     assert compiled.count(KERNEL) == 2
     pairs = tokens * top_k
-    assert re.search(rf"tensor<{pairs}x{d}xf32>", lowered.as_text())
     assert not re.search(rf"tensor<{2 * pairs}x", lowered.as_text())
-    assert re.search(rf"f32\[{pairs},{d}\]", compiled)
     assert not re.search(rf"\[{2 * pairs},", compiled)
+    # the two calls' results, and nothing else as large: of the second's,
+    # one reader (the gated sum); no (pairs, d) and no ungated (pairs, 2 f)
+    assert re.search(rf"f32\[{pairs},{f}\]\S* custom-call", compiled)
+    placed = rf"f32\[{pairs * d // 128},128\]"
+    assert re.search(placed + r"\S* custom-call", compiled)
+    assert len(re.findall(rf"= {placed}", compiled)) == 1
+    assert not re.search(rf"f32\[{pairs},({d}|{2 * f})\]", compiled)
 

@@ -1,6 +1,8 @@
 """The LFM2-MoE family at the sizes of `lfm2moe-serve-agents-closed`,
 compiled for a described v5e (`tests/chip_compile.py` says how)."""
 
+import re
+
 import pytest
 
 from tests.chip_compile import (HBM_BYTES, KERNEL,  # noqa: F401
@@ -53,10 +55,16 @@ def test_lfm2_moe_engine_programs_fit_the_chip(one_chip, monkeypatch):
         assert set(seen) == {(32768, 64, (64, 2048, 3072)),
                              (32768, 64, (64, 1536, 2048))}
         resident = peak_bytes(prefill) / 1e9 + gb(eng._pools)
-        # (the file records PR 42's 13.12 GB, over doubled rows)
-        assert resident == pytest.approx(13.12, abs=0.05) and resident <= \
+        # (the file records PR 42's 13.12 GB, over doubled rows, and the
+        # program still holds it, elsewhere: a ceiling since PR 54)
+        assert resident < 13.17 and resident <= \
             recorded["prefill_many_2x4096_gb"]["peak_with_state_resident"]
         assert resident * 1e9 < 15.75e9 < HBM_BYTES
+        # no pair row is gathered or gated outside a kernel
+        text = prefill.as_text()
+        assert not re.search(r"f32\[32768,(2048|3072)\]", text)
+        assert re.search(r"f32\[32768,1536\]\S* custom-call", text)
+        assert re.search(r"f32\[524288,128\]\S* custom-call", text)
     finally:
         eng.shutdown()
 
