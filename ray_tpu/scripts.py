@@ -443,6 +443,17 @@ def cmd_job(args):
 
 
 def cmd_timeline(args):
+    if args.chip is not None:
+        # A session's span files are read where they lie: no cluster is
+        # joined, and the session may be one that has ended.
+        from ray_tpu.util.timeline import chip_report
+
+        session_dir = args.chip or os.environ.get("RAY_TPU_SESSION_DIR")
+        if not session_dir:  # a head started by `ray_tpu start`
+            with open(args.state_file) as f:
+                session_dir = json.load(f)["session_dir"]
+        print(chip_report(session_dir))
+        return 0
     ray_tpu = _connect_from_state(args)
     from ray_tpu.util.timeline import dump_timeline
 
@@ -568,6 +579,15 @@ def main():
 
     p = sub.add_parser("timeline", help="dump chrome-trace of task events")
     p.add_argument("--output", default="/tmp/ray_tpu_timeline.json")
+    p.add_argument("--chip", nargs="?", metavar="SESSION_DIR", default=None,
+                   const="",
+                   help="print the chip's ledger of a session (its "
+                        "`chip.program` spans) instead: chip seconds by "
+                        "kind, starved seconds by what the loop was doing, "
+                        "the longest starved intervals, the watcher's "
+                        "lateness, the programs longest over their like; "
+                        "SESSION_DIR: a session's directory "
+                        "(this head's where left out)")
     p.set_defaults(fn=cmd_timeline)
 
     args = parser.parse_args()

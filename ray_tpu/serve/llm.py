@@ -80,6 +80,21 @@ class _Slot:
 
 
 @dataclass
+class _Program:
+    """One program the loop has put on the chip, from the loop to the
+    watcher (`_watch`), which sees it end and writes its `chip.program`
+    span: a decode chunk (one dispatch), or a prefill group (its prefill,
+    its write into pages and slots and its sampling, queued back to back)."""
+
+    seq: int            # the chip runs programs in this order
+    kind: str           # "decode" / "prefill"
+    out: object         # its last output, on the device
+    queued_ns: int      # the loop's clock when its FIRST dispatch returned
+    attrs: dict         # what the span says of it besides
+    fetched_ns: int = 0     # the loop's clock when its own fetch returned
+
+
+@dataclass
 class _Flight:
     """A prefill dispatched behind a program that is on the chip, its
     first tokens not fetched yet."""
@@ -87,7 +102,7 @@ class _Flight:
     requests: list      # (slot, seq_id, prompt, handle), pages reserved
     width: int          # rows of the program
     rows: list          # each request's row of the page table
-    toks: object        # first tokens (and counts), on the device
+    program: _Program   # `out`: first tokens (and counts), on the device
     held: int           # bytes of the program's outputs, see `_next_fits`
 
 
@@ -420,10 +435,13 @@ class LLMEngine:
         # Submitted and not yet taken by the loop, first come first; under
         # `_arrivals`, which wakes the loop for an arrival and, while it
         # waits behind a program on the chip, for that program's end
-        # (`_chip_done`, the watcher thread's word).
+        # (`_chip_done`, the watcher thread's word: the number of the last
+        # program that has ended).
         self._pending: deque = deque()
         self._arrivals = threading.Condition(threading.Lock())
-        self._chip_done = False
+        self._chip_done = 0
+        # Every program the loop dispatches, in the chip's order (`_Program`).
+        self._programs = itertools.count(1)
         self._watched: queue.SimpleQueue = queue.SimpleQueue()
         # Streams with tokens booked and not yet handed over -> whether
         # the stream ended with them (`_hand_off`).
@@ -444,8 +462,9 @@ class LLMEngine:
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="llm-engine")
         self._thread.start()
-        threading.Thread(target=self._watch, daemon=True,
-                         name="llm-engine-watch").start()
+        self._watcher = threading.Thread(target=self._watch, daemon=True,
+                                         name="llm-engine-watch")
+        self._watcher.start()
 
     # ---- public API ------------------------------------------------------
 
@@ -539,16 +558,7 @@ class LLMEngine:
             "paged_pages_table": float(self.paged_pages_table),
             "paged_live_share": (self.paged_pages_live
                                  / max(1, self.paged_pages_table)),
-            # Where several layers read ONE pool through one table row
-            # (a shared key-value cache): pages fetched by all of them.
-            "shared_pool_pages_live": float(
-                self.paged_pages_live * self.family.pool_readers),
-            "shared_pool_pages_table": float(
-                self.paged_pages_table * self.family.pool_readers),
-            # Fixed per-slot state: tokens held in the window layers'
-            # rings now, and slots an admission has reset so far.
-            "ring_tokens": float(self.family.ring_tokens(
-                self._lens[[s.request is not None for s in self._slots]])),
+            # Fixed per-slot state: slots an admission has reset so far.
             "state_slots_reset": float(self.state_slots_reset),
             "state_bytes_per_slot": float(self.family.state_bytes_per_slot),
             "state_slot_steps": float(self.state_slot_steps),
@@ -615,7 +625,9 @@ class LLMEngine:
     def shutdown(self):
         self._stop.set()
         self._thread.join(5.0)
+        # (the watcher first writes the span of every program dispatched)
         self._watched.put(None)
+        self._watcher.join(5.0)
         self._fail_all(RuntimeError("engine shut down"))
 
     def _fail_all(self, err: Exception):
@@ -798,11 +810,11 @@ class LLMEngine:
                 group = group[len(chunk):]
                 # A request alone keeps the single-sequence program.
                 W = 1 if len(chunk) == 1 else width
-                with tracing.span("engine.prefill", bucket=bucket,
-                                  rows=len(chunk), width=W,
-                                  computed=self._prefill_computed(
-                                      bucket, [len(c[2]) for c in chunk])):
-                    flight = self._dispatch_prefill(chunk, bucket, W)
+                what = dict(bucket=bucket, rows=len(chunk), width=W,
+                            computed=self._prefill_computed(
+                                bucket, [len(c[2]) for c in chunk]))
+                with tracing.span("engine.prefill", **what):
+                    flight = self._dispatch_prefill(chunk, what)
                     if flight is None:
                         continue
                     if under_chunk:
@@ -836,13 +848,16 @@ class LLMEngine:
             self._free_slot_pages(slot)
             handle._finish(err)
 
-    def _dispatch_prefill(self, chunk: list, bucket: int, W: int):
-        """One prefill dispatch for `chunk` (at most W requests of one
-        bucket, pages reserved): the rows' state into pages and slots and
-        one sampling dispatch, nothing fetched. Returns what
-        `_finish_prefill` takes, or None where the dispatch failed (and
-        its requests with it)."""
+    def _dispatch_prefill(self, chunk: list, what: dict):
+        """One prefill dispatch for `chunk` (at most `what["width"]`
+        requests of the bucket `what["bucket"]`, pages reserved): the rows'
+        state into pages and slots and one sampling dispatch, nothing
+        fetched; the three are ONE program of the chip's ledger
+        (`_on_chip`), which says of it what `what` says of its
+        `engine.prefill`. Returns what `_finish_prefill` takes, or None
+        where the dispatch failed (and its requests with it)."""
         jnp = self._jnp
+        bucket, W = what["bucket"], what["width"]
         npages_row = self.family.prompt_pages(bucket, self.page_size)
         tokens = np.zeros((W, bucket), np.int32)
         last_idx = np.zeros((W,), np.int32)
@@ -871,6 +886,8 @@ class LLMEngine:
         try:
             last_logits, fresh, *counts = prefill(
                 self.params, jnp.asarray(tokens), jnp.asarray(last_idx))
+            # (the chip, where it had nothing queued, is at work from here)
+            queued_ns = time.monotonic_ns()
             fresh = self._device_handoff(fresh)
             self._pools = self._write_prompt_pages(
                 self._pools, fresh, jnp.asarray(slots),
@@ -887,12 +904,16 @@ class LLMEngine:
         except BaseException as e:
             self._fail_group(chunk, e)
             return None
+        program = self._on_chip(
+            "prefill", toks, queued_ns, **what,
+            prompt_tokens=sum(len(c[2]) for c in chunk),
+            rids=[c[3].rid for c in chunk])
         held = self._prefill_bytes.get((W, bucket))
         if held is None:
             held = self._prefill_bytes[W, bucket] = sum(
                 x.nbytes for x in self._jax.tree_util.tree_leaves(
                     (last_logits, fresh)))
-        return _Flight(chunk, W, rows, toks, held)
+        return _Flight(chunk, W, rows, program, held)
 
     def _finish_prefill(self, flight: _Flight, free=()) -> None:
         """A dispatched prefill's first tokens (and what the family's
@@ -901,15 +922,17 @@ class LLMEngine:
         commit. The chip is at work on the prefill, so first what is
         booked is handed over and arrivals are served into `free`."""
         chunk, W, rows = flight.requests, flight.width, flight.rows
+        program = flight.program
         self._hand_off()
-        self._admit_behind(flight.toks, free)
+        self._admit_behind(program, free)
         wait = tracing.span("engine.prefill.wait").begin()
         try:
-            toks = np.asarray(flight.toks)
+            toks = np.asarray(program.out)
         except BaseException as e:
             wait.end()
             self._fail_group(chunk, e)
             return
+        program.fetched_ns = time.monotonic_ns()
         wait.end(**self._count(self._prefill_counters, toks[W:]))
         # Host-only from here: no device call can strand waiters.
         if not self.family.rewinds:
@@ -1042,17 +1065,54 @@ class LLMEngine:
                  ended=sum(self._booked.values()))
         self._booked.clear()
 
+    def _on_chip(self, kind: str, out, queued_ns: int, **attrs) -> _Program:
+        """(The loop thread, when a program's last dispatch call has
+        returned.) The program takes its number, and the watcher is handed
+        it. `queued_ns`: the loop's clock when the program's FIRST dispatch
+        call returned, which is when a chip with nothing queued has begun
+        it (a prefill group's other two dispatches follow the first by
+        1-3 ms of host time, which a stamp at the last would take off a
+        starved group's span)."""
+        program = _Program(next(self._programs), kind, out, queued_ns, attrs)
+        self._watched.put(program)
+        return program
+
     def _watch(self) -> None:
-        """(Its own thread.) Tells the loop, which may be waiting for an
-        arrival, that the program it was handed has left the chip."""
-        while (toks := self._watched.get()) is not None:
+        """(Its own thread.) The chip's ledger: blocks on every program the
+        loop dispatched, in the chip's order, and writes its ONE
+        `chip.program` span (PERF.md section 3), whose interval is the
+        chip's and not any host thread's. It starts when the program was
+        queued or when the program before it ended, whichever is later
+        (`starved_ns`: how long the chip had nothing queued between the
+        two). It ends at the EARLIER of two stamps of one event: this
+        thread's, taken when its wait returns, and the loop's, left by its
+        own fetch of the same output (`fetched_ns`) where that is already
+        there; both wake on the program's end, and the one that loses the
+        interpreter lock reads late (`seen_by`: whose stamp the end is;
+        `late_ns`: how much later this thread read where the loop's was
+        first). Then it tells the loop, which may be waiting for an arrival
+        behind the program (`_admit_behind`), that it has left the chip."""
+        before = time.monotonic_ns()    # the end of the program before
+        while (program := self._watched.get()) is not None:
             try:
-                toks.block_until_ready()
+                program.out.block_until_ready()
             except Exception:  # noqa: S110 (the loop's own fetch raises it)
                 pass
+            seen = time.monotonic_ns()
+            fetched = program.fetched_ns
+            by_fetch = 0 < fetched <= seen
+            ended = fetched if by_fetch else seen
             with self._arrivals:
-                self._chip_done = True
+                self._chip_done = program.seq
                 self._arrivals.notify()
+            queued = program.queued_ns
+            tracing.record_span(
+                "chip.program", max(queued, before), t1_ns=ended,
+                kind=program.kind, seq=program.seq, queued_ns=queued,
+                starved_ns=max(0, queued - before),
+                seen_by="fetch" if by_fetch else "watch",
+                late_ns=seen - ended, **program.attrs)
+            before = ended
 
     def _next_fits(self) -> bool:
         """(Under `_arrivals`.) The next request in line is one to prefill
@@ -1133,9 +1193,9 @@ class LLMEngine:
         admit.end(admitted=len(cands), deferred=len(self._deferred),
                   rids=[c[3].rid for c in cands])
 
-    def _admit_behind(self, toks, free: list) -> None:
-        """The program that returns `toks` (a decode chunk, or a prefill
-        queued behind one) is on the chip and the slots of `free` are
+    def _admit_behind(self, program: _Program, free: list) -> None:
+        """`program` (a decode chunk, or a prefill queued behind one) is
+        on the chip and the slots of `free` are
         empty (at the chunk's dispatch; after its walk, those it freed as
         well) and not spoken for: until the program is done, a plain
         request that arrives (or had arrived) is given one of them and
@@ -1150,14 +1210,12 @@ class LLMEngine:
         straight to that fetch, as it always did."""
         if not free or self._deferred:
             return
-        self._chip_done = False
-        self._watched.put(toks)
         while True:
             with tracing.span("engine.chip.wait"), self._arrivals:
                 self._arrivals.wait_for(
-                    lambda: self._chip_done or (
+                    lambda: self._chip_done >= program.seq or (
                         free and not self._deferred and self._next_fits()))
-                if self._chip_done:
+                if self._chip_done >= program.seq:
                     return
             self._admit(free, under_chunk=True)
 
@@ -1269,6 +1327,10 @@ class LLMEngine:
                     (toks, self._pools, dev["token"], dev["pos"],
                      dev["lens"], dev["chunk_no"]) = \
                         self._decode_chunk_paged(*args)
+                    program = self._on_chip("decode", toks,
+                                            time.monotonic_ns(),
+                                            active=len(decoding),
+                                            steps=slot_steps)
                     # The inputs the carry has replaced die here, with the
                     # device at work: an array's destructor gives the
                     # interpreter lock away, and before the dispatch that
@@ -1279,7 +1341,7 @@ class LLMEngine:
                 # the last walk and the commits booked.
                 self._hand_off()
                 free = self._empty_slots()
-                self._admit_behind(toks, free)
+                self._admit_behind(program, free)
                 # The chunk's ONE `engine.decode.wait` (its counts ride on
                 # it): the whole wait where no slot was empty, else what
                 # is left of it after `engine.chip.wait`.
@@ -1288,6 +1350,7 @@ class LLMEngine:
                                     pages_live=pages_live,
                                     pages_table=pages_table).begin()
                 toks = np.asarray(toks)  # (K, B), the step's counts after
+                program.fetched_ns = time.monotonic_ns()
                 B = self.max_batch
                 wait.end(**self._count(self._step_counters, toks[:, B:]))
                 toks = toks[:, :B]

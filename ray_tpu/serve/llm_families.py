@@ -23,7 +23,6 @@ A family object answers, for its configuration:
     state_bytes_per_slot        bytes of the fixed per-slot state of one
                                 sequence, from shapes alone (0: all of
                                 its state is pages)
-    ring_tokens(lens)           tokens held in fixed-size rings (0: none)
     prefill_width(bucket, max_batch)   rows of the batched prefill program
                                 at a bucket (fixed, so one program a bucket)
     prompt_pages(bucket, page_size)    page columns `write_prompt` takes
@@ -75,7 +74,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ray_tpu.models.granite_hybrid import (GraniteHybridConfig,
                                            GraniteHybridModel)
@@ -120,9 +118,6 @@ class LlamaServing:
     def __init__(self, cfg: LlamaConfig, max_len: int):
         self.cfg, self.max_len = cfg, max_len
         self.model = LlamaModel(cfg)
-
-    def ring_tokens(self, lens) -> int:
-        return 0
 
     def prefill_width(self, bucket: int, max_batch: int) -> int:
         return min(BATCH_PREFILL_WIDTH, max_batch)
@@ -201,13 +196,8 @@ class SambaYServing:
         self.model = SambaYModel(cfg)
         # the full layer and the cross layers behind it
         self.pool_readers = 1 + len(cfg.layers_of("cross"))
-        self._window_layers = len(cfg.layers_of("window"))
         self.state_bytes_per_slot = _fixed_bytes_per_slot(
             self, lambda s: (s["rings"], s["mamba"]))
-
-    def ring_tokens(self, lens) -> int:
-        return int(np.minimum(lens, self.cfg.window).sum()) \
-            * self._window_layers
 
     def prefill_width(self, bucket: int, max_batch: int) -> int:
         return _rows_under_the_token_cap(bucket, max_batch)
@@ -288,9 +278,6 @@ class GraniteHybridServing:
             self.prefill_counters = (("expert_rows_max", "max"),
                                      ("expert_rows", "sum"))
 
-    def ring_tokens(self, lens) -> int:
-        return 0
-
     def prefill_width(self, bucket: int, max_batch: int) -> int:
         # With routed experts a token is `top_k` (row, expert) pairs of
         # float32 rows in and out of the grouped products, whichever chip
@@ -368,9 +355,6 @@ class Lfm2MoeServing:
         self.state_bytes_per_slot = _fixed_bytes_per_slot(
             self, lambda s: s["conv"])
 
-    def ring_tokens(self, lens) -> int:
-        return 0
-
     def prefill_width(self, bucket: int, max_batch: int) -> int:
         # Half the other hybrids' tokens a dispatch: a token is four
         # (row, expert) pairs of two terms each in the grouped products,
@@ -441,9 +425,6 @@ class MlaMoeServing:
         self.cfg, self.max_len = cfg, max_len
         self.model = MlaMoeModel(cfg)
 
-    def ring_tokens(self, lens) -> int:
-        return 0
-
     def prefill_width(self, bucket: int, max_batch: int) -> int:
         # A token is six (row, expert) pairs of two terms each in the
         # grouped products: 8,192 tokens a dispatch, as `Lfm2MoeServing`.
@@ -513,9 +494,6 @@ class MiniCpmSalaServing:
         self.model = MiniCpmSalaModel(cfg)
         self.state_bytes_per_slot = _fixed_bytes_per_slot(
             self, lambda s: (s["open"], s["lightning"]), cfg.block_size)
-
-    def ring_tokens(self, lens) -> int:
-        return 0
 
     def prefill_width(self, bucket: int, max_batch: int) -> int:
         # One program a bucket: the prompts are long (a 32,768 bucket alone

@@ -379,14 +379,19 @@ def span(name: str, rid=None, **attrs) -> Span:
     return Span(name, rid, **attrs)
 
 
-def record_span(name: str, t0_ns: int, rid=None, **attrs) -> None:
+def record_span(name: str, t0_ns: int, rid=None, t1_ns: int | None = None,
+                **attrs) -> None:
     """A span that began at `t0_ns` (a `time.monotonic_ns()` reading,
-    perhaps another thread's) and ends now: a request's wait, which no
-    one thread spends. It has no parent and no profiler annotation; its
-    `rid` ties it to the spans that served it."""
+    perhaps another thread's) and ends now, or at `t1_ns` where the end
+    was seen before this call: a request's wait, which no one thread
+    spends; a program's time on the chip, which no thread of the host
+    spends at all. It has no parent and no profiler annotation; its `rid`
+    ties it to the spans that served it."""
     me = threading.current_thread()
-    _recorder.add((next(_recorder.ids), 0, name, rid, t0_ns,
-                   time.monotonic_ns() - t0_ns, me.ident, me.name, attrs))
+    if t1_ns is None:
+        t1_ns = time.monotonic_ns()
+    _recorder.add((next(_recorder.ids), 0, name, rid, t0_ns, t1_ns - t0_ns,
+                   me.ident, me.name, attrs))
 
 
 def recent_spans() -> list[dict]:

@@ -1,7 +1,11 @@
 """Spans inside `LLMEngine._loop` (`ray_tpu/util/tracing.py`'s recorder): a
 tiny engine serves three requests and what it did is read back from the
 recorder, from the session's span file, from `ray_tpu timeline`'s rows and
-from a `jax.profiler` trace's host plane.
+from a `jax.profiler` trace's host plane.  And the chip's own row
+(`chip.program`, PR 55): one span for every program the loop dispatched,
+written by the watcher thread, held to its contract on what `served` left
+and, where "behind the chunk on the chip" has to be a state and not a
+race, under the doubles of `tests/llm_loop_doubles.py`.
 """
 
 import glob
@@ -11,9 +15,14 @@ import time
 
 import pytest
 
+from ray_tpu.models.generate import SamplingParams
 from ray_tpu.util import timeline, tracing
+from tests.llm_loop_doubles import (  # noqa: F401 (fixtures)
+    WAIT, _end, _held, _prompt, _with_its_first_chunk_held, engine, model)
 
 LOOP_THREAD = "llm-engine"
+WATCH_THREAD = "llm-engine-watch"
+CHIP = "chip.program"
 # Where each loop-scoped span may sit (PERF.md, section 3). The hand-off
 # stands wherever the loop is about to wait: after a chunk's dispatch,
 # before a prefill's fetch, or outside any pass before an idle wait.
@@ -238,12 +247,217 @@ def test_timeline_draws_the_span_files_as_rows(served):
     mine = [r for r in rows if r["args"].get("id") in
             {s["id"] for s in spans}]
     assert len(mine) == len(spans)
-    # One row a process and thread; wall-clock microseconds.
+    # One row a process and thread, the chip's beside the loop's;
+    # wall-clock microseconds.
     assert {(r["pid"], r["tid"]) for r in mine} == \
-        {(f"spans:pid{os.getpid()}", LOOP_THREAD)}
+        {(f"spans:pid{os.getpid()}", LOOP_THREAD),
+         (f"spans:pid{os.getpid()}", WATCH_THREAD)}
+    assert {r["name"] for r in mine if r["tid"] == WATCH_THREAD} == {CHIP}
     wait = next(r for r in mine if r["name"] == "engine.decode.wait")
     assert abs(wait["ts"] / 1e6 - time.time()) < 600
     assert wait["args"]["pages_table"] > 0
+
+
+# ---- the chip's row ---------------------------------------------------------
+
+
+def _programs(spans):
+    return sorted((s for s in spans if s["name"] == CHIP),
+                  key=lambda s: s["attrs"]["seq"])
+
+
+def test_every_dispatched_program_has_one_span_in_the_chips_order(served):
+    _, _, _, spans, _ = served
+    programs = _programs(spans)
+    dispatches = [s for s in spans if s["name"] == "engine.decode.dispatch"]
+    prefills = [s for s in spans if s["name"] == "engine.prefill"]
+    by_kind = {k: [p for p in programs if p["attrs"]["kind"] == k]
+               for k in ("decode", "prefill")}
+    assert len(by_kind["decode"]) == len(dispatches) > 0
+    assert len(by_kind["prefill"]) == len(prefills) > 0
+    assert len(programs) == len(dispatches) + len(prefills)
+    # Numbered as dispatched, and written in that order by ONE thread.
+    seqs = [p["attrs"]["seq"] for p in programs]
+    assert seqs == list(range(seqs[0], seqs[0] + len(seqs)))
+    assert [p["id"] for p in programs] == sorted(p["id"] for p in programs)
+    for before, p in zip([None] + programs, programs):
+        a = p["attrs"]
+        assert p["thread"] == WATCH_THREAD and p["parent"] is None
+        assert a["seen_by"] in ("watch", "fetch") and a["late_ns"] >= 0
+        assert (a["late_ns"] == 0) or a["seen_by"] == "fetch"
+        assert p["dur_ns"] >= 0 and a["starved_ns"] >= 0
+        # It starts when it was queued or when the one before it ended,
+        # whichever is later; the rest of the gap is the chip's hunger.
+        if before is not None:
+            assert p["t0_ns"] == max(a["queued_ns"], _end(before))
+            assert a["starved_ns"] == max(0, a["queued_ns"] - _end(before))
+            assert _end(before) <= p["t0_ns"]           # none overlaps
+    # `queued_ns` is the loop's stamp at the END of the program's dispatch
+    # (of a prefill group's three, the first's).
+    for p, d in zip(by_kind["decode"], dispatches):
+        assert d["t0_ns"] <= p["attrs"]["queued_ns"] <= _end(d)
+
+
+def test_a_chunks_span_ends_no_later_than_its_fetch(served):
+    _, _, _, spans, _ = served
+    chunks = [p for p in _programs(spans) if p["attrs"]["kind"] == "decode"]
+    waits = [s for s in spans if s["name"] == "engine.decode.wait"]
+    assert len(chunks) == len(waits)
+    for p, w in zip(chunks, waits):
+        assert _end(p) <= _end(w)
+        # ... and says of the chunk what its fetch says
+        assert (p["attrs"]["active"], p["attrs"]["steps"]) == \
+            (w["attrs"]["active"], w["attrs"]["steps"])
+    fetches = [s for s in spans if s["name"] == "engine.prefill.wait"]
+    groups = [p for p in _programs(spans) if p["attrs"]["kind"] == "prefill"]
+    for p, w in zip(groups, fetches):
+        assert _end(p) <= _end(w)
+
+
+def test_a_prefills_span_names_its_group_as_engine_prefill_does(served):
+    handles, _, _, spans, _ = served
+    by_id = {s["id"]: s for s in spans}
+    lengths = {h.rid: h.prompt_len for h in handles}
+    groups = [p for p in _programs(spans) if p["attrs"]["kind"] == "prefill"]
+    prefills = [s for s in spans if s["name"] == "engine.prefill"]
+    assert sorted(r for p in groups for r in p["attrs"]["rids"]) == \
+        sorted(lengths)
+    for p, host in zip(groups, prefills):        # both in dispatch order
+        a = p["attrs"]
+        assert {k: a[k] for k in ("bucket", "rows", "width", "computed")} \
+            == host["attrs"]
+        assert len(a["rids"]) == a["rows"]
+        assert set(a["rids"]) <= set(by_id[host["parent"]]["attrs"]["rids"])
+        assert a["prompt_tokens"] == sum(lengths[r] for r in a["rids"])
+        # queued inside its `engine.prefill`: when its first dispatch (of
+        # three) had returned
+        assert host["t0_ns"] <= a["queued_ns"] <= _end(host)
+
+
+def test_a_prefill_queued_under_a_chunk_starts_at_the_chunks_end(
+        model, engine):
+    eng, hold = _held(engine)
+    first_p, second_p = _prompt(71, 9), _prompt(72, 23)
+    first, stream, head = _with_its_first_chunk_held(eng, hold, first_p, 30)
+    second = eng.submit(second_p, SamplingParams(max_new_tokens=9))
+    assert hold.prefilled.wait(WAIT) and hold.held()
+    hold.release()
+    assert model.is_greedy(second_p, second.tokens())
+    assert model.is_greedy(first_p, head + list(stream))
+    eng.shutdown()                      # the watcher has written them all
+    programs = _programs(hold.spans())
+    behind, = [p for p in programs
+               if p["attrs"].get("rids") == [second.rid]]
+    chunk = programs[programs.index(behind) - 1]
+    admit, = [s for s in hold.spans("engine.admit")
+              if second.rid in s["attrs"]["rids"]]
+    assert admit["attrs"]["under_chunk"] is True
+    assert chunk["attrs"]["kind"] == "decode"
+    # Queued while the chunk was on the chip: the chip went from one to
+    # the other, and was never without a program between them.
+    assert behind["attrs"]["queued_ns"] < _end(chunk) == behind["t0_ns"]
+    assert behind["attrs"]["starved_ns"] == 0
+    assert behind["attrs"]["prompt_tokens"] == len(second_p)
+
+
+def test_the_loop_wakes_on_its_own_programs_end_among_those_watched(
+        model, engine):
+    """The watcher sees every program end; the loop, waiting behind a
+    prefill in flight, is not let go by the end of the chunk before it."""
+    eng, hold = _held(engine)
+    prompts = [_prompt(73, 9), _prompt(74, 23), _prompt(75, 14)]
+    eng._prefill_bytes.update({(1, 16): 40, (1, 32): 60, (3, 32): 100})
+    first, stream, head = _with_its_first_chunk_held(
+        eng, hold, prompts[0], 30)
+    hold.hold_prefills = True
+    second = eng.submit(prompts[1], SamplingParams(max_new_tokens=9))
+    assert hold.prefilled.wait(WAIT)
+    hold.hold_prefills = False
+    hold.gates[0].set()                     # the chunk is done
+    held_prefill, = hold.prefills_held
+    assert held_prefill.watched.wait(WAIT)  # the watcher is at the prefill
+    hold.prefilled.clear()
+    third = eng.submit(prompts[2], SamplingParams(max_new_tokens=7))
+    # Admitted behind the prefill, which is still on the chip: the loop
+    # was waiting for THAT program, with the chunk's end long announced.
+    assert hold.prefilled.wait(WAIT) and hold.held()
+    hold.release()
+    for p, out in zip(prompts, (head + list(stream), second.tokens(),
+                                third.tokens())):
+        assert model.is_greedy(p, out)
+    eng.shutdown()
+    programs = _programs(hold.spans())
+    of = {tuple(p["attrs"].get("rids", ())): p for p in programs}
+    held_span = of[(second.rid,)]
+    chunk = programs[programs.index(held_span) - 1]
+    admit, = [s for s in hold.spans("engine.admit")
+              if third.rid in s["attrs"]["rids"]]
+    assert _end(chunk) <= admit["t0_ns"] <= _end(admit) <= _end(held_span)
+    assert of[(third.rid,)]["attrs"]["seq"] == held_span["attrs"]["seq"] + 1
+    assert eng._chip_done == programs[-1]["attrs"]["seq"]
+
+
+def test_a_recorded_span_takes_the_end_it_is_given():
+    t0 = time.monotonic_ns()
+    tracing.record_span("given-end", t0 - 5000, t1_ns=t0 - 2000, a=1)
+    tracing.record_span("ends-now", t0 - 5000)
+    given, now = tracing.recent_spans()[-2:]
+    assert (given["name"], given["t0_ns"], given["dur_ns"]) == \
+        ("given-end", t0 - 5000, 3000)
+    assert given["attrs"] == {"a": 1} and given["parent"] is None
+    assert now["name"] == "ends-now" and now["dur_ns"] >= 5000
+
+
+def test_timeline_chip_prints_the_chips_ledger_of_a_session(
+        served, capsys, monkeypatch):
+    _, _, _, spans, session = served
+    tracing.flush_spans()
+    from ray_tpu import scripts
+
+    monkeypatch.setattr("sys.argv", ["ray_tpu", "timeline", "--chip",
+                                     str(session)])
+    with pytest.raises(SystemExit) as done:
+        scripts.main()
+    assert done.value.code == 0
+    out = capsys.readouterr().out
+    assert out == timeline.chip_report(str(session)) + "\n"
+    programs = _programs(spans)
+    assert f"{len(programs)} programs" in out
+    lines = out.splitlines()
+    # The four parts ISSUE 55 names, and the programs that ran longest
+    # over their like; each a heading and its rows.
+    parts = [i for i, line in enumerate(lines) if line.endswith(":")]
+    assert [" ".join(lines[i].split()[:2]) for i in parts] == [
+        "chip seconds", "starved seconds", "longest starved", "late_ns (the",
+        "longest programs"]
+    kinds = {line.split()[0]: int(line.split()[3])
+             for line in lines[parts[0] + 1:parts[1]]}
+    assert kinds == {k: sum(p["attrs"]["kind"] == k for p in programs)
+                     for k in ("decode", "prefill")}
+    # Every starved nanosecond lies under a label: a span of the loop's,
+    # the deepest over it, or none.
+    starved = [p["attrs"]["starved_ns"] for p in programs]
+    total, intervals = lines[parts[1]].split("(")[1].split(" s in ")
+    assert float(total) == pytest.approx(sum(starved) / 1e9, abs=1e-3)
+    assert int(intervals.split()[0]) == sum(ns > 0 for ns in starved)
+    under = {line.split("  ")[1].strip(): float(line.split()[-2])
+             for line in lines[parts[1] + 1:parts[2]]}
+    # (a label under half a millisecond is left out, the rest rounded)
+    assert 0 < sum(under.values()) <= float(total) + 1e-3 * len(under)
+    assert all(k == timeline.NO_SPAN or k.startswith("engine.")
+               for k in under)
+    longest = lines[parts[2] + 1:parts[3]]
+    assert 1 <= len(longest) <= timeline.LONGEST_SHOWN
+    assert all(" under " in line and " before " in line for line in longest)
+    seen = {line.split()[0] for line in lines[parts[3] + 1:parts[4]]}
+    assert seen == {p["attrs"]["seen_by"] for p in programs}
+    slowest = lines[parts[4] + 1:]
+    assert 1 <= len(slowest) <= timeline.LONGEST_SHOWN
+    assert all(" against " in line and " seq " in line for line in slowest)
+    # Clipped to an interval that holds nothing of it: nothing to say.
+    assert "no chip.program" in timeline.chip_report(
+        str(session), interval=(0.0, 1.0))
+    assert "no chip.program" in timeline.chip_report(str(session / "none"))
 
 
 def test_the_span_file_appears_in_the_session_and_stays_under_its_cap(

@@ -40,9 +40,6 @@ class Spans:
 
 def _sambay(got, spans, lengths, new, repeats):
     assert got["state_slots_reset"] == len(lengths)
-    assert got["ring_tokens"] == 0
-    # the one paged layer is read by the full layer and the cross layer
-    assert got["shared_pool_pages_live"] == 2 * got["paged_pages_live"]
     assert got["paged_pages_live"] > 0
 
 
@@ -50,7 +47,6 @@ def _granite_hybrid(got, spans, lengths, new, repeats):
     # the streams are not echoes of their input: the layers decide
     assert np.mean(repeats) < 0.2
     assert got["state_slots_reset"] == len(lengths)
-    assert got["ring_tokens"] == 0
     assert got["paged_pages_live"] > 0
     # what the fixed state costs, for a reader that knows no model:
     # six Mamba-2 layers of (3, 160) conv inputs and (4, 32, 16) state
