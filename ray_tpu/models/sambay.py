@@ -22,17 +22,23 @@ L/2 = 16):
 
 and every layer is x += Mix_i(LN(x)); x += MLP(LN(x)).
 
-So a served sequence has THREE kinds of state (`prefill` returns them,
-`decode` advances them, `serve/llm_families.py` tells the engine):
-pages of one layer's K/V, read by every cross layer; a ring of the last
-`window` tokens' K/V for each window layer; and (conv window, scan
-state) for each Mamba layer.  Prefill does less than a forward pass,
-exactly: a cross-decoder layer at a prompt position feeds nothing but
-that position's own logits, and of the full-attention layer nothing but
-its K and V is read at another position.  So `prefill` runs layers
-0 .. L/2 and the full layer's K/V projection over the prompt, and the
-rest of the full layer and the cross-decoder at each row's last token
-only: one query a row.
+So a served sequence has THREE kinds of state (`decode` advances them,
+`serve/llm_families.py` tells the engine): pages of one layer's K/V, read
+by every cross layer; a ring of the last `window` tokens' K/V for each
+window layer; and (conv window, scan state) for each Mamba layer.  A
+prompt's prefill does less than a forward pass, exactly: a cross-decoder
+layer at a prompt position feeds nothing but that position's own logits,
+and of the full-attention layer nothing but its K and V is read at another
+position.  And it is computed a BLOCK of positions at a time, by two
+programs that the family's class dispatches from the host
+(`SambaYServing.prefill_from_host`): `prompt_block` runs layers 0 .. L/2
+and the full layer's K/V projection over one block after the state the
+block before left (the same three kinds of state, and the stream and the
+memory at each row's last token once it has passed), as many times as the
+longest prompt has blocks; `prompt_tail` runs the rest of the full layer
+and the cross-decoder at each row's last token only: one query a row.  No
+program loops over blocks, and what lies past a prompt's last block in
+its bucket is not computed.
 
 Differential attention (Ye et al. 2024) on ordinary attention kernels.
 Heads of 64 pair up, (2p, 2p+1) -> pair p; a pair's output is
@@ -96,7 +102,8 @@ class SambaYConfig:
     expand: int = 2
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
-    # full-attention prefill: "flash" (pallas) or "reference" (plain jnp)
+    # the full layer over whole rows (`__call__`): "flash" (pallas) or
+    # "reference" (plain jnp)
     attention: str = "flash"
 
     @property
@@ -260,11 +267,15 @@ def masked_attention(q, k, v, mask, sm_scale: float, precise: bool = False):
 
 
 def window_attention(q, k, v, window: int, sm_scale: float,
-                     precise: bool = False):
+                     precise: bool = False, before=None, start=0):
     """Causal attention in which a query sees the last `window` keys, its
     own among them.  Blocks of `window` queries against their own and the
     previous block of keys, a block at a time (`lax.map`), so the scores
-    in flight are (B, Hq, window, 2 * window) whatever the length."""
+    in flight are (B, Hq, window, 2 * window) whatever the length.
+    `before` (k, v) (B, Hkv, window, D): the `window` keys and values that
+    precede these positions, which begin at `start` (a multiple of
+    `window`, may be traced; at 0 nothing precedes and `before` is not
+    read)."""
     B, Hq, S, D = q.shape
     nb = -(-S // window)
     pad = nb * window - S
@@ -275,24 +286,27 @@ def window_attention(q, k, v, window: int, sm_scale: float,
     def blocks(a):      # (B, H, nb * window, D) -> (nb, B, H, window, D)
         return a.reshape(B, a.shape[1], nb, window, D).transpose(2, 0, 1, 3, 4)
 
-    def with_previous(a):
-        prev = jnp.concatenate([jnp.zeros_like(a[:1]), a[:-1]], axis=0)
+    def with_previous(a, first):
+        first = jnp.zeros_like(a[:1]) if first is None \
+            else first.astype(a.dtype)[None]
+        prev = jnp.concatenate([first, a[:-1]], axis=0)
         return jnp.concatenate([prev, a], axis=3)   # (nb, B, H, 2w, D)
 
     qpos = jnp.arange(window)[:, None] + window
     kpos = jnp.arange(2 * window)[None, :]
     seen = (kpos <= qpos) & (kpos > qpos - window)
+    kb, vb = before if before is not None else (None, None)
 
     def one(args):
         blk, qb, kb, vb = args
         # the first block's "previous" keys are padding, not tokens
-        mask = seen & ((blk > 0) | (kpos >= window))
+        mask = seen & ((blk > 0) | (start > 0) | (kpos >= window))
         return masked_attention(qb, kb, vb, mask, sm_scale, precise)
 
     with jax.named_scope("window_attention"):
         out = jax.lax.map(one, (jnp.arange(nb), blocks(q),
-                                with_previous(blocks(k)),
-                                with_previous(blocks(v))))
+                                with_previous(blocks(k), kb),
+                                with_previous(blocks(v), vb)))
     out = out.transpose(1, 2, 0, 3, 4).reshape(B, Hq, nb * window, D)
     return out[:, :, :S]
 
@@ -448,35 +462,44 @@ class Mamba(nn.Module):
             self.dt_proj(r, precise).astype(jnp.float32))
         return delta, b_sel, c_sel
 
-    def __call__(self, h, last_idx=None, precise: bool = False):
+    def __call__(self, h, last_idx=None, precise: bool = False, state=None,
+                 start=0):
         """h (B, S, d) -> (out, y, state): y (B, S, E) is the scan output
         before the gate (the memory, where this is the memory layer);
         state = (conv window, scan state) after each row's `last_idx`
-        (after its last position where None)."""
+        (after its last position where None).  `state`: the same before
+        these positions, which begin at `start` (may be traced; nothing
+        before them where None).  A row whose `last_idx` lies before
+        `start` keeps its state."""
         c = self.cfg
         B, S, _ = h.shape
         K = c.d_conv
         keep = (lambda a: a) if precise else (lambda a: a.astype(c.dtype))
         u_in, z = jnp.split(self.in_proj(h, precise), 2, axis=-1)
-        u_pad = jnp.pad(u_in, ((0, 0), (K - 1, 0), (0, 0)))
+        conv, s_prev = state if state is not None else (
+            jnp.zeros((B, K - 1, c.d_inner), u_in.dtype), None)
+        u_pad = jnp.concatenate([conv.astype(u_in.dtype), u_in], axis=1)
         f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
         u = sum(f32(u_pad[:, i: i + S]) * f32(self.conv_w[i])
                 for i in range(K))
         u = keep(jax.nn.silu(u + f32(self.conv_b)))
         delta, b_sel, c_sel = self._selective(u, precise)
-        if last_idx is None:
-            last_idx = jnp.full((B,), S - 1, jnp.int32)
+        # each row's last position among these S: -1 where it lies before
+        # them, S - 1 where after
+        last = jnp.full((B,), S - 1, jnp.int32) if last_idx is None \
+            else jnp.clip(last_idx - start, -1, S - 1)
         # A position past a row's last token leaves the state alone.
         delta = jnp.where(jnp.arange(S)[None, :, None]
-                          <= last_idx[:, None, None], delta, 0.0)
+                          <= last[:, None, None], delta, 0.0)
         with jax.named_scope("selective_scan"):
             y, s_last = chunked_selective_scan(
-                delta, u, b_sel, c_sel, -jnp.exp(self.a_log))
+                delta, u, b_sel, c_sel, -jnp.exp(self.a_log), s_prev)
         y = keep(y + self.d_skip * u.astype(jnp.float32))
         # padded position p holds input p - (K - 1): the K - 1 inputs that
-        # end at last_idx are padded positions last_idx + 1 .. + K - 1
+        # end at `last` are padded positions last + 1 .. + K - 1 (at -1:
+        # the window handed in)
         window = jnp.take_along_axis(
-            u_pad, (last_idx[:, None] + 1 + jnp.arange(K - 1))[:, :, None],
+            u_pad, (last[:, None] + 1 + jnp.arange(K - 1))[:, :, None],
             axis=1).astype(c.dtype)
         return (self.out_proj(y * jax.nn.silu(f32(z)), precise), y,
                 (window, s_last))
@@ -576,62 +599,73 @@ class SambaYModel(nn.Module):
         self.layers = [Layer(c, i) for i in range(c.n_layers)]
         self.norm = LayerNorm(c.norm_eps)
 
-    # ---- the self-decoder over whole rows --------------------------------
+    # ---- layers 0 .. L/2 over positions after a state ----------------------
 
-    def _self_decoder(self, tokens, last_idx=None, precise: bool = False):
-        """Layers 0 .. L/2+1 over (B, S) tokens -> x, the memory (B, S, E),
-        the cache's K and V (B, Hkv/2, S, 2 Dh), and the per-sequence
-        state at `last_idx`: Mamba states and window rings, by layer.
-        Given `last_idx` (a prompt), x and the memory are (B, 1, .), at
-        that position alone: of the full layer nothing but K and V is
-        read at another, so it attends, projects out and feeds forward
-        for ONE query a row, every product with two terms."""
+    def fresh_state(self, B: int):
+        """What `_carried` and `prompt_block` carry, before a row's first
+        position: {"mamba": [(conv window, scan state)], "rings": [(k, v)]
+        (B, Hkv/2, window, 2 Dh) in slot order, "x" (B, d), "memory"
+        (B, E): the stream after layer L/2 and the memory at each row's
+        last token}, all zeros."""
         c = self.cfg
-        B, S = tokens.shape
-        whole = last_idx is None
-        if whole:
-            last_idx = jnp.full((B,), S - 1, jnp.int32)
-        x = self.embed(tokens).astype(jnp.float32)
+        ring = (B, c.kv_pairs, c.window, 2 * c.head_dim)
+        return {
+            "mamba": [(jnp.zeros((B, c.d_conv - 1, c.d_inner), c.dtype),
+                       jnp.zeros((B, c.d_state, c.d_inner), jnp.float32))
+                      for _ in c.layers_of("mamba")],
+            "rings": [(jnp.zeros(ring, c.dtype), jnp.zeros(ring, c.dtype))
+                      for _ in c.layers_of("window")],
+            "x": jnp.zeros((B, c.d_model), jnp.float32),
+            "memory": jnp.zeros((B, c.d_inner), c.dtype)}
+
+    def _carried(self, x, start, last_idx, state, precise: bool = False):
+        """Layers 0 .. L/2, the layers that carry state along a row, over
+        x (B, S, d) at positions `start` .. (a multiple of `window`, may be
+        traced) after `state` -> x, the memory (B, S, E), and the state
+        after them.  A ring kept in slot order IS the window before these
+        positions where a row goes on through them, so a window layer
+        attends over it and its own keys.  A row's state stops at its
+        `last_idx`: one that ended before `start` keeps it, and its x and
+        memory at that token are kept as they were found."""
+        c = self.cfg
+        B, S, _ = x.shape
+        # ring slot r after these positions: the newest token t up to the
+        # row's last one here with t % window == r, or what the ring held
+        slot_pos = _ring_slots(jnp.minimum(last_idx, start + S - 1),
+                               c.window) - start                # (B, W)
+        at = jnp.maximum(slot_pos, 0)[:, None, :, None]
+        here = (slot_pos >= 0)[:, None, :, None]
         mamba, rings = [], []
-        memory = cache = None
-        slot_pos = _ring_slots(last_idx, c.window)            # (B, W)
-        for i in range(c.n_self):
-            layer, kind = self.layers[i], c.kind(i)
-            if kind == "mamba":
-                x, memory, state = layer.mix(
-                    x, lambda h: layer.mamba(h, last_idx, precise), precise)
-                mamba.append(state)
+        memory = None
+        for i in range(c.n_self - 1):
+            layer = self.layers[i]
+            if c.kind(i) == "mamba":
+                before = state["mamba"][len(mamba)]
+                x, memory, after = layer.mix(x, lambda h: layer.mamba(
+                    h, last_idx, precise, before, start), precise)
+                mamba.append(after)
                 continue
-            attn = layer.attn
-            if kind == "full" and not whole:
-                cache = k, v = attn.keys_values(layer.input_norm(x), precise)
-                x, memory = (jnp.take_along_axis(
-                    a, last_idx[:, None, None], axis=1) for a in (x, memory))
-                x, = layer.mix(x, lambda h: (attn.combine(masked_attention(
-                    attn.queries(h, True), k, v, _up_to(last_idx, S),
-                    attn.sm_scale, True), True),), True)
-                continue
+            attn, before = layer.attn, state["rings"][len(rings)]
 
             def mixer(h):
                 q, k, v = attn.project(h, precise)
-                if kind == "window":
-                    o = window_attention(q, k, v, c.window, attn.sm_scale,
-                                         precise)
-                else:
-                    o = causal_attention(q, k, v, attn.sm_scale, c.attention,
-                                         precise)
+                o = window_attention(q, k, v, c.window, attn.sm_scale,
+                                     precise, before, start)
                 return attn.combine(o, precise), k, v
 
             x, k, v = layer.mix(x, mixer, precise)
-            if kind == "full":
-                cache = (k, v)
-            else:
-                at = jnp.maximum(slot_pos, 0)[:, None, :, None]
-                held = (slot_pos >= 0)[:, None, :, None]
-                rings.append(tuple(
-                    jnp.where(held, jnp.take_along_axis(a, at, axis=2), 0)
-                    for a in (k, v)))
-        return x, memory, cache, {"mamba": mamba, "rings": rings}
+            rings.append(tuple(
+                jnp.where(here, jnp.take_along_axis(a, at, axis=2), old)
+                for a, old in zip((k, v), before)))
+        last = jnp.clip(last_idx - start, 0, S - 1)[:, None, None]
+        ends_here = ((last_idx >= start) & (last_idx < start + S))[:, None]
+        return x, memory, {
+            "mamba": mamba, "rings": rings,
+            "x": jnp.where(ends_here, jnp.take_along_axis(
+                x, last, axis=1)[:, 0], state["x"]),
+            "memory": jnp.where(ends_here, jnp.take_along_axis(
+                memory, last, axis=1)[:, 0].astype(c.dtype),
+                state["memory"])}
 
     def _head(self, x, precise: bool):
         with jax.named_scope("head"):
@@ -661,30 +695,64 @@ class SambaYModel(nn.Module):
     def __call__(self, tokens, precise: bool = False):
         """Whole forward: (B, S) -> float32 logits (B, S, V), every layer
         at every position."""
-        x, memory, (k, v), _ = self._self_decoder(tokens, None, precise)
-        S = tokens.shape[1]
+        c = self.cfg
+        B, S = tokens.shape
+        x, memory, _ = self._carried(
+            self.embed(tokens).astype(jnp.float32), 0,
+            jnp.full((B,), S - 1, jnp.int32), self.fresh_state(B), precise)
+        layer = self.layers[c.n_self - 1]
+        attn = layer.attn
+
+        def mixer(h):
+            q, k, v = attn.project(h, precise)
+            return attn.combine(causal_attention(
+                q, k, v, attn.sm_scale, c.attention, precise), precise), k, v
+
+        x, k, v = layer.mix(x, mixer, precise)
         mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
         return self._cross_decoder(x, memory, k, v, mask, precise)
 
-    def prefill(self, tokens, last_idx):
-        """Right-padded rows (B, S) with each row's last token at
-        `last_idx` -> float32 logits (B, V) at that token, and the state
-        a decode continues from: {"mamba": [(conv, scan)], "rings":
-        [(k, v)] (B, Hkv/2, window, 2 Dh), "cache": (k, v) over the whole
-        row}.  The full layer but for its K and V, and the cross-decoder,
-        run at the last token only: no `flash_attention` call here."""
-        x, memory, (k, v), state = self._self_decoder(tokens, last_idx)
-        # one token a row: every product with two terms
-        logits = self._cross_decoder(
-            x, memory, k, v, _up_to(last_idx, tokens.shape[1]), True)
-        return logits[:, 0], dict(state, cache=(k, v))
+    # ---- a prompt, a block of positions a program -------------------------
+
+    def prompt_block(self, tokens, start, last_idx, state):
+        """One block of right-padded prompts: tokens (B, block) at positions
+        `start` .. (a traced scalar, a multiple of `window`: every block of
+        a width is ONE program), each row's last token at `last_idx`,
+        `state` as the block before left it (`fresh_state` before the
+        first) -> the state after the block, and the full layer's K and V
+        of the block, (B, Hkv/2, block, 2 Dh): its cache.  A row that ended
+        in an earlier block computes what nothing reads."""
+        layer = self.layers[self.cfg.n_self - 1]
+        x, _, state = self._carried(
+            self.embed(tokens).astype(jnp.float32), start, last_idx, state)
+        return state, layer.attn.keys_values(layer.input_norm(x))
+
+    def prompt_tail(self, state, k, v, last_idx):
+        """After a prompt's last block: `state` as `prompt_block` left it,
+        K and V of the full layer over the rows (B, Hkv/2, S, 2 Dh) ->
+        float32 logits (B, V) at each row's last token.  Of the full layer
+        nothing but K and V is read at another position, and a cross layer
+        feeds nothing but its own position's logits: so the full layer
+        attends, projects out and feeds forward for ONE query a row under
+        `<= last_idx`, and the cross-decoder and the head run at that
+        token; one token a row, so every product with two terms.  No
+        `flash_attention` call here."""
+        layer = self.layers[self.cfg.n_self - 1]
+        attn = layer.attn
+        seen = _up_to(last_idx, k.shape[2])
+        x, = layer.mix(
+            state["x"][:, None], lambda h: (attn.combine(masked_attention(
+                attn.queries(h, True), k, v, seen, attn.sm_scale, True),
+                True),), True)
+        return self._cross_decoder(x, state["memory"][:, None], k, v, seen,
+                                   True)[:, 0]
 
     # ---- one token a sequence ---------------------------------------------
 
     def decode(self, token, state, table, length, live=None):
         """token (B,), `length` (B,) tokens already cached -> float32
         logits (B, V) and the state with this token in it.  state:
-        {"mamba", "rings"} as `prefill` gives them (batch-first) and
+        {"mamba", "rings"} as `prompt_block` leaves them (batch-first) and
         "pool": (k_pool, v_pool) (P, Hkv/2, page, 2 Dh) under `table`
         (B, NP).  A row where `live` is False keeps rings and Mamba state
         (its pool write lands where its next live step writes again)."""

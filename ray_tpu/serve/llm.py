@@ -301,7 +301,8 @@ class LLMEngine:
         # ---- compiled programs ------------------------------------------
         # The family's functions under the engine's own program names (a
         # profile is read by them): `prefill_one` and `prefill_many` are
-        # one function at two widths.
+        # one function at two widths (called for no family that prefills
+        # from the host: `_dispatch_prefill`).
 
         import functools
 
@@ -415,6 +416,9 @@ class LLMEngine:
             return family.prefill(params, tokens, last_idx)
 
         self._prefill_many = prefill_many
+        # ... or the family's own loop of dispatches over a prompt's blocks,
+        # in place of both (`llm_families.py`: `prefill_from_host`).
+        self._prefill_from_host = getattr(family, "prefill_from_host", None)
 
         # The rows' fresh state into the engine's: paged parts to
         # `page_ids` (W, n), fixed parts to `slots` (W,).
@@ -810,9 +814,11 @@ class LLMEngine:
                 group = group[len(chunk):]
                 # A request alone keeps the single-sequence program.
                 W = 1 if len(chunk) == 1 else width
+                lengths = [len(c[2]) for c in chunk]
                 what = dict(bucket=bucket, rows=len(chunk), width=W,
-                            computed=self._prefill_computed(
-                                bucket, [len(c[2]) for c in chunk]))
+                            computed=self._prefill_computed(bucket, lengths))
+                if self._prefill_from_host is not None:
+                    what["blocks"] = self.family.prompt_blocks(lengths)
                 with tracing.span("engine.prefill", **what):
                     flight = self._dispatch_prefill(chunk, what)
                     if flight is None:
@@ -850,9 +856,11 @@ class LLMEngine:
 
     def _dispatch_prefill(self, chunk: list, what: dict):
         """One prefill dispatch for `chunk` (at most `what["width"]`
-        requests of the bucket `what["bucket"]`, pages reserved): the rows'
-        state into pages and slots and one sampling dispatch, nothing
-        fetched; the three are ONE program of the chip's ledger
+        requests of the bucket `what["bucket"]`, pages reserved): the
+        prefill (one program, or where the family prefills from the host,
+        `prefill_from_host`, its own programs queued back to back), the
+        rows' state into pages and slots and one sampling dispatch, nothing
+        fetched; together they are ONE program of the chip's ledger
         (`_on_chip`), which says of it what `what` says of its
         `engine.prefill`. Returns what `_finish_prefill` takes, or None
         where the dispatch failed (and its requests with it)."""
@@ -882,12 +890,23 @@ class LLMEngine:
             temps[r] = handle.sampling.temperature
             topks[r] = handle.sampling.top_k
             topps[r] = handle.sampling.top_p
-        prefill = self._prefill_one if W == 1 else self._prefill_many
         try:
-            last_logits, fresh, *counts = prefill(
-                self.params, jnp.asarray(tokens), jnp.asarray(last_idx))
-            # (the chip, where it had nothing queued, is at work from here)
-            queued_ns = time.monotonic_ns()
+            if self._prefill_from_host is not None:
+                # The family's own dispatches, on this thread: a block of
+                # the prompts' positions a program (`blocks` of them). The
+                # first, a state of zeros, is queued within the call's first
+                # half millisecond: the chip is at work from here.
+                queued_ns = time.monotonic_ns()
+                last_logits, fresh, *counts = self._prefill_from_host(
+                    self.params, tokens, last_idx)
+            else:
+                prefill = self._prefill_one if W == 1 \
+                    else self._prefill_many
+                last_logits, fresh, *counts = prefill(
+                    self.params, jnp.asarray(tokens), jnp.asarray(last_idx))
+                # (the chip, where it had nothing queued, is at work from
+                # here)
+                queued_ns = time.monotonic_ns()
             fresh = self._device_handoff(fresh)
             self._pools = self._write_prompt_pages(
                 self._pools, fresh, jnp.asarray(slots),

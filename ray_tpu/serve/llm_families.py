@@ -53,6 +53,22 @@ A family object answers, for its configuration:
                                 the bucket (host arithmetic; absent: every
                                 row's whole bucket).  `engine.prefill`
                                 carries it as `computed`.
+    prefill_from_host(params, tokens, last_idx)
+                                (optional, and then INSTEAD of `prefill`)
+                                the same from the host's numpy arrays, by
+                                programs the family jits and dispatches
+                                itself on the engine's thread, fetching
+                                nothing: a family that can carry its state
+                                along a prompt computes it a block of
+                                positions a dispatch, as many as the longest
+                                row has, and no program loops over blocks.
+                                Its first dispatch comes at once (the
+                                engine takes the group to be on the chip
+                                from the call).  With it goes
+                                `prompt_blocks(lengths)`, the dispatches a
+                                group takes (host arithmetic): the group's
+                                spans carry it as `blocks`.  The engine jits
+                                no prefill of such a family.
     step_counters, prefill_counters     (optional) what `decode` and
                                 `prefill` count on the device: ((name,
                                 "sum" | "max"), ...).  Where a family names
@@ -74,6 +90,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.models.granite_hybrid import (GraniteHybridConfig,
                                            GraniteHybridModel)
@@ -183,6 +200,32 @@ def _rows_under_the_token_cap(bucket: int, max_batch: int,
     return max(1, min(BATCH_PREFILL_WIDTH, max_batch, tokens // bucket))
 
 
+def sambay_block(cfg: SambaYConfig) -> int:
+    """Positions of one dispatch of a `sambay` prompt's carried layers
+    (`SambaYServing.prefill_from_host`): whole windows, so that a ring in
+    slot order is the window before the next block.  ONE window: device ms
+    of the sequence a prompt takes (blocks, then the tail) at published
+    widths, from a profiler trace (`scripts/tpu_kernel_sweep.py --prefill
+    sambay`, TPU v5 lite, PR 56), the longest row at 75% | 100% of its
+    bucket, beside the parent's whole-bucket program at any fill:
+
+        rows x bucket    whole    1 window (512)   2 (1,024)      4 (2,048)
+        1 x 2,048         67.6    50.4 |  65.3    66.7 |  66.7    68.3 |  68.3
+        1 x 4,096        141.6    95.3 | 125.2    97.3 | 127.9   131.0 | 131.0
+        1 x 8,192        297.0   185.1 | 245.0   189.3 | 250.5   193.9 | 256.7
+        1 x 16,384       584.0   365.0 | 484.7   373.4 | 495.8   382.6 | 508.1
+        2 x 8,192        573.8   383.3 | 508.9   388.7 | 516.1   407.3 | 541.0
+        one block         -      14.96 (2 rows: 31.41)   30.61    62.76
+
+    A position costs no less in a longer block (29.2 / 29.9 / 30.6 us), a
+    longer block computes more of what no prompt holds (a prompt of 513
+    tokens pays for two blocks of 512 or one of 2,048), and the host's
+    dispatches cost the chip nothing it can see (the sequence's wall time
+    is 5-6 ms over its device time at every block and length: the tail's
+    fetch).  The tail takes 5.4-6.2 ms."""
+    return cfg.window
+
+
 class SambaYServing:
     """`models/sambay.py`: pages of ONE layer's K/V (read by every cross
     layer), a ring of `window` tokens for each window layer, and (conv
@@ -198,6 +241,30 @@ class SambaYServing:
         self.pool_readers = 1 + len(cfg.layers_of("cross"))
         self.state_bytes_per_slot = _fixed_bytes_per_slot(
             self, lambda s: (s["rings"], s["mamba"]))
+        # Positions of one dispatch of a prompt's carried layers.
+        self.block = sambay_block(cfg)
+        model = self.model
+
+        def fresh(W):
+            kv = (W, cfg.kv_pairs, self.block, 2 * cfg.head_dim)
+            return model.fresh_state(W), (jnp.zeros(kv, cfg.dtype),
+                                          jnp.zeros(kv, cfg.dtype))
+
+        def prompt_block(params, tokens, start, last_idx, state):
+            return model.apply(params, tokens, start, last_idx, state,
+                               method=SambaYModel.prompt_block)
+
+        def prompt_tail(bucket, params, state, kv, last_idx):
+            k, v = (jnp.concatenate(a, axis=2)[:, :, :bucket]
+                    for a in zip(*kv))
+            return model.apply(params, state, k, v, last_idx,
+                               method=SambaYModel.prompt_tail), (k, v)
+
+        # a group's state before its first block, and a block of K and V
+        # that no row reached
+        self._fresh = jax.jit(fresh, static_argnums=0)
+        self._block = jax.jit(prompt_block, donate_argnums=4)
+        self._tail = jax.jit(prompt_tail, static_argnums=0)
 
     def prefill_width(self, bucket: int, max_batch: int) -> int:
         return _rows_under_the_token_cap(bucket, max_batch)
@@ -207,22 +274,52 @@ class SambaYServing:
 
     def init_state(self, max_batch: int, num_pages: int, page_size: int):
         c = self.cfg
-        B, D2 = max_batch, 2 * c.head_dim
         # (every leaf a buffer of its own: the state is donated)
         pool = lambda: jnp.zeros(  # noqa: E731
-            (num_pages, c.kv_pairs, page_size, D2), c.dtype)
-        ring = lambda: jnp.zeros(  # noqa: E731
-            (B, c.kv_pairs, c.window, D2), c.dtype)
-        return {
-            "pool": (pool(), pool()),
-            "rings": [(ring(), ring()) for _ in c.layers_of("window")],
-            "mamba": [(jnp.zeros((B, c.d_conv - 1, c.d_inner), c.dtype),
-                       jnp.zeros((B, c.d_state, c.d_inner), jnp.float32))
-                      for _ in c.layers_of("mamba")]}
+            (num_pages, c.kv_pairs, page_size, 2 * c.head_dim), c.dtype)
+        # fixed per slot: what a prompt's blocks carry along a row
+        rows = self.model.fresh_state(max_batch)
+        return {"pool": (pool(), pool()), "rings": rows["rings"],
+                "mamba": rows["mamba"]}
 
-    def prefill(self, params, tokens, last_idx):
-        return self.model.apply(params, tokens, last_idx,
-                                method=SambaYModel.prefill)
+    def prompt_blocks(self, lengths) -> int:
+        """Blocks the longest of a group's prompts has: how many times
+        `prefill_from_host` dispatches the block's program."""
+        return -(-max(lengths) // self.block)
+
+    def prefill_computed(self, bucket: int, lengths) -> int:
+        return len(lengths) * self.prompt_blocks(lengths) * self.block
+
+    def prefill_from_host(self, params, tokens, last_idx):
+        """The loop over a prompt's blocks, on the host: `tokens` (W,
+        bucket) and `last_idx` (W,) as numpy arrays.  ONE program a width,
+        "a block of positions through layers 0 .. L/2 after the state
+        before it" (`SambaYModel.prompt_block`; the block's first position
+        is a traced scalar), dispatched as many times as the longest row
+        has blocks, each taking the state the one before returned
+        (donated); then ONE program a (width, bucket) that holds no such
+        layer (`prompt_tail` over the blocks' K and V laid end to end).
+        Nothing is fetched in between, and the first dispatch (the state
+        before the first block: zeros) is made at once."""
+        W, bucket = tokens.shape
+        state, nothing = self._fresh(W)
+        block = self.block
+        n = self.prompt_blocks(last_idx + 1)
+        padded = np.zeros((W, n * block), np.int32)
+        padded[:, :min(bucket, n * block)] = tokens[:, :n * block]
+        last = jnp.asarray(last_idx)
+        kv = []
+        for b in range(n):
+            state, got = self._block(
+                params, padded[:, b * block:(b + 1) * block],
+                np.int32(b * block), last, state)
+            kv.append(got)
+        # (what lies past the longest row's last block: zeros, which the
+        # tail's mask hides and `write_prompt` sends to the dummy page)
+        kv += [nothing] * (-(-bucket // block) - n)
+        logits, cache = self._tail(bucket, params, state, kv, last)
+        return logits, {"mamba": state["mamba"], "rings": state["rings"],
+                        "cache": cache}
 
     def write_prompt(self, state, fresh, slots, page_ids):
         flat = page_ids.reshape(-1)

@@ -190,8 +190,11 @@ class _LoopRows:
 def _shape(program: dict) -> str:
     """What makes two programs comparable in length."""
     a = program["attrs"]
-    return f"prefill of {a['computed']} positions" \
-        if a["kind"] == "prefill" else a["kind"]
+    if a["kind"] != "prefill":
+        return a["kind"]
+    # (`blocks`: a family that dispatches a prompt a block at a time)
+    return f"prefill of {a['computed']} positions" + (
+        f" in {a['blocks']} blocks" if "blocks" in a else "")
 
 
 def _quantile(values: list, q: float) -> float:

@@ -466,6 +466,10 @@ def _device_ms(trace_dir: str) -> dict:
     return out
 
 
+def _median(values):
+    return sorted(values)[len(values) // 2]
+
+
 def _traced_ms(fns: dict, args) -> dict:
     """{name: (median ms of the Pallas calls, of the whole run, of each
     call)} of jitted functions (compiled already) over `args`, GMM_REPS
@@ -480,12 +484,9 @@ def _traced_ms(fns: dict, args) -> dict:
                     got = f(*args)      # (one result held)
                 _sync(got)
         ms = _device_ms(trace_dir)
-    def median(values):
-        return sorted(values)[len(values) // 2]
-
-    return {name: (median([r[0] for r in ms[name]]),
-                   median([r[1] for r in ms[name]]),
-                   tuple(map(median, zip(*(r[2] for r in ms[name])))))
+    return {name: (_median([r[0] for r in ms[name]]),
+                   _median([r[1] for r in ms[name]]),
+                   tuple(map(_median, zip(*(r[2] for r in ms[name])))))
             for name in fns if ms.get(name)}
 
 
@@ -789,10 +790,21 @@ PREFILL_FAMILIES = {
 PREFILL_BLOCKS = (128, 256, 512, 1024)      # positions of a block
 # the flash kernel's (block_q, block_k), tried at PREFILL_AT's block
 PREFILL_KERNEL_BLOCKS = ((512, 1024), (512, 512))
-# (a family with no entry runs every bucket whole: `sambay`, timed as it is)
+# (a family with no entry takes no `prompt_blocks`: `sambay` prefills from
+# the host, `sweep_prefill_from_host`)
 PREFILL_AT = {"dense_decoder": 512, "mla_moe": 256}
 PREFILL_FILLS = (0.6, 0.75, 1.0)
 PREFILL_REPS = 3
+# ... whose block (`SambaYServing.block`) is tried at these, the longest row
+# at these shares of its bucket
+PREFILL_HOST_BLOCKS = (512, 1024, 2048)
+PREFILL_HOST_FILLS = (0.75, 1.0)
+
+
+def _ends_apart(rows: int, bucket: int, fill: float) -> list:
+    """Each row's last index: a group's rows end apart, the longest at
+    `fill` of the bucket."""
+    return [int(bucket * fill * (1 - 0.1 * r)) - 1 for r in range(rows)]
 
 
 def _seeded_like(shapes, key):
@@ -804,6 +816,51 @@ def _seeded_like(shapes, key):
         jax.jit(lambda k, a=a: (jax.random.normal(k, a.shape, jnp.float32)
                                 * 0.02).astype(a.dtype))(k)
         for k, a in zip(keys, leaves)])
+
+
+def sweep_prefill_from_host(family, make_serving, params, cases, emit):
+    """A family that prefills from the host (`prefill_from_host`: `sambay`,
+    a block of positions a dispatch, then a tail): the SEQUENCE the engine
+    dispatches, timed whole.  Lines of {family, rows, bucket, block, fill,
+    blocks, ms, block_ms, tail_ms, wall_ms}: `ms` the device time of all of
+    a sequence's programs (the median of PREFILL_REPS sequences), `block_ms`
+    and `tail_ms` the median run of the two programs that hold layers,
+    `wall_ms` the host's clock from the first dispatch to the logits (what
+    the chip waited for the host is the difference)."""
+    import tempfile
+
+    for block in PREFILL_HOST_BLOCKS:
+        serving = make_serving()
+        serving.block = block       # (before its first program is traced)
+        for rows, bucket in cases:
+            tokens = np.asarray(jax.random.randint(
+                jax.random.PRNGKey(2), (rows, bucket), 1,
+                serving.cfg.vocab_size), np.int32)
+            for fill in PREFILL_HOST_FILLS:
+                last = np.asarray(_ends_apart(rows, bucket, fill), np.int32)
+                _sync(serving.prefill_from_host(params, tokens, last))
+                walls = []
+                with tempfile.TemporaryDirectory() as trace_dir:
+                    with jax.profiler.trace(trace_dir):
+                        for _ in range(PREFILL_REPS):
+                            t0 = time.perf_counter()
+                            got = serving.prefill_from_host(params, tokens,
+                                                            last)
+                            _sync(got)
+                            walls.append((time.perf_counter() - t0) * 1e3)
+                    runs = _device_ms(trace_dir)
+                emit({"family": family, "rows": rows, "bucket": bucket,
+                      "block": block, "fill": fill,
+                      "blocks": int(serving.prompt_blocks(last + 1)),
+                      "ms": round(sum(r[1] for of in runs.values()
+                                      for r in of) / PREFILL_REPS, 3),
+                      "block_ms": round(_median(
+                          [r[1] for r in runs["prompt_block"]]), 3),
+                      "tail_ms": round(_median(
+                          [r[1] for r in runs["prompt_tail"]]), 3),
+                      "wall_ms": round(_median(walls), 3),
+                      "checksum": float(jnp.sum(jnp.abs(got[0]))),
+                      "device": jax.devices()[0].device_kind})
 
 
 def sweep_prefill(families):
@@ -840,6 +897,11 @@ def sweep_prefill(families):
             lambda: serving.model.init(jax.random.PRNGKey(0),
                                        jnp.zeros((1, 8), jnp.int32))),
             jax.random.PRNGKey(1))
+        if hasattr(serving, "prefill_from_host"):
+            sweep_prefill_from_host(
+                family, lambda: family_of(cfg, serving.max_len), params,
+                cases, emit)
+            continue
         for rows, bucket in cases:
             tokens = jax.random.randint(jax.random.PRNGKey(2),
                                         (rows, bucket), 1, cfg.vocab_size)
@@ -865,10 +927,8 @@ def sweep_prefill(families):
                     "x".join(map(str, kernel_blocks or ()))
                 f = jax.jit(prefill)
                 for fill in PREFILL_FILLS:
-                    # a group's rows end apart, the longest at `fill`
-                    last = jnp.asarray(
-                        [int(bucket * fill * (1 - 0.1 * r)) - 1
-                         for r in range(rows)], jnp.int32)
+                    last = jnp.asarray(_ends_apart(rows, bucket, fill),
+                                       jnp.int32)
                     _sync(f(params, tokens, last))
                     with tempfile.TemporaryDirectory() as trace_dir:
                         with jax.profiler.trace(trace_dir):
@@ -881,10 +941,8 @@ def sweep_prefill(families):
                     emit({"family": family, "rows": rows, "bucket": bucket,
                           "block": block, "kernel_blocks": kernel_blocks,
                           "fill": fill,
-                          "ms": round(sorted(r[1] for r in ms)[
-                              len(ms) // 2], 3),
-                          "kernel_ms": round(sorted(r[0] for r in ms)[
-                              len(ms) // 2], 3),
+                          "ms": round(_median([r[1] for r in ms]), 3),
+                          "kernel_ms": round(_median([r[0] for r in ms]), 3),
                           "checksum": float(jnp.sum(jnp.abs(got))),
                           "device": jax.devices()[0].device_kind})
         del params

@@ -460,6 +460,21 @@ def test_timeline_chip_prints_the_chips_ledger_of_a_session(
     assert "no chip.program" in timeline.chip_report(str(session / "none"))
 
 
+@pytest.mark.parametrize("attrs, shape", [
+    ({"kind": "decode"}, "decode"),
+    ({"kind": "prefill", "computed": 4096},
+     "prefill of 4096 positions"),
+    ({"kind": "prefill", "computed": 4096, "blocks": 8},
+     "prefill of 4096 positions in 8 blocks")],
+    ids=["a-chunk", "one-program", "a-block-a-dispatch"])
+def test_programs_are_compared_with_their_like(attrs, shape):
+    """What `timeline --chip` holds a program's length against: a chunk
+    against chunks, a prefill against those that computed as many positions
+    (a family that dispatches a prompt a block at a time: in as many
+    blocks)."""
+    assert timeline._shape({"attrs": attrs}) == shape
+
+
 def test_the_span_file_appears_in_the_session_and_stays_under_its_cap(
         served, monkeypatch):
     """(The last reader of `served`: it turns the session's files over.)"""

@@ -174,110 +174,192 @@ def test_two_term_products_do_not_round_the_activation():
     assert off[1] < 0.02 * off[0]
 
 
-# ---- a prompt's full layer at one query a row (`SambaYModel.prefill`) -----
+# ---- a prompt in blocks from the host (`SambaYServing.prefill_from_host`) --
 
-# lengths of the rows of one bucket of 64 (the window is 8)
-ONE_QUERY = {
-    "shorter-than-a-window": [5], "one-token": [1],
-    "a-windows-last-position": [16], "a-windows-first-position": [17],
-    "mid-window": [29], "fills-its-bucket": [64],
-    "rows-that-end-apart": [40, 9, 1], "one-fills-one-ends-on-an-edge":
-    [64, 32], "neighbours-across-an-edge": [17, 16],
+# (bucket, windows of a block, lengths of the bucket's rows); the window is 8
+IN_BLOCKS = {
+    "shorter-than-a-window": (64, 1, [5]),
+    "one-token": (64, 1, [1]),
+    "a-blocks-last-position": (64, 1, [16]),
+    "a-blocks-first-position": (64, 1, [17]),
+    "mid-block": (64, 1, [29]),
+    "fills-its-bucket": (64, 1, [64]),
+    "rows-that-end-in-other-blocks": (64, 1, [40, 9, 1]),
+    "one-fills-one-ends-on-an-edge": (64, 1, [64, 32]),
+    "neighbours-across-an-edge": (64, 1, [17, 16]),
+    "blocks-of-two-windows": (64, 2, [40, 9, 17]),
+    "blocks-of-four-windows-one-row-fills": (64, 4, [64, 33]),
+    "a-bucket-of-no-whole-blocks": (27, 2, [27, 11]),
+    "a-bucket-shorter-than-a-block": (16, 4, [13, 16]),
 }
 
 
 @pytest.fixture(scope="module")
-def passes(tiny):
-    """The tiny model's self-decoder given `last_idx` and over whole rows,
-    and its whole forward, each jitted once a shape."""
+def over_rows(tiny):
+    """The oracle: the whole forward's logits, and the carried layers over
+    whole rows AT ONCE from a fresh state (the stream, the memory, the state
+    at each row's last token) with the full layer's K and V of every
+    position; each jitted once a shape."""
     import jax
+    import jax.numpy as jnp
 
     _, model, params = tiny
-    self_decoder = type(model)._self_decoder
-    return (jax.jit(lambda t, last: model.apply(params, t, last,
-                                                method=self_decoder)),
-            jax.jit(lambda t: model.apply(params, t, method=self_decoder)),
-            jax.jit(lambda t: model.apply(params, t)))
+
+    def at_once(m, tokens, last):
+        x, memory, state = m._carried(
+            m.embed(tokens).astype(jnp.float32), 0, last,
+            m.fresh_state(tokens.shape[0]))
+        layer = m.layers[m.cfg.n_self - 1]
+        return x, memory, state, layer.attn.keys_values(layer.input_norm(x))
+
+    return (jax.jit(lambda t: model.apply(params, t)),
+            jax.jit(lambda t, last: model.apply(params, t, last,
+                                                method=at_once)))
 
 
-@pytest.mark.parametrize("case", ONE_QUERY)
-def test_a_prompts_full_layer_runs_for_one_query_a_row(tiny, passes, case):
-    """Given `last_idx`, the self-decoder hands back the stream and the
-    memory at that position alone, and they are what the whole rows' pass
-    (the full layer attending, projecting out and feeding forward at every
-    position) holds there; K and V of every position are that pass's; and
-    a prefill's logits are the whole forward's at each row's last token
-    (what lies right of it in the bucket is read by nothing)."""
+@pytest.mark.parametrize("case", IN_BLOCKS)
+def test_a_prompt_in_blocks_equals_the_whole_forward(tiny, over_rows, case):
+    """A block of positions a program, each after the state the one before
+    left, then the tail: the logits are the whole forward's at each row's
+    last token (what lies right of it in the bucket is read by nothing, and
+    past the longest row's block computed by nothing); every conv window,
+    scan state and ring is what the layers hold at that token when they run
+    over the whole row at once, whichever block the row ended in; the
+    stream and the memory handed to the tail are theirs there; and K and V
+    of every real position are theirs."""
+    import jax
     import jax.numpy as jnp
 
     cfg, model, params = tiny
-    at_last, over_rows, forward = passes
-    lengths = ONE_QUERY[case]
-    B, S = len(lengths), 64
+    forward, at_once = over_rows
+    S, windows, lengths = IN_BLOCKS[case]
+    serving = family.serving(cfg, windows)
+    B = len(lengths)
     rows = family.tokens(sum(lengths), (B, S))
-    tokens = jnp.asarray(np.where(np.arange(S)[None] < np.asarray(
-        lengths)[:, None], rows, 0), jnp.int32)
-    last = np.asarray(lengths) - 1
-    x, memory, cache, _ = at_last(tokens, jnp.asarray(last, jnp.int32))
-    wx, wmemory, wcache, _ = over_rows(tokens)
-    assert x.shape == (B, 1, cfg.d_model) and wx.shape == (B, S, cfg.d_model)
-    assert memory.shape == (B, 1, cfg.d_inner)
-    np.testing.assert_allclose(x[:, 0], np.asarray(wx)[np.arange(B), last],
-                               atol=TOL, rtol=0)
-    np.testing.assert_allclose(memory[:, 0],
-                               np.asarray(wmemory)[np.arange(B), last],
-                               atol=TOL, rtol=0)
-    for a, b in zip(cache, wcache):
-        assert a.shape == (B, cfg.kv_pairs, S, 2 * cfg.head_dim)
-        np.testing.assert_allclose(a, b, atol=1e-6, rtol=0)
-    logits, state = family.prefill(
-        model, params, [row[:n] for row, n in zip(rows, lengths)], S)
+    tokens = np.where(np.arange(S)[None] < np.asarray(lengths)[:, None],
+                      rows, 0).astype(np.int32)
+    last = np.asarray(lengths, np.int32) - 1
+    assert serving.prompt_blocks(lengths) == -(-max(lengths) // serving.block)
+    assert serving.prefill_computed(S, lengths) == \
+        B * serving.prompt_blocks(lengths) * serving.block
+    handed = {}     # what the last block's program handed to the tail
+    tail = serving._tail
+    serving._tail = lambda bucket, p, state, kv, at: \
+        handed.update(state) or tail(bucket, p, state, kv, at)
+    try:
+        logits, fresh = serving.prefill_from_host(params, tokens, last)
+    finally:
+        serving._tail = tail
+    wx, wmemory, want, wcache = at_once(jnp.asarray(tokens),
+                                        jnp.asarray(last))
     np.testing.assert_allclose(
-        logits, np.asarray(forward(tokens))[np.arange(B), last], atol=TOL,
+        logits, np.asarray(forward(jnp.asarray(tokens)))[np.arange(B), last],
+        atol=TOL, rtol=0)
+    assert len(fresh["mamba"]) == 3 and len(fresh["rings"]) == 2
+    for kind in ("mamba", "rings"):
+        for got, whole in zip(jax.tree_util.tree_leaves(fresh[kind]),
+                              jax.tree_util.tree_leaves(want[kind])):
+            assert got.shape == whole.shape and got.dtype == whole.dtype
+            np.testing.assert_allclose(got, whole, atol=1e-6, rtol=0)
+    for got, whole in zip(fresh["cache"], wcache):
+        assert got.shape == (B, cfg.kv_pairs, S, 2 * cfg.head_dim)
+        for b, n in enumerate(lengths):
+            np.testing.assert_allclose(got[b, :, :n], whole[b, :, :n],
+                                       atol=1e-6, rtol=0)
+    np.testing.assert_allclose(
+        handed["x"], np.asarray(wx)[np.arange(B), last], atol=TOL, rtol=0)
+    np.testing.assert_allclose(
+        handed["memory"], np.asarray(wmemory)[np.arange(B), last], atol=TOL,
         rtol=0)
-    for a, b in zip(state["cache"], wcache):
-        np.testing.assert_allclose(a, b, atol=1e-6, rtol=0)
 
 
-def test_a_prefill_calls_no_attention_kernel_and_feeds_forward_once_a_row():
-    """With the flash kernel configured, the whole forward calls it (the
-    full layer over every position) and a prefill does not; and of the
-    prefill's feed-forwards over (rows, bucket) one is gone for each layer
-    that runs at the last token: the full layer's and the cross-decoder's."""
+@pytest.mark.parametrize("windows", [1, 2])
+def test_decode_goes_on_from_the_state_the_blocks_left(tiny, monkeypatch,
+                                                       windows):
+    """Sixteen teacher-forced paged decode steps (twice round the window)
+    from what the blocks and the tail left, rows that ended in different
+    blocks: the reference's logits at every position."""
+    cfg, model, params = tiny
+    blocks_of = family.serving(cfg, windows)
+    monkeypatch.setattr(type(family), "serving", lambda self, cfg: blocks_of)
+    assert family.decode_against_reference(
+        model, params, family.tokens(11, (2, 60)), [29, 8], 16) < TOL
+
+
+def _primitives(f, *args):
+    """(primitive, shapes of its outputs) of every equation of `f`'s jaxpr,
+    those inside its loops and calls too."""
+    import jax
+
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            found.append((eqn.primitive.name,
+                          tuple(v.aval.shape for v in eqn.outvars)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jax.make_jaxpr(f)(*args).jaxpr)
+    return found
+
+
+@pytest.fixture(scope="module")
+def programs():
+    """What the whole forward, a prompt's block and its tail are made of,
+    with the flash kernel configured: (the model's configuration, the three
+    lists of primitives)."""
     import dataclasses
 
     import jax
     import jax.numpy as jnp
 
+    from ray_tpu.serve.llm_families import SambaYServing
+
     cfg = dataclasses.replace(family.cfg, attention="flash", d_ff=136)
-    model = family.model(cfg)
-    tokens = jnp.zeros((2, 64), jnp.int32)
+    serving = SambaYServing(cfg, 128)
+    model, B, S = serving.model, 2, 64
+    tokens = jnp.zeros((B, S), jnp.int32)
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0),
                                                tokens[:, :8]))
+    last = jnp.asarray([40, 9], jnp.int32)
+    state, kv = jax.eval_shape(lambda: serving._fresh(B))
+    return cfg, serving.block, (
+        _primitives(lambda p, t: model.apply(p, t), params, tokens),
+        _primitives(serving._block, params, tokens[:, :serving.block],
+                    jnp.int32(8), last, state),
+        _primitives(lambda *a: serving._tail(S, *a), params, state,
+                    [kv] * (S // serving.block), last))
 
-    def shapes(f, *args):
-        found = []
 
-        def walk(jaxpr):
-            for eqn in jaxpr.eqns:
-                found.append((eqn.primitive.name,
-                              tuple(v.aval.shape for v in eqn.outvars)))
-                for sub in jax.core.jaxprs_in_params(eqn.params):
-                    walk(sub)
-        walk(jax.make_jaxpr(f)(params, *args).jaxpr)
-        return found
-
-    forward = shapes(lambda p, t: model.apply(p, t), tokens)
-    prefill = shapes(lambda p, t, last: model.apply(
-        p, t, last, method=type(model).prefill), tokens,
-        jnp.asarray([40, 9], jnp.int32))
+def test_a_prefill_calls_no_attention_kernel_and_feeds_forward_once_a_row(
+        programs):
+    """With the flash kernel configured, the whole forward calls it (the
+    full layer over every position) and neither program of a prompt does;
+    and a prompt's feed-forwards over (rows, positions) are those of layers
+    0 .. L/2 alone, in the block's program: the full layer's and the
+    cross-decoder's run in the tail, at ONE token a row."""
+    cfg, block, (forward, a_block, tail) = programs
     assert any(name == "pallas_call" for name, _ in forward)
-    assert not any(name == "pallas_call" for name, _ in prefill)
+    assert not any(name == "pallas_call" for name, _ in a_block + tail)
     # (an MLP's gate and up products are the two a layer that come out d_ff
     # wide, which no other width of this model is)
     wide = lambda found, rows: sum(  # noqa: E731
         name == "dot_general" and out[0] == (2, rows, cfg.d_ff)
         for name, out in found)
     assert wide(forward, 64) == 2 * cfg.n_layers
-    assert wide(prefill, 64) == 2 * (cfg.n_self - 1)
-    assert wide(prefill, 1) == 2 * (cfg.n_layers - cfg.n_self + 1)
+    assert wide(a_block, block) == 2 * (cfg.n_self - 1)
+    assert wide(a_block, 1) == wide(tail, block) == 0
+    assert wide(tail, 1) == 2 * (cfg.n_layers - cfg.n_self + 1)
+
+
+def test_no_program_of_a_prompt_loops_over_its_blocks(programs):
+    """The loop over a prompt's blocks is the host's.  The block's program
+    holds the loops its layers hold over ANY positions (a Mamba layer's
+    scan, a window layer's map over windows: one each) and none around
+    them; no conditional and no `while` anywhere; the tail holds no loop at
+    all."""
+    cfg, _, (_, a_block, tail) = programs
+    loops = lambda found: [  # noqa: E731
+        name for name, _ in found if name in ("scan", "while", "cond")]
+    assert loops(a_block) == ["scan"] * (cfg.n_self - 1)
+    assert loops(tail) == []

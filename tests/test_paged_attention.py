@@ -315,9 +315,17 @@ def test_a_decode_step_scatters_nothing_into_a_pool(family):
              if x.shape[0] == pages}
     assert pools and not _scatters(jaxpr.jaxpr, pools)
     # (the probe finds one where there is one: the prompt's pages)
-    fresh = jax.eval_shape(fam.prefill, params,
-                           jax.ShapeDtypeStruct((2, 32), jnp.int32),
-                           jax.ShapeDtypeStruct((2,), jnp.int32))[1]
+    if family == "llama":
+        fresh = jax.eval_shape(fam.prefill, params,
+                               jax.ShapeDtypeStruct((2, 32), jnp.int32),
+                               jax.ShapeDtypeStruct((2,), jnp.int32))[1]
+    else:   # (its prompts come a block a program from the host: the same
+        # per-slot state for two rows, and the rows' K and V)
+        kv = jax.ShapeDtypeStruct((2, cfg.kv_pairs, 32, 2 * cfg.head_dim),
+                                  cfg.dtype)
+        rows = jax.eval_shape(lambda: fam.init_state(2, pages, page))
+        fresh = {"rings": rows["rings"], "mamba": rows["mamba"],
+                 "cache": (kv, kv)}
     written = jax.make_jaxpr(fam.write_prompt)(
         state, fresh, jax.ShapeDtypeStruct((2,), jnp.int32),
         jax.ShapeDtypeStruct((2, fam.prompt_pages(32, page)), jnp.int32))

@@ -301,8 +301,9 @@ def test_the_prefill_sweep_times_programs_the_cells_engines_run():
     cells' configuration files, and each (rows, bucket) it times is a
     program that cell's engine runs: one row, or the width the family gives
     the bucket; the blocks it tries bracket the rule's (`sambay`, which
-    takes no block, is timed whole at the buckets its cell's long prompts
-    fall in)."""
+    prefills from the host a block a dispatch, is timed as that sequence at
+    the buckets its cell's long prompts fall in, at blocks that bracket
+    the family's own)."""
     import importlib
     import json
     import os
@@ -330,12 +331,14 @@ def test_the_prefill_sweep_times_programs_the_cells_engines_run():
             assert rows in (1, serving.prefill_width(bucket,
                                                      engine["max_batch"]))
         if family not in sweep.PREFILL_AT:
-            assert not hasattr(serving, "prefill_computed")
+            assert not hasattr(serving, "prefill")
+            assert serving.block in sweep.PREFILL_HOST_BLOCKS
+            assert serving.prefill_computed(1 << 20, [1]) == serving.block
             continue
         block = serving.prefill_computed(1 << 20, [1])  # (a row's first)
         assert min(sweep.PREFILL_BLOCKS) <= block <= max(sweep.PREFILL_BLOCKS)
         assert sweep.PREFILL_AT[family] in sweep.PREFILL_BLOCKS
-    assert 1.0 in sweep.PREFILL_FILLS
+    assert 1.0 in sweep.PREFILL_FILLS and 1.0 in sweep.PREFILL_HOST_FILLS
     assert (1, 16384) in sweep.PREFILL_FAMILIES["sambay"][1]
 
 

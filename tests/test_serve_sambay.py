@@ -139,28 +139,87 @@ def test_the_family_sizes_the_batched_prefill_by_bucket(tiny):
         [1, 32, 36]
 
 
-def test_an_engines_prefill_computes_every_buckets_positions(tiny):
-    """The family's prefill runs layers 0 .. L/2 over every position of a
-    bucket and says nothing of what it computes, so `computed` on
-    `engine.prefill` is every row's whole bucket; and the streams decoded
-    from what those prefills wrote (the full layer at ONE query a row) are
-    the reference's greedy tokens."""
-    from ray_tpu.models.generate import SamplingParams
+def _prefills(kind, seen):
+    from ray_tpu.util import tracing
+
+    return [s["attrs"] for s in tracing.recent_spans()
+            if s["name"] == kind and s["id"] not in seen
+            and s["attrs"].get("kind", "prefill") == "prefill"]
+
+
+def test_an_engines_prefill_computes_rows_x_blocks_x_block(tiny):
+    """The family prefills from the host, a block of positions (a window
+    here: 8) a dispatch: a group's `computed` is its rows x the blocks of
+    its longest prompt x the block, whatever its bucket, on `engine.prefill`
+    and on the chip's own `chip.program`, which both carry `blocks`; and
+    the streams decoded from what the blocks and the tail wrote, a row
+    alone and a group whose rows end in different blocks, are the
+    reference's greedy tokens."""
     from ray_tpu.serve.llm import LLMEngine
     from ray_tpu.util import tracing
 
+    from tests.tiny_families import serve
+
     cfg, params = tiny
     eng = LLMEngine(cfg, params, **ENGINE)
-    assert not hasattr(eng.family, "prefill_computed")
+    assert eng.family.block == cfg.window == 8
+    assert not hasattr(eng.family, "prefill")
     try:
         seen = {s["id"] for s in tracing.recent_spans()}
-        for prompt in _prompts(3, (40, 10)):
-            out = eng.submit(prompt, SamplingParams(
-                max_new_tokens=20)).tokens()
+        alone = _prompts(3, (40, 10))
+        for prompt in alone:
+            out, = serve(eng, [prompt], 20)
             assert len(out) == 20 and family.is_greedy(prompt, out)
+        group = _prompts(4, (50, 33, 41))
+        for prompt, out in zip(group, serve(eng, group, 12)):
+            assert len(out) == 12 and family.is_greedy(prompt, out)
+        time.sleep(0.05)        # (the watcher writes a program's span)
     finally:
         eng.shutdown()
-    got = [(s["attrs"]["bucket"], s["attrs"]["rows"], s["attrs"]["computed"])
-           for s in tracing.recent_spans()
-           if s["name"] == "engine.prefill" and s["id"] not in seen]
-    assert got == [(64, 1, 64), (16, 1, 16)]
+    want = [(64, 1, 1, 5, 40), (16, 1, 1, 2, 16), (64, 3, 4, 7, 168)]
+    for kind in ("engine.prefill", "chip.program"):
+        assert [(a["bucket"], a["rows"], a["width"], a["blocks"],
+                 a["computed"]) for a in _prefills(kind, seen)] == want
+    assert [a["prompt_tokens"] for a in _prefills("chip.program", seen)] \
+        == [40, 10, 124]
+
+
+def test_a_family_that_prefills_in_one_program_dispatches_what_it_did():
+    """The seam leaves a family without `prefill_from_host` where it was:
+    a Llama engine's groups go through the same two jitted callables
+    (`prefill_many` for a group, `prefill_one` for a request alone), called
+    once a group with device arrays, and their spans carry no `blocks`."""
+    import jax
+
+    from ray_tpu.serve.llm import LLMEngine
+    from ray_tpu.util import tracing
+
+    from tests.tiny_families import dense, serve
+
+    eng = LLMEngine(dense.cfg, dense.params, **ENGINE)
+    assert not hasattr(eng.family, "prefill_from_host")
+    assert not hasattr(eng.family, "prompt_blocks")
+    calls = []
+    real = {"many": eng._prefill_many, "one": eng._prefill_one}
+
+    def spy(which):
+        def call(params, tokens, last_idx):
+            assert isinstance(tokens, jax.Array) \
+                and isinstance(last_idx, jax.Array)
+            calls.append((which, tokens.shape))
+            return real[which](params, tokens, last_idx)
+        return call
+
+    eng._prefill_many, eng._prefill_one = spy("many"), spy("one")
+    try:
+        seen = {s["id"] for s in tracing.recent_spans()}
+        group = _prompts(5, (20, 30, 17))
+        alone, = _prompts(6, (9,))
+        outs = serve(eng, group, 6) + serve(eng, [alone], 6)
+        assert all(dense.is_greedy(p, o)
+                   for p, o in zip(group + [alone], outs))
+    finally:
+        eng.shutdown()
+    assert calls == [("many", (4, 32)), ("one", (1, 16))]
+    assert all("blocks" not in a and a["computed"] == a["bucket"] * a["rows"]
+               for a in _prefills("engine.prefill", seen))

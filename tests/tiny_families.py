@@ -211,15 +211,19 @@ class Recurrent(Family):
 
     def prefill(self, model, params, rows, bucket, last=None):
         """Right-padded rows through `prefill` -> logits, state."""
-        import jax.numpy as jnp
-
         padded = np.zeros((len(rows), bucket), np.int32)
         for r, row in enumerate(rows):
             padded[r, : len(row)] = row
         if last is None:
             last = [len(row) - 1 for row in rows]
+        return self._prefill(model, params, padded,
+                             np.asarray(last, np.int32))
+
+    def _prefill(self, model, params, padded, last):
+        import jax.numpy as jnp
+
         return self._programs(model)[0](params, jnp.asarray(padded),
-                                        jnp.asarray(last, jnp.int32))
+                                        jnp.asarray(last))
 
     def _table(self, batch):
         import jax.numpy as jnp
@@ -307,6 +311,25 @@ class SambaY(Recurrent):
         from ray_tpu.models.sambay import SambaYModel
 
         return SambaYModel(cfg or self.cfg)
+
+    @functools.lru_cache(maxsize=None)
+    def serving(self, cfg, windows=None):
+        """The family's class at a configuration: its prompt's programs,
+        jitted once for every test of the process that runs them.
+        `windows`: of a block, where not the family's own (the programs are
+        traced at their first call: the block is set before)."""
+        from ray_tpu.serve.llm_families import SambaYServing
+
+        serving = SambaYServing(cfg, ENGINE["max_len"])
+        if windows is not None:
+            serving.block = windows * cfg.window
+        return serving
+
+    def _prefill(self, model, params, padded, last):
+        """As the engine prefills them: a block of positions a program from
+        the host, then the tail (`SambaYServing.prefill_from_host`)."""
+        return self.serving(model.cfg).prefill_from_host(params, padded,
+                                                         last)
 
     def paged_state(self, fresh, batch):
         table = self._table(batch)
